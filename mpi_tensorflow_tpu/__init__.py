@@ -22,13 +22,6 @@ The public surface mirrors what a user of the reference needs: build a model,
 get sharded data, run the training loop, read the 50-step error trace.
 """
 
-__version__ = "0.1.0"
+__version__ = "0.3.0"
 
 from mpi_tensorflow_tpu.config import Config  # noqa: F401
-
-# older jaxlibs spell shard_map / axis_size differently; one shim at
-# package import keeps every call site on the modern jax surface
-from mpi_tensorflow_tpu.utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()
-del _jaxcompat

@@ -570,7 +570,12 @@ def main(argv=None) -> int:
 
     meshlib.initialize_distributed()
 
+    from mpi_tensorflow_tpu.utils import cache as cache_lib
+    from mpi_tensorflow_tpu.utils import logging as logs
     from mpi_tensorflow_tpu.utils import profiling
+
+    cache_lib.enable_compile_cache()
+    logs.device_banner(profiling.device_identity())
 
     def run_once():
         if config.model in ("bert_base", "moe_bert", "gpt_base",

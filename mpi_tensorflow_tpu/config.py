@@ -36,7 +36,7 @@ class Config:
     eval_every: int = 50          # reference evaluates EVERY step
                                   # (mpipy.py:86) — an accidental cost; we
                                   # evaluate on the log cadence and keep it off
-                                  # the timed path (BASELINE.md measurement rule)
+                                  # the timed path
     early_stop_patience: int = 0  # >0: stop when validation error hasn't
                                   # improved for N trace points.  The
                                   # reference scatters validation shards and

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import shutil
 import urllib.error
 import urllib.request
 
@@ -33,6 +34,7 @@ FILES = {
     "test_labels": "t10k-labels-idx1-ubyte.gz",
 }
 _TRAIN_N, _TEST_N, _VAL_N = 60000, 10000, 5000
+DOWNLOAD_TIMEOUT_S = 10.0      # per socket operation, per file
 
 
 @dataclasses.dataclass
@@ -67,7 +69,13 @@ def ensure_downloaded(data_dir: str = "./data", synthetic_fallback: bool = True,
         path = os.path.join(data_dir, fname)
         if not os.path.exists(path):
             try:
-                urllib.request.urlretrieve(DATA_URL + fname, path)
+                # bounded: a sealed machine must fail over to the
+                # synthetic set in seconds, not wait on a dead resolver
+                with urllib.request.urlopen(
+                        DATA_URL + fname,
+                        timeout=DOWNLOAD_TIMEOUT_S) as r, \
+                        open(path, "wb") as f:
+                    shutil.copyfileobj(r, f)
             except (urllib.error.URLError, OSError) as e:
                 if os.path.exists(path):
                     os.remove(path)

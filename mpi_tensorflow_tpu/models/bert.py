@@ -94,17 +94,14 @@ class BertConfig:
                                   # unused, keeping checkpoint layout
                                   # stable across the knob)
     flash_min_seq: int = 4096     # engage the Pallas flash kernel only at
-                                  # sequence length >= this; below it XLA's
-                                  # fused dense attention wins on measured
-                                  # hardware (TPU v5e, BASELINE.md round 3:
-                                  # XLA beats flash 121.3k vs 100.3k tok/s
-                                  # at S=128 and 30.7k vs 27.5k at S=2048
-                                  # — the kernel's unfused epilogue + lse
-                                  # round-trips cost more than the (S, S)
-                                  # score materialization saves until the
-                                  # scores stop fitting in VMEM-friendly
-                                  # tiles).  0 = always engage (kernel
-                                  # A/B measurement arms)
+                                  # sequence length >= this.  The kernel
+                                  # lost to XLA's fused dense attention
+                                  # at S=128 and S=2048 on a v5e under
+                                  # the previous toolchain (ROADMAP.md,
+                                  # "What the chip has actually said");
+                                  # not re-measured on the installed
+                                  # JAX — ROADMAP S7/D5 decide its fate.
+                                  # 0 = always engage (kernel A/B arms)
 
     def __post_init__(self):
         # a misspelled value ("rotary", "Rope") would silently fall back
@@ -339,13 +336,18 @@ class BertMlm:
         it; otherwise the Pallas flash kernel on TPU for sequences at or
         above ``cfg.flash_min_seq``, XLA's fused dense attention below it
         (the measured winner at short/medium S — see flash_min_seq)."""
+        from mpi_tensorflow_tpu.ops import flash_attention as fa
+
         on_tpu = jax.devices()[0].platform == "tpu"
         causal = self.causal
         # captured OUTSIDE shard_map: the threshold compares the FULL
         # sequence length, not a shard's slice of it
         S_full = q.shape[2]
+        # selection is by what can be observed (platform, length, the
+        # operator switch) — never by whether the kernel compiles: a
+        # Mosaic refusal raises from the step's compile
         flash_ok = self.use_flash and on_tpu \
-            and S_full >= self.cfg.flash_min_seq
+            and S_full >= self.cfg.flash_min_seq and fa.kernel_enabled()
         if self.mesh is not None and self.mesh.shape.get("seq", 1) > 1:
             specs = P("data" if self.mesh.shape.get("data", 1) > 1 else None,
                       "model" if self.mesh.shape.get("model", 1) > 1 else None,
@@ -360,15 +362,8 @@ class BertMlm:
                         # post-all-to-all each shard sees the FULL sequence
                         # for its head slice — S_full is the right length
                         # for the kernel threshold
-                        from mpi_tensorflow_tpu.ops import \
-                            flash_attention as fa
-
-                        if fa.kernel_supported(jnp.dtype(q.dtype).name,
-                                               causal):
-                            def inner_attn(q, k, v, causal=False,
-                                           scale=None):
-                                return fa.flash_attention(q, k, v, causal,
-                                                          scale)
+                        def inner_attn(q, k, v, causal=False, scale=None):
+                            return fa.flash_attention(q, k, v, causal, scale)
                     engagement.record(
                         "attention", "ulysses+flash" if inner_attn is not None
                         else "ulysses+xla")
@@ -384,14 +379,9 @@ class BertMlm:
                                  in_specs=(specs, specs, specs),
                                  out_specs=specs, check_vma=False)(q, k, v)
         if flash_ok:
-            # any S: the kernel pads/masks to the block size internally;
-            # kernel_supported() guards against a Mosaic regression (falls
-            # back to XLA attention instead of failing the train step)
-            from mpi_tensorflow_tpu.ops import flash_attention as fa
-
-            if fa.kernel_supported(jnp.dtype(q.dtype).name, causal):
-                engagement.record("attention", "flash")
-                return fa.flash_attention(q, k, v, causal)
+            # any S: the kernel pads/masks to the block size internally
+            engagement.record("attention", "flash")
+            return fa.flash_attention(q, k, v, causal)
         engagement.record("attention", "xla_dense")
         return ring.dense_attention(q, k, v, causal=causal)
 

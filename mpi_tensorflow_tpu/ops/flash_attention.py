@@ -23,8 +23,8 @@ Sequence lengths that are not multiples of the block size are padded and
 masked (``s_valid``), so the kernels apply to any shape; ``interpret=True``
 runs the same kernels on CPU for tests.
 
-``blockwise_attention`` (pure-JAX online-softmax scan) remains as the
-portable fallback; ``dense_attention`` (parallel/ring.py) is the reference
+``blockwise_attention`` (pure-JAX online-softmax scan) is the portable
+O(S) form; ``dense_attention`` (parallel/ring.py) is the reference
 implementation.
 """
 
@@ -55,7 +55,7 @@ LSE_SUBLANES = 8     # f32 sublane tile
 
 
 # ---------------------------------------------------------------------------
-# pure-JAX blockwise online softmax (portable fallback)
+# pure-JAX blockwise online softmax (portable)
 # ---------------------------------------------------------------------------
 
 def blockwise_attention(q, k, v, *, causal: bool = False,
@@ -397,49 +397,17 @@ def _flash_backward(q, k, v, o, lse, do, *, causal: bool, scale: float,
 # public entry: padding + custom VJP (Pallas forward AND backward)
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=8)
-def kernel_supported(dtype_name: str = "bfloat16",
-                     causal: bool = False) -> bool:
-    """One-time probe per (dtype, causal): do the fwd+bwd kernels compile
-    for this backend's Mosaic?  Model code gates on this (passing the dtype
-    and mask mode it will actually run) so a toolchain regression degrades
-    to the XLA attention paths instead of killing the training step.  The
-    probe shape fixes B*H=8 / S=256 / D=64: B*H > 1 exercises the
-    batch-blocked (1, ...) specs real Mosaic constrains (a (1,1,S,D) probe
-    green-lit round 2's kernels while every real model shape failed), and
-    S=256 makes the grid multi-block in both q and k.
+def kernel_enabled() -> bool:
+    """False only when the operator kill switch
+    ``MPI_TF_TPU_DISABLE_FLASH=1`` is set (also the control arm for
+    flash-vs-XLA A/B runs).  There is no compile probe: model code
+    selects the kernel from what it can observe (platform, sequence
+    length) and a Mosaic refusal raises from the train step's own
+    compile with the compiler's message — it never degrades to the XLA
+    attention path."""
+    import os
 
-    ``MPI_TF_TPU_DISABLE_FLASH=1`` force-disables the kernels (operator
-    kill switch; also the control arm for flash-vs-XLA A/B benches).
-    Checked inside the cached body, so it must be set before first use."""
-    import os as _os
-
-    import jax as _jax
-
-    try:
-        if _os.environ.get("MPI_TF_TPU_DISABLE_FLASH", "") not in ("", "0"):
-            import sys as _sys
-
-            print("[flash_attention] disabled via MPI_TF_TPU_DISABLE_FLASH",
-                  file=_sys.stderr)
-            return False
-        if _jax.devices()[0].platform != "tpu":
-            return False
-        q = jnp.zeros((2, 4, 256, 64), jnp.dtype(dtype_name))
-
-        def f(q, k, v):
-            return jnp.sum(
-                flash_attention(q, k, v, causal).astype(jnp.float32))
-
-        _jax.jit(_jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q).compile()
-        return True
-    except Exception as e:   # noqa: BLE001 — any compile failure disables
-        import sys as _sys
-
-        print(f"[flash_attention] Pallas kernel probe failed for "
-              f"{dtype_name} (causal={causal}); falling back to XLA "
-              f"attention ({e!r})", file=_sys.stderr)
-        return False
+    return os.environ.get("MPI_TF_TPU_DISABLE_FLASH", "") in ("", "0")
 
 
 def _padded_len(S: int, block_q: int, block_k: int) -> int:

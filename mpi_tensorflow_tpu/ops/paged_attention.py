@@ -28,11 +28,14 @@ scratch, reads of it are masked by the causal visibility test.
 ``--serve-kernel`` knob (CLI -> Config -> ServeConfig -> engine)
 resolves through ``resolve_kernel`` to either
 
-- ``pallas`` — the fused kernel, reading pool blocks in place through
-  the block table with an fp32 online softmax (TPU; ``interpret=True``
-  on CPU for tests), or
-- ``xla``    — this module's gather + dense masked softmax, the
-  always-available exact fallback (TPU-lowerable, CPU-exact).
+- ``pallas`` — the fused kernel compiled by Mosaic, reading pool
+  blocks in place through the block table with an fp32 online softmax
+  (TPU only),
+- ``pallas-interpret`` — the same kernel under the Pallas interpreter
+  (what a forced ``pallas`` resolves to off TPU: the tier-1 correctness
+  vehicle, named apart so no result can pass for a Mosaic run), or
+- ``xla``    — this module's gather + dense masked softmax (TPU-
+  lowerable, CPU-exact).
 
 Tensor parallelism (serving/tp): every op here treats H as a PURE
 BATCH dimension — ``write_kv`` scatters per-head rows independently,
@@ -46,10 +49,18 @@ row-parallel projections, not in attention.
 
 from __future__ import annotations
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 
 NULL_BLOCK = 0
+
+# resolved lowering literals (resolve_kernel): Mosaic-compiled vs the
+# Pallas interpreter — distinct names so reports cannot confuse them
+PALLAS = "pallas"
+PALLAS_INTERPRET = "pallas-interpret"
 
 
 def masked_softmax_attention(q, k, v, vis, dt, scale=None):
@@ -364,11 +375,12 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
     k/v_pool:    (num_blocks, H, block_size, D)
     block_table: (B, NB) int32
     lengths:     (B,) int32 cache entries already present per row
-    kernel:      "xla" (gather + dense masked softmax) or "pallas"
-                 (fused blockwise online softmax; interpret mode off
-                 TPU).  Callers resolve "auto" BEFORE tracing via
+    kernel:      "xla" (gather + dense masked softmax), "pallas"
+                 (fused blockwise online softmax compiled by Mosaic) or
+                 "pallas-interpret" (the same kernel interpreted).
+                 Callers resolve the knob BEFORE tracing via
                  ``resolve_kernel`` — this runs under jit, where the
-                 choice must be static.
+                 choice must be static and must not consult the backend.
     k/v_scale:   fp32 scales when the pools hold quantized codes; both
                  or neither.  3-d ``(num_blocks, H, block_size)`` row
                  scales mean int8 codes (--serve-kv-dtype int8); 4-d
@@ -405,19 +417,20 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
         raise ValueError(
             "fp-residual k_new/v_new only apply to int4 (group-scaled) "
             "pools")
-    if kernel == "pallas":
+    if kernel in (PALLAS, PALLAS_INTERPRET):
         from mpi_tensorflow_tpu.ops import paged_attention_kernel as pk
 
-        interpret = jax.default_backend() != "tpu"
         fused = (pk.paged_decode_attention if q.shape[2] == 1
                  else pk.paged_prefill_attention)
         return fused(q, k_pool, v_pool, block_table, lengths,
-                     interpret=interpret, k_scale=k_scale,
-                     v_scale=v_scale, k_new=k_new, v_new=v_new)
+                     interpret=kernel == PALLAS_INTERPRET,
+                     k_scale=k_scale, v_scale=v_scale,
+                     k_new=k_new, v_new=v_new)
     if kernel != "xla":
         raise ValueError(
             f"unresolved paged-attention kernel {kernel!r}: callers "
-            f"resolve 'auto' host-side via resolve_kernel before tracing")
+            f"resolve the knob host-side via resolve_kernel before "
+            f"tracing")
     S = q.shape[2]
     pos = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)
     if k_scale is not None:
@@ -455,31 +468,40 @@ def resolve_kernel(choice: str, cfg, block_size: int,
                    prefill_chunk: int = 64,
                    kv_dtype: str = "fp32",
                    kv_group: int = 32) -> str:
-    """Resolve the ``--serve-kernel`` knob to a static lowering choice.
+    """Resolve the ``--serve-kernel`` knob to a static lowering literal.
 
-    - "xla"    -> "xla"     (always available, exact)
-    - "pallas" -> "pallas"  (forced; interpret mode off TPU — the test
-                             configuration)
-    - "auto"   -> "pallas" on TPU when the compile probe
-                  (paged_attention_kernel.kernel_supported) passes for
-                  this model geometry, else "xla".  Off TPU, "auto"
-                  stays on XLA: the interpreter is a correctness
-                  vehicle, not a serving path.
+    - "xla"    -> "xla"
+    - "pallas" -> "pallas" on TPU, "pallas-interpret" elsewhere (the
+                  test configuration — a row can never carry "pallas"
+                  from an interpreted run)
+    - "auto"   -> "pallas" on TPU, "xla" elsewhere (the interpreter is
+                  a correctness vehicle, not a serving path);
+                  ``MPI_TF_TPU_DISABLE_PAGED_KERNEL=1`` maps it to
+                  "xla" on TPU too (the operator kill switch)
+
+    Whenever the result is "pallas" the kernel is compiled for this
+    geometry first (paged_attention_kernel.probe_compile): a Mosaic
+    refusal RAISES here with the compiler's message.  No failure ever
+    selects the XLA path.
 
     Host-side, once per engine: the resolved literal is baked into the
     jitted decode/prefill steps, so kernel choice can never add dispatch
-    shapes or recompiles.
+    shapes or recompiles, and ``engagement``/``paths`` report it as is.
     """
-    if choice in ("xla", "pallas"):
+    if choice == "xla":
         return choice
-    if choice != "auto":
+    if choice not in ("auto", PALLAS):
         raise ValueError(
             f"serve kernel must be auto|xla|pallas, got {choice!r}")
     if jax.default_backend() != "tpu":
+        return PALLAS_INTERPRET if choice == PALLAS else "xla"
+    if choice == "auto" and os.environ.get(
+            "MPI_TF_TPU_DISABLE_PAGED_KERNEL", "") not in ("", "0"):
+        print("[paged_attention] auto -> xla: kernel disabled via "
+              "MPI_TF_TPU_DISABLE_PAGED_KERNEL", file=sys.stderr)
         return "xla"
     from mpi_tensorflow_tpu.ops import paged_attention_kernel as pk
 
-    ok = pk.kernel_supported(jnp.dtype(cfg.dtype).name, cfg.heads,
-                             cfg.head_dim, block_size, prefill_chunk,
-                             kv_dtype, kv_group)
-    return "pallas" if ok else "xla"
+    pk.probe_compile(jnp.dtype(cfg.dtype).name, cfg.heads, cfg.head_dim,
+                     block_size, prefill_chunk, kv_dtype, kv_group)
+    return PALLAS

@@ -39,8 +39,9 @@ fetched block is ``(H, block_size, D)`` and both matmuls batch over H
 with no in-kernel transpose (the official TPU paged-attention kernels
 use the same orientation).
 
-``kernel_supported()`` gates the TPU path behind a real compile probe
-(toolchain regressions degrade to the XLA gather path);
+``probe_compile()`` compiles the served geometry (decode + every
+prefill bucket) up front so a Mosaic refusal surfaces at engine build
+with the compiler's message — it never selects another lowering;
 ``interpret=True`` runs the same kernel on CPU for the tier-1 parity
 suite.
 """
@@ -80,11 +81,17 @@ def _dequant_int4_block(codes, scales, dt):
     hi = (c >> 4) & 0xF
     full = jnp.concatenate([lo, hi], axis=-1)          # (H, bs, D)
     full = full - jnp.where(full > 7, 16, 0)
-    H, bs, D = full.shape
+    D = full.shape[-1]
     G = scales.shape[-1]
-    x = full.reshape(H, bs, G, D // G).astype(jnp.float32)
-    x = x * scales[..., None]
-    return x.reshape(H, bs, D).astype(dt)
+    # expand the (H, bs, G) group scales to (H, bs, D) by lane select:
+    # Mosaic has no layout for the minor-dim split reshape
+    # (H, bs, D) -> (H, bs, G, D//G) the XLA path uses, while a width-1
+    # lane slice broadcast along lanes is native.  G is small and static
+    group = lax.broadcasted_iota(jnp.int32, full.shape, 2) // (D // G)
+    sc = jnp.broadcast_to(scales[..., 0:1], full.shape)
+    for g in range(1, G):
+        sc = jnp.where(group == g, scales[..., g:g + 1], sc)
+    return (full.astype(jnp.float32) * sc).astype(dt)
 
 
 def _paged_kernel(*refs, scale: float, block_size: int,
@@ -296,6 +303,10 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
                           mode=mode, residual=residual),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        # rows are independent; the kv-block axis carries the online
+        # softmax accumulators and must run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
@@ -389,77 +400,62 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, lengths, *,
 
 
 @functools.lru_cache(maxsize=16)
-def kernel_supported(dtype_name: str = "bfloat16", heads: int = 12,
-                     head_dim: int = 64, block_size: int = 16,
-                     prefill_chunk: int = 64,
-                     kv_dtype: str = "fp32",
-                     kv_group: int = 32) -> bool:
-    """One-time probe per geometry: do the decode AND prefill kernels
-    compile for this backend's Mosaic?  The serving dispatcher gates
-    ``--serve-kernel auto`` on this (passing the dtype/heads/head_dim/
-    block_size/prefill_chunk it will actually run) so a toolchain
-    regression degrades to the XLA gather path instead of killing the
-    engine.  The probe compiles decode (S=1) plus EVERY pow2 prefill
+def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
+                  head_dim: int = 64, block_size: int = 16,
+                  prefill_chunk: int = 64, kv_dtype: str = "fp32",
+                  kv_group: int = 32, sharding=None) -> None:
+    """Compile the kernel for the geometry an engine is about to serve,
+    on this backend's Mosaic: decode (S=1) plus EVERY pow2 prefill
     bucket up to ``prefill_chunk`` — the exact S set the engine
     dispatches (engine._bucket), since S changes the kernel's tile
-    shapes.  (Grid extents B/NB vary per dispatch too, but only as grid
-    bounds and scalar-table width, not tile shapes — the fixed B=8/NB=4
-    probe stands in for them.)  Mirrors
-    ops/flash_attention.kernel_supported, including the operator kill
-    switch: ``MPI_TF_TPU_DISABLE_PAGED_KERNEL=1`` force-disables the
-    kernel (also the control arm for kernel A/B benches).  Checked
-    inside the cached body, so it must be set before first use."""
-    import os as _os
-    import sys as _sys
+    shapes — in the pool storage variant ``kv_dtype`` selects (for int4
+    that is nibble-packed uint8 codes + 4-d group scales + the
+    fp-residual k_new/v_new operands).  Grid extents B/NB vary per
+    dispatch too, but only as grid bounds and scalar-table width, not
+    tile shapes — the fixed B=8/NB=4 probe stands in for them.
 
-    try:
-        if _os.environ.get("MPI_TF_TPU_DISABLE_PAGED_KERNEL", "") \
-                not in ("", "0"):
-            print("[paged_attention_kernel] disabled via "
-                  "MPI_TF_TPU_DISABLE_PAGED_KERNEL", file=_sys.stderr)
-            return False
-        if jax.devices()[0].platform != "tpu":
-            return False
-        dt = jnp.dtype(dtype_name)
-        B, NB, bs = 8, 4, block_size
-        # quantized modes swap the pool storage for codes + scale
-        # siblings; Mosaic's sub-fp tiling rules differ from fp, so the
-        # probe must compile the exact variant the engine will dispatch
-        # — for int4 that is nibble-packed uint8 codes + 4-d group
-        # scales + the fp-residual k_new/v_new operands
+    Returns nothing; a refusal RAISES with the compiler's message, so a
+    selected kernel that cannot compile stops the engine at build time
+    instead of mid-traffic — and never turns into another lowering.
+    Successes are cached per geometry (``lru_cache`` does not cache
+    exceptions).
+
+    ``sharding`` places the abstract operands; None is the default
+    device.  tests/test_paged_kernel.py passes a device of a deviceless
+    TPU topology, which runs the real Mosaic compiler without a chip."""
+    dt = jnp.dtype(dtype_name)
+    B, NB, bs = 8, 4, block_size
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    kw = {}
+    if kv_dtype == "int4":
+        g = min(kv_group, head_dim)
+        pool = arg((1 + B * NB, heads, bs, head_dim // 2), jnp.uint8)
+        scales = arg((1 + B * NB, heads, bs, head_dim // g), jnp.float32)
+    elif kv_dtype == "int8":
+        pool = arg((1 + B * NB, heads, bs, head_dim), jnp.int8)
+        scales = arg((1 + B * NB, heads, bs), jnp.float32)
+    else:
+        pool = arg((1 + B * NB, heads, bs, head_dim), dt)
+        scales = None
+    if scales is not None:
+        kw.update(k_scale=scales, v_scale=scales)
+    bt = arg((B, NB), jnp.int32)
+    lens = arg((B,), jnp.int32)
+    S = 1
+    while S <= prefill_chunk:
+        q = arg((B, heads, S, head_dim), dt)
         if kv_dtype == "int4":
-            g = min(kv_group, head_dim)
-            pool = jnp.zeros((1 + B * NB, heads, bs, head_dim // 2),
-                             jnp.uint8)
-            scales = jnp.zeros((1 + B * NB, heads, bs, head_dim // g),
-                               jnp.float32)
-        elif kv_dtype == "int8":
-            pool = jnp.zeros((1 + B * NB, heads, bs, head_dim), jnp.int8)
-            scales = jnp.zeros((1 + B * NB, heads, bs), jnp.float32)
-        else:
-            pool = jnp.zeros((1 + B * NB, heads, bs, head_dim), dt)
-            scales = None
-        bt = jnp.arange(1, 1 + B * NB, dtype=jnp.int32).reshape(B, NB)
-        lens = jnp.full((B,), bs, jnp.int32)
-        chunks = []                       # 1 (decode) + pow2 buckets
-        S = 1
-        while S <= prefill_chunk:
-            chunks.append(S)
-            S *= 2
-        for S in chunks:
-            q = jnp.zeros((B, heads, S, head_dim), dt)
-            kn = (jnp.zeros((B, heads, S, head_dim), dt)
-                  if kv_dtype == "int4" else None)
+            kw.update(k_new=q, v_new=q)
+        try:
             # graft-lint: jit-ok(compile probe: runs once at kernel resolve, not per step)
-            jax.jit(functools.partial(
-                paged_attention_kernel,
-                k_scale=scales, v_scale=scales,
-                k_new=kn, v_new=kn)).lower(
-                q, pool, pool, bt, lens).compile()
-        return True
-    except Exception as e:   # noqa: BLE001 — any compile failure disables
-        print(f"[paged_attention_kernel] Pallas probe failed for "
-              f"{dtype_name} (H={heads}, D={head_dim}, bs={block_size}); "
-              f"falling back to the XLA gather path ({e!r})",
-              file=_sys.stderr)
-        return False
+            jax.jit(paged_attention_kernel).lower(
+                q, pool, pool, bt, lens, **kw).compile()
+        except Exception as e:
+            raise RuntimeError(
+                f"Pallas paged-attention kernel failed to compile for "
+                f"{dtype_name} q, kv_dtype={kv_dtype}, H={heads}, "
+                f"D={head_dim}, block_size={block_size}, S={S}: {e}") from e
+        S *= 2

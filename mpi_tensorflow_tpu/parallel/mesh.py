@@ -22,37 +22,30 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
-def _distributed_client_active() -> bool:
-    """Whether ``jax.distributed.initialize`` has already run.
-
-    Must NOT call ``jax.process_count()``: that initializes the XLA
-    backend, after which ``jax.distributed.initialize`` is a hard error —
-    the old guard made every real (non-monkeypatched) multi-process
-    bring-up fail.  Found by the 2-process bring-up test
-    (tests/test_distributed_bringup.py)."""
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:        # private-API drift: fall back, accept the cost
-        return jax.process_count() > 1
-
-
 def initialize_distributed(coordinator_address: Optional[str] = None,
                            num_processes: Optional[int] = None,
                            process_id: Optional[int] = None) -> None:
     """Multi-host bring-up (the ``mpiexec`` equivalent).
 
-    On TPU pods the arguments are auto-detected from the environment; calling
-    with no arguments is correct there.  Safe no-op for single-process runs
-    and when already initialized.
+    Joins a coordinator only for a launch that NAMES one: an explicit
+    ``coordinator_address`` / ``JAX_COORDINATOR_ADDRESS``, or a TPU pod
+    whose ``TPU_WORKER_HOSTNAMES`` lists more than one host (arguments
+    are then auto-detected from the environment).  A single-host machine
+    — one entry there, whatever it is called (the sealed one-chip and
+    four-chip machines set ``localhost``) — never calls
+    ``jax.distributed.initialize`` and so can never wait on a
+    coordinator that will not answer.  No-op when already initialized.
+
+    Must NOT touch ``jax.process_count()`` before deciding: that
+    initializes the XLA backend, after which
+    ``jax.distributed.initialize`` is a hard error.
     """
-    if _distributed_client_active():
-        return  # already initialized
+    if jax.distributed.is_initialized():
+        return
     explicit = coordinator_address or os.environ.get("JAX_COORDINATOR_ADDRESS")
-    auto_env = any(v in os.environ for v in
-                   ("TPU_WORKER_HOSTNAMES", "CLOUD_TPU_TASK_ID"))
-    if explicit or (auto_env and os.environ.get("TPU_WORKER_HOSTNAMES") != "localhost"):
+    hosts = [h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+             if h.strip()]
+    if explicit or len(hosts) > 1:
         try:
             jax.distributed.initialize(coordinator_address, num_processes,
                                        process_id)

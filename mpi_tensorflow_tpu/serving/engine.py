@@ -425,12 +425,14 @@ class PagedDecodeEngine:
         tp_lib.check_geometry(model.cfg, serve.tp)
         self.tp_mesh = (tp_lib.make_tp_mesh(serve.tp)
                         if serve.tp > 1 else None)
-        # resolve auto -> xla|pallas ONCE, host-side: the literal bakes
-        # into the jitted steps below, so kernel choice cannot add
-        # dispatch shapes or recompiles (the zero-recompile contract
-        # covers the kernel path by construction).  Under TP each shard
-        # runs the kernel over its LOCAL heads, so the compile probe
-        # must see the per-shard head count
+        # resolve the knob -> xla|pallas|pallas-interpret ONCE,
+        # host-side (compiled vs interpreted is decided here too, never
+        # under jit): the literal bakes into the jitted steps below, so
+        # kernel choice cannot add dispatch shapes or recompiles (the
+        # zero-recompile contract covers the kernel path by
+        # construction).  Under TP each shard runs the kernel over its
+        # LOCAL heads, so the compile probe must see the per-shard head
+        # count
         kcfg = (model.cfg if serve.tp == 1 else dataclasses.replace(
             model.cfg, heads=model.cfg.heads // serve.tp))
         self.kernel = paged_ops.resolve_kernel(
@@ -448,24 +450,22 @@ class PagedDecodeEngine:
                 model.forward_paged(params, tokens, pools, tables,
                                     lengths, valid=valid,
                                     kernel=self.kernel))
-        # donate the pools so the TPU cache updates in place; CPU (the
-        # test platform) does not implement donation — skip the arg to
-        # keep the suite free of spurious donation warnings
-        donate = (1,) if jax.default_backend() == "tpu" else ()
+        # donate the pools so the cache updates in place — on every
+        # platform (XLA:CPU honours donation too), so tier-1 sees a
+        # use-after-donate before the chip does
+        donate = (1,)
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=donate)
         self._prefill_fn = jax.jit(self._prefill_impl,
                                    donate_argnums=donate)
         # copy-on-write block copy: pools in, pools out, fixed shapes —
         # exactly ONE compile ever (block ids ride as traced scalars)
         self._cow_fn = jax.jit(
-            self._cow_impl,
-            donate_argnums=(0,) if jax.default_backend() == "tpu" else ())
+            self._cow_impl, donate_argnums=(0,))
         # partial tail-block copy (prefix v2): same discipline as
         # _cow_fn — block ids AND the row count ride as traced scalars,
         # so every (src, dst, n) reuses the one compiled program
         self._partial_fn = jax.jit(
-            self._partial_impl,
-            donate_argnums=(0,) if jax.default_backend() == "tpu" else ())
+            self._partial_impl, donate_argnums=(0,))
         # host-tier promotion (--serve-kv-tier host): write a demoted
         # block's host bytes into a freshly allocated device block —
         # same discipline as _cow_fn/_partial_fn: the destination id
@@ -473,8 +473,7 @@ class PagedDecodeEngine:
         # shape (a single block row per pool leaf), so every promotion
         # reuses the one compiled program
         self._promote_fn = jax.jit(
-            self._promote_impl,
-            donate_argnums=(0,) if jax.default_backend() == "tpu" else ())
+            self._promote_impl, donate_argnums=(0,))
         # speculative decoding: the verify step runs pending + k draft
         # tokens through one forward (chunked-prefill math, decode-style
         # batching); the drafter is a host-side policy object built ONCE

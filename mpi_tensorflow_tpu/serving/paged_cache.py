@@ -182,30 +182,32 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     if kv_dtype not in ("fp32", "int8", "int4"):
         raise ValueError(
             f"serve kv dtype must be fp32|int8|int4, got {kv_dtype!r}")
-    if kv_dtype == "int8":
-        z = jnp.zeros((num_blocks, cfg.heads, block_size, cfg.head_dim),
-                      jnp.int8)
-        s = jnp.zeros((num_blocks, cfg.heads, block_size), jnp.float32)
-        return [{"k": z, "v": z, "k_scale": s, "v_scale": s}
+    # every leaf is its OWN buffer: the engine donates the pools into
+    # each step, and one zeros array shared between k and v (or across
+    # layers) would be donated twice in a single Execute()
+    code_shape = (num_blocks, cfg.heads, block_size, cfg.head_dim)
+    if kv_dtype == "fp32":
+        return [{"k": jnp.zeros(code_shape, cfg.dtype),
+                 "v": jnp.zeros(code_shape, cfg.dtype)}
                 for _ in range(cfg.layers)]
-    if kv_dtype == "int4":
+    if kv_dtype == "int8":
+        code_dt = jnp.int8
+        scale_shape = code_shape[:3]
+    else:
         g = min(kv_group, cfg.head_dim)
         if cfg.head_dim % 2 or g < 1 or cfg.head_dim % g:
             raise ValueError(
                 f"int4 pool needs even head_dim divisible by the "
                 f"effective group min(kv_group, head_dim); got "
                 f"head_dim={cfg.head_dim}, kv_group={kv_group}")
-        z = jnp.zeros(
-            (num_blocks, cfg.heads, block_size, cfg.head_dim // 2),
-            jnp.uint8)
-        s = jnp.zeros(
-            (num_blocks, cfg.heads, block_size, cfg.head_dim // g),
-            jnp.float32)
-        return [{"k": z, "v": z, "k_scale": s, "v_scale": s}
-                for _ in range(cfg.layers)]
-    z = jnp.zeros((num_blocks, cfg.heads, block_size, cfg.head_dim),
-                  cfg.dtype)
-    return [{"k": z, "v": z} for _ in range(cfg.layers)]
+        code_dt = jnp.uint8
+        code_shape = code_shape[:3] + (cfg.head_dim // 2,)
+        scale_shape = code_shape[:3] + (cfg.head_dim // g,)
+    return [{"k": jnp.zeros(code_shape, code_dt),
+             "v": jnp.zeros(code_shape, code_dt),
+             "k_scale": jnp.zeros(scale_shape, jnp.float32),
+             "v_scale": jnp.zeros(scale_shape, jnp.float32)}
+            for _ in range(cfg.layers)]
 
 
 class HostBlockStore:

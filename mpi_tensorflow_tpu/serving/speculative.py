@@ -185,8 +185,7 @@ class DraftModelDrafter(Drafter):
         # drafted, never correctness
         self.kv_dtype = kv_dtype
         self.kv_group = kv_group
-        donate = (1,) if jax.default_backend() == "tpu" else ()
-        self._feed_fn = jax.jit(self._feed_impl, donate_argnums=donate)
+        self._feed_fn = jax.jit(self._feed_impl, donate_argnums=(1,))
         self._clock = 0
         self.reset()
 

@@ -147,9 +147,7 @@ def make_paged_forward(model, mesh: Mesh, kernel: str,
                                    reduce=red)
 
     # check_vma off: the psum points make the logits replicated by
-    # construction, and the legacy-jax shard_map shim (utils/jaxcompat)
-    # cannot see through psum-into-replicated anyway — exactly the
-    # train-step call sites' convention
+    # construction — the train-step call sites' convention
     return jax.shard_map(inner, mesh=mesh,
                          in_specs=(specs, rep, pspec, rep, rep, rep),
                          out_specs=(rep, pspec), check_vma=False)

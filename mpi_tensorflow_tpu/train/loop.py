@@ -12,7 +12,7 @@ Semantics preserved from the reference:
 Deliberate divergences (documented in SURVEY.md §7):
 - evaluation runs on the trace cadence, OFF the timed path — the reference
   evaluates the full test shard EVERY step (mpipy.py:86), an accidental cost
-  excluded by BASELINE.md's measurement rule;
+  kept out of what is timed;
 - ``psum`` mode replaces the reference's rank-0-only periodic averaging with
   per-step gradient allreduce (true synchronous SGD).
 """

@@ -93,7 +93,6 @@ def _sync_step_body(model, config, schedule):
     # (lax.psum below).  Both accum paths share the pattern; relying on
     # the autodiff transpose of replicated params to emit the psum would
     # tie the gradient semantics to shard_map's replication machinery
-    # (and silently break on jaxlibs without it — utils/jaxcompat.pcast)
     to_varying = lambda t: jax.tree.map(
         lambda x: lax.pcast(x, "data", to="varying"), t)
 

@@ -2,10 +2,9 @@
 
 The transformer stack selects between implementations at trace time (Pallas
 flash kernel vs XLA dense attention, chunked vs dense CE, packed vs
-all-position MLM head) — and the flash path additionally degrades silently
-when the Mosaic compile probe fails (ops/flash_attention.kernel_supported).
-A benchmark number is meaningless if the artifact can't say which path it
-measured: an XLA-fallback run would masquerade as a kernel number.
+all-position MLM head; Mosaic-compiled vs interpreted vs XLA paged
+attention).  A benchmark number is meaningless if the artifact can't say
+which path it measured.
 
 Model code calls ``record(key, value)`` at each selection point; the bench
 harness calls ``reset()`` before tracing and ``snapshot()`` after, embedding

@@ -3,8 +3,8 @@
 NaN/Inf are not JSON: ``json.dumps`` happily writes literal ``NaN`` /
 ``Infinity`` tokens (``allow_nan`` defaults True) and strict consumers
 (jq, ``JSON.parse``) abort the whole stream on one bad line.  bench.py's
-output lines and the measurement queue's MEASURE_LOG.jsonl route through
-``json_safe``; utils/metrics_writer.py applies the same rule inline at
+output lines route through ``json_safe``; utils/metrics_writer.py applies
+the same rule inline at
 its single scalar() write site (a scalar check, not a tree walk).
 """
 

@@ -37,3 +37,12 @@ def timing_summary(images_per_sec: float, step_time_ms: float,
           f"({images_per_sec / max(num_devices, 1):,.0f} /chip) | "
           f"step {step_time_ms:.3f} ms | {num_devices} device(s)")
     sys.stdout.flush()
+
+
+def device_banner(identity: dict, file=None) -> None:
+    """One start-up line naming the device (utils/profiling.
+    device_identity): no run's output can leave its platform in doubt."""
+    print(f"[device] platform={identity['platform']} "
+          f"device_kind={identity['device_kind']!r} "
+          f"count={identity['device_count']}", file=file or sys.stdout,
+          flush=True)

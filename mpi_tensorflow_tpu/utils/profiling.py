@@ -10,13 +10,14 @@ tracing row).  Here measurement is a first-class utility:
   (host side) and, via ``jax.named_scope``, in the compiled HLO;
 - ``device_memory_stats()``: per-device HBM usage snapshot, for finding the
   working-set the rematerialization knobs should target;
+- ``device_identity()``: platform / device_kind / device count as JAX
+  reports them — stamped on every bench row, printed by every entry point;
 - ``StepTimer`` / ``time_step_fn``: warmup-skipping wall-clock step
   timers for the TRAIN loops and bench — JAX dispatch is asynchronous,
   so both block on the final output (``block_until_ready``) and
-  amortize over many steps.  Measurement rule from BASELINE.md:
-  evaluation stays OFF the timed path (the reference's accidental
-  every-step full-test eval at mpipy.py:86 is not replicated in what
-  we time).
+  amortize over many steps.  Measurement rule: evaluation stays OFF
+  the timed path (the reference's accidental every-step full-test eval
+  at mpipy.py:86 is not replicated in what we time).
 
 The SERVING side has its own timing layer — ``serving/tracing``
 stamps request-lifecycle spans and per-step phase durations on the
@@ -108,6 +109,17 @@ def time_step_fn(step_fn, state, make_args, iters: int = 20, warmup: int = 3):
         state, metrics = step_fn(state, *make_args(i))
     jax.block_until_ready(state)
     return (time.perf_counter() - t0) / iters, state
+
+
+def device_identity() -> dict:
+    """The device this process runs on, as JAX reports it.  Initializes
+    the backend on first use."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
 
 
 def device_memory_stats() -> list:

@@ -6,7 +6,7 @@ Answers "where do the milliseconds go" directly — the diagnosis
 scripts/bert_diagnose.py locates the stall by ablation; this names it.
 ``--model bert_base`` (default) profiles the flagship MLM step;
 ``--model resnet50`` profiles the image step at its best-known config
-(b128 + remat, BASELINE.md round-3 table).
+(b128 + remat).
 """
 
 from __future__ import annotations

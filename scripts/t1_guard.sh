@@ -1,12 +1,11 @@
 #!/usr/bin/env bash
-# t1_guard.sh — segfault-truncation guard around the tier-1 pytest run.
+# t1_guard.sh — truncation guard around the tier-1 pytest run.
 #
-# The legacy jaxlib on this image intermittently segfaults mid-suite
-# (CHANGES.md PR 1), killing the pytest process outright: the -q run
-# ends with no summary line, the dot stream stops wherever the crash
-# landed, and a DOTS_PASSED count computed from the truncated log
-# silently under-reports — a flaky abort masquerading as a red (or,
-# worse, compared against a stale green).  This wrapper:
+# A tier-1 run that is cut short — by the wall-clock budget (rc 124: the
+# suite no longer fits the ROADMAP command's 870 s, ROADMAP.md D10) or
+# by a process-fatal crash — ends with no summary line, the dot stream
+# stops wherever it was cut, and a DOTS_PASSED count computed from the
+# truncated log silently under-reports.  This wrapper:
 #
 #   1. collects the ordered test list (ids per file) up front;
 #   2. runs the tier-1 suite once, teeing the log;
@@ -23,25 +22,16 @@
 # retry loop): the merged count so far is emitted with rc 139 so the
 # flake stays visible instead of masquerading as green or red.
 #
-# The same merge covers a BUDGET overflow (timeout kill, rc 124): a
-# run cut off by the wall-clock cap also ends summary-less, and the
-# rerun picks up from the in-flight file with the outcomes before it
-# credited exactly once.  The suite keeps growing (PR 5 added
-# tests/test_speculative.py, ~2.5 min of parity/replay pins that the
-# dynamic `tests/` collection folds straight into the dot stream), so
-# the per-run budget is tunable: T1_BUDGET=<seconds> (default 870, the
+# The per-run budget is tunable: T1_BUDGET=<seconds> (default 870, the
 # ROADMAP command's cap) applies to each of the two runs.
 #
 # Targeted reruns: T1_FILES is a space-separated allowlist of test
 # files; when set (and no positional args are given) the guard runs
 # exactly those files instead of the whole tier-1 sweep — the fast way
 # to re-verify a specific area (e.g. the fleet fault tests) with the
-# same truncation merge and cache hygiene as the full run.
+# same truncation merge as the full run.
 # T1_CACHE_OFF=1 additionally applies the MPI_TPU_DISABLE_COMPILE_CACHE
-# kill switch to the FIRST run too (not just the rerun): the right mode
-# for subprocess-heavy fault-injection files, whose child processes are
-# exactly the cross-process AOT-reload victims the cache poisoning
-# bites.
+# kill switch to the FIRST run too (not just the rerun).
 #
 # Usage: scripts/t1_guard.sh            # the ROADMAP tier-1 invocation
 #        scripts/t1_guard.sh tests/ -m 'not slow'   # custom args
@@ -88,16 +78,10 @@ cd "$(dirname "$0")/.."
 
 T1_BUDGET=${T1_BUDGET:-870}
 
-# The persistent XLA:CPU AOT cache is poisoned CROSS-PROCESS on this
-# image: entries written by one process deterministically abort a LATER
-# process reloading them (crash sites test_checkpoint/test_elastic;
-# the round-trip canary passes, so utils/cache.py cannot detect it).
-# The cache is pure regenerable state — purge it up front instead of
-# relying on the manual `rm -rf .jax_cache` CHANGES.md keeps asking
-# for.  T1_KEEP_JAX_CACHE=1 opts out (e.g. on a host known clean).
-if [ "${T1_KEEP_JAX_CACHE:-0}" != "1" ]; then
-    rm -rf .jax_cache
-fi
+# (No .jax_cache purge: under jaxlib 0.9.0 the round-trip canary in
+# utils/cache.py DETECTS a box that cannot reload its own XLA:CPU AOT
+# entries — this image is one, verdict "unsafe" — and leaves the CPU
+# cache off there, so there is nothing to poison.)
 
 # Pre-flight: the graft-lint static scan (docs/ANALYSIS.md) — the
 # knob-bridge / recompile-hazard / host-sync / lock-discipline / names
@@ -228,23 +212,10 @@ for a in "${PYTEST_ARGS[@]}"; do
     [ -e "${a%%::*}" ] || OPTS+=("$a")
 done
 
-# the known AOT-reload poisoning aborts in test_checkpoint/test_elastic:
-# a crash landing there means the cache regrown DURING run 1 is already
-# poisoned for the rerun process — purge it again (regenerable) so the
-# rerun starts from a clean slate
-case "${REMAIN[0]:-}" in
-    *test_checkpoint*|*test_elastic*)
-        echo "[t1_guard] crash in ${REMAIN[0]}: purging .jax_cache " \
-             "(known cross-process AOT-reload poisoning)"
-        rm -rf .jax_cache
-        ;;
-esac
-
-# rerun with the persistent compile cache OFF: the usual truncation
-# cause on this image is an AOT entry aborting on reload (utils/cache.py
-# same-host hazard) — a rerun that reloads the same entry dies the same
-# death.  Cold compiles for the remaining files are the price; slow
-# beats fatal.
+# rerun with the persistent compile cache OFF: if the truncation was an
+# AOT entry aborting on reload (utils/cache.py same-host hazard), a
+# rerun that reloads the same entry dies the same death.  Cold compiles
+# for the remaining files are the price; slow beats fatal.
 "${RUN_ENV[@]}" MPI_TPU_DISABLE_COMPILE_CACHE=1 timeout -k 10 "$T1_BUDGET" \
     python -m pytest "${REMAIN[@]}" "${OPTS[@]}" "${COMMON[@]}" \
     2>&1 | tee "$LOG2"

@@ -1,9 +1,9 @@
 """Path-engagement recording (utils/engagement.py).
 
-VERDICT r2 #2: a green BENCH number must say which attention/CE
-implementation actually compiled into the step — a silent XLA fallback
-(ops/flash_attention.kernel_supported returning False) must be visible in
-the artifact.  These tests pin that the records flip with the probe.
+A bench number must say which attention/CE implementation actually
+compiled into the step.  These tests pin that the records follow the
+selection (platform, sequence length, operator switch) and that a kernel
+that fails to compile raises instead of becoming an XLA row.
 """
 
 from types import SimpleNamespace
@@ -40,19 +40,19 @@ def test_records_cpu_fallback_paths():
     loss = _tiny_loss()
     assert np.isfinite(loss)
     snap = engagement.snapshot()
-    # CPU: the kernel probe rejects the platform -> XLA dense attention
+    # CPU: the kernel is selected on TPU only -> XLA dense attention
     assert snap["attention"] == "xla_dense"
     assert snap["ce_positions"] == "masked_packed"
     # packed positions -> auto CE picks dense logits (bert._use_chunked_ce)
     assert snap["ce"] == "dense"
 
 
-def test_attention_record_flips_with_probe(monkeypatch):
-    """Force the probe True (and stub the kernel + platform) -> the record
-    must say 'flash'; force it False -> 'xla_dense'."""
+def test_attention_record_flips_with_switch(monkeypatch):
+    """On (stubbed) TPU at S >= flash_min_seq the record must say
+    'flash'; with the operator kill switch set -> 'xla_dense'."""
     monkeypatch.setattr(jax, "devices",
                         lambda *a: [SimpleNamespace(platform="tpu")])
-    monkeypatch.setattr(fa, "kernel_supported", lambda *a, **k: True)
+    monkeypatch.delenv("MPI_TF_TPU_DISABLE_FLASH", raising=False)
     monkeypatch.setattr(
         fa, "flash_attention",
         lambda q, k, v, causal=False, scale=None:
@@ -61,20 +61,37 @@ def test_attention_record_flips_with_probe(monkeypatch):
     _tiny_loss(flash_min_seq=0)
     assert engagement.snapshot()["attention"] == "flash"
 
-    monkeypatch.setattr(fa, "kernel_supported", lambda *a, **k: False)
+    monkeypatch.setenv("MPI_TF_TPU_DISABLE_FLASH", "1")
     engagement.reset()
     _tiny_loss(flash_min_seq=0)
     assert engagement.snapshot()["attention"] == "xla_dense"
 
 
-def test_short_seq_prefers_xla_even_with_kernel_available(monkeypatch):
-    """The flash_min_seq policy: below the threshold the step uses XLA
-    dense attention EVEN when the kernel probe passes — the measured
-    winner at short S (BASELINE.md round 3: 121.3k vs 100.3k tok/s at
-    S=128).  The record must say so."""
+def test_flash_compile_failure_raises(monkeypatch):
+    """A selected kernel that does not compile must RAISE out of
+    BertMlm._attention with the compiler's message — never select the
+    XLA dense path instead."""
     monkeypatch.setattr(jax, "devices",
                         lambda *a: [SimpleNamespace(platform="tpu")])
-    monkeypatch.setattr(fa, "kernel_supported", lambda *a, **k: True)
+    monkeypatch.delenv("MPI_TF_TPU_DISABLE_FLASH", raising=False)
+
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: boom")
+
+    monkeypatch.setattr(fa, "flash_attention", refuse)
+    engagement.reset()
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        _tiny_loss(flash_min_seq=0)
+    assert engagement.snapshot().get("attention") != "xla_dense"
+
+
+def test_short_seq_prefers_xla_even_with_kernel_available(monkeypatch):
+    """The flash_min_seq policy: below the threshold the step uses XLA
+    dense attention EVEN on TPU with the kernel enabled.  The record
+    must say so."""
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [SimpleNamespace(platform="tpu")])
+    monkeypatch.delenv("MPI_TF_TPU_DISABLE_FLASH", raising=False)
     engagement.reset()
     _tiny_loss()                     # default flash_min_seq (4096) >> S=32
     assert engagement.snapshot()["attention"] == "xla_dense"
@@ -96,10 +113,8 @@ def test_ce_records_flip_with_config():
     assert snap["ce_positions"] == "all"
 
 
-def test_env_kill_switch_disables_probe(monkeypatch):
+def test_env_kill_switch_disables_kernel(monkeypatch):
     monkeypatch.setenv("MPI_TF_TPU_DISABLE_FLASH", "1")
-    fa.kernel_supported.cache_clear()
-    try:
-        assert fa.kernel_supported("bfloat16", False) is False
-    finally:
-        fa.kernel_supported.cache_clear()
+    assert fa.kernel_enabled() is False
+    monkeypatch.setenv("MPI_TF_TPU_DISABLE_FLASH", "0")
+    assert fa.kernel_enabled() is True

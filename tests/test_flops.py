@@ -71,12 +71,30 @@ def test_image_flops_and_unknown_model():
 
 
 def test_mfu_pct():
+    from types import SimpleNamespace
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
     # 98.5 TFLOP/s of bf16 on a 197 TFLOP/s chip = 50%
-    assert fl.mfu_pct(98.5e12 * 0.1, 0.1, "bf16") == pytest.approx(50.0)
-    assert fl.mfu_pct(None, 0.1, "bf16") is None
-    assert fl.mfu_pct(1e12, 0.1, "int8") is None   # unknown peak
-    # the peak table is the v5e's — a CPU run must not claim an MFU
-    assert fl.mfu_pct(1e12, 0.1, "bf16", platform="cpu") is None
+    assert fl.mfu_pct(98.5e12 * 0.1, 0.1, "bf16", v5e) == pytest.approx(50.0)
+    assert fl.mfu_pct(None, 0.1, "bf16", v5e) is None
+    # no published peak for the precision -> no percentage
+    assert fl.mfu_pct(1e12, 0.1, "fp32", v5e) is None
+    # a CPU run must not claim an MFU
+    cpu = SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert fl.mfu_pct(1e12, 0.1, "bf16", cpu) is None
+
+
+def test_unknown_device_kind_raises():
+    """A TPU that is not in the peaks table is an error, never scored
+    against another chip's peak."""
+    from types import SimpleNamespace
+
+    with pytest.raises(fl.UnknownDeviceError, match="TPU v9"):
+        fl.device_peaks("TPU v9")
+    v9 = SimpleNamespace(platform="tpu", device_kind="TPU v9")
+    with pytest.raises(fl.UnknownDeviceError):
+        fl.mfu_pct(1e12, 0.1, "bf16", v9)
+    assert fl.device_peaks("TPU v5 lite")["hbm_gbps"] == 819.0
 
 
 def test_bench_detail_carries_flops_and_gates_mfu_by_platform(monkeypatch):

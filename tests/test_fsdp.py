@@ -18,7 +18,6 @@ from mpi_tensorflow_tpu.data import synthetic
 from mpi_tensorflow_tpu.models import bert
 from mpi_tensorflow_tpu.parallel import fsdp, mesh as meshlib
 from mpi_tensorflow_tpu.train import gspmd
-from mpi_tensorflow_tpu.utils import jaxcompat
 
 TINY = bert.BertConfig(vocab_size=128, hidden=32, layers=2, heads=4,
                        mlp=64, max_positions=32, dropout=0.0)
@@ -164,10 +163,6 @@ class TestFsdpTraining:
         assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.skipif(
-    bool(jaxcompat.LEGACY_SHIMS),
-    reason="legacy jaxlib segfaults (process-fatal, kills the whole "
-           "suite) tracing the ZeRO-1 x PP graphs")
 class TestZero1WithPipeline:
     """ZeRO-1 x PP (VERDICT r4 #7): stage parameters keep the pipeline's
     pipe-sharded, data-replicated layout — the manual schedules'

@@ -131,3 +131,23 @@ class TestLoudInitFailure:
         monkeypatch.delenv("TPU_WORKER_HOSTNAMES", raising=False)
         monkeypatch.delenv("CLOUD_TPU_TASK_ID", raising=False)
         meshlib.initialize_distributed()   # must not raise
+
+    @pytest.mark.parametrize("hosts", ["localhost", "t1v-n-abc-w-0",
+                                       "10.0.0.7"])
+    def test_single_host_never_joins_a_coordinator(self, monkeypatch,
+                                                   hosts):
+        """A one-host machine (the sealed chip machines set
+        TPU_WORKER_HOSTNAMES=localhost, TPU_WORKER_ID=0) must never call
+        jax.distributed.initialize — it could wait forever on a
+        coordinator that does not exist."""
+        import jax
+
+        def boom(*a, **k):
+            raise AssertionError("single host tried to join a coordinator")
+
+        monkeypatch.setattr(jax.distributed, "initialize", boom)
+        monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)
+        monkeypatch.setenv("TPU_WORKER_HOSTNAMES", hosts)
+        monkeypatch.setenv("TPU_WORKER_ID", "0")
+        monkeypatch.setenv("CLOUD_TPU_TASK_ID", "0")
+        meshlib.initialize_distributed()

@@ -106,10 +106,8 @@ class TestUlyssesAttention:
         monkeypatch.setattr(ulysses_mod, "ulysses_attention", spy)
         # pretend we're on TPU for the gate (after building the mesh —
         # bert.jax IS the global jax module, so devices() is patched
-        # everywhere), and short-circuit the Mosaic compile probe
-        from mpi_tensorflow_tpu.ops import flash_attention as fa
-
-        monkeypatch.setattr(fa, "kernel_supported", lambda *a: True)
+        # everywhere)
+        monkeypatch.delenv("MPI_TF_TPU_DISABLE_FLASH", raising=False)
         monkeypatch.setattr(
             bert.jax, "devices",
             lambda *a: [type("D", (), {"platform": "tpu"})()])
