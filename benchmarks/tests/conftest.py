@@ -1,0 +1,17 @@
+"""Run by hand: ``python3 -m pytest benchmarks/tests -q -p no:cacheprovider``
+(not part of the repo's tier-1).  CPU only, four virtual devices so the
+four-chip cell's code runs."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in flags:
+    os.environ["XLA_FLAGS"] = (
+        flags + " --xla_force_host_platform_device_count=4").strip()
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
