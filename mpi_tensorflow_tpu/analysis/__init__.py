@@ -26,8 +26,8 @@ since PR 1 (stdlib ``ast`` only — no new dependencies):
                       sweep over the whole package.
 
 Run it: ``python -m mpi_tensorflow_tpu.analysis`` (see
-``analysis/runner.py`` and docs/ANALYSIS.md).  ``scripts/t1_guard.sh``
-runs it as a pre-flight before the tier-1 suite.
+``analysis/runner.py`` and docs/ANALYSIS.md).  Tier-1 runs it on the live
+tree: ``tests/test_analysis.py::test_live_repo_scans_clean``.
 """
 
 from mpi_tensorflow_tpu.analysis.core import Finding  # noqa: F401

@@ -29,13 +29,13 @@ Memory schedules, from cheapest to most capable:
   (P-1)/(M+P-1) each way.
 - Microbatch groups: ``num_microbatches = P`` + the train step's
   ``grad_accum`` — O(P) activations at bubble (P-1)/(2P-1) per group
-  (pinned by tests/test_moe_pipeline.py::test_pipeline_with_grad_accum).
+  (pinned by tests/test_pipeline_bert.py::test_pipeline_with_grad_accum).
 - Interleaved 1F1B (``schedule="1f1b"``): hand-interleaved
   one-forward-one-backward via ``parallel/pipeline.pipeline_1f1b`` — the
   same (P-1)/(M+P-1) bubble as end-to-end GPipe but only O(P) stashed
   activations (each stage's backward recomputes its forward from the
   stashed input).  Loss/grad parity with GPipe is pinned by
-  tests/test_moe_pipeline.py::TestOneFOneB.
+  tests/test_pipeline_1f1b.py::TestOneFOneB.
 ``cfg.remat`` additionally recomputes within-stage activations in the
 backward.  TP inside a stage works with both schedules (the 1F1B path
 runs a vocab-parallel CE in-schedule); SP inside a stage works with

@@ -100,7 +100,7 @@ def pipeline(stage_fn: Callable, stage_params: Any, microbatches,
 # ppermute per tick each way); the last tick is 2M+2P-3, so the schedule is
 # T = 2(M+P-1) ticks with exactly 2(P-1) idle ticks per stage — idle
 # fraction (P-1)/(M+P-1), the 1F1B bubble (pinned by
-# tests/test_moe_pipeline.py::TestOneFOneB::test_bubble_accounting).
+# tests/test_pipeline_1f1b.py::TestOneFOneB::test_bubble_accounting).
 # Microbatch i's input activation is stashed from its F tick to its B tick;
 # at stage s that window holds at most P-s microbatches, so a P-slot ring
 # buffer (indexed i mod P) suffices — O(P) activation memory, the whole

@@ -218,16 +218,7 @@ def gated_cpu_cache(base: str):
     Every place that sets ``jax_compilation_cache_dir`` or
     ``JAX_COMPILATION_CACHE_DIR`` for a forced-CPU run must go through
     here — a direct ``host_scoped_cpu_cache`` call reopens the
-    same-host reload abort this module exists to close.
-
-    ``MPI_TPU_DISABLE_COMPILE_CACHE=1`` forces the cache off regardless
-    of the canary verdict — the escape hatch for boxes where the simple
-    canary round-trips but a REAL entry (the scanned train step) still
-    aborts on reload (scripts/t1_guard.sh uses it for the post-segfault
-    rerun: slow beats fatal, and a rerun must not re-crash on the very
-    reload that killed the first pass)."""
-    if os.environ.get("MPI_TPU_DISABLE_COMPILE_CACHE", "") not in ("", "0"):
-        return None
+    same-host reload abort this module exists to close."""
     scoped = host_scoped_cpu_cache(base)
     return scoped if cpu_cache_roundtrip_safe(scoped) else None
 

@@ -289,10 +289,19 @@ class TestMeasureServing:
         assert ff["failovers"] == 0 and ff["migrated_requests"] == 0
         assert r["replicas"]["per_replica"][0]["health"] == "healthy"
 
-    def test_serving_fault_injection_failover_token_identical(self):
+    def test_serving_fault_injection_failover_token_identical(
+            self, monkeypatch):
         """--serve-fault-*: the routed arm loses a replica mid-trace
         and still emits exactly the single engine's tokens, with the
         fleet_faults block recording the failover."""
+        # the fault fires at replica 0's THIRD TICK, and only sequential
+        # stepping ties ticks to work (ReplicaFault: "deterministic under
+        # parallel=False"): a replica thread on a loaded host can idle
+        # through three ticks before its first request is routed, and the
+        # fault then has nothing to migrate
+        from mpi_tensorflow_tpu.serving import router
+
+        monkeypatch.setattr(router, "default_parallelism", lambda: False)
         r = bench.measure_serving(num_requests=4, rate_rps=1e6,
                                   max_slots=2, block_size=8,
                                   prompt_max=8, output_max=8,

@@ -167,8 +167,8 @@ class TestRemat:
                 return jnp.sum(out ** 2) / out.size
             return f
 
-        gp = jax.grad(loss(m_p))(params)
-        gr = jax.grad(loss(m_r))(params)
+        gp = jax.jit(jax.grad(loss(m_p)))(params)
+        gr = jax.jit(jax.grad(loss(m_r)))(params)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),

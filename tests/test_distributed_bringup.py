@@ -63,7 +63,7 @@ def test_two_process_bringup(tmp_path, devices_per_proc):
     try:
         for p in procs:
             try:
-                p.wait(timeout=900)
+                p.wait(timeout=170)
             except subprocess.TimeoutExpired:
                 timed_out = True       # read the logs before failing —
                 break                  # they localize the hang

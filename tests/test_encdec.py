@@ -143,13 +143,11 @@ class TestForward:
         params = m_p.init(jax.random.key(0))
         b = _batch()
         key = jax.random.key(3)
-        lp, _ = m_p.loss(params, None, b, rng=key, train=True)
-        lr, _ = m_r.loss(params, None, b, rng=key, train=True)
+        lp, gp = jax.jit(jax.value_and_grad(lambda p: m_p.loss(
+            p, None, b, rng=key, train=True)[0]))(params)
+        lr, gr = jax.jit(jax.value_and_grad(lambda p: m_r.loss(
+            p, None, b, rng=key, train=True)[0]))(params)
         np.testing.assert_allclose(float(lp), float(lr), rtol=1e-6)
-        gp = jax.grad(lambda p: m_p.loss(p, None, b, rng=key,
-                                         train=True)[0])(params)
-        gr = jax.grad(lambda p: m_r.loss(p, None, b, rng=key,
-                                         train=True)[0])(params)
         jax.tree.map(lambda a, c: np.testing.assert_allclose(
             np.asarray(a), np.asarray(c), rtol=1e-5, atol=1e-6), gp, gr)
 

@@ -6,7 +6,13 @@ import io
 import contextlib
 import sys
 
+import pytest
 
+
+# seventeen strategies, each its own train-step compile, in one process: 89 s
+# in the six-worker run and 158 s on the box ISSUE 24 was timed on, where
+# the common 180 s limit would leave it 22 s
+@pytest.mark.limit(360)
 def test_dryrun_multichip_all_strategies(capsys):
     sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
     import __graft_entry__

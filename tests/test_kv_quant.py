@@ -5,9 +5,10 @@ tier-1 pin here is that every serving subsystem composes with it
 unchanged: the radix prefix trie (same prompt => same quantized bytes,
 CoW copies codes AND scale siblings), eviction + restart-from-scratch
 under pool pressure, speculative draft/verify/rollback, and SIGKILL
-journal replay (replayed prefills re-quantize to the SAME pool bytes a
-straight run writes, because the per-(block, head, slot) row scales
-make quantization write-granularity independent).
+journal replay (replayed prefills re-quantize to the SAME codes a
+straight run writes, and scales equal to matmul rounding, because the
+per-(block, head, slot) row scales make quantization write-granularity
+independent).
 
 Token identity in this file is WITHIN int8 mode (int8-with-feature vs
 int8-without-feature): greedy decode over the same quantized pool is
@@ -80,11 +81,17 @@ def _pool_bytes(engine):
             for p in engine.pools]
 
 
-def _assert_pools_equal(a, b):
+def _assert_pools_equal(a, b, scale_ulps=0):
+    """Codes always byte-equal; the fp32 row scales byte-equal too unless
+    ``scale_ulps`` allows them that many units in the last place."""
     for pa, pb in zip(a, b):
         assert pa.keys() == pb.keys()
         for key in pa:
-            np.testing.assert_array_equal(pa[key], pb[key])
+            if scale_ulps and key.endswith("_scale"):
+                np.testing.assert_array_max_ulp(pa[key], pb[key],
+                                                maxulp=scale_ulps)
+            else:
+                np.testing.assert_array_equal(pa[key], pb[key])
 
 
 # ------------------------------------------------------- determinism
@@ -272,10 +279,20 @@ class TestInt8JournalReplay:
         re-quantizes ``prompt + delivered prefix`` in chunks, the
         original run wrote those rows one decode token at a time — the
         per-(block, head, slot) row scales make both write shapes land
-        byte-identical codes AND scales, so the survivor engine's pool
-        equals a straight run's pool exactly (null block excluded: dead
-        decode lanes scatter garbage there and the dispatch count
-        legitimately differs)."""
+        byte-identical CODES in the same blocks and slots, so the
+        survivor engine's pool dequantizes to what a straight run's does
+        (null block excluded: dead decode lanes scatter garbage there
+        and the dispatch count legitimately differs).
+
+        The scales are held to 5 ulps, not to the byte: a (1, 1, E) decode
+        matmul and a (1, chunk, E) prefill matmul round the same K/V row
+        differently in its last bits on XLA:CPU (an fp32 pool shows the
+        same rows apart by the same amount, 7e-5 relative at most),
+        ``max|row| / 127`` follows its row (4 ulps at most here; 5 is
+        that plus one), and the codes absorb it on this trace: a value
+        within 7e-5 of a rounding boundary would not, so a new seed or
+        model here may need another.  Same write shape => same bytes is
+        TestInt8PoolDeterminism's pin."""
         model, params = model_params
         one = [Request(0, [5, 6, 7, 8, 9], 12)]
         straight = PagedDecodeEngine(model, params, SERVE)
@@ -287,7 +304,7 @@ class TestInt8JournalReplay:
         assert res["replays"] == 1
         assert res["outputs"] == want["outputs"]
         _assert_pools_equal(_pool_bytes(straight),
-                            _pool_bytes(engines[-1]))
+                            _pool_bytes(engines[-1]), scale_ulps=5)
         engines[-1].sched.check_quiescent()
 
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _jitted import generate_ref as _generate_ref
 from mpi_tensorflow_tpu.models import bert, gpt
 from mpi_tensorflow_tpu.ops import paged_attention as paged_ops
 from mpi_tensorflow_tpu.ops import paged_attention_kernel as pk
@@ -226,12 +227,6 @@ class TestDispatch:
 
 
 # ----------------------------------------------- engine end to end
-
-def _generate_ref(model, params, prompt, n):
-    out = np.asarray(model.generate(
-        params, jnp.asarray([prompt], jnp.int32), n))
-    return list(map(int, out[0, len(prompt):]))
-
 
 class TestEnginePallas:
     """The acceptance pins: greedy decode through the engine with

@@ -20,6 +20,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from _jitted import generate_ref as _generate_ref
 from mpi_tensorflow_tpu.models import bert, gpt
 from mpi_tensorflow_tpu.serving import (BlockAllocator, PagedDecodeEngine,
                                         PrefixCache, Request, Scheduler,
@@ -28,14 +29,6 @@ from mpi_tensorflow_tpu.serving.paged_cache import init_pools, \
     partial_copy_block
 
 TINY = dataclasses.replace(bert.BERT_TINY, ce_positions="all")
-
-
-def _generate_ref(model, params, prompt, n):
-    import jax.numpy as jnp
-
-    out = np.asarray(model.generate(
-        params, jnp.asarray([prompt], jnp.int32), n))
-    return list(map(int, out[0, len(prompt):]))
 
 
 def _seed_trie(pc, stream):

@@ -21,6 +21,7 @@ wall-clock claim.
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -182,6 +183,17 @@ class TestRoutedServing:
         assert "shed" in statuses and "ok" in statuses
 
 
+@functools.cache
+def _single(seed, serve_overrides):
+    """The unfaulted single-engine reference for ``_fleet``: one engine per
+    (seed, serve) for the whole module — its compiled programs are the same
+    for every test; ``_fleet`` resets its pools and scheduler.
+    (``failover_backoff_ms`` is a fleet knob no single engine reads.)"""
+    model, params = _model(seed)
+    return PagedDecodeEngine(
+        model, params, ServeConfig(**BASE, **dict(serve_overrides)))
+
+
 def _fleet(n_replicas=2, seed=3, backoff_ms=1e6, make_engine=False,
            **serve_overrides):
     """A router over fresh replicas + the matching single-engine
@@ -191,7 +203,8 @@ def _fleet(n_replicas=2, seed=3, backoff_ms=1e6, make_engine=False,
     model, params = _model(seed)
     serve = ServeConfig(**BASE, failover_backoff_ms=backoff_ms,
                         **serve_overrides)
-    single = PagedDecodeEngine(model, params, serve)
+    single = _single(seed, tuple(sorted(serve_overrides.items())))
+    single.reset()
     factory = ((lambda: PagedDecodeEngine(model, params, serve))
                if make_engine else None)
     router = ReplicaRouter([PagedDecodeEngine(model, params, serve)

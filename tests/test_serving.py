@@ -15,6 +15,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from _jitted import generate_ref as _generate_ref
 from mpi_tensorflow_tpu.models import bert, gpt
 from mpi_tensorflow_tpu.serving import (BlockAllocator, PagedDecodeEngine,
                                         PrefixCache, Request, Scheduler,
@@ -29,14 +30,6 @@ def _prompts(rng, n, lo=4, hi=14, vocab=None):
     vocab = vocab or TINY.vocab_size
     return [list(map(int, rng.integers(0, vocab, int(s))))
             for s in rng.integers(lo, hi + 1, n)]
-
-
-def _generate_ref(model, params, prompt, n):
-    import jax.numpy as jnp
-
-    out = np.asarray(model.generate(
-        params, jnp.asarray([prompt], jnp.int32), n))
-    return list(map(int, out[0, len(prompt):]))
 
 
 # ---------------------------------------------------------------- blocks

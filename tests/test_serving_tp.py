@@ -13,6 +13,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from _jitted import generate_ref as _generate_ref
 from mpi_tensorflow_tpu.models import bert, gpt
 from mpi_tensorflow_tpu.serving import (PagedDecodeEngine, Request,
                                         ServeConfig)
@@ -27,14 +28,6 @@ BASE = dict(num_blocks=40, block_size=4, max_slots=3, max_seq_len=24,
 def _prompts(rng, n, lo=3, hi=13):
     return [list(map(int, rng.integers(0, TINY.vocab_size, int(s))))
             for s in rng.integers(lo, hi + 1, n)]
-
-
-def _generate_ref(model, params, prompt, n):
-    import jax.numpy as jnp
-
-    out = np.asarray(model.generate(
-        params, jnp.asarray([prompt], jnp.int32), n))
-    return list(map(int, out[0, len(prompt):]))
 
 
 def _model(cfg=TINY, seed=0):

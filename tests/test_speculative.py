@@ -17,6 +17,11 @@ token unique), while rope dynamics are position-relative and fall into
 the recurrent regime n-gram self-drafting targets.  Token identity is
 asserted on BOTH geometries either way — acceptance only changes how
 much work the verify path saves, never which tokens come out.
+
+This file: drafters, scheduler generalization, token identity, CLI guards.
+Prefix cache / CoW / eviction / deadline / replay are in
+tests/test_speculative_faults.py; the draft window (auto-tune, rollback,
+recompile discipline) in tests/test_speculative_window.py.
 """
 
 import dataclasses
@@ -24,54 +29,12 @@ import dataclasses
 import numpy as np
 import pytest
 
-from mpi_tensorflow_tpu.models import bert, gpt
-from mpi_tensorflow_tpu.serving import (BlockAllocator, Drafter,
-                                        NgramDrafter, PagedDecodeEngine,
-                                        ReplayJournal, Request, Scheduler,
-                                        ServeConfig, run_with_replay)
-
-TINY = dataclasses.replace(bert.BERT_TINY, ce_positions="all")
-ROPE = dataclasses.replace(TINY, pos_kind="rope")
-
-
-def _generate_ref(model, params, prompt, n):
-    import jax.numpy as jnp
-
-    out = np.asarray(model.generate(
-        params, jnp.asarray([prompt], jnp.int32), n))
-    return list(map(int, out[0, len(prompt):]))
-
-
-def _shared_trace(rng, n=5, prefix=8, tail_hi=5, budget=24, vocab=None,
-                  tail_lens=None):
-    vocab = vocab or TINY.vocab_size
-    shared = list(map(int, rng.integers(0, vocab, prefix)))
-    if tail_lens is None:
-        tail_lens = rng.integers(1, tail_hi + 1, n)
-    prompts = [shared + list(map(int, rng.integers(0, vocab, int(s))))
-               for s in tail_lens]
-    return [Request(i, p, budget, arrival=0.0)
-            for i, p in enumerate(prompts)]
-
-
-SERVE = ServeConfig(num_blocks=96, block_size=4, max_slots=3,
-                    max_seq_len=64, prefill_chunk=8)
-
-
-def _pair(cfg, *, key=0, **spec_kw):
-    """(model, params, off-engine, speculative-engine) on one config."""
-    import jax
-
-    model = gpt.CausalLm(cfg)
-    params = model.init(jax.random.key(key))
-    serve_kw = {k: v for k, v in spec_kw.items()
-                if k not in ("draft_model", "draft_params")}
-    eng_kw = {k: v for k, v in spec_kw.items()
-              if k in ("draft_model", "draft_params")}
-    off = PagedDecodeEngine(model, params, SERVE)
-    spec = PagedDecodeEngine(
-        model, params, dataclasses.replace(SERVE, **serve_kw), **eng_kw)
-    return model, params, off, spec
+from _jitted import generate_ref as _generate_ref
+from _speculative_common import ROPE, SERVE, TINY, _pair, _shared_trace
+from mpi_tensorflow_tpu.models import gpt
+from mpi_tensorflow_tpu.serving import (BlockAllocator, NgramDrafter,
+                                        PagedDecodeEngine, Request, Scheduler,
+                                        ServeConfig)
 
 
 # ------------------------------------------------------------- drafters
@@ -278,446 +241,6 @@ class TestSpeculativeParity:
         res = spec.run([Request(0, [5, 6, 7], 8)])
         assert res["outputs"][0] == full[:full.index(eos) + 1]
         spec.sched.check_quiescent()
-
-
-# ----------------------------------------- prefix cache / CoW / stress
-
-class TestSpeculativeWithPrefixCache:
-    def test_shared_prefix_cache_on_token_identical_with_hits(self):
-        """Prefix cache AND speculation on together: trie hits land,
-        drafts verify, outputs equal the everything-off engine's."""
-        model, params, off, spec = _pair(
-            ROPE, speculative="ngram", draft_k=4, prefix_cache="on")
-        rng = np.random.default_rng(4)
-        reqs = _shared_trace(rng, n=5, prefix=12, budget=24)
-        want = off.run([dataclasses.replace(r) for r in reqs])
-        got = spec.run([dataclasses.replace(r) for r in reqs])
-        assert got["outputs"] == want["outputs"]
-        assert got["prefix"]["hit_tokens"] > 0
-        assert got["speculation"]["accepted_tokens"] > 0
-
-    def test_cow_on_shared_block_inside_draft_window(self):
-        """Identical exact-block-multiple prompts, one slot, drafter ==
-        target: the verify window's FIRST write (the shared-final-block
-        recompute) plus its accepted draft writes span a shared block —
-        the CoW guard must privatize the whole range before the
-        dispatch, and the donor's cached content must survive."""
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, max_slots=1,
-                                    prefix_cache="on",
-                                    speculative="draft-model", draft_k=4)
-        spec = PagedDecodeEngine(model, params, serve,
-                                 draft_model=model, draft_params=params)
-        rng = np.random.default_rng(21)
-        prompt = list(map(int, rng.integers(0, TINY.vocab_size, 8)))
-        assert len(prompt) % serve.block_size == 0
-        budgets = [6, 4, 2]
-        res = spec.run([Request(i, list(prompt), n, arrival=0.0)
-                        for i, n in enumerate(budgets)])
-        assert res["prefix"]["cow_copies"] >= 1, \
-            "the shared-final-block recompute must trigger CoW"
-        assert res["speculation"]["accepted_tokens"] > 0, \
-            "the draft window was meant to be live through the CoW"
-        want = _generate_ref(model, params, prompt, max(budgets))
-        for i, n in enumerate(budgets):
-            assert res["outputs"][i] == want[:n], \
-                f"request {i} diverged after CoW inside a draft window"
-
-    def test_eviction_mid_draft_restarts_exact(self):
-        """A tight pool preempts a sequence while speculation is live:
-        restart-from-scratch replay (and the drafter's stale per-request
-        state) must not perturb a single token."""
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        serve = ServeConfig(num_blocks=9, block_size=2, max_slots=2,
-                            max_seq_len=12, prefill_chunk=2,
-                            speculative="draft-model", draft_k=3)
-        engine = PagedDecodeEngine(model, params, serve,
-                                   draft_model=model, draft_params=params)
-        rng = np.random.default_rng(8)
-        pa = list(map(int, rng.integers(0, TINY.vocab_size, 2)))
-        pb = list(map(int, rng.integers(0, TINY.vocab_size, 11)))
-        res = engine.run([Request(0, pa, 10, arrival=0.0),
-                          Request(1, pb, 1, arrival=0.0)])
-        assert engine.sched.evictions >= 1, \
-            "trace was meant to exercise eviction"
-        assert res["outputs"][0] == _generate_ref(model, params, pa, 10)
-        assert res["outputs"][1] == _generate_ref(model, params, pb, 1)
-        engine.allocator.check()
-        engine.drafter.check_quiescent()
-
-    def test_deadline_expiry_mid_draft_is_terminal_not_fatal(self):
-        """A deadline sweep that kills a sequence between draft windows
-        frees its engine blocks AND its drafter state; survivors keep
-        their exact streams."""
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, speculative="draft-model",
-                                    draft_k=3)
-        engine = PagedDecodeEngine(model, params, serve,
-                                   draft_model=model, draft_params=params)
-        clock = {"t": 0.0}
-
-        def fake_time():
-            clock["t"] += 0.01
-            return clock["t"]
-
-        res = engine.run(
-            [Request(0, [1, 2, 3], 20, arrival=0.0, deadline=0.05),
-             Request(1, [4, 5], 3, arrival=0.0)], time_fn=fake_time)
-        assert res["statuses"][0] == "deadline_exceeded"
-        assert res["statuses"][1] == "ok"
-        assert res["outputs"][1] == _generate_ref(model, params, [4, 5], 3)
-        assert engine.allocator.num_used == 0
-        engine.drafter.check_quiescent()
-
-
-# -------------------------------------------------------------- rollback
-
-class _WrongDrafter(Drafter):
-    """Adversarial drafter: proposes, at every position, the true next
-    token PLUS ONE (mod vocab) — guaranteed to mismatch the target's
-    argmax chain at lane 0, so every verify step allocates a full draft
-    window and must roll all of it back."""
-
-    def __init__(self, truth, prompts, vocab):
-        self.truth = truth        # rid -> full true output stream
-        self.prompts = prompts    # rid -> prompt (to locate ctx in it)
-        self.vocab = vocab
-        self.calls = 0
-
-    def draft(self, rid, ctx, k):
-        self.calls += 1
-        # ctx = prompt + generated; the next emitted tokens would be
-        # truth[len(generated):] — corrupt exactly those
-        g = len(ctx) - len(self.prompts[rid])
-        return [(t + 1) % self.vocab
-                for t in self.truth[rid][g:g + k]]
-
-
-class TestDraftAutoTune:
-    """--serve-draft-auto on: the EFFECTIVE draft window follows the
-    observed accept rate (EWMA, clamped to [1, draft_k]) while the
-    verify dispatch width — and therefore the compile set — never
-    changes, and emitted tokens never move."""
-
-    def test_always_wrong_drafter_shrinks_window_to_floor(self):
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        rng = np.random.default_rng(11)
-        prompts = [list(map(int, rng.integers(0, TINY.vocab_size, 5)))
-                   for _ in range(3)]
-        budget = 12
-        truth = {i: _generate_ref(model, params, p, budget)
-                 for i, p in enumerate(prompts)}
-        serve = dataclasses.replace(SERVE, speculative="ngram",
-                                    draft_k=4, draft_auto="on")
-        engine = PagedDecodeEngine(model, params, serve)
-        engine.drafter = _WrongDrafter(truth, dict(enumerate(prompts)),
-                                       TINY.vocab_size)
-        res = engine.run([Request(i, p, budget, arrival=0.0)
-                          for i, p in enumerate(prompts)])
-        # zero accepts: the EWMA decays and the window hits its floor —
-        # 1, never 0 (a dead window could never observe a recovery)
-        assert engine._draft_k_eff == 1
-        sp = res["speculation"]
-        assert sp["draft_auto"] == "on"
-        assert sp["effective_k"] < serve.draft_k, \
-            "auto-tuning never shrank the window"
-        for i in truth:
-            assert res["outputs"][i] == truth[i], \
-                "auto-tuning changed emitted tokens"
-        engine.sched.check_quiescent()
-
-    def test_self_draft_all_accept_keeps_full_window(self):
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, speculative="draft-model",
-                                    draft_k=4, draft_auto="on")
-        spec = PagedDecodeEngine(model, params, serve,
-                                 draft_model=model, draft_params=params)
-        rng = np.random.default_rng(12)
-        reqs = _shared_trace(rng, n=4, budget=12)
-        got = spec.run([dataclasses.replace(r) for r in reqs])
-        sp = got["speculation"]
-        assert sp["accept_rate"] == 1.0
-        assert spec._draft_k_eff == serve.draft_k, \
-            "a fully-accepting drafter must keep the full window"
-        assert sp["effective_k"] == float(serve.draft_k)
-        for r in reqs:
-            assert got["outputs"][r.id] == _generate_ref(
-                model, params, r.prompt, r.max_new_tokens)
-
-    def test_auto_off_reports_the_configured_k(self):
-        model, params, off, spec = _pair(ROPE, key=5,
-                                         speculative="ngram", draft_k=3)
-        rng = np.random.default_rng(13)
-        reqs = _shared_trace(rng, n=3, budget=10)
-        got = spec.run([dataclasses.replace(r) for r in reqs])
-        sp = got["speculation"]
-        assert sp["draft_auto"] == "off"
-        assert sp["effective_k"] == float(3)
-
-    def test_zero_recompiles_with_auto_on(self):
-        """Shrinking/growing the effective k only changes n_valid lane
-        counts inside the FIXED draft_k+1 verify width — the jit caches
-        must not grow across a second trace."""
-        import jax
-
-        model = gpt.CausalLm(ROPE)
-        params = model.init(jax.random.key(1))
-        serve = dataclasses.replace(SERVE, speculative="ngram",
-                                    draft_k=4, draft_auto="on")
-        engine = PagedDecodeEngine(model, params, serve)
-
-        def trace(seed):
-            r = np.random.default_rng(seed)
-            return _shared_trace(r, n=4, budget=12)
-
-        engine.run(trace(0))
-        warm = engine.compile_counts()
-        engine.reset()
-        engine.run(trace(9))
-        assert engine.compile_counts() == warm, \
-            "draft-window auto-tuning recompiled"
-
-
-class TestRollback:
-    def test_rejected_draft_blocks_released_and_quiescent(self):
-        """THE rollback pin: with an always-wrong drafter, every verify
-        window's trailing blocks are phantom storage — after each step
-        they must be back in the pool (live blocks never exceed the
-        off-mode requirement) and check_quiescent() holds at the end."""
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        rng = np.random.default_rng(9)
-        prompts = [list(map(int, rng.integers(0, TINY.vocab_size, 5)))
-                   for _ in range(3)]
-        budget = 10
-        truth = {i: _generate_ref(model, params, p, budget)
-                 for i, p in enumerate(prompts)}
-
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-        engine = PagedDecodeEngine(model, params, serve)
-        engine.drafter = _WrongDrafter(truth, dict(enumerate(prompts)),
-                                       TINY.vocab_size)
-        reqs = [Request(i, p, budget, arrival=0.0)
-                for i, p in enumerate(prompts)]
-        res = engine.run(reqs)
-        assert engine.drafter.calls > 0
-        sp = res["speculation"]
-        assert sp["draft_tokens"] > 0 and sp["accepted_tokens"] == 0
-        assert sp["steps_saved"] == 0
-        for i, p in enumerate(prompts):
-            assert res["outputs"][i] == truth[i], \
-                "an all-rejected draft changed emitted tokens"
-        # every draft-window block was rolled back: nothing leaked
-        engine.sched.check_quiescent()
-        assert engine.allocator.num_used == 0
-
-    def test_rollback_frees_blocks_step_by_step(self):
-        """Track the pool between steps: after a verify step with zero
-        acceptance, the sequence holds exactly the blocks off-mode
-        decode would (no phantom tail)."""
-        import jax
-
-        from mpi_tensorflow_tpu.serving.paged_cache import blocks_for
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        prompt = [3, 1, 4, 1, 5]
-        truth = {0: _generate_ref(model, params, prompt, 8)}
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-        engine = PagedDecodeEngine(model, params, serve)
-        engine.drafter = _WrongDrafter(truth, {0: prompt},
-                                       TINY.vocab_size)
-        engine.sched.submit(Request(0, prompt, 8, arrival=0.0))
-        while not engine.sched.all_done():
-            engine.step()
-            for seq in engine.sched.slots:
-                if seq is None or seq.prefilled < len(prompt):
-                    continue
-                assert len(seq.block_ids) <= blocks_for(
-                    seq.length + 1, serve.block_size), \
-                    "phantom draft blocks survived the step"
-        assert engine.allocator.num_used == 0
-
-
-# ---------------------------------------------------- replay / recovery
-
-class TestSpeculativeReplay:
-    def _flaky_verify_factory(self, model, params, serve, fail_on_call=3,
-                              times=1, **eng_kw):
-        state = {"faults_left": times}
-
-        def make_engine():
-            engine = PagedDecodeEngine(model, params, serve, **eng_kw)
-            if state["faults_left"] > 0:
-                state["faults_left"] -= 1
-                orig, calls = engine._verify_fn, {"n": 0}
-
-                def flaky(*a, **k):
-                    calls["n"] += 1
-                    if calls["n"] == fail_on_call:
-                        raise RuntimeError(
-                            "UNAVAILABLE: simulated device loss")
-                    return orig(*a, **k)
-
-                engine._verify_fn = flaky
-            return engine
-
-        return make_engine
-
-    def test_transient_fault_replay_token_identical(self):
-        """Mid-verify device loss -> engine (and draft pool) rebuilt ->
-        replay: merged outputs equal an unfaulted OFF-mode run's, and
-        the merged speculation block spans both attempts."""
-        import jax
-
-        model = gpt.CausalLm(ROPE)
-        params = model.init(jax.random.key(1))
-        rng = np.random.default_rng(11)
-        reqs = _shared_trace(rng, n=4, budget=20)
-        want = PagedDecodeEngine(model, params, SERVE).run(
-            [dataclasses.replace(r) for r in reqs])
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-        res = run_with_replay(
-            self._flaky_verify_factory(model, params, serve),
-            [dataclasses.replace(r) for r in reqs])
-        assert res["replays"] == 1
-        assert res["outputs"] == want["outputs"]
-        assert res["speculation"]["enabled"]
-        assert res["speculation"]["verify_forwards"] > 0
-
-    def test_sigkill_journal_holds_accepted_tokens_only(self, tmp_path):
-        """Simulated SIGKILL mid-run: the journal on disk must contain,
-        for every live request, a strict PREFIX of the true greedy
-        stream — accepted tokens only, never a rejected draft — and a
-        cold resume completes token-identically."""
-        import jax
-
-        model = gpt.CausalLm(ROPE)
-        params = model.init(jax.random.key(1))
-        rng = np.random.default_rng(12)
-        reqs = _shared_trace(rng, n=4, budget=20)
-        want = PagedDecodeEngine(model, params, SERVE).run(
-            [dataclasses.replace(r) for r in reqs])
-        path = str(tmp_path / "journal.jsonl")
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-
-        factory = self._flaky_verify_factory(model, params, serve,
-                                             fail_on_call=4)
-        with pytest.raises(RuntimeError):
-            factory().run([dataclasses.replace(r) for r in reqs],
-                          journal=ReplayJournal(path))
-
-        mid = ReplayJournal(path)
-        assert any(ent.toks for ent in mid.entries.values()), \
-            "the crash was meant to land mid-stream"
-        for rid, ent in mid.entries.items():
-            n = len(ent.toks)
-            assert ent.toks == want["outputs"][rid][:n], (
-                f"request {rid}: journal holds non-accepted tokens "
-                f"{ent.toks} vs true stream {want['outputs'][rid]}")
-        mid.close()
-
-        res = run_with_replay(
-            lambda: PagedDecodeEngine(model, params, serve),
-            [dataclasses.replace(r) for r in reqs], journal_path=path)
-        assert res["outputs"] == want["outputs"]
-        assert all(s == "ok" for s in res["statuses"].values())
-
-
-# ------------------------------------------------- recompile discipline
-
-class TestSpeculativeCompileDiscipline:
-    def test_zero_recompiles_steady_state_ngram(self):
-        """THE zero-recompile acceptance pin for speculative mode: the
-        verify pre-warm covers every bucket at build, so a fresh trace
-        with DIFFERENT content (hence different acceptance patterns,
-        hence different bucket visits) adds no compiles."""
-        import jax
-
-        model = gpt.CausalLm(ROPE)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-        engine = PagedDecodeEngine(model, params, serve)
-        warm0 = engine.compile_counts()
-        assert warm0["verify"] > 0, "verify pre-warm did not compile"
-
-        def trace(seed):
-            # fixed tail LENGTHS across seeds: prefill bucket visits
-            # depend on the trace envelope for off-mode and speculative
-            # alike — only CONTENT (and hence acceptance, the thing the
-            # verify pre-warm must cover) varies here
-            r = np.random.default_rng(seed)
-            return _shared_trace(r, n=5, budget=24,
-                                 tail_lens=[1, 2, 3, 4, 5])
-
-        engine.run(trace(0))
-        warm = engine.compile_counts()
-        engine.reset()
-        engine.run(trace(13))                # new content, same envelope
-        assert engine.compile_counts() == warm, \
-            "speculative steady state recompiled"
-
-    def test_zero_recompiles_steady_state_draft_model(self):
-        import jax
-
-        model = gpt.CausalLm(TINY)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, speculative="draft-model",
-                                    draft_k=3)
-        engine = PagedDecodeEngine(model, params, serve,
-                                   draft_model=model, draft_params=params)
-        assert engine.compile_counts()["draft"] > 0, \
-            "drafter chunk-bucket pre-warm did not compile"
-
-        def trace(seed):
-            # fixed tail lengths: content-only variation (see ngram pin)
-            r = np.random.default_rng(seed)
-            return _shared_trace(r, n=4, budget=10,
-                                 tail_lens=[1, 2, 3, 4])
-
-        engine.run(trace(0))
-        warm = engine.compile_counts()
-        engine.reset()
-        engine.run(trace(5))
-        assert engine.compile_counts() == warm, \
-            "draft-model steady state recompiled"
-
-    def test_verify_dispatch_shapes_are_bucketed(self):
-        import jax
-
-        model = gpt.CausalLm(ROPE)
-        params = model.init(jax.random.key(0))
-        serve = dataclasses.replace(SERVE, speculative="ngram", draft_k=4)
-        engine = PagedDecodeEngine(model, params, serve)
-        rng = np.random.default_rng(14)
-        engine.run(_shared_trace(rng, n=5, budget=12))
-        kinds = {s[0] for s in engine.dispatch_shapes}
-        assert "verify" in kinds and "decode" not in kinds, \
-            "speculative mode must route all decode work through verify"
-        caps = (serve.max_slots, serve.max_blocks_per_seq)
-        for shape in engine.dispatch_shapes:
-            for dim, cap in zip(shape[1:], caps):
-                # pow2, or clamped at the configured cap (engine._bucket
-                # rounds up then caps — same discipline as decode)
-                assert dim & (dim - 1) == 0 or dim == cap, \
-                    f"unbucketed dispatch {shape}"
 
 
 # ------------------------------------------------------------ cli guards
