@@ -362,18 +362,18 @@ def _paged_case(S: int, q_dtype: str, variant: str):
     valid = jnp.ones((B, L), bool)
     kw = {}
     if variant in ("bf16", "fp32"):
-        z = jnp.zeros((nblk, H, bs, D), dt)
+        z = jnp.zeros((nblk, bs, H * D), dt)
         kp = pa.write_kv(z, kf, bt, pos, valid)
         vp = pa.write_kv(z, vf, bt, pos, valid)
     elif variant == "int8":
-        z = jnp.zeros((nblk, H, bs, D), jnp.int8)
-        zs = jnp.zeros((nblk, H, bs), jnp.float32)
+        z = jnp.zeros((nblk, bs, H * D), jnp.int8)
+        zs = jnp.zeros((nblk, bs, H), jnp.float32)
         kp, ks = pa.write_kv_quant(z, zs, kf, bt, pos, valid)
         vp, vs = pa.write_kv_quant(z, zs, vf, bt, pos, valid)
         kw = dict(k_scale=ks, v_scale=vs)
     else:
-        z = jnp.zeros((nblk, H, bs, D // 2), jnp.uint8)
-        zs = jnp.zeros((nblk, H, bs, D // 32), jnp.float32)
+        z = jnp.zeros((nblk, bs, H * D // 2), jnp.uint8)
+        zs = jnp.zeros((nblk, bs, H * D // 32), jnp.float32)
         kp, ks = pa.write_kv_quant_int4(z, zs, kf, bt, pos, valid)
         vp, vs = pa.write_kv_quant_int4(z, zs, vf, bt, pos, valid)
         kw = dict(k_scale=ks, v_scale=vs)

@@ -170,10 +170,10 @@ class CausalLm(bert_lib.BertMlm):
         prefill+decode on the contiguous path.
 
         pools:        per-layer [{"k", "v"}] block pools, each
-                      (num_blocks, H, block_size, D) — head-major,
+                      (num_blocks, block_size, H*D) — token-major,
                       ops/paged_attention's layout.  An int8 pool
                       (--serve-kv-dtype int8) additionally carries
-                      {"k_scale", "v_scale"} (num_blocks, H, block_size)
+                      {"k_scale", "v_scale"} (num_blocks, block_size, H)
                       fp32 row scales (serving/paged_cache.init_pools);
                       writes then quantize on store and attention
                       dequantizes inside the consume path
@@ -239,8 +239,9 @@ class CausalLm(bert_lib.BertMlm):
                 q = bert_lib.rope(q, pos)
                 k = bert_lib.rope(k, pos)
             q = self._constrain(q, qkv_axes)
-            if "k_scale" in pl and pl["k_scale"].ndim == 4:
-                # int4 pool (--serve-kv-dtype int4, 4-d group scales):
+            mode = paged_ops.pool_mode(pl["k"], pl.get("k_scale"))
+            if mode == "int4":
+                # int4 pool (--serve-kv-dtype int4, uint8 nibble codes):
                 # group-quantize on store, consume through attend's
                 # dequantizing paths WITH the fp-residual self lane —
                 # the in-register k/v of this step's own tokens give
@@ -256,7 +257,7 @@ class CausalLm(bert_lib.BertMlm):
                                      dt, kernel=kernel,
                                      k_scale=ks, v_scale=vs,
                                      k_new=k, v_new=v)
-            elif "k_scale" in pl:
+            elif mode == "int8":
                 # int8 pool (--serve-kv-dtype int8): quantize on store —
                 # codes and per-row scales scatter through the same
                 # block/offset indexing — and consume through attend's

@@ -1,7 +1,7 @@
 """Paged KV-cache device ops: block-table gather/scatter + attention.
 
 The serving path (serving/) stores K/V in a fixed pool of
-``(num_blocks, heads, block_size, head_dim)`` blocks instead of one
+``(num_blocks, block_size, heads * head_dim)`` blocks instead of one
 contiguous ``(B, H, max_len, D)`` buffer per request batch
 (models/gpt.init_cache).  Each live sequence owns an ordered list of
 pool blocks (its block table); block ``j`` of a sequence holds absolute
@@ -14,10 +14,16 @@ implementation both paths call), with padding lanes exactly zeroed
 (``exp(finfo.min - max)`` underflows to 0.0, and 0-weighted V lanes add
 exact 0.0 terms).
 
-The pool layout is head-major so a single block is ``(H, block_size,
-D)`` — the orientation the fused Pallas kernel
-(ops/paged_attention_kernel) streams blockwise with no in-kernel
-transpose.
+The pool is token-major and lane-dense: a block is ``(block_size,
+H*D)``, one row per token slot with the heads side by side (head ``h``
+in lanes ``[h*D, (h+1)*D)``).  That is the one geometry on which the
+runtime's default device layout (row-major only when the minor dimension
+fills the 128-lane tile; a 64-wide ``D`` gets ``num_blocks`` rotated
+minor-most), the XLA scatter of ``write_kv`` (each update is one
+contiguous row) and the Mosaic operand of the fused kernel
+(ops/paged_attention_kernel, row-major) all agree, so no serving program
+re-lays a pool leaf; the old ``(num_blocks, H, block_size, D)`` pool
+cost three pool-sized copies per leaf and program (PERF.md, PR 25).
 
 Block 0 is the NULL block: never allocated to a sequence, it absorbs
 scatter writes from masked-out lanes (padded prefill tail, inactive
@@ -38,13 +44,15 @@ resolves through ``resolve_kernel`` to either
   lowerable, CPU-exact).
 
 Tensor parallelism (serving/tp): every op here treats H as a PURE
-BATCH dimension — ``write_kv`` scatters per-head rows independently,
-``gather_kv``/``attend`` contract only within a head — so under a
-head-sharded pool each shard runs these ops unchanged over its local
-``H/tp`` heads with the SAME replicated block table (a block id
-addresses the same slot of every shard's pool).  Nothing in this
-module is tp-aware; the cross-shard reduction lives in the model's
-row-parallel projections, not in attention.
+BATCH dimension — ``write_kv`` lays each head's row slice side by side,
+``gather_kv``/``attend`` contract only within a head, and the head
+count is read off ``kv``/``q``, never off the pool — so under a
+head-sharded pool (contiguous ``H/tp`` heads of the last axis) each
+shard runs these ops unchanged over its local heads with the SAME
+replicated block table (a block id addresses the same slot of every
+shard's pool).  Nothing in this module is tp-aware; the cross-shard
+reduction lives in the model's row-parallel projections, not in
+attention.
 """
 
 from __future__ import annotations
@@ -90,7 +98,7 @@ def masked_softmax_attention(q, k, v, vis, dt, scale=None):
 def write_kv(pool, kv, block_table, positions, valid):
     """Scatter per-token K or V vectors into the block pool.
 
-    pool:        (num_blocks, H, block_size, D)
+    pool:        (num_blocks, block_size, H*D)
     kv:          (B, H, S, D)  — new keys or values, head-major like the
                  qkv projection emits
     block_table: (B, NB) int32 — pool block ids, position order
@@ -101,16 +109,26 @@ def write_kv(pool, kv, block_table, positions, valid):
     (the allocator hands each block to one sequence); invalid lanes all
     land in block 0, whose contents are never read unmasked.
     """
-    bs = pool.shape[2]
+    blk, off = _slots(pool, block_table, positions, valid)
+    # one whole (H*D,) row per token: pool[blk[b,s], off[b,s], :]
+    return pool.at[blk, off].set(_token_rows(kv).astype(pool.dtype))
+
+
+def _slots(pool, block_table, positions, valid):
+    """(block id, slot) of each token of a write, both (B, S): the block
+    comes from the row's table, invalid lanes go to the null block."""
+    bs = pool.shape[1]
     nb = block_table.shape[1]
     blk_idx = jnp.clip(positions // bs, 0, nb - 1)
-    blk = jnp.take_along_axis(block_table, blk_idx, axis=1)      # (B, S)
-    blk = jnp.where(valid, blk, NULL_BLOCK)
-    off = positions % bs
-    vals = jnp.transpose(kv, (0, 2, 1, 3))                       # (B, S, H, D)
-    # two advanced indices around the head slice: the broadcast (B, S)
-    # index dims lead, so this writes pool[blk[b,s], h, off[b,s], :]
-    return pool.at[blk, :, off].set(vals.astype(pool.dtype))
+    blk = jnp.take_along_axis(block_table, blk_idx, axis=1)
+    return jnp.where(valid, blk, NULL_BLOCK), positions % bs
+
+
+def _token_rows(x):
+    """(B, H, S, ...) per-head values -> (B, S, H * prod(...)) token rows
+    with the heads side by side: the order of a pool row."""
+    x = jnp.moveaxis(x, 1, 2)
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 def quantize_kv(kv):
@@ -147,25 +165,18 @@ def write_kv_quant(pool, pool_scale, kv, block_table, positions, valid):
     (``quantize_kv``) and scatter codes AND scales through the same
     block/offset indexing.
 
-    pool:        (num_blocks, H, block_size, D) int8 codes
-    pool_scale:  (num_blocks, H, block_size) fp32 row scales
+    pool:        (num_blocks, block_size, H*D) int8 codes
+    pool_scale:  (num_blocks, block_size, H) fp32 row scales
     kv/block_table/positions/valid: as ``write_kv``
 
     Returns ``(pool, pool_scale)`` updated.  Each write dispatch
     computes fresh scales for exactly the rows it writes — invalid
     lanes land codes and scales in the null block, never read unmasked.
     """
-    bs = pool.shape[2]
-    nb = block_table.shape[1]
-    blk_idx = jnp.clip(positions // bs, 0, nb - 1)
-    blk = jnp.take_along_axis(block_table, blk_idx, axis=1)      # (B, S)
-    blk = jnp.where(valid, blk, NULL_BLOCK)
-    off = positions % bs
+    blk, off = _slots(pool, block_table, positions, valid)
     codes, scale = quantize_kv(kv)
-    vals = jnp.transpose(codes, (0, 2, 1, 3))                    # (B, S, H, D)
-    sv = jnp.transpose(scale, (0, 2, 1))                         # (B, S, H)
-    return (pool.at[blk, :, off].set(vals),
-            pool_scale.at[blk, :, off].set(sv))
+    return (pool.at[blk, off].set(_token_rows(codes)),
+            pool_scale.at[blk, off].set(_token_rows(scale)))
 
 
 def dequantize_kv(codes, scale, dt):
@@ -254,30 +265,25 @@ def write_kv_quant_int4(pool, pool_scale, kv, block_table, positions,
     (``quantize_kv_int4``) and scatter packed codes AND group scales
     through the same block/offset indexing.
 
-    pool:        (num_blocks, H, block_size, D//2) uint8 packed codes
-    pool_scale:  (num_blocks, H, block_size, G) fp32 group scales
+    pool:        (num_blocks, block_size, H*D//2) uint8 packed codes,
+                 each head's D//2 bytes side by side
+    pool_scale:  (num_blocks, block_size, H*G) fp32 group scales, each
+                 head's G scales side by side
     kv/block_table/positions/valid: as ``write_kv``
 
-    The group size is implied by the pool geometry (``g = D // G``), so
-    the write path can never disagree with ``init_pools`` about it.
+    The group size is implied by the pool geometry (a row's ``H*D``
+    values over its ``H*G`` scales), so the write path can never
+    disagree with ``init_pools`` about it.
     Returns ``(pool, pool_scale)`` updated.
     """
-    bs = pool.shape[2]
-    nb = block_table.shape[1]
-    D = pool.shape[-1] * 2
-    G = pool_scale.shape[-1]
-    blk_idx = jnp.clip(positions // bs, 0, nb - 1)
-    blk = jnp.take_along_axis(block_table, blk_idx, axis=1)      # (B, S)
-    blk = jnp.where(valid, blk, NULL_BLOCK)
-    off = positions % bs
-    packed, scale = quantize_kv_int4(kv, D // G)
-    vals = jnp.transpose(packed, (0, 2, 1, 3))                   # (B, S, H, D/2)
-    sv = jnp.transpose(scale, (0, 2, 1, 3))                      # (B, S, H, G)
-    return (pool.at[blk, :, off].set(vals),
-            pool_scale.at[blk, :, off].set(sv))
+    blk, off = _slots(pool, block_table, positions, valid)
+    packed, scale = quantize_kv_int4(
+        kv, pool.shape[-1] * 2 // pool_scale.shape[-1])
+    return (pool.at[blk, off].set(_token_rows(packed)),
+            pool_scale.at[blk, off].set(_token_rows(scale)))
 
 
-def gather_kv(pool, block_table):
+def gather_kv(pool, block_table, heads: int):
     """Reassemble a (B, H, L, D) contiguous view from the pool.
 
     L = NB * block_size; entry ``l`` holds the sequence's absolute
@@ -285,9 +291,15 @@ def gather_kv(pool, block_table):
     visibility test against absolute query positions carries over
     unchanged from the contiguous path.
     """
-    g = pool[block_table]                        # (B, NB, H, bs, D)
-    B, NB, H, bs, D = g.shape
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, H, NB * bs, D)
+    return _head_rows(pool[block_table], heads)  # (B, NB, bs, H*D) in
+
+
+def _head_rows(g, heads: int):
+    """Gathered blocks ``(B, NB, bs, H*W)`` -> ``(B, H, NB*bs, W)``: the
+    inverse of ``_token_rows`` over a row's blocks in position order."""
+    B, NB, bs, HW = g.shape
+    g = g.reshape(B, NB * bs, heads, HW // heads)
+    return jnp.moveaxis(g, 2, 1)
 
 
 def paged_attention(q, ck, cv, q_positions, dt):
@@ -372,7 +384,7 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
     q:           (B, H, S, D) queries at positions [lengths[b],
                  lengths[b] + S) — their K/V already scattered into the
                  pools (write_kv runs first)
-    k/v_pool:    (num_blocks, H, block_size, D)
+    k/v_pool:    (num_blocks, block_size, H*D)
     block_table: (B, NB) int32
     lengths:     (B,) int32 cache entries already present per row
     kernel:      "xla" (gather + dense masked softmax), "pallas"
@@ -382,13 +394,13 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
                  ``resolve_kernel`` — this runs under jit, where the
                  choice must be static and must not consult the backend.
     k/v_scale:   fp32 scales when the pools hold quantized codes; both
-                 or neither.  3-d ``(num_blocks, H, block_size)`` row
-                 scales mean int8 codes (--serve-kv-dtype int8); 4-d
-                 ``(num_blocks, H, block_size, G)`` group scales mean
-                 int4 nibble-packed codes (--serve-kv-dtype int4) —
-                 the scale RANK is the dtype discriminator, so no new
-                 pool leaf key is needed and CoW/TP/partial-copy stay
-                 generic.  Dequantization happens INSIDE the consume
+                 or neither.  ``(num_blocks, block_size, H)`` row
+                 scales beside int8 codes (--serve-kv-dtype int8);
+                 ``(num_blocks, block_size, H*G)`` group scales beside
+                 uint8 nibble-packed codes (--serve-kv-dtype int4) —
+                 the CODE DTYPE is the discriminator (``pool_mode``), so
+                 no new pool leaf key is needed and CoW/TP/partial-copy
+                 stay generic.  Dequantization happens INSIDE the consume
                  path — in-register in the kernel, elementwise on the
                  gathered view here — so no fp pool ever materializes.
     k/v_new:     (B, H, S, D) fp K/V of the query tokens themselves
@@ -413,7 +425,7 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
         raise ValueError("quantized pools need both k_scale and v_scale")
     if (k_new is None) != (v_new is None):
         raise ValueError("fp residual needs both k_new and v_new")
-    if k_new is not None and (k_scale is None or k_scale.ndim != 4):
+    if k_new is not None and pool_mode(k_pool, k_scale) != "int4":
         raise ValueError(
             "fp-residual k_new/v_new only apply to int4 (group-scaled) "
             "pools")
@@ -431,37 +443,44 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
             f"unresolved paged-attention kernel {kernel!r}: callers "
             f"resolve the knob host-side via resolve_kernel before "
             f"tracing")
-    S = q.shape[2]
+    H, S = q.shape[1:3]
     pos = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)
     if k_scale is not None:
-        # dequantize the gathered blocks elementwise, in lockstep with
+        # dequantize the gathered rows elementwise, in lockstep with
         # the kernel's in-register step (dequantize_kv /
         # dequantize_kv_int4 are the shared contracts), BEFORE the
-        # unchanged transpose/reshape + softmax
-        ck = _gather_kv_dequant(k_pool, k_scale, block_table, q.dtype)
-        cv = _gather_kv_dequant(v_pool, v_scale, block_table, q.dtype)
+        # unchanged softmax
+        ck = _gather_kv_dequant(k_pool, k_scale, block_table, H, q.dtype)
+        cv = _gather_kv_dequant(v_pool, v_scale, block_table, H, q.dtype)
     else:
-        ck = gather_kv(k_pool, block_table)
-        cv = gather_kv(v_pool, block_table)
+        ck = gather_kv(k_pool, block_table, H)
+        cv = gather_kv(v_pool, block_table, H)
     if k_new is not None:
         return paged_attention_self_residual(q, ck, cv, pos, dt,
                                              k_new, v_new)
     return paged_attention(q, ck, cv, pos, dt)
 
 
-def _gather_kv_dequant(pool, pool_scale, block_table, dt):
+def _gather_kv_dequant(pool, pool_scale, block_table, heads: int, dt):
     """``gather_kv`` over a quantized pool: gather codes and scales
-    through the same table, dequantize (int8 row scales or int4 group
-    scales, discriminated by scale rank), reassemble the (B, H, L, D)
-    view."""
-    g = pool[block_table]                        # (B, NB, H, bs, D|D/2)
-    gs = pool_scale[block_table]                 # (B, NB, H, bs[, G])
-    if pool_scale.ndim == 4:
-        g = dequantize_kv_int4(g, gs, dt)        # unpacks D/2 -> D
-    else:
-        g = dequantize_kv(g, gs, dt)
-    B, NB, H, bs, D = g.shape
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(B, H, NB * bs, D)
+    through the same table, take both apart by head, dequantize (int8
+    row scales or int4 group scales, ``pool_mode``) into the
+    (B, H, L, D) view."""
+    g = _head_rows(pool[block_table], heads)         # (B, H, L, D|D/2)
+    gs = _head_rows(pool_scale[block_table], heads)  # (B, H, L, 1|G)
+    if pool_mode(pool, pool_scale) == "int4":
+        return dequantize_kv_int4(g, gs, dt)         # unpacks D/2 -> D
+    return dequantize_kv(g, gs[..., 0], dt)
+
+
+def pool_mode(pool, pool_scale) -> str:
+    """The storage variant of a pool leaf, read off what it holds:
+    "fp32" (no scales: blocks in the compute dtype), "int8" (int8 codes,
+    one scale per head and row) or "int4" (uint8 nibble pairs, group
+    scales)."""
+    if pool_scale is None:
+        return "fp32"
+    return "int4" if pool.dtype == jnp.uint8 else "int8"
 
 
 def resolve_kernel(choice: str, cfg, block_size: int,

@@ -6,7 +6,7 @@ copies per layer per decode token, then a dense masked softmax over the
 full bucketed table width.  This kernel reads the pool **blocks in
 place** through the block table with an fp32 online softmax (the
 PagedAttention / Flash-Decoding recipe, PAPERS.md): per grid step one
-``(H, block_size, D)`` K block and V block stream HBM->VMEM, scores and
+``(block_size, H*D)`` K block and V block stream HBM->VMEM, scores and
 the running (m, l, acc) statistics stay in VMEM scratch, and the
 ``(B, H, NB*block_size, D)`` gathered view never exists.
 
@@ -34,10 +34,18 @@ whose positions the visibility test already rejects, and slack rows
 (all-null table, length 0) produce garbage the engine discards —
 exactly as on the XLA path.
 
-Pool layout is head-major — ``(num_blocks, H, block_size, D)`` — so a
-fetched block is ``(H, block_size, D)`` and both matmuls batch over H
-with no in-kernel transpose (the official TPU paged-attention kernels
-use the same orientation).
+Pool layout is token-major and lane-dense — ``(num_blocks, block_size,
+H*D)`` — so a fetched block is ``(block_size, H*D)``: whole 128-lane
+tiles, row-major, which is what a Mosaic operand must be AND what the
+runtime's default layout and ``write_kv``'s row scatter already are, so
+the pool reaches the kernel with no re-layout (a 64-wide minor ``D``
+made the runtime rotate ``num_blocks`` minor-most, and every program
+copied every leaf to and fro).  The kernel takes head ``h`` out of the
+row as the static lane slice ``[h*D, (h+1)*D)`` and runs the same
+online softmax per head; ``H`` and ``D`` come from ``q``.  Decode over
+an unquantized pool, the hot path, skips even the slicing: block-
+diagonal queries meet the whole row in one pair of matmuls
+(``_decode_kernel``).
 
 ``probe_compile()`` compiles the served geometry (decode + every
 prefill bucket) up front so a Mosaic refusal surfaces at engine build
@@ -56,6 +64,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpi_tensorflow_tpu.ops.paged_attention import pool_mode
+
 # stats rows are lane-broadcast to the f32 tile width, mirroring
 # ops/flash_attention's LSE_LANES treatment of per-row statistics
 STAT_LANES = 128
@@ -69,29 +79,46 @@ def _nlive(length, S: int, bs: int, NB: int):
 
 
 def _dequant_int4_block(codes, scales, dt):
-    """In-register int4 dequant of one fetched pool block — the exact
-    ops/paged_attention.dequantize_kv_int4 contract (unpack split-half
-    nibbles, sign-extend, scale per D-group).
+    """In-register int4 dequant of one head of a fetched pool block —
+    the exact ops/paged_attention.dequantize_kv_int4 contract (unpack
+    split-half nibbles, sign-extend, scale per D-group).
 
-    codes:  (H, bs, D//2) uint8 packed, scales: (H, bs, G) fp32.
-    Returns (H, bs, D) in ``dt``.
+    codes:  (bs, D//2) uint8 packed, scales: (bs, G) fp32.
+    Returns (bs, D) in ``dt``.
     """
     c = codes.astype(jnp.int32)
     lo = c & 0xF
     hi = (c >> 4) & 0xF
-    full = jnp.concatenate([lo, hi], axis=-1)          # (H, bs, D)
+    full = jnp.concatenate([lo, hi], axis=-1)          # (bs, D)
     full = full - jnp.where(full > 7, 16, 0)
     D = full.shape[-1]
     G = scales.shape[-1]
-    # expand the (H, bs, G) group scales to (H, bs, D) by lane select:
+    # expand the (bs, G) group scales to (bs, D) by lane select:
     # Mosaic has no layout for the minor-dim split reshape
-    # (H, bs, D) -> (H, bs, G, D//G) the XLA path uses, while a width-1
+    # (bs, D) -> (bs, G, D//G) the XLA path uses, while a width-1
     # lane slice broadcast along lanes is native.  G is small and static
-    group = lax.broadcasted_iota(jnp.int32, full.shape, 2) // (D // G)
-    sc = jnp.broadcast_to(scales[..., 0:1], full.shape)
+    group = lax.broadcasted_iota(jnp.int32, full.shape, 1) // (D // G)
+    sc = jnp.broadcast_to(scales[:, 0:1], full.shape)
     for g in range(1, G):
-        sc = jnp.where(group == g, scales[..., g:g + 1], sc)
+        sc = jnp.where(group == g, scales[:, g:g + 1], sc)
     return (full.astype(jnp.float32) * sc).astype(dt)
+
+
+def _head_block(ref, scale_ref, h: int, H: int, mode: str, dt):
+    """Head ``h``'s ``(bs, D)`` K or V rows of the fetched block, in
+    ``dt``: a static lane slice of the ``(1, bs, H*W)`` block (``W`` is
+    D, or D//2 packed), dequantized from the same head's slice of the
+    scale block where the pool holds codes."""
+    W = ref.shape[-1] // H
+    x = ref[0, :, h * W:(h + 1) * W]
+    if mode == "int8":
+        return (x.astype(jnp.float32)
+                * scale_ref[0, :, h:h + 1]).astype(dt)
+    if mode == "int4":
+        G = scale_ref.shape[-1] // H
+        return _dequant_int4_block(
+            x, scale_ref[0, :, h * G:(h + 1) * G], dt)
+    return x
 
 
 def _paged_kernel(*refs, scale: float, block_size: int,
@@ -99,15 +126,19 @@ def _paged_kernel(*refs, scale: float, block_size: int,
     """One (batch-slot, kv-block) grid step of the online softmax.
 
     q_ref:  (1, H, S, D)   — the row's whole query block (revisited)
-    k_ref:  (1, H, bs, D)  — pool block ``bt[b, min(j, nlive-1)]``
-    v_ref:  (1, H, bs, D)
+    k_ref:  (1, bs, H*D)   — pool block ``bt[b, min(j, nlive-1)]``
+    v_ref:  (1, bs, H*D)
     o_ref:  (1, H, S, D)   — written once, at the last LIVE block
     scratch: acc (H, S, D) f32, m/l (H, S, STAT_LANES) f32
+
+    The heads are a static loop: head ``h`` reads lanes
+    ``[h*D, (h+1)*D)`` of the K and V rows (``_head_block``) and runs its
+    own two matmuls and softmax update on 2-d tiles.
 
     ``mode`` selects the pool storage format the step consumes:
 
     - "int8" (--serve-kv-dtype int8): k/v_ref hold int8 codes and two
-      extra refs ride between them — ks_ref/vs_ref, the ``(1, H, bs)``
+      extra refs ride between them — ks_ref/vs_ref, the ``(1, bs, H)``
       fp32 row scales of the SAME pool block (their BlockSpec shares
       the kv index map, so code block and scale block can never skew).
       The codes dequantize IN REGISTER right here — ``(codes.astype(f32)
@@ -115,8 +146,8 @@ def _paged_kernel(*refs, scale: float, block_size: int,
       dequantize_kv contract the XLA gather path applies elementwise —
       before the unchanged fp32 matmul/softmax; no fp pool ever
       materializes.
-    - "int4" (--serve-kv-dtype int4): k/v_ref hold ``(1, H, bs, D//2)``
-      nibble-packed uint8 codes, ks/vs_ref the ``(1, H, bs, G)`` fp32
+    - "int4" (--serve-kv-dtype int4): k/v_ref hold ``(1, bs, H*D//2)``
+      nibble-packed uint8 codes, ks/vs_ref the ``(1, bs, H*G)`` fp32
       GROUP scales; ``_dequant_int4_block`` unpacks + dequantizes in
       register (the dequantize_kv_int4 contract).
 
@@ -131,6 +162,7 @@ def _paged_kernel(*refs, scale: float, block_size: int,
     lowerings agree within tolerance.  The self column lives in exactly
     one live grid step; the denominator (l) keeps its weight.
     """
+    ks_ref = vs_ref = kn_ref = vn_ref = None
     if mode == "int4" and residual:
         (bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, ks_ref, v_ref,
          vs_ref, o_ref, acc, m_scr, l_scr) = refs
@@ -155,57 +187,49 @@ def _paged_kernel(*refs, scale: float, block_size: int,
 
     @pl.when(j < nlive)
     def _step():
-        q = q_ref[0]                                   # (H, S, D)
-        k = k_ref[0]                                   # (H, bs, D)
-        v = v_ref[0]
-        if mode == "int8":
-            k = (k.astype(jnp.float32)
-                 * ks_ref[0][..., None]).astype(q.dtype)
-            v = (v.astype(jnp.float32)
-                 * vs_ref[0][..., None]).astype(q.dtype)
-        elif mode == "int4":
-            k = _dequant_int4_block(k, ks_ref[0], q.dtype)
-            v = _dequant_int4_block(v, vs_ref[0], q.dtype)
-        s = lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # (H, S, bs)
         # visibility: key position <= query position, exactly the XLA
         # path's mask (q positions are lengths[b] + [0, S))
         col = j * bs + lax.broadcasted_iota(jnp.int32, (S, bs), 1)
         qpos = len_ref[b] + lax.broadcasted_iota(jnp.int32, (S, bs), 0)
-        if residual:
-            # fp self lane: exact q·k_new score for each row's own
-            # column, overriding the int4 score BEFORE scale+mask
-            self_m = col == qpos                       # (S, bs)
-            kn = kn_ref[0]                             # (H, S, D)
-            s_self = jnp.sum(q.astype(jnp.float32)
-                             * kn.astype(jnp.float32), axis=-1)  # (H, S)
-            s = jnp.where(self_m[None], s_self[:, :, None], s)
-        s = jnp.where((col <= qpos)[None], s * scale,
-                      jnp.finfo(jnp.float32).min)
-        m_prev = m_scr[:, :, 0:1]                      # (H, S, 1)
-        l_prev = l_scr[:, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (H, S, bs)
-        corr = jnp.exp(m_prev - m_new)                 # (H, S, 1)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if residual:
-            # the self column's weight multiplies the fp v_new row, not
-            # the dequantized pool row; l keeps the full p sum
-            p_main = jnp.where(self_m[None], 0.0, p)
-            p_self = jnp.sum(jnp.where(self_m[None], p, 0.0),
-                             axis=-1)                  # (H, S)
-            vn = vn_ref[0]                             # (H, S, D)
-            acc[:] = acc[:] * corr + lax.dot_general(
-                p_main.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) \
-                + p_self[..., None] * vn.astype(jnp.float32)
-        else:
-            acc[:] = acc[:] * corr + lax.dot_general(
-                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)    # (H, S, D)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        self_m = col == qpos if residual else None     # (S, bs)
+        for h in range(H):
+            q = q_ref[0, h]                            # (S, D)
+            k = _head_block(k_ref, ks_ref, h, H, mode, q.dtype)  # (bs, D)
+            v = _head_block(v_ref, vs_ref, h, H, mode, q.dtype)
+            s = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)    # (S, bs)
+            if residual:
+                # fp self lane: exact q·k_new score for each row's own
+                # column, overriding the int4 score BEFORE scale+mask
+                s_self = jnp.sum(
+                    q.astype(jnp.float32)
+                    * kn_ref[0, h].astype(jnp.float32),
+                    axis=-1, keepdims=True)            # (S, 1)
+                s = jnp.where(self_m, s_self, s)
+            s = jnp.where(col <= qpos, s * scale,
+                          jnp.finfo(jnp.float32).min)
+            m_prev = m_scr[h, :, 0:1]                  # (S, 1)
+            l_prev = l_scr[h, :, 0:1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)                     # (S, bs)
+            corr = jnp.exp(m_prev - m_new)             # (S, 1)
+            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if residual:
+                # the self column's weight multiplies the fp v_new row,
+                # not the dequantized pool row; l keeps the full p sum
+                p_self = jnp.sum(jnp.where(self_m, p, 0.0),
+                                 axis=-1, keepdims=True)   # (S, 1)
+                p = jnp.where(self_m, 0.0, p)
+            pv = lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # (S, D)
+            if residual:
+                pv = pv + p_self * vn_ref[0, h].astype(jnp.float32)
+            acc[h] = acc[h] * corr + pv
+            m_scr[h] = jnp.broadcast_to(m_new, (S, STAT_LANES))
+            l_scr[h] = jnp.broadcast_to(l_new, (S, STAT_LANES))
 
     @pl.when(j == nlive - 1)
     def _emit():
@@ -214,95 +238,140 @@ def _paged_kernel(*refs, scale: float, block_size: int,
         o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
 
 
+def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                   acc, m_scr, l_scr, *, scale: float, block_size: int,
+                   head_dim: int):
+    """``_paged_kernel`` for a single query token over an unquantized
+    pool — the decode hot path — with ALL heads in one pair of matmuls.
+
+    q_ref:  (1, H, H*D)  — the row's queries laid out block-diagonally:
+            row ``h`` holds head ``h``'s query in lanes ``[h*D, (h+1)*D)``
+            and exact zeros elsewhere (built by ``_paged_call``)
+    k_ref:  (1, bs, H*D), v_ref: idem — the pool block as stored
+    o_ref:  (1, 1, H*D)  — the heads' outputs side by side
+    scratch: acc (H, H*D) f32, m/l (H, STAT_LANES) f32
+
+    ``q @ k.T`` over all ``H*D`` lanes is head ``h``'s score in row
+    ``h`` (the other heads' lanes meet zeros), and ``p @ v`` is head
+    ``h``'s output in lanes ``[h*D, (h+1)*D)`` of row ``h``; the other
+    lanes of a row are another head's values under this head's weights
+    and are dropped at the emit.  That spends H times the arithmetic on
+    an idle MXU and saves the per-head loop: with one query row a head's
+    tiles are a sublane each, and the loop's 2*H tiny matmuls and H
+    softmax updates cost three times this step (0.88 us against the
+    0.28 us a grid step costs at all: PERF.md, PR 25).  Same visibility
+    test, same fp32 online softmax, same early-out as ``_paged_kernel``.
+    """
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    NB = pl.num_programs(1)
+    H, HD = q_ref.shape[1:]
+    bs = block_size
+    nlive = _nlive(len_ref[b], 1, bs, NB)
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    @pl.when(j < nlive)
+    def _step():
+        v = v_ref[0]                                   # (bs, H*D)
+        s = lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (H, bs)
+        # visibility: key position <= query position (= lengths[b])
+        col = j * bs + lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+        s = jnp.where(col <= len_ref[b], s * scale,
+                      jnp.finfo(jnp.float32).min)
+        m_prev = m_scr[:, 0:1]                         # (H, 1)
+        l_prev = l_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                         # (H, bs)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc[:] = acc[:] * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (H, H*D)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == nlive - 1)
+    def _emit():
+        l = l_scr[:, 0:1]
+        o = acc[:] / jnp.where(l == 0.0, 1.0, l)       # (H, H*D)
+        own = (lax.broadcasted_iota(jnp.int32, (H, HD), 1) // head_dim
+               == lax.broadcasted_iota(jnp.int32, (H, HD), 0))
+        o_ref[0] = jnp.sum(jnp.where(own, o, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+
+
 def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
-                scale: float, interpret: bool,
+                scale: float, interpret: bool, mode: str,
                 k_scale=None, v_scale=None, k_new=None, v_new=None):
     B, H, S, D = q.shape
     NB = block_table.shape[1]
-    bs = k_pool.shape[2]
-    if k_scale is None:
-        mode = "fp32"
-    elif k_scale.ndim == 4:
-        mode = "int4"                    # group scales (.., bs, G)
-    else:
-        mode = "int8"                    # row scales (.., bs)
+    bs = k_pool.shape[1]
     residual = k_new is not None
-    Dk = k_pool.shape[-1]                # D (fp/int8) or D//2 (int4)
 
     def kv_map(b, j, bt, lens):
         # clamp dead steps to the last live block: the repeated index
         # makes the Pallas pipeline skip the refetch, so padded table
         # width costs no HBM traffic
         jl = jnp.minimum(j, _nlive(lens[b], S, bs, NB) - 1)
-        return (bt[b, jl], 0, 0, 0)
-
-    def ks_map(b, j, bt, lens):
-        # the scale sibling of kv_map: same clamped block id, 3-D block
-        jl = jnp.minimum(j, _nlive(lens[b], S, bs, NB) - 1)
         return (bt[b, jl], 0, 0)
 
-    def gs_map(b, j, bt, lens):
-        # int4 group-scale sibling: same clamped block id, 4-D block
-        jl = jnp.minimum(j, _nlive(lens[b], S, bs, NB) - 1)
-        return (bt[b, jl], 0, 0, 0)
-
-    def q_map(b, j, bt, lens):
-        return (b, 0, 0, 0)
-
-    if mode == "int8":
-        # scales ride as regular streamed inputs indexed by the SAME
-        # (clamped) block id as their code block — each grid step DMAs
-        # the (1, H, bs) scale rows next to the (1, H, bs, D) codes
-        in_specs = [
-            pl.BlockSpec((1, H, S, D), q_map),
-            pl.BlockSpec((1, H, bs, D), kv_map),
-            pl.BlockSpec((1, H, bs), ks_map),
-            pl.BlockSpec((1, H, bs, D), kv_map),
-            pl.BlockSpec((1, H, bs), ks_map),
-        ]
-        operands = (q, k_pool, k_scale, v_pool, v_scale)
-    elif mode == "int4":
-        G = k_scale.shape[-1]
-        in_specs = [pl.BlockSpec((1, H, S, D), q_map)]
-        operands = [q]
-        if residual:
-            # fp residual K/V of the query tokens: q_map-indexed, so
-            # every grid step revisits the row's own (1, H, S, D) block
-            in_specs += [pl.BlockSpec((1, H, S, D), q_map),
-                         pl.BlockSpec((1, H, S, D), q_map)]
-            operands += [k_new, v_new]
-        in_specs += [
-            pl.BlockSpec((1, H, bs, Dk), kv_map),
-            pl.BlockSpec((1, H, bs, G), gs_map),
-            pl.BlockSpec((1, H, bs, Dk), kv_map),
-            pl.BlockSpec((1, H, bs, G), gs_map),
-        ]
-        operands += [k_pool, k_scale, v_pool, v_scale]
-        operands = tuple(operands)
+    lane_dense = S == 1 and mode == "fp32"
+    if lane_dense:
+        # decode over an unquantized pool: block-diagonal queries in,
+        # (1, H*D) rows out (_decode_kernel)
+        kernel = functools.partial(_decode_kernel, scale=scale,
+                                   block_size=bs, head_dim=D)
+        eye = jnp.eye(H, dtype=q.dtype)[None, :, :, None]
+        q = (q[:, :, 0, None, :] * eye).reshape(B, H, H * D)
+        q_block, out_block, lead = (1, H, H * D), (1, 1, H * D), (H,)
+        acc_shape = (H, H * D)
     else:
-        in_specs = [
-            pl.BlockSpec((1, H, S, D), q_map),
-            pl.BlockSpec((1, H, bs, D), kv_map),
-            pl.BlockSpec((1, H, bs, D), kv_map),
-        ]
-        operands = (q, k_pool, v_pool)
+        kernel = functools.partial(_paged_kernel, scale=scale,
+                                   block_size=bs, mode=mode,
+                                   residual=residual)
+        q_block = out_block = (1, H, S, D)
+        lead, acc_shape = (H, S), (H, S, D)
+
+    def row_map(b, j, bt, lens):
+        return (b,) + (0,) * (len(q_block) - 1)
+
+    in_specs = [pl.BlockSpec(q_block, row_map)]
+    operands = [q]
+    if residual:
+        # fp residual K/V of the query tokens: row_map-indexed, so every
+        # grid step revisits the row's own (1, H, S, D) block
+        in_specs += [pl.BlockSpec(q_block, row_map)] * 2
+        operands += [k_new, v_new]
+    # every pool leaf is (num_blocks, bs, lanes): codes and, where the
+    # pool is quantized, the scale rows of the SAME (clamped) block id
+    # ride in as whole (1, bs, lanes) blocks through the one index map
+    for leaf in (k_pool, k_scale, v_pool, v_scale):
+        if leaf is not None:
+            in_specs.append(pl.BlockSpec((1, bs, leaf.shape[-1]), kv_map))
+            operands.append(leaf)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, NB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, S, D), q_map),
+        out_specs=pl.BlockSpec(out_block, row_map),
         scratch_shapes=[
-            pltpu.VMEM((H, S, D), jnp.float32),
-            pltpu.VMEM((H, S, STAT_LANES), jnp.float32),
-            pltpu.VMEM((H, S, STAT_LANES), jnp.float32),
+            pltpu.VMEM(acc_shape, jnp.float32),
+            pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
+            pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                          mode=mode, residual=residual),
+    out = pl.pallas_call(
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + out_block[1:], q.dtype),
         # rows are independent; the kv-block axis carries the online
         # softmax accumulators and must run in order
         compiler_params=pltpu.CompilerParams(
@@ -310,6 +379,10 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
+    if lane_dense:
+        # (B, 1, H*D) rows back to (B, H, 1, D)
+        out = jnp.moveaxis(out.reshape(B, S, H, D), 2, 1)
+    return out
 
 
 def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
@@ -319,7 +392,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     """Fused paged attention over pool blocks — no gathered view.
 
     q:           (B, H, S, D) queries; S=1 decode, S=chunk prefill
-    k_pool:      (num_blocks, H, block_size, D) key pool (head-major,
+    k_pool:      (num_blocks, block_size, H*D) key pool (token-major,
                  ops/paged_attention.write_kv layout)
     v_pool:      idem, values
     block_table: (B, NB) int32 pool block ids, position order; entries
@@ -329,12 +402,12 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
                  [lengths[b], lengths[b] + S) and their K/V must already
                  be scattered into the pool (write_kv runs first)
     k/v_scale:   fp32 scales when the pools hold quantized codes (both
-                 or neither): 3-d ``(num_blocks, H, block_size)`` row
-                 scales = int8 codes; 4-d ``(num_blocks, H, block_size,
-                 G)`` group scales = int4 nibble-packed codes (the
-                 scale RANK discriminates, mirroring attend).  The
-                 kernel streams them beside the code blocks and
-                 dequantizes in register (see _paged_kernel)
+                 or neither): ``(num_blocks, block_size, H)`` row
+                 scales beside int8 codes; ``(num_blocks, block_size,
+                 H*G)`` group scales beside uint8 nibble-packed int4
+                 codes (the code dtype discriminates, mirroring
+                 attend).  The kernel streams them beside the code
+                 blocks and dequantizes in register (see _paged_kernel)
     k/v_new:     (B, H, S, D) fp K/V of the query tokens (int4 only,
                  both or neither) — enables the fp-residual self lane
 
@@ -357,13 +430,14 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
         raise ValueError("quantized pools need both k_scale and v_scale")
     if (k_new is None) != (v_new is None):
         raise ValueError("fp residual needs both k_new and v_new")
-    if k_new is not None and (k_scale is None or k_scale.ndim != 4):
+    mode = pool_mode(k_pool, k_scale)        # static: dtype and None-ness
+    if k_new is not None and mode != "int4":
         raise ValueError(
             "fp-residual k_new/v_new only apply to int4 (group-scaled) "
             "pools")
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     return _paged_call(q, k_pool, v_pool, block_table, lengths,
-                       scale=scale, interpret=interpret,
+                       scale=scale, interpret=interpret, mode=mode,
                        k_scale=k_scale, v_scale=v_scale,
                        k_new=k_new, v_new=v_new)
 
@@ -374,7 +448,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                            k_new=None, v_new=None):
     """Single-token decode specialization (S must be 1) — the serving
     hot path.  Thin wrapper so call sites (and probes) name the phase
-    they are on; the grid/kernel body is shared with chunked prefill."""
+    they are on; the grid is shared with chunked prefill, and so is the
+    kernel body unless the pool is unquantized (``_decode_kernel``)."""
     if q.shape[2] != 1:
         raise ValueError(f"decode takes one query token per row, got "
                          f"S={q.shape[2]} (use paged_prefill_attention)")
@@ -409,7 +484,7 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
     bucket up to ``prefill_chunk`` — the exact S set the engine
     dispatches (engine._bucket), since S changes the kernel's tile
     shapes — in the pool storage variant ``kv_dtype`` selects (for int4
-    that is nibble-packed uint8 codes + 4-d group scales + the
+    that is nibble-packed uint8 codes + group scales + the
     fp-residual k_new/v_new operands).  Grid extents B/NB vary per
     dispatch too, but only as grid bounds and scalar-table width, not
     tile shapes — the fixed B=8/NB=4 probe stands in for them.
@@ -430,15 +505,16 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     kw = {}
+    width = heads * head_dim
     if kv_dtype == "int4":
         g = min(kv_group, head_dim)
-        pool = arg((1 + B * NB, heads, bs, head_dim // 2), jnp.uint8)
-        scales = arg((1 + B * NB, heads, bs, head_dim // g), jnp.float32)
+        pool = arg((1 + B * NB, bs, width // 2), jnp.uint8)
+        scales = arg((1 + B * NB, bs, width // g), jnp.float32)
     elif kv_dtype == "int8":
-        pool = arg((1 + B * NB, heads, bs, head_dim), jnp.int8)
-        scales = arg((1 + B * NB, heads, bs), jnp.float32)
+        pool = arg((1 + B * NB, bs, width), jnp.int8)
+        scales = arg((1 + B * NB, bs, heads), jnp.float32)
     else:
-        pool = arg((1 + B * NB, heads, bs, head_dim), dt)
+        pool = arg((1 + B * NB, bs, width), dt)
         scales = None
     if scales is not None:
         kw.update(k_scale=scales, v_scale=scales)

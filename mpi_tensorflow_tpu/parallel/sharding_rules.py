@@ -43,8 +43,8 @@ DEFAULT_RULES: dict[str, Optional[str]] = {
 # of attention and MLP.  embed/vocab/pos stay replicated so after the two
 # per-layer psum points (attention out-proj, MLP down-proj) every shard
 # holds the identical residual stream and computes identical logits; the
-# paged KV pool follows ``heads`` (its axis 1), which is why a block
-# table that indexes BLOCKS, not heads, replicates cleanly.
+# paged KV pool follows ``heads`` (slices of its last axis), which is
+# why a block table that indexes BLOCKS, not heads, replicates cleanly.
 SERVING_TP_RULES: dict[str, Optional[str]] = {
     "heads": "tp",
     "mlp": "tp",

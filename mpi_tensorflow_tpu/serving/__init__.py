@@ -45,7 +45,7 @@ Six cooperating layers, host-side policy over device-side math:
                      decode is deterministic); plus the fleet journal
                      merge/replay helpers the router's failover uses.
 - ``tp``           — tensor parallelism for the engine: shard the
-                     head-major pool, QKV/O projections, and MLP over a
+                     pool (by head), QKV/O projections, and MLP over a
                      ``tp`` mesh axis via shard_map (one psum per
                      row-parallel output); block tables replicate, so
                      every host-side layer above stays tp-unaware.

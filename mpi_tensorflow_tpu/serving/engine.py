@@ -26,7 +26,7 @@ updates in place instead of ping-ponging two pool-sized buffers.
 
 Tensor parallelism (``--serve-tp N``): the jitted steps below run the
 forward through a shard_map seam (serving/tp) that partitions the
-head-major pool, QKV/O, and MLP over a ``tp`` mesh axis with one psum
+pool (by head), QKV/O, and MLP over a ``tp`` mesh axis with one psum
 per row-parallel projection.  Block tables index blocks, not heads, so
 everything host-side in this file is tp-unaware; the seam is resolved
 once at construction, so TP adds no dispatch shapes and the
@@ -198,7 +198,7 @@ class ServeConfig:
                                   # paths are the tier's keys); "off"
                                   # is byte-for-byte untiered
     tp: int = 1                   # tensor-parallel shards (--serve-tp):
-                                  # >1 partitions the head-major pool,
+                                  # >1 partitions the pool by head,
                                   # QKV/O projections, and MLP over a
                                   # ``tp`` mesh axis via shard_map
                                   # (serving/tp), psum-combining the

@@ -1,10 +1,14 @@
 """Paged KV cache: host block allocator + device pool construction.
 
-The device side is a fixed pool of ``(num_blocks, heads, block_size,
+The device side is a fixed pool of ``(num_blocks, block_size, heads *
 head_dim)`` K and V blocks per transformer layer (ops/paged_attention
-reads/writes it through per-sequence block tables; the head-major
-layout lets the fused Pallas kernel stream whole ``(H, block_size, D)``
-blocks with no transpose).  The host side —
+reads/writes it through per-sequence block tables).  Token-major and
+lane-dense is the one geometry that the runtime's default device layout
+(row-major only when the minor dimension fills the 128-lane tile), the
+K/V scatter (one contiguous row per token) and the Mosaic kernel's
+operand (row-major) all take as it is stored, so no serving program
+copies a pool leaf.  Every leaf, scale siblings included, has the block
+id on axis 0 and the token slot on axis 1.  The host side —
 this module — owns WHICH block belongs to WHOM: a refcounted free-list
 allocator whose accounting the scheduler's admit/evict decisions hang
 off.
@@ -131,9 +135,8 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
     ``src``/``dst``/``n`` are TRACED int32 scalars — the caller jits
     this once (the ``_cow_fn`` discipline) and every (src, dst, n)
     triple reuses that one executable; ``n == 0`` with ``src == dst``
-    is the no-op pre-warm dispatch.  The row mask broadcasts over the
-    4-d code leaves AND the 3-d int8 scale siblings (slot axis is axis
-    1 of ``leaf[src]`` either way), so quantized pools copy codes and
+    is the no-op pre-warm dispatch.  The slot axis is axis 1 of every
+    leaf (axis 0 of ``leaf[src]``), so quantized pools copy codes and
     scales together.
     """
     import jax.numpy as jnp
@@ -142,8 +145,8 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
     for p in pools:
         layer = {}
         for key, leaf in p.items():
-            rows = jnp.arange(leaf.shape[2]) < n
-            mask = rows.reshape((-1,) + (1,) * (leaf.ndim - 3))
+            rows = jnp.arange(leaf.shape[1]) < n
+            mask = rows.reshape((-1,) + (1,) * (leaf.ndim - 2))
             layer[key] = leaf.at[dst].set(
                 jnp.where(mask, leaf[src], leaf[dst]))
         out.append(layer)
@@ -161,21 +164,22 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     - "fp32": blocks in the model compute dtype — byte-for-byte the
       pre-quantization pool (the parity reference);
     - "int8": blocks hold int8 codes, and each layer dict gains sibling
-      ``{"k_scale", "v_scale"}`` arrays of shape ``(num_blocks, heads,
-      block_size)`` fp32 — one symmetric-absmax scale per (block, head,
-      token-slot) row (ops/paged_attention.quantize_kv).  The scale
-      arrays share the pool's first two axes, so block-table indexing,
-      copy-on-write, and TP head-sharding treat them exactly like the
-      code arrays.
+      ``{"k_scale", "v_scale"}`` arrays of shape ``(num_blocks,
+      block_size, heads)`` fp32 — one symmetric-absmax scale per (block,
+      token-slot, head) row (ops/paged_attention.quantize_kv).  The
+      scale arrays share the pool's first two axes and keep the heads in
+      order on the last, so block-table indexing, copy-on-write, and TP
+      head-sharding treat them exactly like the code arrays.
     - "int4": blocks hold nibble-packed uint8 codes of shape
-      ``(num_blocks, heads, block_size, head_dim // 2)`` — two codes
-      per byte (ops/paged_attention.pack_int4) — and the scale siblings
-      grow a trailing group axis: ``(num_blocks, heads, block_size,
-      head_dim // g)`` fp32 with ``g = min(kv_group, head_dim)`` (the
-      --serve-kv-group knob, clamped so the default 32 stays valid on
-      tiny heads; ``g`` must divide head_dim).  The 4-d scale rank is
-      what the consume paths discriminate int4 on — no new leaf keys,
-      so CoW/partial-copy/TP/journal stay dtype-agnostic.
+      ``(num_blocks, block_size, heads * head_dim // 2)`` — two codes
+      per byte within a head (ops/paged_attention.pack_int4) — and the
+      scale siblings hold a head's groups side by side:
+      ``(num_blocks, block_size, heads * head_dim // g)`` fp32 with
+      ``g = min(kv_group, head_dim)`` (the --serve-kv-group knob,
+      clamped so the default 32 stays valid on tiny heads; ``g`` must
+      divide head_dim).  The uint8 code dtype is what the consume paths
+      discriminate int4 on (ops/paged_attention.pool_mode) — no new leaf
+      keys, so CoW/partial-copy/TP/journal stay dtype-agnostic.
     """
     import jax.numpy as jnp
 
@@ -185,14 +189,15 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     # every leaf is its OWN buffer: the engine donates the pools into
     # each step, and one zeros array shared between k and v (or across
     # layers) would be donated twice in a single Execute()
-    code_shape = (num_blocks, cfg.heads, block_size, cfg.head_dim)
+    width = cfg.heads * cfg.head_dim
+    code_shape = (num_blocks, block_size, width)
     if kv_dtype == "fp32":
         return [{"k": jnp.zeros(code_shape, cfg.dtype),
                  "v": jnp.zeros(code_shape, cfg.dtype)}
                 for _ in range(cfg.layers)]
     if kv_dtype == "int8":
         code_dt = jnp.int8
-        scale_shape = code_shape[:3]
+        scale_shape = code_shape[:2] + (cfg.heads,)
     else:
         g = min(kv_group, cfg.head_dim)
         if cfg.head_dim % 2 or g < 1 or cfg.head_dim % g:
@@ -201,8 +206,8 @@ def init_pools(cfg, num_blocks: int, block_size: int,
                 f"effective group min(kv_group, head_dim); got "
                 f"head_dim={cfg.head_dim}, kv_group={kv_group}")
         code_dt = jnp.uint8
-        code_shape = code_shape[:3] + (cfg.head_dim // 2,)
-        scale_shape = code_shape[:3] + (cfg.head_dim // g,)
+        code_shape = code_shape[:2] + (width // 2,)
+        scale_shape = code_shape[:2] + (width // g,)
     return [{"k": jnp.zeros(code_shape, code_dt),
              "v": jnp.zeros(code_shape, code_dt),
              "k_scale": jnp.zeros(scale_shape, jnp.float32),

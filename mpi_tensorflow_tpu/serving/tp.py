@@ -2,13 +2,15 @@
 mesh axis.
 
 The serving engine's device state is one paged KV pool per layer,
-head-major ``(num_blocks, H, block_size, D)``.  Heads are embarrassingly
+token-major ``(num_blocks, block_size, H*D)`` with the heads side by
+side on the last axis.  Heads are embarrassingly
 parallel through attention (every head attends independently; the only
 cross-head contractions are the row-parallel output projections), so the
 Megatron split carries over to serving unchanged:
 
-- the POOL shards on its head axis (axis 1): each of the ``tp`` shards
-  holds ``H / tp`` heads of every block — aggregate KV capacity in
+- the POOL shards on its last axis, whose contiguous ``tp``-ths are
+  whole heads: each of the ``tp`` shards holds ``H / tp`` heads of
+  every block — aggregate KV capacity in
   tokens is unchanged per pool, but the HBM for it is spread over the
   mesh, and (the point) per-chip attention/projection work drops
   ``tp``-fold;
@@ -53,6 +55,10 @@ from mpi_tensorflow_tpu.parallel import sharding_rules as rules_lib
 #: the mesh axis name the serving TP split lives on
 TP_AXIS = "tp"
 
+#: every pool leaf is (num_blocks, block_size, heads * width): heads
+#: shard as contiguous slices of the last axis
+_POOL_SPEC = P(None, None, TP_AXIS)
+
 
 def _check_device_count(tp: int) -> None:
     """THE device-count rule, shared by ``check_geometry`` and
@@ -75,7 +81,7 @@ def check_geometry(cfg, tp: int) -> None:
     if cfg.heads % tp or cfg.mlp % tp:
         raise ValueError(
             f"--serve-tp {tp} must divide both heads ({cfg.heads}) and "
-            f"mlp ({cfg.mlp}): the pool shards on the head axis and the "
+            f"mlp ({cfg.mlp}): the pool shards by head and the "
             f"MLP up-projection on its hidden axis")
 
 
@@ -95,17 +101,16 @@ def param_specs(model, mesh: Mesh):
 
 
 def pool_specs(layers: int, kv_dtype: str = "fp32"):
-    """PartitionSpec pytree for the per-layer K/V pools: the head axis
-    (axis 1 of ``(num_blocks, H, block_size, D)``) over ``tp``.  A
-    quantized pool's scale siblings — ``(num_blocks, H, block_size)``
-    int8 row scales or ``(num_blocks, H, block_size, G)`` int4 group
-    scales — carry heads on the SAME axis 1, so one spec serves every
-    leaf (int4's packed-code D//2 axis is unsharded, like D)."""
-    s = P(None, TP_AXIS)
-    if kv_dtype in ("int8", "int4"):
-        return [{"k": s, "v": s, "k_scale": s, "v_scale": s}
-                for _ in range(layers)]
-    return [{"k": s, "v": s} for _ in range(layers)]
+    """PartitionSpec pytree for the per-layer K/V pools: the last axis
+    of ``(num_blocks, block_size, H*D)`` over ``tp``, a contiguous
+    ``H/tp`` heads to a shard.  A quantized pool's scale siblings —
+    ``(num_blocks, block_size, H)`` int8 row scales or ``(num_blocks,
+    block_size, H*G)`` int4 group scales — and int4's packed
+    ``H*D//2`` codes lay the heads out in the same order on the same
+    axis, so one spec serves every leaf."""
+    keys = ("k", "v") + (("k_scale", "v_scale")
+                         if kv_dtype in ("int8", "int4") else ())
+    return [dict.fromkeys(keys, _POOL_SPEC) for _ in range(layers)]
 
 
 def shard_params(model, params, mesh: Mesh):
@@ -116,9 +121,11 @@ def shard_params(model, params, mesh: Mesh):
 
 def shard_pools(pools, mesh: Mesh):
     """Place freshly initialized (host-built) pools onto the mesh,
-    head-axis sharded — generic over the layer dict's leaves (codes and,
-    under int8, their scale siblings all put heads on axis 1)."""
-    s = NamedSharding(mesh, P(None, TP_AXIS))
+    head-sharded on the last axis — generic over the layer dict's leaves
+    (codes and their scale siblings all lay the heads side by side
+    there).  The pool is token-major so that no serving program re-lays
+    it (serving/paged_cache); a shard's slice is still whole heads."""
+    s = NamedSharding(mesh, _POOL_SPEC)
     return [{key: jax.device_put(leaf, s) for key, leaf in p.items()}
             for p in pools]
 
@@ -126,7 +133,7 @@ def shard_pools(pools, mesh: Mesh):
 def make_paged_forward(model, mesh: Mesh, kernel: str,
                        kv_dtype: str = "fp32"):
     """The shard_map-wrapped ``forward_paged``: params and pools enter
-    pre-sharded (heads/mlp/pool-head-axis over ``tp``), tokens / block
+    pre-sharded (heads/mlp/the pool's last axis over ``tp``), tokens / block
     tables / lengths / valid masks replicated.  Each shard runs the full
     per-layer math over its local heads with ``lax.psum`` over ``tp`` as
     the row-parallel reduce hook, so the returned logits are replicated

@@ -17,6 +17,7 @@ topology (libtpu, no chip).  Numerics on the chip are
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +36,8 @@ ROPE = dataclasses.replace(TINY, pos_kind="rope")
 
 
 def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0):
-    """One randomized kernel-vs-XLA input set.
+    """One randomized kernel-vs-XLA input set, the pools in the stored
+    geometry ``(nblocks, bs, H*D)``.
 
     Rows cycle through the interesting populations: full table, ragged
     partial table (null-block tail), and — when B allows — a bucket-
@@ -46,8 +48,8 @@ def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0):
     diff.
     """
     nblocks = 1 + B * NB
-    k_pool = rng.normal(size=(nblocks, H, bs, D)).astype(np.float32)
-    v_pool = rng.normal(size=(nblocks, H, bs, D)).astype(np.float32)
+    k_pool = rng.normal(size=(nblocks, bs, H * D)).astype(np.float32)
+    v_pool = rng.normal(size=(nblocks, bs, H * D)).astype(np.float32)
     bt = np.zeros((B, NB), np.int32)
     lengths = np.zeros((B,), np.int32)
     nxt = 1
@@ -71,8 +73,8 @@ def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0):
                 base = j * bs
                 for o in range(bs):
                     if base + o >= lengths[b] + S:
-                        k_pool[bt[b, j], :, o] = poison
-                        v_pool[bt[b, j], :, o] = poison
+                        k_pool[bt[b, j], o] = poison
+                        v_pool[bt[b, j], o] = poison
     q = rng.normal(size=(B, H, S, D)).astype(np.float32)
     return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(bt), jnp.asarray(lengths))
@@ -144,12 +146,13 @@ class TestKernelParity:
         got = np.asarray(pk.paged_attention_kernel(q, kp, vp, bt, lens,
                                                    interpret=True))
         kp, vp, bt, lens = map(np.asarray, (kp, vp, bt, lens))
+        # a slot's row apart by head: (nblocks, bs, H, D)
+        kp, vp = (x.reshape(x.shape[:2] + (H, D)) for x in (kp, vp))
         for b in range(B):
             L = int(lens[b]) + 1
-            ks = np.concatenate([kp[bt[b, j]] for j in range(NB)],
-                                axis=1)[:, :L]          # (H, L, D)
-            vs = np.concatenate([vp[bt[b, j]] for j in range(NB)],
-                                axis=1)[:, :L]
+            ks = np.concatenate([kp[bt[b, j]] for j in range(NB)])[:L]
+            vs = np.concatenate([vp[bt[b, j]] for j in range(NB)])[:L]
+            ks, vs = ks.swapaxes(0, 1), vs.swapaxes(0, 1)   # (H, L, D)
             s = np.einsum("hd,hld->hl", np.asarray(q)[b, :, 0], ks)
             s = s * (D ** -0.5)
             p = np.exp(s - s.max(-1, keepdims=True))
@@ -157,6 +160,56 @@ class TestKernelParity:
             ref = np.einsum("hl,hld->hd", p, vs)
             np.testing.assert_allclose(got[b, :, 0], ref,
                                        rtol=2e-5, atol=2e-5)
+
+
+class TestPoolGeometry:
+    """The stored geometry itself: ``(num_blocks, block_size, H*D)``
+    token-major rows with the heads side by side, block id on axis 0 and
+    slot on axis 1 of every leaf."""
+
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8", "int4"])
+    def test_write_then_gather_returns_rows_in_position_order(
+            self, kv_dtype):
+        """A sequence written through ``write_kv*`` into blocks handed
+        out in scrambled order, its length not a multiple of the block
+        size, reads back through ``gather_kv`` head by head in position
+        order — bit for bit what the storage variant keeps of it."""
+        rng = np.random.default_rng(11)
+        cfg = dataclasses.replace(TINY, hidden=24, heads=3)    # D = 8
+        H, D, bs, L = cfg.heads, cfg.head_dim, 4, 10
+        kv = jnp.asarray(rng.normal(size=(2, H, L, D)).astype(np.float32))
+        bt = jnp.asarray([[5, 2, 7], [1, 6, 3]], jnp.int32)
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (2, L))
+        valid = jnp.ones((2, L), bool)
+        leaf = init_pools(cfg, 8, bs, kv_dtype, kv_group=4)[0]
+        assert leaf["k"].shape[:2] == (8, bs)
+        assert all(x.shape[:2] == (8, bs) for x in leaf.values())
+        if kv_dtype == "fp32":
+            assert leaf["k"].shape == (8, bs, H * D)
+            pool = paged_ops.write_kv(leaf["k"], kv, bt, pos, valid)
+            got = paged_ops.gather_kv(pool, bt, H)
+            want = kv
+        else:
+            write, quant, dequant = {
+                "int8": (paged_ops.write_kv_quant, paged_ops.quantize_kv,
+                         paged_ops.dequantize_kv),
+                "int4": (paged_ops.write_kv_quant_int4,
+                         functools.partial(paged_ops.quantize_kv_int4,
+                                           group=4),
+                         paged_ops.dequantize_kv_int4)}[kv_dtype]
+            pool, scale = write(leaf["k"], leaf["k_scale"], kv, bt, pos,
+                                valid)
+            assert paged_ops.pool_mode(pool, scale) == kv_dtype
+            got = paged_ops._gather_kv_dequant(pool, scale, bt, H,
+                                               jnp.float32)
+            want = dequant(*quant(kv), jnp.float32)
+        assert got.shape == (2, H, 3 * bs, D)
+        np.testing.assert_array_equal(np.asarray(got)[:, :, :L],
+                                      np.asarray(want))
+        # the slots past the length were never written
+        assert not np.asarray(got)[:, :, L:].any()
+        # and nothing landed in the null block
+        assert not np.asarray(pool)[0].any()
 
 
 # ------------------------------------------------------- dispatch seam
@@ -366,13 +419,19 @@ class TestNoMaterializedGather:
 
 # ------------------------------------------------- int8 quantization
 
+def _by_head(quantize, pool, H=2):
+    """Quantize a whole stored fp32 pool head by head — every
+    ``quantize_kv*`` takes its rows along the last axis, whatever leads
+    — and lay the codes and the scales out as the pool stores them:
+    a slot's heads side by side."""
+    rows = pool.reshape(pool.shape[:2] + (H, -1))
+    return tuple(x.reshape(x.shape[:2] + (-1,)) for x in quantize(rows))
+
+
 def _quantize_pools(kp, vp):
-    """Quantize whole fp32 pools to (codes, scales) pairs — the pool
-    layout ``(nblocks, H, bs, D)`` is row-compatible with
-    ``quantize_kv``'s ``(B, H, S, D)`` contract (amax over D)."""
-    kc, ks = paged_ops.quantize_kv(kp)
-    vc, vs = paged_ops.quantize_kv(vp)
-    return kc, ks, vc, vs
+    """Whole fp32 pools to int8 (codes, scales) pairs."""
+    return (_by_head(paged_ops.quantize_kv, kp)
+            + _by_head(paged_ops.quantize_kv, vp))
 
 
 class TestInt8Quantization:
@@ -424,8 +483,8 @@ class TestInt8Quantization:
         bt = jnp.asarray([[1, 2]], jnp.int32)
 
         def fresh():
-            return (jnp.zeros((3, H, bs, D), jnp.int8),
-                    jnp.zeros((3, H, bs), jnp.float32))
+            return (jnp.zeros((3, bs, H * D), jnp.int8),
+                    jnp.zeros((3, bs, H), jnp.float32))
 
         pool_a, scale_a = fresh()
         pos = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -596,9 +655,8 @@ def _quantize_pools_int4(kp, vp, group=4):
     """Quantize whole fp32 pools to int4 (packed codes, group scales)
     pairs; group=4 over the test D=8 gives two scale groups per row, so
     the group axis actually exercises multi-group dequantization."""
-    kc, ks = paged_ops.quantize_kv_int4(kp, group)
-    vc, vs = paged_ops.quantize_kv_int4(vp, group)
-    return kc, ks, vc, vs
+    quantize = functools.partial(paged_ops.quantize_kv_int4, group=group)
+    return _by_head(quantize, kp) + _by_head(quantize, vp)
 
 
 class TestInt4Quantization:
@@ -656,8 +714,8 @@ class TestInt4Quantization:
         bt = jnp.asarray([[1, 2]], jnp.int32)
 
         def fresh():
-            return (jnp.zeros((3, H, bs, D // 2), jnp.uint8),
-                    jnp.zeros((3, H, bs, G), jnp.float32))
+            return (jnp.zeros((3, bs, H * D // 2), jnp.uint8),
+                    jnp.zeros((3, bs, H * G), jnp.float32))
 
         pool_a, scale_a = fresh()
         pos = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -913,3 +971,90 @@ class TestMosaicCompile:
         pk.probe_compile.cache_clear()
         pk.probe_compile("bfloat16", 6, 64, 16, 64, "fp32", 32,
                          sharding=tpu_topology_device)
+
+
+class TestNoPoolSizedCopy:
+    """The structural witness of the pool geometry: the engine's decode
+    program and its 64-token prefill program — the real ``forward_paged``
+    at gpt_base widths over the benchmark cell's 8,193-block pool,
+    donated, two layers (the copies were per leaf) — compiled for the
+    deviceless v5e hold no ``copy``/``transpose`` of a pool-sized
+    operand, keep every pool leaf at its unpadded size, and need less
+    scratch than one leaf.  The head-major ``(num_blocks, H, bs, D)``
+    pool failed all three: its default layout put ``num_blocks``
+    minor-most, the scatter and the Mosaic call each wanted another, and
+    every program copied every leaf three times (PERF.md, PR 25)."""
+
+    NUM_BLOCKS, BLOCK, SLOTS, TABLE, CHUNK = 8193, 16, 128, 64, 64
+
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8", "int4"])
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_serving_program_takes_the_pool_in_place(
+            self, tpu_topology_device, program, kv_dtype):
+        import re
+        import types
+
+        dev = tpu_topology_device
+        cfg = bert.BertConfig(vocab_size=50257, hidden=768, layers=2,
+                              heads=12, mlp=3072, max_positions=1024,
+                              dropout=0.0, dtype=jnp.bfloat16)
+        model = gpt.CausalLm(cfg)
+
+        def on_chip(tree, dtype=None):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, dtype or x.dtype, sharding=dev), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+        params = on_chip(jax.eval_shape(model.init, jax.random.key(0)),
+                         jnp.bfloat16)
+        pools = on_chip(jax.eval_shape(lambda: init_pools(
+            cfg, self.NUM_BLOCKS, self.BLOCK, kv_dtype)))
+        # the engine's own step bodies over the engine's own forward seam
+        engine = types.SimpleNamespace(
+            _paged_forward=lambda p, tokens, pools, tables, lengths, valid:
+            model.forward_paged(p, tokens, pools, tables, lengths,
+                                valid=valid, kernel=paged_ops.PALLAS))
+        if program == "decode":
+            impl = PagedDecodeEngine._decode_impl
+            rest = (ints(self.SLOTS), ints(self.SLOTS),
+                    ints(self.SLOTS, self.TABLE))
+        else:
+            impl = PagedDecodeEngine._prefill_impl
+            rest = (ints(1, self.CHUNK), ints(), ints(),
+                    ints(1, self.TABLE))
+        compiled = jax.jit(
+            lambda params, pools, *a: impl(engine, params, pools, *a),
+            donate_argnums=(1,)).lower(params, pools, *rest).compile()
+
+        # every instruction of the optimised HLO, fused bodies included,
+        # by the dims of what it makes
+        made = {}
+        for dims, op in re.findall(
+                r"= \w+\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(",
+                compiled.as_text()):
+            made.setdefault(tuple(map(int, dims.split(","))) if dims
+                            else (), set()).add(op)
+        codes = tuple(pools[0]["k"].shape)
+        # fp pool: every leaf; quantized: the code leaves (their 6-12 MB
+        # scale siblings still get a rotated default layout and a small
+        # re-layout each; no cell serves a quantized pool yet)
+        assert "scatter" in made[codes], made[codes]     # the probe sees
+        assert not made[codes] & {"copy", "transpose"}, made[codes]
+        assert compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"') == cfg.layers
+        for layer in compiled.input_formats[0][1]:
+            for key in ("k", "v"):
+                assert tuple(layer[key].layout.major_to_minor) \
+                    == (0, 1, 2), (key, layer[key])
+        mem = compiled.memory_analysis()
+        logical = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree.leaves(pools))
+        leaf_bytes = int(np.prod(codes)) * pools[0]["k"].dtype.itemsize
+        # donated in, aliased out, at the unpadded size
+        assert logical <= mem.alias_size_in_bytes <= 1.01 * logical
+        if kv_dtype == "fp32":
+            assert leaf_bytes == 8193 * 16 * 768 * 2        # 201.4 MB
+            assert mem.alias_size_in_bytes - logical < 4096
+            assert mem.temp_size_in_bytes < leaf_bytes

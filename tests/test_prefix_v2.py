@@ -121,7 +121,7 @@ class TestMatchPartial:
 # ------------------------------------------------- partial-copy device op
 
 class TestPartialCopyOp:
-    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8", "int4"])
     def test_copies_leading_rows_only(self, kv_dtype):
         import jax.numpy as jnp
 
@@ -136,8 +136,8 @@ class TestPartialCopyOp:
         for p in out:
             for k, v in p.items():
                 arr = np.asarray(v, np.float32)
-                assert (arr[5, :, :3] == 1).all(), k   # copied rows
-                assert (arr[5, :, 3:] == 2).all(), k   # untouched tail
+                assert (arr[5, :3] == 1).all(), k      # copied rows
+                assert (arr[5, 3:] == 2).all(), k      # untouched tail
                 assert (arr[2] == 1).all(), k          # src intact
                 assert (arr[1] == 0).all(), k          # bystander
 

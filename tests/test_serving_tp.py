@@ -63,14 +63,15 @@ class TestTpGeometry:
             ServeConfig(**BASE, tp=0)
 
     def test_pools_and_params_shard_on_declared_axes(self):
-        """The pool shards on its head axis; a head-sharded weight
+        """The pool shards its heads, contiguous slices of the last
+        axis of ``(num_blocks, block_size, H*D)``; a head-sharded weight
         (wq) splits, a replicated one (tok_emb) does not."""
         from jax.sharding import PartitionSpec as P
 
         model, params = _model()
         engine = PagedDecodeEngine(model, params,
                                    ServeConfig(**BASE, tp=2))
-        assert engine.pools[0]["k"].sharding.spec == P(None, "tp")
+        assert engine.pools[0]["k"].sharding.spec == P(None, None, "tp")
         wq = engine.params["layers"][0]["wq"]
         assert wq.sharding.spec == P(None, "tp")       # (embed, heads, D)
         assert engine.params["tok_emb"].sharding.spec == P()
