@@ -1,6 +1,9 @@
 """Drives a serving cell: ``EngineLoop.submit`` / ``EngineLoop.iterate``
 over a ``PagedDecodeEngine`` — admission, chunked prefill, decode through
-``CausalLm.forward_paged`` and the paged-attention kernel, the LM head.
+the model's paged forward and its kernels, the LM head.  What belongs to
+one model (sizes, the program's model object, weights, flops, cache bytes,
+the plain reference) sits in ``models/<program.model>.py``; the window,
+the counting, the sample and the check below are every serving cell's.
 
 Traffic is a closed loop (``traffic.closed_loop_request``): every client
 sends its next request when its last one reached a terminal status.  The
@@ -13,17 +16,25 @@ Once the window has closed and the engine is freed, the plain reference
 runs a sample of the finished requests (drawn from the seed, the longest
 among them) and ``check.served_gap`` reads how far a served token lies
 below the reference's best.
+
+``counts`` in the result line says what a window held (iterations,
+dispatches, requests, host time in ``iterate``, iterations that took over
+twice the median and the time they lost, the interpreter's collections),
+so that two runs can be told apart: equal counts per iteration and other
+times is the machine (a pace, or with ``stalls`` a pause), other counts is
+the cut of the window.  ``--sub-windows 20,40``
+reads the same for the windows of those lengths that start where the
+run's own does (``benchmarks/spread.py --sub``).
 """
 
 from __future__ import annotations
 
+import gc
 import time
 
 import numpy as np
 
-from . import check, flops, traffic
-from ..reference import causal_lm as ref_lm
-from ..reference import transformer as ref_tf
+from . import check, models, traffic
 
 DRAIN_LIMIT_S = 60.0
 SEQ_BUCKET = 256
@@ -34,29 +45,22 @@ class ServeCell:
         import jax
         import jax.numpy as jnp
 
-        from mpi_tensorflow_tpu.models import bert, gpt
         from mpi_tensorflow_tpu.serving import (PagedDecodeEngine,
                                                 ServeConfig)
 
         cfg, mix = cell["config_data"], cell["traffic_data"]
         prog = cfg["program"]
-        if prog["model"] != "causal_lm":
-            raise ValueError(f"serve driver has no model {prog['model']!r}")
+        self.kind = kind = models.lookup(prog["model"])
         if mix["kind"] != "closed_loop":
             raise ValueError(f"serve driver has no traffic kind "
                              f"{mix['kind']!r}")
-        self.sz = ref_tf.sizes(cfg)
+        self.sz = sz = kind.sizes(cfg)
         self.mix, self.seed = mix, int(seed)
         dt = jnp.dtype(prog["compute_dtype"])
         pdt = jnp.dtype(prog["param_dtype"])
-        bcfg = bert.BertConfig(
-            vocab_size=self.sz["vocab"], hidden=self.sz["hidden"],
-            layers=self.sz["layers"], heads=self.sz["heads"],
-            mlp=self.sz["mlp"], max_positions=self.sz["positions"],
-            dropout=0.0, dtype=dt)
-        self.model = gpt.CausalLm(bcfg)
+        self.model = kind.build(sz, dt)
         self.make_params = jax.jit(lambda key: jax.tree.map(
-            lambda x: x.astype(pdt), ref_tf.init_params(self.sz, key)))
+            lambda x: x.astype(pdt), kind.init_params(sz, key)))
         params = self.make_params(jax.random.key(self.seed))
         check.require_weight_tree(self.model, params)
         eng = dict(mix["engine"])
@@ -64,7 +68,23 @@ class ServeCell:
         self.serve = ServeConfig(trace="on" if traced else "off", **eng)
         self.engine = PagedDecodeEngine(self.model, params, self.serve)
         self.kv_bytes = dt.itemsize
+        prefill = self.engine._prefill_fn
+
+        def counted_prefill(*a):
+            self.prefill_calls.append(self.now())
+            return prefill(*a)
+        self.engine._prefill_fn = counted_prefill
+        self.gc_pauses: list = []        # (start, seconds, generation)
+        gc.callbacks.append(self._on_gc)
         self._new_loop()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """Times the interpreter's collections (it changes none)."""
+        if phase == "start":
+            self._gc_t = self.now()
+        else:
+            self.gc_pauses.append((self._gc_t, self.now() - self._gc_t,
+                                   info["generation"]))
 
     def _new_loop(self) -> None:
         from mpi_tensorflow_tpu.serving import EngineLoop
@@ -75,6 +95,8 @@ class ServeCell:
         self.done = 0
         self._failed_seen = 0
         self.decode_calls: list = []     # (time, rows) per decode dispatch
+        self.iter_ends: list = []        # time each iteration returned
+        self.prefill_calls: list = []    # time of each prefill dispatch
         self.t0 = time.perf_counter()
 
     def reseed(self, seed: int) -> None:
@@ -86,6 +108,11 @@ class ServeCell:
         self.engine.params = self.make_params(jax.random.key(self.seed))
         self.engine.reset()
         self._new_loop()
+
+    def free(self) -> None:
+        """Drop the engine (pool, weights) before the reference runs."""
+        self.loop = self.engine = None
+        gc.callbacks.remove(self._on_gc)
 
     def now(self) -> float:
         return time.perf_counter() - self.t0
@@ -136,6 +163,7 @@ class ServeCell:
             emitted = self.loop.iterate(self.now(), time.perf_counter,
                                         self.t0)
         t = self.now()
+        self.iter_ends.append(t)
         ended = []
         n_first = 0
         for rid, tok in emitted:
@@ -167,17 +195,18 @@ def _p95(values) -> float:
     return float(np.percentile(np.asarray(values, np.float64), 95))
 
 
-def _prefill_wait(tracer, records, lo, hi) -> list:
-    """Submit to the first prefill chunk, per request submitted in the
-    window, from the program's ``EngineTracer`` spans."""
+def _prefill_wait(tracer, lo, hi) -> list:
+    """Submit to the first prefill chunk, per request whose first chunk
+    fell inside [lo, hi), from the program's ``EngineTracer`` spans.  In a
+    steady loop the chunks that land in a window sample the queue fairly;
+    a request still waiting at ``hi`` is charged nothing of what follows
+    the close (stopping the profiler there takes many seconds)."""
     out = []
-    for rid, sp in tracer.spans.items():
-        rec = records.get(rid)
-        if rec is None or not (lo <= rec["submit"] < hi):
-            continue
-        first = [t for t, name in sp.events if name == "prefill_chunk"]
-        if first:
-            out.append(first[0] - sp.arrive)
+    for sp in tracer.spans.values():
+        first = next((t for t, name in sp.events if name == "prefill_chunk"),
+                     None)
+        if first is not None and lo <= first < hi:
+            out.append(first - sp.arrive)
     return out
 
 
@@ -197,14 +226,60 @@ def _window_work(sc: ServeCell, lo: float, hi: float) -> dict:
         tokens += len(idx)
         P = len(rec["prompt"])
         with_prompt = idx[0] == 0
-        fl += flops.serve_request_flops(sc.sz, P, idx[0], idx[-1],
-                                        with_prompt)
+        fl += sc.kind.request_flops(sc.sz, P, idx[0], idx[-1], with_prompt)
         ctx = [P + j for j in idx if j > 0]
         if with_prompt:
             ctx += [min(a + chunk, P) for a in range(0, P, chunk)]
-        kv += flops.paged_attention_bytes(sc.sz, ctx, sc.kv_bytes)
+        kv += sc.kind.cache_bytes(sc.sz, ctx, sc.kv_bytes)
         gaps += [tm[j] - tm[j - 1] for j in idx if j > 0]
     return {"tokens": tokens, "flops": fl, "paged_bytes": kv, "gaps": gaps}
+
+
+def window_numbers(sc: ServeCell, spans, lo: float, hi: float) -> tuple:
+    """``(work, end-to-end metrics, counts)`` of the window [lo, hi), read
+    once the loop has run past ``hi`` and the first tokens are in."""
+    work = _window_work(sc, lo, hi)
+    mine = [r for r in sc.records.values() if lo <= r["submit"] < hi]
+    ttft = [r["times"][0] - r["submit"] for r in mine if r["tokens"]]
+    rows = work["decode_rows"] = [n for t, n in sc.decode_calls
+                                  if lo <= t < hi]
+    work["window_s"] = hi - lo
+    iterate = np.asarray(spans.durations("serve_iterate", sc.t0 + lo,
+                                         sc.t0 + hi))
+    typical = float(np.median(iterate)) if len(iterate) else float("nan")
+    stalled = iterate[iterate > 2 * typical]     # a pause, not a pace
+    e2e = {
+        "serve_tokens_per_s": work["tokens"] / (hi - lo),
+        "ttft_p95_ms": 1e3 * _p95(ttft) if ttft else float("nan"),
+        "token_gap_p95_ms": (1e3 * _p95(work["gaps"])
+                             if work["gaps"] else float("nan"))}
+    counts = {
+        "seed": sc.seed, "window_s": hi - lo, "tokens": work["tokens"],
+        "iterations": sum(1 for t in sc.iter_ends if lo <= t < hi),
+        "iterate_span_s": float(iterate.sum()),
+        "iterate_p50_ms": 1e3 * typical,
+        "iterate_max_ms": 1e3 * float(iterate.max()) if len(iterate)
+        else None,
+        "stalls": len(stalled),
+        "stall_s": float((stalled - typical).sum()),
+        "decode_dispatches": len(rows),
+        "decode_rows_mean": float(np.mean(rows)) if rows else None,
+        "prefill_chunks": sum(1 for t in sc.prefill_calls if lo <= t < hi),
+        "gc_pause_s": float(sum(d for t, d, g in sc.gc_pauses
+                                if lo <= t < hi)),
+        "gc_full_collections": sum(1 for t, d, g in sc.gc_pauses
+                                   if lo <= t < hi and g == 2),
+        "submitted": len(mine),
+        "finished": len(finished_in(sc, lo, hi)), **e2e}
+    return work, e2e, counts
+
+
+def sub_window_end(sc: ServeCell, lo: float, seconds: float) -> float:
+    """Where a window of ``seconds`` that opened at ``lo`` would have
+    closed: just past the first iteration that returned at or after
+    ``lo + seconds``."""
+    return float(np.nextafter(
+        next(t for t in sc.iter_ends if t - lo >= seconds), np.inf))
 
 
 def closed_loop(sc: ServeCell, spans, seconds: float, tracer, compiles,
@@ -263,46 +338,37 @@ def run(cell: dict, devices, args, clock) -> dict:
             seconds = min(seconds, float(mix["trace_seconds"]))
         lo, hi, mine, compiles, setup_s = closed_loop(
             sc, spans, seconds, clock.tracer, clock.compiles, setup_done)
-        window_s = hi - lo
         clock.phase("ramp, window, wait for first tokens")
         device = clock.describe(devices)
 
-        work = _window_work(sc, lo, hi)
-        ttft = [r["times"][0] - r["submit"] for r in mine if r["tokens"]]
+        work, end_to_end, counts = window_numbers(sc, spans, lo, hi)
+        for sub in args.sub_windows:
+            if sub < seconds:
+                counts[f"sub_{sub:g}"] = window_numbers(
+                    sc, spans, lo, sub_window_end(sc, lo, sub))[2]
         failed = sum(1 for r in mine if not r["tokens"]
                      or r["status"] not in (None, "ok"))
-        counters = {
-            "compiles_in_window": compiles,
-            "decode_rows": [n for t, n in sc.decode_calls if lo <= t < hi],
-            "dispatch_shapes": sorted(sc.engine.dispatch_shapes),
-            "kernel": sc.engine.kernel,
-        }
-        if sc.engine.tracer is not None:
-            counters["prefill_wait_s"] = _prefill_wait(
-                sc.engine.tracer, sc.records, lo, hi)
         finished = finished_in(sc, lo, hi)
-        make_params, seed, t0 = sc.make_params, sc.seed, sc.t0
-        # free the engine (pool, weights) before the reference runs
-        sc.loop = sc.engine = None
+        work.update({
+            "chips": 1, "compiles_in_window": compiles,
+            "requests_finished": len(finished),
+            "dispatch_shapes": sorted(sc.engine.dispatch_shapes),
+            "kernel": sc.engine.kernel})
+        if sc.engine.tracer is not None:
+            work["prefill_wait_s"] = _prefill_wait(sc.engine.tracer, lo, hi)
+        kind, make_params, seed, t0 = sc.kind, sc.make_params, sc.seed, sc.t0
+        sc.free()
         del sc
 
         numbers = {"served_logit_gap": served_gap_of(
-            make_params(jax.random.key(seed)), finished,
-            int(mix["check_requests"]), seed)}
+            kind.reference_logits, make_params(jax.random.key(seed)),
+            finished, int(mix["check_requests"]), seed)}
         clock.phase("reference")
     return {
         "attempted": len(mine), "failed": failed, "numbers": numbers,
         "device": device, "window": (t0 + lo, t0 + hi),
-        "end_to_end": {
-            "serve_tokens_per_s": work["tokens"] / window_s,
-            "ttft_p95_ms": 1e3 * _p95(ttft) if ttft else float("nan"),
-            "token_gap_p95_ms": (1e3 * _p95(work["gaps"])
-                                 if work["gaps"] else float("nan")),
-            "setup_s": setup_s},
-        "work": {"tokens": work["tokens"], "flops": work["flops"],
-                 "paged_bytes": work["paged_bytes"], "window_s": window_s,
-                 "requests_finished": len(finished), "chips": 1,
-                 "compiles_in_window": compiles, **counters},
+        "end_to_end": {**end_to_end, "setup_s": setup_s},
+        "work": work, "counts": counts,
     }
 
 
@@ -322,10 +388,11 @@ def sample(finished: list, n: int, seed: int) -> list:
     return [finished[i] for i in pick]
 
 
-def served_gap_of(params, finished: list, n: int, seed: int,
-                  stats: dict = None) -> float:
-    """Run the reference once over each sampled prompt with its served
-    tokens; the widest gap of a served token below the reference's best.
+def served_gap_of(reference_logits, params, finished: list, n: int,
+                  seed: int, stats: dict = None) -> float:
+    """Run the model's reference (``models/<model>.reference_logits``)
+    once over each sampled prompt with its served tokens; the widest gap
+    of a served token below the reference's best.
     With ``stats`` also the control's reading: at the same positions, the
     gap of the token that ``stats['control']`` precision puts first."""
     import jax.numpy as jnp
@@ -340,11 +407,11 @@ def served_gap_of(params, finished: list, n: int, seed: int,
         toks[:len(seq)] = seq
         pos = np.full((N,), P - 1, np.int32)
         pos[:len(served)] = np.arange(P - 1, P - 1 + len(served))
-        logits = np.asarray(ref_lm.next_token_logits(
+        logits = np.asarray(reference_logits(
             params, jnp.asarray(toks), jnp.asarray(pos)))[:len(served)]
         widest = np.nanmax([widest, check.served_gap(logits, served)])
         if stats is not None:
-            low = np.asarray(ref_lm.next_token_logits(
+            low = np.asarray(reference_logits(
                 params, jnp.asarray(toks), jnp.asarray(pos),
                 precision=stats["control"]))[:len(served)]
             stats["control_gap"] = float(np.nanmax([

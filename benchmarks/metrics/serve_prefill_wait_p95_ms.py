@@ -1,5 +1,6 @@
-"""95th percentile, over requests submitted in the window, of submit to
-the first prefill chunk (the program's ``EngineTracer`` span events)."""
+"""95th percentile, over requests whose first prefill chunk fell in the
+window, of submit to that chunk (the program's ``EngineTracer`` span
+events; the waits ``serve_driver._prefill_wait`` gathers)."""
 
 import numpy as np
 

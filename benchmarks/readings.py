@@ -87,6 +87,7 @@ def serve_readings(cell, devices, seeds, n_control, seconds, say,
             finished = serve_driver.finished_in(sc, 0.0, hi)
             stats = {"control": "fp8"} if i < n_control else None
             gap = serve_driver.served_gap_of(
+                sc.kind.reference_logits,
                 sc.make_params(jax.random.key(seed)), finished,
                 int(sc.mix["check_requests"]), seed, stats=stats)
             row = {"seed": seed, "program": {"served_logit_gap": gap},

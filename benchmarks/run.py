@@ -9,7 +9,8 @@ harness's spans and the program's counters) with a ``breakdown``.  Exits
 with 3 and prints no result when JAX finds no TPU or fewer chips than the
 cell asks for.  ``--rehearse-cpu`` drives the same code at the tiny sizes
 in the configuration's and the mix's ``rehearsal`` blocks and prints no
-metric at all.  See ``benchmarks/README.md``.
+metric at all.  A serving run's line also carries ``counts``: what its
+window held (see ``harness/serve_driver.py``).  See ``benchmarks/README.md``.
 """
 
 from __future__ import annotations
@@ -89,6 +90,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--keep-trace", action="store_true",
                     help="leave .bench_trace/<cell> for describe_trace.py")
+    ap.add_argument("--sub-windows", default=(),
+                    type=lambda s: [float(x) for x in s.split(",") if x],
+                    help="serving: also read, into 'counts', the windows "
+                         "of these lengths that open with the run's own")
     args = ap.parse_args(argv)
 
     from benchmarks.harness import (check, device, manifest, spans as
@@ -154,6 +159,8 @@ def main(argv=None) -> int:
         line["breakdown"] = breakdown
     if args.rehearse_cpu:
         line["rehearsal"] = True
+    elif res.get("counts") is not None:
+        line["counts"] = res["counts"]
     line["checked"] = checked
     sys.stdout.flush()
     check.report(checked, correct)

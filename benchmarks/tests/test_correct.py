@@ -109,6 +109,7 @@ def test_serving_control_reads_wider_than_the_reference_itself():
     sz = ref_tf.sizes(cell["config_data"])
     params = jax.jit(lambda k: ref_tf.init_params(sz, k))(jax.random.key(3))
     rng = np.random.default_rng(0)
+    from benchmarks.harness.models import causal_lm as kind
     from benchmarks.reference import causal_lm
 
     prompt = rng.integers(0, sz["vocab"], 24).tolist()
@@ -121,12 +122,13 @@ def test_serving_control_reads_wider_than_the_reference_itself():
         toks.append(int(np.argmax(lg[0])))
     rec = {"client": 0, "k": 0, "prompt": prompt, "tokens": toks[24:]}
     stats = {"control": "fp8"}
-    gap = serve_driver.served_gap_of(params, [rec], 1, 0, stats=stats)
+    gap = serve_driver.served_gap_of(kind.reference_logits, params, [rec],
+                                     1, 0, stats=stats)
     assert gap == 0.0
     assert stats["control_gap"] > 0.0
     altered = dict(rec, tokens=[(t + 1) % sz["vocab"] for t in rec["tokens"]])
-    assert serve_driver.served_gap_of(params, [altered], 1, 0) > \
-        stats["control_gap"]
+    assert serve_driver.served_gap_of(kind.reference_logits, params,
+                                      [altered], 1, 0) > stats["control_gap"]
 
 
 def test_sound_runs_come_out_correct(capsys, monkeypatch):

@@ -70,3 +70,28 @@ def test_copied_mlm_batches_equal_the_programs():
     assert np.array_equal(mine["tokens"], tok)
     assert np.array_equal(mine["targets"], tgt)
     assert np.array_equal(mine["mask"], msk)
+
+
+class _Span:
+    def __init__(self, arrive, events):
+        self.arrive, self.events = arrive, events
+
+
+def test_prefill_wait_counts_the_chunks_that_land_in_the_window():
+    """A request whose first chunk lies past ``hi`` waited through the
+    profiler's stop, not the queue: it is not read.  One submitted before
+    ``lo`` whose first chunk lands inside is."""
+    from types import SimpleNamespace
+
+    from benchmarks.harness import serve_driver
+
+    lo, hi = 10.0, 14.0
+    tracer = SimpleNamespace(spans={
+        0: _Span(9.0, [(9.0, "queued"), (11.5, "prefill_chunk"),
+                       (11.6, "prefill_chunk")]),        # in: 2.5
+        1: _Span(12.0, [(12.0, "queued"), (13.0, "prefill_chunk")]),  # 1.0
+        2: _Span(13.5, [(13.5, "queued"), (33.0, "prefill_chunk")]),  # past
+        3: _Span(13.9, [(13.9, "queued")]),              # still queued
+        4: _Span(5.0, [(5.0, "queued"), (9.9, "prefill_chunk")]),  # before
+    })
+    assert sorted(serve_driver._prefill_wait(tracer, lo, hi)) == [1.0, 2.5]
