@@ -483,6 +483,21 @@ def pool_mode(pool, pool_scale) -> str:
     return "int4" if pool.dtype == jnp.uint8 else "int8"
 
 
+def resolve_for(model, choice: str, block_size: int,
+                prefill_chunk: int = 64, kv_dtype: str = "fp32",
+                kv_group: int = 32, cfg=None) -> str:
+    """Ask ``model`` what ``choice`` resolves to.  A model that brings
+    its own attention kernel has ``resolve_kernel(choice, block_size,
+    prefill_chunk)`` (models/mla_moe); the K/V models use this module's,
+    over ``cfg`` (default ``model.cfg``; the per-shard config under
+    TP)."""
+    own = getattr(model, "resolve_kernel", None)
+    if own is not None:
+        return own(choice, block_size, prefill_chunk)
+    return resolve_kernel(choice, cfg or model.cfg, block_size,
+                          prefill_chunk, kv_dtype, kv_group)
+
+
 def resolve_kernel(choice: str, cfg, block_size: int,
                    prefill_chunk: int = 64,
                    kv_dtype: str = "fp32",
@@ -507,6 +522,19 @@ def resolve_kernel(choice: str, cfg, block_size: int,
     jitted decode/prefill steps, so kernel choice can never add dispatch
     shapes or recompiles, and ``engagement``/``paths`` report it as is.
     """
+    def probe():
+        from mpi_tensorflow_tpu.ops import paged_attention_kernel as pk
+
+        pk.probe_compile(jnp.dtype(cfg.dtype).name, cfg.heads,
+                         cfg.head_dim, block_size, prefill_chunk, kv_dtype,
+                         kv_group)
+    return resolve_choice(choice, probe)
+
+
+def resolve_choice(choice: str, probe) -> str:
+    """The rules of ``resolve_kernel`` for any attention kernel: ``probe``
+    compiles the caller's kernel at the served geometry and is called
+    only when the result is Mosaic."""
     if choice == "xla":
         return choice
     if choice not in ("auto", PALLAS):
@@ -519,8 +547,5 @@ def resolve_kernel(choice: str, cfg, block_size: int,
         print("[paged_attention] auto -> xla: kernel disabled via "
               "MPI_TF_TPU_DISABLE_PAGED_KERNEL", file=sys.stderr)
         return "xla"
-    from mpi_tensorflow_tpu.ops import paged_attention_kernel as pk
-
-    pk.probe_compile(jnp.dtype(cfg.dtype).name, cfg.heads, cfg.head_dim,
-                     block_size, prefill_chunk, kv_dtype, kv_group)
+    probe()
     return PALLAS

@@ -387,6 +387,27 @@ def pow2_ceil(n: int) -> int:
     return b
 
 
+def _weakly(method):
+    """``method`` as a plain function that does not keep its engine
+    alive.  The engine hands its own bound methods to objects it owns
+    (the jitted steps, the scheduler's terminal hook, the trie's tier
+    hooks); held strongly, each is a reference cycle, and an engine that
+    is dropped then keeps its pool and its weights on the device until
+    the interpreter's next FULL collection — which a caller that drops an
+    engine to make room (a ten-gigabyte model's benchmark, before its
+    reference runs) cannot wait for."""
+    import weakref
+
+    owner, fn = weakref.ref(method.__self__), method.__func__
+
+    def call(*args, **kw):
+        return fn(owner(), *args, **kw)
+    # the method's own name: jit names the compiled program, its cache
+    # entry and an unnamed Pallas kernel inside it after the function
+    call.__name__, call.__qualname__ = fn.__name__, fn.__qualname__
+    return call
+
+
 def _bucket(n: int, cap: int) -> int:
     """Round ``n`` up to a power of two, capped at ``cap``."""
     return min(pow2_ceil(n), cap)
@@ -435,9 +456,9 @@ class PagedDecodeEngine:
         # count
         kcfg = (model.cfg if serve.tp == 1 else dataclasses.replace(
             model.cfg, heads=model.cfg.heads // serve.tp))
-        self.kernel = paged_ops.resolve_kernel(
-            serve.kernel, kcfg, serve.block_size,
-            serve.prefill_chunk, serve.kv_dtype, serve.kv_group)
+        self.kernel = paged_ops.resolve_for(
+            model, serve.kernel, serve.block_size, serve.prefill_chunk,
+            serve.kv_dtype, serve.kv_group, cfg=kcfg)
         if self.tp_mesh is not None:
             self.params = tp_lib.shard_params(model, params, self.tp_mesh)
             self._paged_forward = tp_lib.make_paged_forward(
@@ -445,27 +466,28 @@ class PagedDecodeEngine:
                 kv_dtype=serve.kv_dtype)
         else:
             self.params = params
+            kernel = self.kernel        # no ``self`` in the closure
             self._paged_forward = (
                 lambda params, tokens, pools, tables, lengths, valid:
                 model.forward_paged(params, tokens, pools, tables,
-                                    lengths, valid=valid,
-                                    kernel=self.kernel))
+                                    lengths, valid=valid, kernel=kernel))
         # donate the pools so the cache updates in place — on every
         # platform (XLA:CPU honours donation too), so tier-1 sees a
         # use-after-donate before the chip does
         donate = (1,)
-        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=donate)
-        self._prefill_fn = jax.jit(self._prefill_impl,
+        self._decode_fn = jax.jit(_weakly(self._decode_impl),
+                                  donate_argnums=donate)
+        self._prefill_fn = jax.jit(_weakly(self._prefill_impl),
                                    donate_argnums=donate)
         # copy-on-write block copy: pools in, pools out, fixed shapes —
         # exactly ONE compile ever (block ids ride as traced scalars)
         self._cow_fn = jax.jit(
-            self._cow_impl, donate_argnums=(0,))
+            _weakly(self._cow_impl), donate_argnums=(0,))
         # partial tail-block copy (prefix v2): same discipline as
         # _cow_fn — block ids AND the row count ride as traced scalars,
         # so every (src, dst, n) reuses the one compiled program
         self._partial_fn = jax.jit(
-            self._partial_impl, donate_argnums=(0,))
+            _weakly(self._partial_impl), donate_argnums=(0,))
         # host-tier promotion (--serve-kv-tier host): write a demoted
         # block's host bytes into a freshly allocated device block —
         # same discipline as _cow_fn/_partial_fn: the destination id
@@ -473,16 +495,18 @@ class PagedDecodeEngine:
         # shape (a single block row per pool leaf), so every promotion
         # reuses the one compiled program
         self._promote_fn = jax.jit(
-            self._promote_impl, donate_argnums=(0,))
+            _weakly(self._promote_impl), donate_argnums=(0,))
         # speculative decoding: the verify step runs pending + k draft
         # tokens through one forward (chunked-prefill math, decode-style
         # batching); the drafter is a host-side policy object built ONCE
         # so its jit cache (draft-model mode) survives reset()
-        self._verify_fn = jax.jit(self._verify_impl, donate_argnums=donate)
+        self._verify_fn = jax.jit(_weakly(self._verify_impl),
+                                  donate_argnums=donate)
         # mixed batching: ONE fused prefill+decode forward per step
         # (--serve-mixed-batch on); shares the verify dispatch's
         # masking math — decode rows are the chunk=1 degenerate case
-        self._mixed_fn = jax.jit(self._mixed_impl, donate_argnums=donate)
+        self._mixed_fn = jax.jit(_weakly(self._mixed_impl),
+                                 donate_argnums=donate)
         self.drafter = spec_lib.make_drafter(
             serve.speculative, serve, model,
             draft_model=draft_model, draft_params=draft_params)
@@ -514,8 +538,9 @@ class PagedDecodeEngine:
                 # write into the null block pays its one compile, so a
                 # first promotion inside a timed steady-state window
                 # can never register as a recompile
-                host0 = [{key: jnp.zeros(leaf.shape[1:], leaf.dtype)
-                          for key, leaf in p.items()} for p in self.pools]
+                host0 = paged_cache.block_rows(
+                    self.pools,
+                    lambda leaf: jnp.zeros(leaf.shape[1:], leaf.dtype))
                 self.pools = self._promote_fn(self.pools, host0, z)
         if self.drafter is not None:
             # pre-warm the verify dispatch at EVERY (slot bucket, table
@@ -545,7 +570,13 @@ class PagedDecodeEngine:
 
         self.pools = paged_cache.init_pools(
             self.model.cfg, self.serve.num_blocks, self.serve.block_size,
-            self.serve.kv_dtype, self.serve.kv_group)
+            self.serve.kv_dtype, self.serve.kv_group, model=self.model)
+        # device counters the model declared beside its pool leaves
+        # (routed experts' load): totals as last read, None = none held
+        self._counters = ({} if any(
+            paged_cache.is_counter(k) for p in self.pools for k in p)
+            else None)
+        self._unread: list = []         # dispatch records awaiting a read
         if self.tp_mesh is not None:
             # head-axis sharding (serving/tp): one block id addresses
             # the same slot of every shard's local-heads pool, so the
@@ -567,8 +598,8 @@ class PagedDecodeEngine:
                      if self.serve.kv_tier == "host" else None)
         if self.tier is not None and self.prefix_cache is not None:
             self.prefix_cache.tier = self.tier
-            self.prefix_cache.demote_fetch = self._demote_fetch
-            self.prefix_cache.promote_put = self._promote_put
+            self.prefix_cache.demote_fetch = _weakly(self._demote_fetch)
+            self.prefix_cache.promote_put = _weakly(self._promote_put)
         if self.drafter is not None:
             # the draft pool indexes device state that resets with the
             # engine's own pools (crash recovery rebuilds both)
@@ -580,7 +611,7 @@ class PagedDecodeEngine:
             max_evictions=self.serve.max_evictions,
             prefix_cache=self.prefix_cache,
             prefix_gen=self.serve.prefix_gen == "on",
-            on_terminal=self._on_terminal)
+            on_terminal=_weakly(self._on_terminal))
         # pool-occupancy high-water marks: raw = every referenced block
         # (includes trie-retained blocks, which are reclaimable cache);
         # live = distinct blocks mapped by live sequences — the
@@ -662,12 +693,12 @@ class PagedDecodeEngine:
         return nxt.astype(jnp.int32), pools
 
     def _cow_impl(self, pools, src, dst):
-        """Copy one pool block (all layers, K and V — and, under an int8
-        pool, the scale siblings riding the same leading block axis):
-        the device half of copy-on-write.  ``src``/``dst`` are traced
-        scalars, so every copy reuses the one compiled program."""
-        return [{key: leaf.at[dst].set(leaf[src])
-                 for key, leaf in p.items()} for p in pools]
+        """Copy one pool block (all layers, every block leaf the model
+        declared — K and V with their scale siblings, or latent rows;
+        counters pass through): the device half of copy-on-write.
+        ``src``/``dst`` are traced scalars, so every copy reuses the one
+        compiled program."""
+        return paged_cache.copy_block(pools, src, dst)
 
     def _partial_impl(self, pools, src, dst, n):
         """Copy the first ``n`` token-slot rows of block ``src`` into
@@ -682,16 +713,15 @@ class PagedDecodeEngine:
         their scale siblings): the device half of tier promotion.
         ``dst`` is a traced scalar; ``host`` is a per-layer list of
         single-block leaves with one fixed shape — one compile."""
-        return [{key: leaf.at[dst].set(hb[key])
-                 for key, leaf in p.items()}
-                for p, hb in zip(pools, host)]
+        return paged_cache.write_block(pools, host, dst)
 
     def _demote_fetch(self, block: int) -> list:
         """Copy pool block ``block`` to host (per-layer dicts of
         np.ndarray rows) — the prefix cache calls this just before
         eviction releases the device block (--serve-kv-tier host)."""
-        return [{key: np.asarray(leaf[block])  # graft-lint: sync-ok(cold-block demotion off the dispatch path)
-                 for key, leaf in p.items()} for p in self.pools]
+        return paged_cache.block_rows(
+            self.pools,
+            lambda leaf: np.asarray(leaf[block]))  # graft-lint: sync-ok(cold-block demotion off the dispatch path)
 
     def _promote_put(self, leaves: list, block: int) -> None:
         """Land demoted host bytes in freshly allocated device block
@@ -972,6 +1002,8 @@ class PagedDecodeEngine:
             jnp.asarray(len(chunk), jnp.int32), jnp.asarray(tables))
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
+            n, at = len(chunk), seq.prefilled
+            self._log_dispatch("prefill", n, n * at + n * (n + 1) // 2)
         seq.prefilled += len(chunk)
         if seq.prefilled < len(prompt):
             return []
@@ -989,6 +1021,7 @@ class PagedDecodeEngine:
             _m0 = time.monotonic()
         tok = int(nxt)  # graft-lint: sync-ok(one scalar per admission, not per step)
         if tr is not None:
+            self._read_counters()
             tr.consume_s += time.monotonic() - _m0
         self._last_token[slot] = tok
         if self._journal is not None:
@@ -1070,6 +1103,9 @@ class PagedDecodeEngine:
             tr.dispatch_s += _m1 - _m0
         nxt = np.asarray(nxt)  # graft-lint: sync-ok(the one budgeted bulk sync per decode dispatch)
         if tr is not None:
+            self._log_dispatch("decode", len(live),
+                               int(lengths.sum()) + len(live))
+            self._read_counters()
             tr.consume_s += time.monotonic() - _m1
         for j, slot in enumerate(live):
             tok = int(nxt[j])
@@ -1484,6 +1520,7 @@ class PagedDecodeEngine:
             "prefix": self.prefix_block(),
             "speculation": self.speculation_block(),
             "tier": self.tier_block(),
+            "moe": self.moe_block(),
             "peak_blocks_in_use": self.peak_blocks_in_use,
             "peak_live_blocks": self.peak_live_blocks,
             "tokens": total,
@@ -1522,6 +1559,64 @@ class PagedDecodeEngine:
                 "steps_dropped": h["steps_dropped"],
             }
         return res
+
+    def _log_dispatch(self, kind: str, rows: int, attended: int) -> None:
+        """Traced runs only: one record per model dispatch in the
+        process-wide registry (utils/dispatch_log) — when, what, how many
+        rows (decode) or chunk tokens (prefill), and how many cached
+        tokens its queries attended, from the scheduler's host state.
+        The routed experts' share of the record is filled by the next
+        ``_read_counters``."""
+        from mpi_tensorflow_tpu.utils import dispatch_log
+
+        self._unread.append(dispatch_log.record(
+            time.perf_counter(), kind, rows, attended))
+
+    def _read_counters(self) -> None:
+        """Traced runs only, and only once a dispatch's tokens are on
+        the host (the counter leaves of the same program are then ready
+        buffers of a few dozen bytes): read the model's device counters
+        and give every dispatch logged since the last read its share —
+        the counter keeps decode calls and the rest apart, and an
+        iteration holds at most one of each."""
+        if self._counters is None:
+            return
+        from mpi_tensorflow_tpu.utils import dispatch_log
+
+        tot = self._counter_totals()
+        prev = self._counters.get("totals", np.zeros_like(tot))
+        delta = tot - prev
+        self._counters["totals"] = tot
+        for rec in self._unread:
+            row = delta[0 if rec[1] == "decode" else 1]
+            rec[4], rec[5] = int(row[:-1].sum()), int(row[-1])
+            row[:] = 0              # one dispatch of a kind per read
+        self._unread.clear()
+        dispatch_log.set_totals(tot[:, :-1].sum(axis=0).tolist())
+        if self.tracer is not None:
+            self.tracer.moe = self.moe_block(tot)
+
+    def _counter_totals(self):
+        """The counter leaves summed over layers, on the host (a
+        sync)."""
+        return np.sum([leaf for layer in paged_cache.read_counters(
+            self.pools) for leaf in layer.values()], axis=0)
+
+    def moe_block(self, totals=None) -> dict:
+        """Routed-expert load accounting, like ``prefix_block``: the
+        assignments each held expert received, experts touched (summed
+        over calls and layers) and the load's max over mean.  Reads the
+        device counters (a sync) unless given ``totals``; zero-safe for
+        a model that declares none."""
+        from mpi_tensorflow_tpu.utils.metrics_writer import moe_block
+
+        if self._counters is None:
+            return moe_block()
+        if totals is None:
+            totals = self._counter_totals()
+        return moe_block(enabled=True,
+                         per_expert=totals[:, :-1].sum(axis=0).tolist(),
+                         experts_touched=int(totals[:, -1].sum()))
 
     def load_signals(self) -> dict:
         """Instantaneous load signals for autoscale advice

@@ -1,14 +1,24 @@
 """Paged KV cache: host block allocator + device pool construction.
 
-The device side is a fixed pool of ``(num_blocks, block_size, heads *
-head_dim)`` K and V blocks per transformer layer (ops/paged_attention
-reads/writes it through per-sequence block tables).  Token-major and
+The device side is a fixed pool of blocks per transformer layer, and the
+MODEL says what a block holds: a model with a ``pool_leaves`` method
+declares its own leaves (models/mla_moe: one latent row per token), any
+other model gets the ``(num_blocks, block_size, heads * head_dim)`` K and
+V leaves below (ops/paged_attention reads/writes them through
+per-sequence block tables).  Only the model's ``forward_paged`` and its
+ops may assume what the leaves are called or hold; everything here and
+in the engine (copy-on-write, partial copy, host tier, ``reset``) takes
+whatever leaves it is given, and assumes only that  Token-major and
 lane-dense is the one geometry that the runtime's default device layout
 (row-major only when the minor dimension fills the 128-lane tile), the
 K/V scatter (one contiguous row per token) and the Mosaic kernel's
 operand (row-major) all take as it is stored, so no serving program
-copies a pool leaf.  Every leaf, scale siblings included, has the block
-id on axis 0 and the token slot on axis 1.  The host side —
+copies a pool leaf.  Every block leaf, scale siblings included, has the
+block id on axis 0 and the token slot on axis 1.  A leaf whose name ends
+in ``_count`` is a COUNTER the model accumulates on the device (routed
+experts' load): it has no block axis, block copies pass it through
+(``copy_block``), ``reset`` zeroes it with the rest, and the host reads
+it only when asked (``read_counters``).  The host side —
 this module — owns WHICH block belongs to WHOM: a refcounted free-list
 allocator whose accounting the scheduler's admit/evict decisions hang
 off.
@@ -123,6 +133,42 @@ def blocks_for(tokens: int, block_size: int) -> int:
     return -(-tokens // block_size)
 
 
+def is_counter(key: str) -> bool:
+    """A pool entry's device counter (no block axis), by its name."""
+    return key.endswith("_count")
+
+
+def copy_block(pools: list, src, dst) -> list:
+    """Copy pool block ``src`` onto ``dst`` in every block leaf of every
+    layer (codes and scale siblings alike); counters pass through."""
+    return [{key: leaf if is_counter(key) else leaf.at[dst].set(leaf[src])
+             for key, leaf in p.items()} for p in pools]
+
+
+def block_rows(pools: list, pick) -> list:
+    """Per layer ``{key: pick(leaf)}`` over the block leaves only."""
+    return [{key: pick(leaf) for key, leaf in p.items()
+             if not is_counter(key)} for p in pools]
+
+
+def write_block(pools: list, rows: list, dst) -> list:
+    """Write one block's ``rows`` (``block_rows`` of some pool) into
+    block ``dst``; counters pass through."""
+    return [{key: leaf if is_counter(key) else leaf.at[dst].set(r[key])
+             for key, leaf in p.items()} for p, r in zip(pools, rows)]
+
+
+def read_counters(pools: list) -> list:
+    """The counter leaves on the host, one ``{key: np.ndarray}`` per
+    layer that has any (a device sync: callers do this off the step's
+    critical path, or once the step's tokens are already on the host)."""
+    import jax
+
+    return jax.device_get([
+        {key: leaf for key, leaf in p.items() if is_counter(key)}
+        for p in pools if any(is_counter(k) for k in p)])
+
+
 def partial_copy_block(pools: list, src, dst, n) -> list:
     """Copy the first ``n`` token-slot rows of block ``src`` into block
     ``dst`` across every pool leaf, leaving rows ``>= n`` of ``dst``
@@ -145,6 +191,9 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
     for p in pools:
         layer = {}
         for key, leaf in p.items():
+            if is_counter(key):
+                layer[key] = leaf
+                continue
             rows = jnp.arange(leaf.shape[1]) < n
             mask = rows.reshape((-1,) + (1,) * (leaf.ndim - 2))
             layer[key] = leaf.at[dst].set(
@@ -154,10 +203,14 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
 
 
 def init_pools(cfg, num_blocks: int, block_size: int,
-               kv_dtype: str = "fp32", kv_group: int = 32) -> list:
-    """Per-layer K/V block pools (zeros), mirroring the per-layer
-    ``{"k", "v"}`` pytree shape of models/gpt.init_cache so the engine
-    threads them through jit the same way.
+               kv_dtype: str = "fp32", kv_group: int = 32,
+               model=None) -> list:
+    """Per-layer block pools (zeros).  A ``model`` with ``pool_leaves``
+    declares its own (``{name: ShapeDtypeStruct}`` per layer, counters
+    included; it refuses a ``kv_dtype`` it has no form for); otherwise
+    per-layer K/V pools, mirroring the per-layer ``{"k", "v"}`` pytree
+    shape of models/gpt.init_cache so the engine threads them through
+    jit the same way.
 
     ``kv_dtype`` selects the pool storage format (--serve-kv-dtype):
 
@@ -189,6 +242,11 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     # every leaf is its OWN buffer: the engine donates the pools into
     # each step, and one zeros array shared between k and v (or across
     # layers) would be donated twice in a single Execute()
+    declare = getattr(model, "pool_leaves", None)
+    if declare is not None:
+        return [{key: jnp.zeros(s.shape, s.dtype)
+                 for key, s in layer.items()}
+                for layer in declare(num_blocks, block_size, kv_dtype)]
     width = cfg.heads * cfg.head_dim
     code_shape = (num_blocks, block_size, width)
     if kv_dtype == "fp32":

@@ -192,7 +192,7 @@ class DraftModelDrafter(Drafter):
     def reset(self) -> None:
         self.pools = init_pools(self.model.cfg, self.num_blocks,
                                 self.block_size, self.kv_dtype,
-                                self.kv_group)
+                                self.kv_group, model=self.model)
         self.allocator = BlockAllocator(self.num_blocks)
         self._state: Dict[int, _DraftState] = {}
 
@@ -345,6 +345,9 @@ def make_drafter(mode: str, serve, target_model, *, draft_model=None,
     if mode != "draft-model":
         raise ValueError(
             f"speculative mode must be off|ngram|draft-model, got {mode!r}")
+    require = getattr(target_model, "require_draft", None)
+    if require is not None:
+        require(draft_model)     # a family the default draft is not of
     if draft_model is None:
         import jax
 
@@ -366,8 +369,8 @@ def make_drafter(mode: str, serve, target_model, *, draft_model=None,
         num_blocks=serve.num_blocks, block_size=serve.block_size,
         max_blocks_per_seq=serve.max_blocks_per_seq,
         chunk=min(16, serve.prefill_chunk),
-        kernel=paged_ops.resolve_kernel(
-            serve.kernel, draft_model.cfg, serve.block_size,
+        kernel=paged_ops.resolve_for(
+            draft_model, serve.kernel, serve.block_size,
             min(16, serve.prefill_chunk), serve.kv_dtype,
             serve.kv_group),
         kv_dtype=serve.kv_dtype, kv_group=serve.kv_group)

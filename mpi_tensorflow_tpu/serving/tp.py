@@ -77,6 +77,9 @@ def check_geometry(cfg, tp: int) -> None:
         raise ValueError(f"--serve-tp must be >= 1, got {tp}")
     if tp == 1:
         return
+    refusal = getattr(cfg, "tp_refusal", None)
+    if refusal is not None:
+        raise ValueError(f"--serve-tp {tp}: {refusal}")
     _check_device_count(tp)
     if cfg.heads % tp or cfg.mlp % tp:
         raise ValueError(

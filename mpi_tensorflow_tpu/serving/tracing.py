@@ -201,6 +201,8 @@ class EngineTracer:
         self.dispatch_s = 0.0
         self.consume_s = 0.0
         self._last_now = 0.0
+        self.moe: Optional[dict] = None  # routed-expert load as last
+                                         # read (engine._read_counters)
 
     # ---- request lifecycle -------------------------------------------
 
@@ -340,6 +342,7 @@ class EngineTracer:
             "spans": spans,
             "steps": self.buffer.records(),
             "steps_dropped": self.buffer.dropped,
+            "moe": self.moe,
         }
 
 

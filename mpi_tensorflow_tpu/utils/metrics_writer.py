@@ -284,6 +284,30 @@ def tier_block(*, enabled: bool = False, mode: str = "off",
     }
 
 
+#: canonical routed-expert load keys — THE shape of the ``moe`` block
+MOE_KEYS = ("enabled", "assignments", "experts_touched", "per_expert",
+            "load_max_over_mean")
+
+
+def moe_block(*, enabled: bool = False, per_expert=(),
+              experts_touched: int = 0) -> dict:
+    """Normalize the routed experts' device counters into the canonical
+    serving ``moe`` block: assignments each held expert received,
+    experts touched summed over calls and layers, and the busiest
+    expert's load over the mean (1.0 = even; zero-safe)."""
+    per_expert = [int(n) for n in per_expert]
+    total = sum(per_expert)
+    return {
+        "enabled": bool(enabled),
+        "assignments": total,
+        "experts_touched": int(experts_touched),
+        "per_expert": per_expert,
+        "load_max_over_mean": (
+            round(max(per_expert) * len(per_expert) / total, 4)
+            if total else 0.0),
+    }
+
+
 #: canonical goodput-under-SLO keys — THE shape of the ``goodput``
 #: block every consumer sees (bench.py --mode serving JSON, the metric
 #: line's goodput_tokens_per_sec / slo_attainment fields).  Goodput =
