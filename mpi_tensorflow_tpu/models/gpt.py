@@ -230,6 +230,13 @@ class CausalLm(bert_lib.BertMlm):
 
         qkv_axes = ("batch", "heads", "seq", "head_dim")
         engagement.record("paged_attention", kernel)
+        # the kernel's live (row, block) pairs depend on nothing but the
+        # lengths and the table's width: one list for every layer
+        work = None
+        if kernel in (paged_ops.PALLAS, paged_ops.PALLAS_INTERPRET):
+            work = paged_ops.paged_work(lengths, S_in,
+                                        pools[0]["k"].shape[1],
+                                        block_tables.shape[1])
         new_pools = []
         for lp, pl in zip(params["layers"], pools):
             q, k, v = bert_lib.qkv_proj(lp, h, dt, fused=c.fused_qkv)
@@ -256,7 +263,7 @@ class CausalLm(bert_lib.BertMlm):
                 a = paged_ops.attend(q, pk, pv, block_tables, lengths,
                                      dt, kernel=kernel,
                                      k_scale=ks, v_scale=vs,
-                                     k_new=k, v_new=v)
+                                     k_new=k, v_new=v, work=work)
             elif mode == "int8":
                 # int8 pool (--serve-kv-dtype int8): quantize on store —
                 # codes and per-row scales scatter through the same
@@ -270,7 +277,7 @@ class CausalLm(bert_lib.BertMlm):
                                   "k_scale": ks, "v_scale": vs})
                 a = paged_ops.attend(q, pk, pv, block_tables, lengths,
                                      dt, kernel=kernel,
-                                     k_scale=ks, v_scale=vs)
+                                     k_scale=ks, v_scale=vs, work=work)
             else:
                 pk = paged_ops.write_kv(pl["k"], k, block_tables, pos,
                                         valid)
@@ -278,7 +285,7 @@ class CausalLm(bert_lib.BertMlm):
                                         valid)
                 new_pools.append({"k": pk, "v": pv})
                 a = paged_ops.attend(q, pk, pv, block_tables, lengths,
-                                     dt, kernel=kernel)
+                                     dt, kernel=kernel, work=work)
             a = bert_lib.attn_out_proj(lp, a, dt, reduce=reduce)
             h = _layernorm(h + a, lp["ln1"]).astype(dt)
             h = self._constrain(h, ("batch", "seq", "embed"))

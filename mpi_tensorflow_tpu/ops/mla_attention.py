@@ -30,7 +30,8 @@ Two forms of the same attention, both over ``q_nope`` (B, S, H, Dn),
 
 The kernel's grid follows LIVE blocks.  A work list of (row, query
 tile, kv block) triples is built on the device from ``lengths``
-(``_work_list``) and rides in as scalar-prefetch operands; the grid's
+(``paged_attention.work_list``, the one list both Pallas attention
+kernels walk) and rides in as scalar-prefetch operands; the grid's
 one dimension is the list's live length, a traced value, so a
 9,216-token table costs a 1,000-token row nothing.
 """
@@ -133,27 +134,6 @@ def attend(q_nope, q_rope, pool, block_table, lengths, w_ukv, scale, dt,
     return jnp.einsum("bshc,chd->bshd", ot, w_uv)
 
 
-def _work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int):
-    """The live (row, query tile, kv block) triples in execution order.
-
-    Tile ``t`` of row ``b`` holds query tokens ``[t*tq, (t+1)*tq)`` and
-    needs the blocks that hold positions up to its last real token.
-    Returns int32 arrays of the static bound ``B * NT * NB`` (row, tile,
-    block, blocks of that tile) and the live count: entries past it are
-    never run."""
-    B = lengths.shape[0]
-    last = jnp.minimum((jnp.arange(NT, dtype=jnp.int32) + 1) * tq, S)
-    need = jnp.clip((lengths[:, None] + last[None, :] + bs - 1) // bs,
-                    1, NB).reshape(-1)                     # (B * NT,)
-    ends = jnp.cumsum(need)
-    w = jnp.arange(B * NT * NB, dtype=jnp.int32)
-    pair = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
-                       B * NT - 1).astype(jnp.int32)
-    n = need[pair]
-    blk = jnp.clip(w - (ends[pair] - n), 0, NB - 1).astype(jnp.int32)
-    return pair // NT, pair % NT, blk, n.astype(jnp.int32), ends[-1]
-
-
 def _kernel(bt_ref, len_ref, row_ref, tile_ref, blk_ref, n_ref,
             q_ref, kv_ref, o_ref, acc, m_scr, l_scr, *, scale: float,
             heads: int, q_tile: int, latent: int):
@@ -226,7 +206,7 @@ def mla_paged_attention(qt, q_rope, pool, block_table, lengths, *,
                     (0, W - q.shape[-1])))
     q = q.reshape(B, NT * tq * H, W)
     lengths = lengths.astype(jnp.int32)
-    row, tile, blk, n, live = _work_list(lengths, S, tq, NT, bs, NB)
+    row, tile, blk, n, live = paged_ops.work_list(lengths, S, tq, NT, bs, NB)
     R = tq * H
 
     def q_map(w, bt, lens, row, tile, blk, n):

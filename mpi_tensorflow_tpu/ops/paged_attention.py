@@ -375,9 +375,55 @@ def paged_attention_self_residual(q, ck, cv, q_positions, dt, k_new,
             + p_self[..., None].astype(dt) * v_new.astype(dt))
 
 
+def work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int):
+    """The live (row, query tile, kv block) triples of one dispatch in
+    execution order: THE work list of the Pallas attention kernels
+    (ops/paged_attention_kernel, whose rows are one tile; ops/
+    mla_attention), built on the device from ``lengths`` and passed to
+    the kernel as scalar-prefetch operands.
+
+    Tile ``t`` of row ``b`` holds query tokens ``[t*tq, (t+1)*tq)`` and
+    needs the blocks that hold positions up to its last real token: the
+    step's own tokens were scattered into the pool before attention, so
+    lanes up to ``length + S`` are real and everything past them is
+    null-block padding.  A slack row (all-null table, length 0) keeps
+    one step, and the garbage the engine discards.
+    Returns int32 arrays of the static bound ``B * NT * NB`` (row, tile,
+    block, blocks of that tile) and the live count: entries past it are
+    never run."""
+    B = lengths.shape[0]
+    last = jnp.minimum((jnp.arange(NT, dtype=jnp.int32) + 1) * tq, S)
+    need = jnp.clip((lengths[:, None] + last[None, :] + bs - 1) // bs,
+                    1, NB).reshape(-1)                     # (B * NT,)
+    ends = jnp.cumsum(need)
+    w = jnp.arange(B * NT * NB, dtype=jnp.int32)
+    pair = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
+                       B * NT - 1).astype(jnp.int32)
+    n = need[pair]
+    blk = jnp.clip(w - (ends[pair] - n), 0, NB - 1).astype(jnp.int32)
+    return pair // NT, pair % NT, blk, n.astype(jnp.int32), ends[-1]
+
+
+def paged_work(lengths, S: int, bs: int, NB: int):
+    """``work_list`` for the K/V kernel, a row's ``S`` queries being one
+    tile: (row, block, blocks of that row, live count).  The same for
+    every layer of a forward, which builds it once and hands it down the
+    ``attend`` seam.
+
+    Each array is one entry longer than the list's bound ``B * NB``: the
+    kernel's pipeline evaluates the index maps of the step AFTER the one
+    it runs, to fetch ahead, so with every table full (always so at
+    ``B = NB = 1``) it reads one entry past the list — out of the array,
+    whatever scalar memory holds there, as a row and a block of the
+    table; the chip halts on it."""
+    row, _, blk, n, live = work_list(lengths.astype(jnp.int32), S, S, 1,
+                                     bs, NB)
+    return tuple(jnp.pad(x, (0, 1)) for x in (row, blk, n)) + (live,)
+
+
 def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
            kernel: str = "xla", k_scale=None, v_scale=None,
-           k_new=None, v_new=None):
+           k_new=None, v_new=None, work=None):
     """THE paged-attention dispatch seam: one entry point, two lowering
     strategies, identical greedy tokens (tests/test_paged_kernel.py).
 
@@ -408,6 +454,10 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
                  enables the fp-residual self lane
                  (``paged_attention_self_residual``).  int4 pools only;
                  both or neither.
+    work:        the dispatch's ``paged_work`` where the caller built it
+                 (a forward builds one for all its layers); None lets a
+                 Pallas lowering build its own.  The XLA path has no use
+                 for it.
 
     MIXED-ROW CONTRACT: ``lengths`` is per-row and the causal mask is
     built per row from it (``pos = lengths[:, None] + arange(S)``), so
@@ -437,7 +487,7 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
         return fused(q, k_pool, v_pool, block_table, lengths,
                      interpret=kernel == PALLAS_INTERPRET,
                      k_scale=k_scale, v_scale=v_scale,
-                     k_new=k_new, v_new=v_new)
+                     k_new=k_new, v_new=v_new, work=work)
     if kernel != "xla":
         raise ValueError(
             f"unresolved paged-attention kernel {kernel!r}: callers "
@@ -485,23 +535,27 @@ def pool_mode(pool, pool_scale) -> str:
 
 def resolve_for(model, choice: str, block_size: int,
                 prefill_chunk: int = 64, kv_dtype: str = "fp32",
-                kv_group: int = 32, cfg=None) -> str:
+                kv_group: int = 32, cfg=None, max_slots: int = 8,
+                max_blocks: int = 4) -> str:
     """Ask ``model`` what ``choice`` resolves to.  A model that brings
     its own attention kernel has ``resolve_kernel(choice, block_size,
     prefill_chunk)`` (models/mla_moe); the K/V models use this module's,
     over ``cfg`` (default ``model.cfg``; the per-shard config under
-    TP)."""
+    TP) and the largest dispatch the caller serves (``max_slots`` rows
+    under tables of ``max_blocks`` blocks)."""
     own = getattr(model, "resolve_kernel", None)
     if own is not None:
         return own(choice, block_size, prefill_chunk)
     return resolve_kernel(choice, cfg or model.cfg, block_size,
-                          prefill_chunk, kv_dtype, kv_group)
+                          prefill_chunk, kv_dtype, kv_group, max_slots,
+                          max_blocks)
 
 
 def resolve_kernel(choice: str, cfg, block_size: int,
                    prefill_chunk: int = 64,
                    kv_dtype: str = "fp32",
-                   kv_group: int = 32) -> str:
+                   kv_group: int = 32, max_slots: int = 8,
+                   max_blocks: int = 4) -> str:
     """Resolve the ``--serve-kernel`` knob to a static lowering literal.
 
     - "xla"    -> "xla"
@@ -514,9 +568,11 @@ def resolve_kernel(choice: str, cfg, block_size: int,
                   "xla" on TPU too (the operator kill switch)
 
     Whenever the result is "pallas" the kernel is compiled for this
-    geometry first (paged_attention_kernel.probe_compile): a Mosaic
-    refusal RAISES here with the compiler's message.  No failure ever
-    selects the XLA path.
+    geometry first, at its largest dispatch — ``max_slots`` rows under
+    tables of ``max_blocks`` blocks, which size the kernel's scalar
+    operands (paged_attention_kernel.probe_compile): a Mosaic refusal
+    RAISES here with the compiler's message.  No failure ever selects
+    the XLA path.
 
     Host-side, once per engine: the resolved literal is baked into the
     jitted decode/prefill steps, so kernel choice can never add dispatch
@@ -527,7 +583,7 @@ def resolve_kernel(choice: str, cfg, block_size: int,
 
         pk.probe_compile(jnp.dtype(cfg.dtype).name, cfg.heads,
                          cfg.head_dim, block_size, prefill_chunk, kv_dtype,
-                         kv_group)
+                         kv_group, max_slots, max_blocks)
     return resolve_choice(choice, probe)
 
 

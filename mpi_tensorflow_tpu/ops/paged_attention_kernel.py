@@ -10,18 +10,24 @@ PagedAttention / Flash-Decoding recipe, PAPERS.md): per grid step one
 the running (m, l, acc) statistics stay in VMEM scratch, and the
 ``(B, H, NB*block_size, D)`` gathered view never exists.
 
-Grid: ``(batch-slot, kv-block)``, kv-block innermost.  The block table
-rides in as a **scalar-prefetch** operand, so each step's BlockSpec
-index map picks the pool block to DMA (``bt[b, j]``) before the kernel
-body runs — the Pallas pipeline turns the host-side block table into
-device-side streamed reads with no gather materialization.
-
-Early-out: a sequence of length ``len_b`` only has
-``nlive = ceil((len_b + S) / block_size)`` live blocks.  Steps with
-``j >= nlive`` clamp their index map to the last live block — Pallas
-skips the DMA when the block index repeats — and ``pl.when`` skips the
-compute, so per-token cost tracks **live tokens**, not the padded NB
-bucket.
+Grid: one dimension, the LIVE (row, kv-block) pairs of the dispatch.
+A row of length ``len_b`` has ``ceil((len_b + S) / block_size)`` live
+blocks (at least one, at most the table's width); the pairs, row-major
+with a row's blocks ascending, are built on the device from ``lengths``
+(``ops/paged_attention.work_list``, the list the latent kernel of
+ops/mla_attention walks too) and the grid's bound is their count, a
+traced value.  The list and the block table ride in as **scalar-
+prefetch** operands, so step ``w``'s BlockSpec index maps pick the
+row's query block (``row[w]``) and the pool block to DMA
+(``bt[row[w], blk[w]]``) before the kernel body runs — the Pallas
+pipeline turns the host-side block table into device-side streamed
+reads with no gather materialization, and a call costs its live blocks,
+not its slots x table bucket.  The pipeline evaluates the maps of the
+step after the one it runs, so the list holds one valid entry past its
+bound (``paged_work``).  The one axis carries each row's
+accumulators through its blocks in order (``"arbitrary"``): a v5e chip
+has one TensorCore, so nothing is lost; a two-core chip would want the
+rows split between the cores first.
 
 Masking contract (kept in LOCKSTEP with ops/paged_attention.
 paged_attention — the parity suite in tests/test_paged_kernel.py pins
@@ -48,8 +54,9 @@ diagonal queries meet the whole row in one pair of matmuls
 (``_decode_kernel``).
 
 ``probe_compile()`` compiles the served geometry (decode + every
-prefill bucket) up front so a Mosaic refusal surfaces at engine build
-with the compiler's message — it never selects another lowering;
+prefill bucket, each at its largest dispatch: the table and the list
+must fit scalar memory) up front so a Mosaic refusal surfaces at engine
+build with the compiler's message — it never selects another lowering;
 ``interpret=True`` runs the same kernel on CPU for the tier-1 parity
 suite.
 """
@@ -64,18 +71,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_tensorflow_tpu.ops.paged_attention import pool_mode
+from mpi_tensorflow_tpu.ops.paged_attention import (paged_work,
+                                                    pool_mode)
 
 # stats rows are lane-broadcast to the f32 tile width, mirroring
 # ops/flash_attention's LSE_LANES treatment of per-row statistics
 STAT_LANES = 128
-
-
-def _nlive(length, S: int, bs: int, NB: int):
-    """Live block count for a row: lanes up to ``length + S`` hold real
-    cache entries (the step's own tokens were scattered in by write_kv
-    before attention), everything past them is null-block padding."""
-    return jnp.clip((length + S + bs - 1) // bs, 1, NB)
 
 
 def _dequant_int4_block(codes, scales, dt):
@@ -123,12 +124,14 @@ def _head_block(ref, scale_ref, h: int, H: int, mode: str, dt):
 
 def _paged_kernel(*refs, scale: float, block_size: int,
                   mode: str = "fp32", residual: bool = False):
-    """One (batch-slot, kv-block) grid step of the online softmax.
+    """One live (row, kv-block) pair of the online softmax: step ``w``
+    of the work list is block ``j = blk[w]`` of row ``b = row[w]``, which
+    has ``n[w]`` live blocks.
 
     q_ref:  (1, H, S, D)   — the row's whole query block (revisited)
-    k_ref:  (1, bs, H*D)   — pool block ``bt[b, min(j, nlive-1)]``
+    k_ref:  (1, bs, H*D)   — pool block ``bt[b, j]``
     v_ref:  (1, bs, H*D)
-    o_ref:  (1, H, S, D)   — written once, at the last LIVE block
+    o_ref:  (1, H, S, D)   — written once, at the row's last live block
     scratch: acc (H, S, D) f32, m/l (H, S, STAT_LANES) f32
 
     The heads are a static loop: head ``h`` reads lanes
@@ -163,21 +166,19 @@ def _paged_kernel(*refs, scale: float, block_size: int,
     one live grid step; the denominator (l) keeps its weight.
     """
     ks_ref = vs_ref = kn_ref = vn_ref = None
+    _, len_ref, row_ref, blk_ref, n_ref = refs[:5]
     if mode == "int4" and residual:
-        (bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, ks_ref, v_ref,
-         vs_ref, o_ref, acc, m_scr, l_scr) = refs
+        (q_ref, kn_ref, vn_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
+         acc, m_scr, l_scr) = refs[5:]
     elif mode in ("int8", "int4"):
-        (bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-         acc, m_scr, l_scr) = refs
+        (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
+         acc, m_scr, l_scr) = refs[5:]
     else:
-        (bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-         acc, m_scr, l_scr) = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    NB = pl.num_programs(1)
+        q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr = refs[5:]
+    w = pl.program_id(0)
+    b, j = row_ref[w], blk_ref[w]
     H, S, D = q_ref.shape[1:]
     bs = block_size
-    nlive = _nlive(len_ref[b], S, bs, NB)
 
     @pl.when(j == 0)
     def _init():
@@ -185,62 +186,60 @@ def _paged_kernel(*refs, scale: float, block_size: int,
         m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    @pl.when(j < nlive)
-    def _step():
-        # visibility: key position <= query position, exactly the XLA
-        # path's mask (q positions are lengths[b] + [0, S))
-        col = j * bs + lax.broadcasted_iota(jnp.int32, (S, bs), 1)
-        qpos = len_ref[b] + lax.broadcasted_iota(jnp.int32, (S, bs), 0)
-        self_m = col == qpos if residual else None     # (S, bs)
-        for h in range(H):
-            q = q_ref[0, h]                            # (S, D)
-            k = _head_block(k_ref, ks_ref, h, H, mode, q.dtype)  # (bs, D)
-            v = _head_block(v_ref, vs_ref, h, H, mode, q.dtype)
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)    # (S, bs)
-            if residual:
-                # fp self lane: exact q·k_new score for each row's own
-                # column, overriding the int4 score BEFORE scale+mask
-                s_self = jnp.sum(
-                    q.astype(jnp.float32)
-                    * kn_ref[0, h].astype(jnp.float32),
-                    axis=-1, keepdims=True)            # (S, 1)
-                s = jnp.where(self_m, s_self, s)
-            s = jnp.where(col <= qpos, s * scale,
-                          jnp.finfo(jnp.float32).min)
-            m_prev = m_scr[h, :, 0:1]                  # (S, 1)
-            l_prev = l_scr[h, :, 0:1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)                     # (S, bs)
-            corr = jnp.exp(m_prev - m_new)             # (S, 1)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-            if residual:
-                # the self column's weight multiplies the fp v_new row,
-                # not the dequantized pool row; l keeps the full p sum
-                p_self = jnp.sum(jnp.where(self_m, p, 0.0),
-                                 axis=-1, keepdims=True)   # (S, 1)
-                p = jnp.where(self_m, 0.0, p)
-            pv = lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)    # (S, D)
-            if residual:
-                pv = pv + p_self * vn_ref[0, h].astype(jnp.float32)
-            acc[h] = acc[h] * corr + pv
-            m_scr[h] = jnp.broadcast_to(m_new, (S, STAT_LANES))
-            l_scr[h] = jnp.broadcast_to(l_new, (S, STAT_LANES))
+    # visibility: key position <= query position, exactly the XLA
+    # path's mask (q positions are lengths[b] + [0, S))
+    col = j * bs + lax.broadcasted_iota(jnp.int32, (S, bs), 1)
+    qpos = len_ref[b] + lax.broadcasted_iota(jnp.int32, (S, bs), 0)
+    self_m = col == qpos if residual else None     # (S, bs)
+    for h in range(H):
+        q = q_ref[0, h]                            # (S, D)
+        k = _head_block(k_ref, ks_ref, h, H, mode, q.dtype)  # (bs, D)
+        v = _head_block(v_ref, vs_ref, h, H, mode, q.dtype)
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)    # (S, bs)
+        if residual:
+            # fp self lane: exact q·k_new score for each row's own
+            # column, overriding the int4 score BEFORE scale+mask
+            s_self = jnp.sum(
+                q.astype(jnp.float32)
+                * kn_ref[0, h].astype(jnp.float32),
+                axis=-1, keepdims=True)            # (S, 1)
+            s = jnp.where(self_m, s_self, s)
+        s = jnp.where(col <= qpos, s * scale,
+                      jnp.finfo(jnp.float32).min)
+        m_prev = m_scr[h, :, 0:1]                  # (S, 1)
+        l_prev = l_scr[h, :, 0:1]
+        m_new = jnp.maximum(m_prev,
+                            jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                     # (S, bs)
+        corr = jnp.exp(m_prev - m_new)             # (S, 1)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if residual:
+            # the self column's weight multiplies the fp v_new row,
+            # not the dequantized pool row; l keeps the full p sum
+            p_self = jnp.sum(jnp.where(self_m, p, 0.0),
+                             axis=-1, keepdims=True)   # (S, 1)
+            p = jnp.where(self_m, 0.0, p)
+        pv = lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # (S, D)
+        if residual:
+            pv = pv + p_self * vn_ref[0, h].astype(jnp.float32)
+        acc[h] = acc[h] * corr + pv
+        m_scr[h] = jnp.broadcast_to(m_new, (S, STAT_LANES))
+        l_scr[h] = jnp.broadcast_to(l_new, (S, STAT_LANES))
 
-    @pl.when(j == nlive - 1)
+    @pl.when(j == n_ref[w] - 1)
     def _emit():
         l = l_scr[:, :, 0:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc, m_scr, l_scr, *, scale: float, block_size: int,
-                   head_dim: int):
+def _decode_kernel(bt_ref, len_ref, row_ref, blk_ref, n_ref,
+                   q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
+                   scale: float, block_size: int, head_dim: int):
     """``_paged_kernel`` for a single query token over an unquantized
     pool — the decode hot path — with ALL heads in one pair of matmuls.
 
@@ -260,14 +259,12 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     tiles are a sublane each, and the loop's 2*H tiny matmuls and H
     softmax updates cost three times this step (0.88 us against the
     0.28 us a grid step costs at all: PERF.md, PR 25).  Same visibility
-    test, same fp32 online softmax, same early-out as ``_paged_kernel``.
+    test, same fp32 online softmax, same work list as ``_paged_kernel``.
     """
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    NB = pl.num_programs(1)
+    w = pl.program_id(0)
+    b, j = row_ref[w], blk_ref[w]
     H, HD = q_ref.shape[1:]
     bs = block_size
-    nlive = _nlive(len_ref[b], 1, bs, NB)
 
     @pl.when(j == 0)
     def _init():
@@ -275,29 +272,27 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    @pl.when(j < nlive)
-    def _step():
-        v = v_ref[0]                                   # (bs, H*D)
-        s = lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (H, bs)
-        # visibility: key position <= query position (= lengths[b])
-        col = j * bs + lax.broadcasted_iota(jnp.int32, (H, bs), 1)
-        s = jnp.where(col <= len_ref[b], s * scale,
-                      jnp.finfo(jnp.float32).min)
-        m_prev = m_scr[:, 0:1]                         # (H, 1)
-        l_prev = l_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (H, bs)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (H, H*D)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    v = v_ref[0]                                   # (bs, H*D)
+    s = lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (H, bs)
+    # visibility: key position <= query position (= lengths[b])
+    col = j * bs + lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+    s = jnp.where(col <= len_ref[b], s * scale,
+                  jnp.finfo(jnp.float32).min)
+    m_prev = m_scr[:, 0:1]                         # (H, 1)
+    l_prev = l_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                         # (H, bs)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc[:] = acc[:] * corr + lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (H, H*D)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(j == nlive - 1)
+    @pl.when(j == n_ref[w] - 1)
     def _emit():
         l = l_scr[:, 0:1]
         o = acc[:] / jnp.where(l == 0.0, 1.0, l)       # (H, H*D)
@@ -309,18 +304,19 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
                 scale: float, interpret: bool, mode: str,
-                k_scale=None, v_scale=None, k_new=None, v_new=None):
+                k_scale=None, v_scale=None, k_new=None, v_new=None,
+                work=None):
     B, H, S, D = q.shape
     NB = block_table.shape[1]
     bs = k_pool.shape[1]
     residual = k_new is not None
+    lengths = lengths.astype(jnp.int32)
+    if work is None:
+        work = paged_work(lengths, S, bs, NB)
+    row, blk, n, live = work
 
-    def kv_map(b, j, bt, lens):
-        # clamp dead steps to the last live block: the repeated index
-        # makes the Pallas pipeline skip the refetch, so padded table
-        # width costs no HBM traffic
-        jl = jnp.minimum(j, _nlive(lens[b], S, bs, NB) - 1)
-        return (bt[b, jl], 0, 0)
+    def kv_map(w, bt, lens, row, blk, n):
+        return (bt[row[w], blk[w]], 0, 0)
 
     lane_dense = S == 1 and mode == "fp32"
     if lane_dense:
@@ -339,27 +335,27 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         q_block = out_block = (1, H, S, D)
         lead, acc_shape = (H, S), (H, S, D)
 
-    def row_map(b, j, bt, lens):
-        return (b,) + (0,) * (len(q_block) - 1)
+    def row_map(w, bt, lens, row, blk, n):
+        return (row[w],) + (0,) * (len(q_block) - 1)
 
     in_specs = [pl.BlockSpec(q_block, row_map)]
     operands = [q]
     if residual:
         # fp residual K/V of the query tokens: row_map-indexed, so every
-        # grid step revisits the row's own (1, H, S, D) block
+        # step of a row revisits the row's own (1, H, S, D) block
         in_specs += [pl.BlockSpec(q_block, row_map)] * 2
         operands += [k_new, v_new]
     # every pool leaf is (num_blocks, bs, lanes): codes and, where the
-    # pool is quantized, the scale rows of the SAME (clamped) block id
-    # ride in as whole (1, bs, lanes) blocks through the one index map
+    # pool is quantized, the scale rows of the SAME block id ride in as
+    # whole (1, bs, lanes) blocks through the one index map
     for leaf in (k_pool, k_scale, v_pool, v_scale):
         if leaf is not None:
             in_specs.append(pl.BlockSpec((1, bs, leaf.shape[-1]), kv_map))
             operands.append(leaf)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NB),
+        num_scalar_prefetch=5,
+        grid=(live,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(out_block, row_map),
         scratch_shapes=[
@@ -372,13 +368,12 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + out_block[1:], q.dtype),
-        # rows are independent; the kv-block axis carries the online
-        # softmax accumulators and must run in order
+        # the one axis carries each row's online-softmax accumulators
+        # through its blocks in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+    )(block_table.astype(jnp.int32), lengths, row, blk, n, *operands)
     if lane_dense:
         # (B, 1, H*D) rows back to (B, H, 1, D)
         out = jnp.moveaxis(out.reshape(B, S, H, D), 2, 1)
@@ -388,7 +383,7 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
 def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
                            scale=None, interpret: bool = False,
                            k_scale=None, v_scale=None,
-                           k_new=None, v_new=None):
+                           k_new=None, v_new=None, work=None):
     """Fused paged attention over pool blocks — no gathered view.
 
     q:           (B, H, S, D) queries; S=1 decode, S=chunk prefill
@@ -410,6 +405,9 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
                  blocks and dequantizes in register (see _paged_kernel)
     k/v_new:     (B, H, S, D) fp K/V of the query tokens (int4 only,
                  both or neither) — enables the fp-residual self lane
+    work:        the dispatch's ``paged_attention.paged_work`` where the
+                 caller holds it (one list serves every layer of a
+                 forward); None builds it here
 
     Returns (B, H, S, D) in q.dtype.  Numerically this is the online-
     softmax evaluation of ops/paged_attention.paged_attention over the
@@ -417,7 +415,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     tests/test_paged_kernel.py.
 
     MIXED-ROW CONTRACT (lockstep with ops/paged_attention.attend): the
-    grid is (batch row, kv block) and every visibility test uses that
+    grid walks (row, kv block) pairs and every visibility test uses that
     row's own ``lengths[b]``, so one dispatch may mix decode rows
     (one real lane) with prefill rows carrying chunks at different
     offsets — the --serve-mixed-batch fused step.  Slack lanes past a
@@ -439,13 +437,13 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     return _paged_call(q, k_pool, v_pool, block_table, lengths,
                        scale=scale, interpret=interpret, mode=mode,
                        k_scale=k_scale, v_scale=v_scale,
-                       k_new=k_new, v_new=v_new)
+                       k_new=k_new, v_new=v_new, work=work)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                            scale=None, interpret: bool = False,
                            k_scale=None, v_scale=None,
-                           k_new=None, v_new=None):
+                           k_new=None, v_new=None, work=None):
     """Single-token decode specialization (S must be 1) — the serving
     hot path.  Thin wrapper so call sites (and probes) name the phase
     they are on; the grid is shared with chunked prefill, and so is the
@@ -457,13 +455,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                                   lengths, scale=scale,
                                   interpret=interpret,
                                   k_scale=k_scale, v_scale=v_scale,
-                                  k_new=k_new, v_new=v_new)
+                                  k_new=k_new, v_new=v_new, work=work)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_table, lengths, *,
                             scale=None, interpret: bool = False,
                             k_scale=None, v_scale=None,
-                            k_new=None, v_new=None):
+                            k_new=None, v_new=None, work=None):
     """Chunked-prefill variant: S = chunk queries per row at positions
     [lengths[b], lengths[b] + S), causal within the chunk and over the
     cache via the same visibility test (col <= q position)."""
@@ -471,23 +469,26 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, lengths, *,
                                   lengths, scale=scale,
                                   interpret=interpret,
                                   k_scale=k_scale, v_scale=v_scale,
-                                  k_new=k_new, v_new=v_new)
+                                  k_new=k_new, v_new=v_new, work=work)
 
 
 @functools.lru_cache(maxsize=16)
 def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
                   head_dim: int = 64, block_size: int = 16,
                   prefill_chunk: int = 64, kv_dtype: str = "fp32",
-                  kv_group: int = 32, sharding=None) -> None:
+                  kv_group: int = 32, max_slots: int = 8,
+                  max_blocks: int = 4, sharding=None) -> None:
     """Compile the kernel for the geometry an engine is about to serve,
-    on this backend's Mosaic: decode (S=1) plus EVERY pow2 prefill
-    bucket up to ``prefill_chunk`` — the exact S set the engine
+    on this backend's Mosaic, at the LARGEST dispatch of each kind:
+    decode (S=1) over ``max_slots`` rows, and one row at EVERY pow2
+    prefill bucket up to ``prefill_chunk`` — the exact S set the engine
     dispatches (engine._bucket), since S changes the kernel's tile
-    shapes — in the pool storage variant ``kv_dtype`` selects (for int4
-    that is nibble-packed uint8 codes + group scales + the
-    fp-residual k_new/v_new operands).  Grid extents B/NB vary per
-    dispatch too, but only as grid bounds and scalar-table width, not
-    tile shapes — the fixed B=8/NB=4 probe stands in for them.
+    shapes — both under a table of ``max_blocks`` blocks, in the pool
+    storage variant ``kv_dtype`` selects (for int4 that is nibble-packed
+    uint8 codes + group scales + the fp-residual k_new/v_new operands).
+    The table and the work list live in scalar memory and grow with
+    rows x table width, so a smaller dispatch fits wherever the largest
+    does, and a geometry too large for scalar memory is refused here.
 
     Returns nothing; a refusal RAISES with the compiler's message, so a
     selected kernel that cannot compile stops the engine at build time
@@ -499,39 +500,41 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
     device.  tests/test_paged_kernel.py passes a device of a deviceless
     TPU topology, which runs the real Mosaic compiler without a chip."""
     dt = jnp.dtype(dtype_name)
-    B, NB, bs = 8, 4, block_size
+    NB, bs = max_blocks, block_size
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     kw = {}
     width = heads * head_dim
+    nblocks = 1 + max_slots * NB
     if kv_dtype == "int4":
         g = min(kv_group, head_dim)
-        pool = arg((1 + B * NB, bs, width // 2), jnp.uint8)
-        scales = arg((1 + B * NB, bs, width // g), jnp.float32)
+        pool = arg((nblocks, bs, width // 2), jnp.uint8)
+        scales = arg((nblocks, bs, width // g), jnp.float32)
     elif kv_dtype == "int8":
-        pool = arg((1 + B * NB, bs, width), jnp.int8)
-        scales = arg((1 + B * NB, bs, heads), jnp.float32)
+        pool = arg((nblocks, bs, width), jnp.int8)
+        scales = arg((nblocks, bs, heads), jnp.float32)
     else:
-        pool = arg((1 + B * NB, bs, width), dt)
+        pool = arg((nblocks, bs, width), dt)
         scales = None
     if scales is not None:
         kw.update(k_scale=scales, v_scale=scales)
-    bt = arg((B, NB), jnp.int32)
-    lens = arg((B,), jnp.int32)
     S = 1
     while S <= prefill_chunk:
+        B = max_slots if S == 1 else 1
         q = arg((B, heads, S, head_dim), dt)
         if kv_dtype == "int4":
             kw.update(k_new=q, v_new=q)
         try:
             # graft-lint: jit-ok(compile probe: runs once at kernel resolve, not per step)
             jax.jit(paged_attention_kernel).lower(
-                q, pool, pool, bt, lens, **kw).compile()
+                q, pool, pool, arg((B, NB), jnp.int32),
+                arg((B,), jnp.int32), **kw).compile()
         except Exception as e:
             raise RuntimeError(
                 f"Pallas paged-attention kernel failed to compile for "
                 f"{dtype_name} q, kv_dtype={kv_dtype}, H={heads}, "
-                f"D={head_dim}, block_size={block_size}, S={S}: {e}") from e
+                f"D={head_dim}, block_size={block_size}, S={S}, "
+                f"{B} rows x {NB} table blocks: {e}") from e
         S *= 2

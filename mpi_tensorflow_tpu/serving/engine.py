@@ -458,7 +458,9 @@ class PagedDecodeEngine:
             model.cfg, heads=model.cfg.heads // serve.tp))
         self.kernel = paged_ops.resolve_for(
             model, serve.kernel, serve.block_size, serve.prefill_chunk,
-            serve.kv_dtype, serve.kv_group, cfg=kcfg)
+            serve.kv_dtype, serve.kv_group, cfg=kcfg,
+            max_slots=serve.max_slots,
+            max_blocks=serve.max_blocks_per_seq)
         if self.tp_mesh is not None:
             self.params = tp_lib.shard_params(model, params, self.tp_mesh)
             self._paged_forward = tp_lib.make_paged_forward(
@@ -644,6 +646,14 @@ class PagedDecodeEngine:
         # not tokens): dispatches-per-emitted-token is THE CPU-visible
         # win metric of mixed batching (bench --serve-mixed-ab)
         self.forward_dispatches = 0
+        # what the attention kernel's grid walks over the decode
+        # dispatches of this run — each row's live blocks, a slack row's
+        # one (ops/paged_attention.work_list) — beside the rows x table
+        # bucket those dispatches span: their ratio is the share of the
+        # bucketed table that is work, and steps over the kernel's
+        # device time is what a step costs
+        self.paged_grid_steps = 0
+        self.paged_grid_bound = 0
 
     def _on_terminal(self, req, status: str) -> None:
         """THE per-request exit hook (installed on every scheduler this
@@ -1092,6 +1102,9 @@ class PagedDecodeEngine:
             tables[j] = self._table_row(seq, NBb)
         self.dispatch_shapes.add(("decode", Bb, NBb))
         self.forward_dispatches += 1
+        self.paged_grid_steps += int(np.minimum(
+            lengths // self.serve.block_size + 1, NBb).sum())
+        self.paged_grid_bound += Bb * NBb
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
@@ -1536,6 +1549,10 @@ class PagedDecodeEngine:
             "forward_dispatches": self.forward_dispatches,
             "dispatches_per_token": (self.forward_dispatches
                                      / max(1, total)),
+            # decode dispatches: grid steps of live (row, block) pairs
+            # beside the rows x table bucket they were cut from
+            "paged_grid_steps": self.paged_grid_steps,
+            "paged_grid_bound": self.paged_grid_bound,
             # final-token emit time per request on the run clock (the
             # same clock as Request.arrival): attained whole-request
             # latency = finish - arrival (serving/loadgen goodput join)

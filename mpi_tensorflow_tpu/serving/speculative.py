@@ -372,5 +372,6 @@ def make_drafter(mode: str, serve, target_model, *, draft_model=None,
         kernel=paged_ops.resolve_for(
             draft_model, serve.kernel, serve.block_size,
             min(16, serve.prefill_chunk), serve.kv_dtype,
-            serve.kv_group),
+            serve.kv_group, max_slots=1,     # the drafter feeds one row
+            max_blocks=serve.max_blocks_per_seq),
         kv_dtype=serve.kv_dtype, kv_group=serve.kv_group)

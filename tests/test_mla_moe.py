@@ -34,6 +34,7 @@ from benchmarks.reference import pangu_ultra_moe as ref
 from mpi_tensorflow_tpu.models import bert, gpt, mla_moe
 from mpi_tensorflow_tpu.ops import mla_attention as mla_ops
 from mpi_tensorflow_tpu.ops import moe_experts
+from mpi_tensorflow_tpu.ops import paged_attention as paged_ops
 from mpi_tensorflow_tpu.serving import (PagedDecodeEngine, Request,
                                         ServeConfig)
 from mpi_tensorflow_tpu.serving import paged_cache
@@ -198,7 +199,7 @@ class TestAbsorbedForm:
         """The kernel's grid is the live (row, tile, block) triples, not
         rows x table width."""
         lens = jnp.asarray([0, 7, 20, 300], jnp.int32)
-        row, tile, blk, n, live = mla_ops._work_list(lens, 1, 1, 1, 8, 64)
+        row, tile, blk, n, live = paged_ops.work_list(lens, 1, 1, 1, 8, 64)
         need = [1, 1, 3, 38]
         assert int(live) == sum(need) < 4 * 64
         assert np.asarray(row)[:int(live)].tolist() == sum(

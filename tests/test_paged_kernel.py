@@ -162,6 +162,71 @@ class TestKernelParity:
                                        rtol=2e-5, atol=2e-5)
 
 
+def _lengths_of(kind, rng, B, S, bs, NB):
+    """Row lengths of one population; ``length + S`` is what a row must
+    reach (its own tokens are in the pool before attention)."""
+    cap = NB * bs
+    if kind == "zero":                    # slack rows only
+        return np.zeros((B,), np.int32)
+    if kind == "ragged":
+        return rng.integers(0, 2 * cap, B).astype(np.int32)
+    if kind == "edge":                    # on, one short of, one past
+        k = rng.integers(1, NB + 1, B) * bs
+        return np.maximum(k + rng.integers(-1, 2, B) - S, 0).astype(np.int32)
+    return np.full((B,), max(cap - S, 0), np.int32)      # full tables
+
+
+class TestWorkList:
+    """``ops/paged_attention.work_list`` — the one list both Pallas
+    attention kernels walk — against a brute-force enumeration."""
+
+    @pytest.mark.parametrize("kind", ["zero", "ragged", "edge", "full"])
+    @pytest.mark.parametrize("NB", [4, 64])
+    @pytest.mark.parametrize("bs", [8, 16])
+    @pytest.mark.parametrize("S,tq", [(1, 1), (64, 64), (64, 8)])
+    def test_matches_brute_force(self, S, tq, bs, NB, kind):
+        B, NT = 5, -(-S // tq)
+        rng = np.random.default_rng([S, tq, bs, NB, len(kind)])
+        lens = _lengths_of(kind, rng, B, S, bs, NB)
+        want = []
+        for b in range(B):
+            for t in range(NT):
+                last = min((t + 1) * tq, S)
+                need = min(max(-(-(int(lens[b]) + last) // bs), 1), NB)
+                want += [(b, t, j, need) for j in range(need)]
+        row, tile, blk, n, live = paged_ops.work_list(
+            jnp.asarray(lens), S, tq, NT, bs, NB)
+        assert row.shape == (B * NT * NB,) and int(live) == len(want)
+        got = list(zip(*(np.asarray(x)[:len(want)].tolist()
+                         for x in (row, tile, blk, n))))
+        assert got == want
+        if NT == 1:
+            # the K/V kernel's view of it
+            prow, pblk, pn, plive = paged_ops.paged_work(
+                jnp.asarray(lens), S, bs, NB)
+            assert int(plive) == int(live)
+            for a, b in ((prow, row), (pblk, blk), (pn, n)):
+                # one valid entry more: the pipeline reads a step ahead
+                np.testing.assert_array_equal(a, np.append(b, 0))
+
+    def test_forward_builds_one_list_for_all_layers(self):
+        """``forward_paged`` hands its one list down the ``attend``
+        seam: one cumsum in the traced step, one kernel call a layer."""
+        model = gpt.CausalLm(TINY)
+        params = model.init(jax.random.key(0))
+        B, NB, bs = 4, 4, 4
+        closed = jax.make_jaxpr(
+            lambda p, pools, tok, lens, bt: model.forward_paged(
+                p, tok, pools, bt, lens, kernel="pallas-interpret"))(
+            params, init_pools(TINY, 1 + B * NB, bs),
+            jnp.zeros((B, 1), jnp.int32), jnp.full((B,), 5, jnp.int32),
+            jnp.ones((B, NB), jnp.int32))
+        names = [e.primitive.name for e in _all_eqns(closed, into=(
+            "pjit", "jit", "closed_call"))]
+        assert names.count("pallas_call") == TINY.layers > 1
+        assert names.count("cumsum") == 1
+
+
 class TestPoolGeometry:
     """The stored geometry itself: ``(num_blocks, block_size, H*D)``
     token-major rows with the heads side by side, block id on axis 0 and
@@ -353,9 +418,10 @@ class TestEnginePallas:
 
 # ------------------------------------------- lowered-graph assertions
 
-def _all_avals(closed):
-    """Every output aval in the jaxpr, recursing into sub-jaxprs
-    (scan/cond/pjit/pallas_call bodies)."""
+def _all_eqns(closed, into=None):
+    """Every equation of the jaxpr, recursing into the sub-jaxprs of the
+    equations whose primitive is named in ``into`` (default: all of
+    them — scan/cond/pjit/pallas_call bodies)."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(val):
@@ -369,13 +435,20 @@ def _all_avals(closed):
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            for v in eqn.outvars:
-                yield v.aval
-            for p in eqn.params.values():
-                for sub in subs(p):
-                    yield from walk(sub)
+            yield eqn
+            if into is None or eqn.primitive.name in into:
+                for p in eqn.params.values():
+                    for sub in subs(p):
+                        yield from walk(sub)
 
     yield from walk(closed.jaxpr)
+
+
+def _all_avals(closed):
+    """Every output aval in the jaxpr, sub-jaxprs included."""
+    for eqn in _all_eqns(closed):
+        for v in eqn.outvars:
+            yield v.aval
 
 
 class TestNoMaterializedGather:
@@ -830,6 +903,65 @@ class TestInt4KernelParity:
         self._assert_parity_int4(q, kp, vp, bt, lens, dead_rows=(3,))
 
 
+class TestWideTable:
+    """What the live-only grid is for: a 64-block table over rows of one
+    to three live blocks, slack rows between live ones, every masked
+    lane poisoned.  The list walks 10 of the 384 (row, block) pairs; both
+    kernel bodies and every pool mode must give the XLA path's rows."""
+
+    B, NB, bs = 6, 64, 4
+    SLACK = (1, 4)
+
+    def _case(self, rng, S):
+        bs, NB = self.bs, self.NB
+        # tokens a row must reach (length + S): 1 block, 3 blocks, 2
+        # blocks exactly on the edge, 1 token into a 2nd block
+        reach = {0: max(S, 2), 2: 2 * bs + max(S, 3), 3: 2 * bs,
+                 5: bs + 1}
+        H, D = 2, 8
+        kp = rng.normal(size=(1 + 3 * self.B, bs, H * D)).astype(np.float32)
+        vp = rng.normal(size=kp.shape).astype(np.float32)
+        kp[0] = vp[0] = 1e30                       # the null block
+        bt = np.zeros((self.B, NB), np.int32)
+        lens = np.zeros((self.B,), np.int32)
+        for b, r in reach.items():
+            lens[b] = r - S
+            for j in range(-(-r // bs)):
+                blk = bt[b, j] = 1 + 3 * b + j
+                kp[blk, max(r - j * bs, 0):] = 1e30    # past the reach
+                vp[blk, max(r - j * bs, 0):] = 1e30
+        q = rng.normal(size=(self.B, H, S, D)).astype(np.float32)
+        return tuple(map(jnp.asarray, (q, kp, vp, bt, lens)))
+
+    @pytest.mark.parametrize("mode", ["fp32", "int8", "int4",
+                                      "int4-residual"])
+    @pytest.mark.parametrize("S", [1, 4])
+    def test_parity_under_a_wide_table(self, S, mode):
+        rng = np.random.default_rng(S)
+        q, kp, vp, bt, lens = self._case(rng, S)
+        _, _, n, live = paged_ops.paged_work(lens, S, self.bs, self.NB)
+        assert int(live) == 10 and int(n.max()) == 3
+        kw = {}
+        if mode == "int8":
+            kp, ks, vp, vs = _quantize_pools(kp, vp)
+            kw = dict(k_scale=ks, v_scale=vs)
+        elif mode != "fp32":
+            kp, ks, vp, vs = _quantize_pools_int4(kp, vp)
+            kw = dict(k_scale=ks, v_scale=vs)
+            if mode == "int4-residual":
+                kw.update(
+                    k_new=jnp.asarray(rng.normal(size=q.shape), jnp.float32),
+                    v_new=jnp.asarray(rng.normal(size=q.shape), jnp.float32))
+        want = np.array(paged_ops.attend(q, kp, vp, bt, lens, jnp.float32,
+                                         kernel="xla", **kw))
+        got = np.array(pk.paged_attention_kernel(q, kp, vp, bt, lens,
+                                                 interpret=True, **kw))
+        for b in self.SLACK:
+            want[b] = got[b] = 0.0
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
 class TestEngineInt4:
     """End-to-end int4 serving pins: deterministic, lowering-identical,
     tracking fp32 at the token-match-rate gate, zero-recompile, pool
@@ -954,8 +1086,10 @@ def tpu_topology_device():
 
 class TestMosaicCompile:
     """Every variant the engine can select compiles under Mosaic at the
-    served geometry (gpt_base: H=12, D=64, block 16), decode + every
-    prefill bucket — the set ``resolve_kernel`` probes on the chip."""
+    served geometry (gpt_base: H=12, D=64, block 16) and its largest
+    dispatches — decode over 128 slots, one row at every prefill bucket,
+    both under 64-block tables, whose table and work list must fit
+    scalar memory — the set ``resolve_kernel`` probes on the chip."""
 
     @pytest.mark.parametrize("dtype_name,kv_dtype", [
         ("bfloat16", "fp32"), ("float32", "fp32"), ("bfloat16", "int8"),
@@ -964,13 +1098,26 @@ class TestMosaicCompile:
                                       dtype_name, kv_dtype):
         pk.probe_compile.cache_clear()
         pk.probe_compile(dtype_name, 12, 64, 16, 64, kv_dtype, 32,
+                         max_slots=128, max_blocks=64,
                          sharding=tpu_topology_device)
 
     def test_tp_shard_geometry_compiles(self, tpu_topology_device):
         """--serve-tp 2 runs the kernel over H/2 local heads."""
         pk.probe_compile.cache_clear()
         pk.probe_compile("bfloat16", 6, 64, 16, 64, "fp32", 32,
+                         max_slots=128, max_blocks=64,
                          sharding=tpu_topology_device)
+
+    def test_geometry_past_scalar_memory_is_refused(
+            self, tpu_topology_device):
+        """The table and the work list grow with slots x table width; a
+        geometry they cannot fit raises with the compiler's words and
+        the dispatch it was probed at."""
+        pk.probe_compile.cache_clear()
+        with pytest.raises(RuntimeError, match="512 rows x 128 table"):
+            pk.probe_compile("bfloat16", 12, 64, 16, 1, "fp32", 32,
+                             max_slots=512, max_blocks=128,
+                             sharding=tpu_topology_device)
 
 
 class TestNoPoolSizedCopy:
@@ -983,7 +1130,9 @@ class TestNoPoolSizedCopy:
     scratch than one leaf.  The head-major ``(num_blocks, H, bs, D)``
     pool failed all three: its default layout put ``num_blocks``
     minor-most, the scatter and the Mosaic call each wanted another, and
-    every program copied every leaf three times (PERF.md, PR 25)."""
+    every program copied every leaf three times (PERF.md, PR 25).
+    Since PR 29 the Mosaic call's grid is the live (row, block) pairs of
+    a list built once a program, not once a layer."""
 
     NUM_BLOCKS, BLOCK, SLOTS, TABLE, CHUNK = 8193, 16, 128, 64, 64
 
@@ -1044,6 +1193,11 @@ class TestNoPoolSizedCopy:
         assert not made[codes] & {"copy", "transpose"}, made[codes]
         assert compiled.as_text().count(
             'custom_call_target="tpu_custom_call"') == cfg.layers
+        # one work list a program: the binary search of its one
+        # ``searchsorted`` is the program's only loop (one row's list,
+        # the prefill program's, needs no search)
+        assert compiled.as_text().count(" while(") \
+            == (1 if program == "decode" else 0)
         for layer in compiled.input_formats[0][1]:
             for key in ("k", "v"):
                 assert tuple(layer[key].layout.major_to_minor) \
