@@ -14,8 +14,9 @@ points a user would call, at the full width of models the repo has
 - ``bert``     ``cli.main(["--model", "bert_base", "--precision",
                "bf16", "--epochs", "1"])`` — BERT-base 12x768, b64/chip,
                s128, the GSPMD step, every visible chip on ``data``;
-- ``server``   ``bench.main(["--mode", "serving", "--precision", "bf16",
-               "--serve-kernel", K])`` for K in auto, pallas — gpt_base
+- ``server``   ``python -m mpi_tensorflow_tpu.serving``'s ``main(
+               ["--precision", "bf16", "--kernel", K])`` for K in auto,
+               pallas — gpt_base
                behind the paged-KV engine on the default 24-request
                Poisson trace; both must serve through the Mosaic-compiled
                Pallas kernel;
@@ -89,7 +90,8 @@ FLASH_GRAD_RTOL = 5e-2
 
 class _Stream(io.TextIOBase):
     """stdout/stderr wrapper: prefixes every line (rehearsal) and can
-    record what passes through (to read bench.main's JSON line)."""
+    record what passes through (to read the serving entry point's JSON
+    line)."""
 
     def __init__(self, stream, prefix: str = ""):
         self._stream, self._prefix = stream, prefix
@@ -303,14 +305,14 @@ def run_bert(out: str, data: str, rehearsal: bool, devices, facts) -> None:
 
 
 def run_server(rehearsal: bool, stdout: _Stream, facts) -> None:
-    import bench
+    from mpi_tensorflow_tpu.serving import __main__ as serving_main
 
-    base = ["--mode", "serving", "--precision", "bf16"]
+    base = ["--precision", "bf16"]
     want_kernel, n_req = "pallas", 24
     if rehearsal:
-        base = ["--mode", "serving", "--precision", "fp32", "--serve-tiny",
-                "--requests", "4", "--prompt-len", "8", "--new-tokens", "8",
-                "--arrival-rate", "1000"]
+        base = ["--precision", "fp32", "--tiny", "--num-requests", "4",
+                "--prompt-max", "8", "--output-max", "8",
+                "--rate-rps", "1000"]
         n_req = 4
     for choice in ("auto", "pallas"):
         if rehearsal:
@@ -318,22 +320,22 @@ def run_server(rehearsal: bool, stdout: _Stream, facts) -> None:
             # interpreter — and must say so
             want_kernel = {"auto": "xla", "pallas": "pallas-interpret"}[choice]
         stdout.record = []
-        require(bench.main(base + ["--serve-kernel", choice]) == 0,
-                f"bench.main (serving, --serve-kernel {choice}) returned 0")
+        require(serving_main.main(base + ["--kernel", choice]) == 0,
+                f"serving main (--kernel {choice}) returned 0")
         lines = "".join(stdout.record).strip().splitlines()
         stdout.record = None
-        d = json.loads(lines[-1])["detail"]
+        d = json.loads(lines[-1])
         require(d["status_counts"] == {"ok": n_req},
                 f"every request ok: {d['status_counts']}")
         require(d["tokens"] == d["tokens_requested"] > 0,
                 f"tokens {d['tokens']} == requested {d['tokens_requested']}")
         require(d["kernel"] == want_kernel
                 and d["paths"].get("paged_attention") == want_kernel,
-                f"--serve-kernel {choice} served through {want_kernel}: "
+                f"--kernel {choice} served through {want_kernel}: "
                 f"kernel={d['kernel']} paths={d['paths']}")
         require(d["zero_recompile_steady_state"] is True,
                 f"zero steady-state recompiles: "
-                f"{d['compiles_after_warmup']} -> {d['compiles_after_steady']}")
+                f"{d['compiles_after_warmup']} -> {d['compiles_after_served']}")
         require(d["platform"] == ("cpu" if rehearsal else "tpu"),
                 f"row platform {d['platform']}")
         facts[choice] = {"kernel": d["kernel"], "tokens": d["tokens"],

@@ -3,10 +3,6 @@
 Mechanizes the cross-cutting contracts every PR has hand-enforced
 since PR 1 (stdlib ``ast`` only — no new dependencies):
 
-- ``knob_bridge``   — every ``--serve-*`` CLI flag bridges to a Config
-                      field and is validated at argparse, ``cli.main``,
-                      AND its downstream consumer (ServeConfig /
-                      WorkloadSpec / router); no dead knobs.
 - ``jit_stability`` — the zero-steady-state-recompile contract's static
                       half: no Python-value branching on traced args
                       inside jit/shard_map-reachable functions, no

@@ -43,9 +43,8 @@ def repo_root() -> str:
 
 
 def load_sources(root: Optional[str] = None) -> Dict[str, str]:
-    """Package sources + the repo-root entry points (bench.py consumes
-    serve knobs directly, so the knob-bridge dead-field check must see
-    it).  Keys are repo-relative with forward slashes."""
+    """The package's sources.  Keys are repo-relative with forward
+    slashes."""
     root = root or repo_root()
     pkg = os.path.join(root, "mpi_tensorflow_tpu")
     out: Dict[str, str] = {}
@@ -57,11 +56,6 @@ def load_sources(root: Optional[str] = None) -> Dict[str, str]:
             rel = os.path.relpath(path, root).replace(os.sep, "/")
             with open(path, encoding="utf-8") as fh:
                 out[rel] = fh.read()
-    for extra in ("bench.py",):
-        path = os.path.join(root, extra)
-        if os.path.exists(path):
-            with open(path, encoding="utf-8") as fh:
-                out[extra] = fh.read()
     return out
 
 

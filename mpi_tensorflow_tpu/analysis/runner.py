@@ -1,7 +1,7 @@
 """Runner: collect sources, run every pass, apply the baseline ratchet.
 
-``python -m mpi_tensorflow_tpu.analysis`` runs all five passes over
-the package (plus ``bench.py``) and prints one line per finding::
+``python -m mpi_tensorflow_tpu.analysis`` runs all four passes over
+the package and prints one line per finding::
 
     mpi_tensorflow_tpu/serving/router.py:419: LOCK-HELD self.fleet_...
 
@@ -30,9 +30,9 @@ import sys
 from typing import Dict, List
 
 from mpi_tensorflow_tpu.analysis import (core, host_sync, jit_stability,
-                                         knob_bridge, locks, names)
+                                         locks, names)
 
-PASSES = (knob_bridge, jit_stability, host_sync, locks, names)
+PASSES = (jit_stability, host_sync, locks, names)
 
 _DEFAULT_BASELINE = os.path.join(os.path.dirname(__file__),
                                  "baseline.json")

@@ -126,196 +126,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="transformer-family optimizer (lamb = layer-wise "
                         "trust ratios, the large-batch BERT recipe); the "
                         "image families keep the reference's momentum SGD")
-    p.add_argument("--serve-pool-blocks", type=int,
-                   default=d.serve_pool_blocks,
-                   help="serving: paged KV pool size in blocks (block 0 "
-                        "reserved as the null block; serving/paged_cache)")
-    p.add_argument("--serve-block-size", type=int,
-                   default=d.serve_block_size,
-                   help="serving: cache entries per pool block")
-    p.add_argument("--serve-max-slots", type=int,
-                   default=d.serve_max_slots,
-                   help="serving: concurrent sequences (continuous-"
-                        "batching decode batch cap)")
-    p.add_argument("--serve-max-seq-len", type=int,
-                   default=d.serve_max_seq_len,
-                   help="serving: per-request prompt+output cap (sizes "
-                        "the per-sequence block table)")
-    p.add_argument("--serve-kernel", choices=["auto", "xla", "pallas"],
-                   default=d.serve_kernel,
-                   help="serving: paged-attention lowering — auto picks "
-                        "the fused Pallas decode kernel on TPU when its "
-                        "compile probe passes and the XLA gather path "
-                        "otherwise; xla/pallas force one side "
-                        "(ops/paged_attention.resolve_kernel)")
-    p.add_argument("--serve-kv-dtype", choices=["fp32", "int8", "int4"],
-                   default=d.serve_kv_dtype,
-                   help="serving: paged-pool storage format — fp32 "
-                        "keeps the blocks in the model compute dtype "
-                        "(byte-for-byte the pre-quantization pool); "
-                        "int8 stores symmetric-absmax codes with "
-                        "per-(block, head, slot) fp32 row scales "
-                        "(~4x effective KV capacity), dequantized "
-                        "inside the attention consume paths "
-                        "(serving/paged_cache, ops/paged_attention); "
-                        "int4 nibble-packs two codes per byte with "
-                        "per-group fp32 scales (--serve-kv-group) plus "
-                        "a full-precision self lane for each step's "
-                        "own tokens — the next capacity rung")
-    p.add_argument("--serve-kv-group", type=int, default=d.serve_kv_group,
-                   help="serving: int4 scale-group size along head_dim "
-                        "— one fp32 scale per group (clamped to "
-                        "head_dim on small heads, must divide it); "
-                        "smaller groups quantize tighter at more scale "
-                        "bytes; consumed only with --serve-kv-dtype "
-                        "int4")
-    p.add_argument("--serve-kv-tier", choices=["off", "host"],
-                   default=d.serve_kv_tier,
-                   help="serving: host-RAM KV block tier — host "
-                        "demotes cold prefix-cache blocks to host "
-                        "memory on eviction and promotes them back "
-                        "into fresh device blocks when a later prompt "
-                        "matches their trie path, so multi-turn "
-                        "sessions stop re-paying prefill; requires "
-                        "--serve-prefix-cache on; off is byte-for-byte "
-                        "untiered (serving/paged_cache.HostBlockStore)")
-    p.add_argument("--serve-prefix-cache", choices=["off", "on"],
-                   default=d.serve_prefix_cache,
-                   help="serving: radix prefix cache — on shares "
-                        "already-cached full prompt blocks across "
-                        "requests (refcounted block reuse, copy-on-"
-                        "write on divergence, LRU trie eviction under "
-                        "pool pressure; serving/prefix_cache); off "
-                        "preserves the unshared behavior byte-for-byte")
-    p.add_argument("--serve-prefix-gen", choices=["off", "on"],
-                   default=d.serve_prefix_gen,
-                   help="serving: prefix cache v2 — on additionally "
-                        "caches a finished request's generated full "
-                        "blocks in the trie (multi-turn reuse) and "
-                        "shares partial tail blocks via a one-compile "
-                        "row-prefix copy; off keeps "
-                        "--serve-prefix-cache on behavior byte-for-"
-                        "byte; requires --serve-prefix-cache on")
-    p.add_argument("--serve-prefix-route", choices=["off", "on"],
-                   default=d.serve_prefix_route,
-                   help="serving: prefix-aware fleet routing — on "
-                        "biases sessionless placement toward the "
-                        "replica whose trie caches the prompt's "
-                        "leading full block (load-bounded; never "
-                        "overrides the health gate, never changes "
-                        "tokens; serving/router); requires "
-                        "--serve-prefix-cache on")
-    p.add_argument("--serve-speculative",
-                   choices=["off", "ngram", "draft-model"],
-                   default=d.serve_speculative,
-                   help="serving: speculative decoding — ngram drafts "
-                        "from the sequence's own earlier tokens, "
-                        "draft-model runs a tiny CausalLm over its own "
-                        "paged pool; k drafted tokens verify in ONE "
-                        "batched forward and only the argmax-matching "
-                        "prefix is emitted, so greedy outputs stay "
-                        "token-identical to off (the byte-for-byte "
-                        "one-token loop; serving/speculative)")
-    p.add_argument("--serve-draft-k", type=int, default=d.serve_draft_k,
-                   help="serving: speculative draft window — tokens "
-                        "proposed per verify forward (dispatch width "
-                        "draft_k + 1); >= 1")
-    p.add_argument("--serve-draft-auto", choices=["off", "on"],
-                   default=d.serve_draft_auto,
-                   help="serving: auto-tune the speculative draft "
-                        "window — on adapts the effective k to an EWMA "
-                        "of the observed accept length, clamped to "
-                        "[1, --serve-draft-k] (the verify dispatch "
-                        "width never changes, so the zero-recompile "
-                        "contract is untouched); needs a drafter "
-                        "(--serve-speculative ngram|draft-model)")
-    p.add_argument("--serve-mixed-batch", choices=["off", "on"],
-                   default=d.serve_mixed_batch,
-                   help="serving: stall-free mixed batching — on fuses "
-                        "budget-capped prefill chunks from multiple "
-                        "mid-prefill sequences into the decode dispatch "
-                        "so every step is ONE forward (chunked-prefill "
-                        "math, token-identical to off by construction); "
-                        "off preserves the two-dispatch prefill-then-"
-                        "decode loop byte-for-byte")
-    p.add_argument("--serve-prefill-budget", type=int,
-                   default=d.serve_prefill_budget,
-                   help="serving: mixed-batching budget — max prefill "
-                        "tokens fused into one step across all "
-                        "mid-prefill sequences (>= 1; consumed only "
-                        "with --serve-mixed-batch on)")
-    p.add_argument("--serve-tp", type=int, default=d.serve_tp,
-                   help="serving: tensor-parallel shards for the decode "
-                        "engine — >1 partitions the paged pool's head "
-                        "axis, the QKV/O projections, and the MLP over "
-                        "a tp mesh axis (serving/tp; one psum per "
-                        "row-parallel output, block tables replicated)."
-                        " Must divide the model's heads/mlp dims and "
-                        "fit the visible device count")
-    p.add_argument("--serve-replicas", type=int, default=d.serve_replicas,
-                   help="serving: data-parallel engine replicas fronted "
-                        "by the serving router (session-affinity "
-                        "placement + least-load admission over queue "
-                        "depth / pool occupancy / shed rate); each "
-                        "replica owns its own pool and scheduler")
-    p.add_argument("--serve-deadline-ms", type=float,
-                   default=d.serve_deadline_ms,
-                   help="serving: default per-request TTL from arrival; "
-                        "work not complete by then fails with "
-                        "deadline_exceeded instead of occupying a slot "
-                        "(default: no deadline)")
-    p.add_argument("--serve-queue-depth", type=int,
-                   default=d.serve_queue_depth,
-                   help="serving: bound on the waiting queue; a full "
-                        "queue load-sheds the newest submit with a "
-                        "queue_full reason (default: unbounded)")
-    p.add_argument("--serve-max-evictions", type=int,
-                   default=d.serve_max_evictions,
-                   help="serving: a request preempted more than this "
-                        "many times fails with evicted_too_often "
-                        "instead of requeueing forever (default: "
-                        "unbounded)")
-    p.add_argument("--serve-failover-backoff-ms", type=float,
-                   default=d.serve_failover_backoff_ms,
-                   help="serving replica circuit breaker: base probe "
-                        "backoff after a transient replica fault "
-                        "(doubled per consecutive fault, capped at "
-                        "64x) before the router rebuilds and probes "
-                        "the replica back in (serving/router)")
-    p.add_argument("--serve-drain-ms", type=float,
-                   default=d.serve_drain_ms,
-                   help="serving: graceful-drain budget after SIGTERM — "
-                        "in-flight sequences finish inside it, the rest "
-                        "terminate with status `drained` (default: "
-                        "finish all in-flight work)")
-    p.add_argument("--serve-workload",
-                   choices=["poisson", "bursty", "multi-tenant",
-                            "diurnal"], default=d.serve_workload,
-                   help="serving: synthetic trace shape for bench "
-                        "--mode serving (serving/loadgen) — poisson is "
-                        "the historical byte-identical default; bursty "
-                        "= 2-state MMPP arrivals; multi-tenant adds an "
-                        "interactive-vs-batch tenant mix with "
-                        "per-tenant SLOs and sticky sessions; diurnal "
-                        "= raised-cosine rate envelope")
-    p.add_argument("--serve-slo-ms", type=float, default=d.serve_slo_ms,
-                   help="serving: per-request latency budget, stamped "
-                        "as each request's deadline; the goodput block "
-                        "scores tokens/sec from requests that finished "
-                        "within it (default: no SLO)")
-    p.add_argument("--serve-trace", choices=["off", "on"],
-                   default=d.serve_trace,
-                   help="serving: request-lifecycle + step-phase "
-                        "tracing (serving/tracing) — host-side span "
-                        "stamps (zero device syncs) plus the "
-                        "`breakdown` latency-attribution block in "
-                        "bench detail; off is byte-for-byte the "
-                        "untraced behavior")
-    p.add_argument("--serve-trace-out", type=str,
-                   default=d.serve_trace_out,
-                   help="serving: write the run's Chrome trace-event "
-                        "JSON here (open in Perfetto or "
-                        "chrome://tracing); requires --serve-trace on")
     p.add_argument("--prng", choices=["threefry", "rbg", "unsafe_rbg"],
                    default=d.prng_impl,
                    help="dropout-mask PRNG: threefry (JAX default, "
@@ -354,33 +164,6 @@ def config_from_args(args) -> Config:
         pp_schedule=args.pp_schedule,
         virtual_stages=args.virtual_stages,
         param_sharding=args.param_sharding,
-        serve_pool_blocks=args.serve_pool_blocks,
-        serve_block_size=args.serve_block_size,
-        serve_max_slots=args.serve_max_slots,
-        serve_max_seq_len=args.serve_max_seq_len,
-        serve_kernel=args.serve_kernel,
-        serve_kv_dtype=args.serve_kv_dtype,
-        serve_kv_group=args.serve_kv_group,
-        serve_kv_tier=args.serve_kv_tier,
-        serve_prefix_cache=args.serve_prefix_cache,
-        serve_prefix_gen=args.serve_prefix_gen,
-        serve_prefix_route=args.serve_prefix_route,
-        serve_speculative=args.serve_speculative,
-        serve_draft_k=args.serve_draft_k,
-        serve_draft_auto=args.serve_draft_auto,
-        serve_mixed_batch=args.serve_mixed_batch,
-        serve_prefill_budget=args.serve_prefill_budget,
-        serve_tp=args.serve_tp,
-        serve_replicas=args.serve_replicas,
-        serve_deadline_ms=args.serve_deadline_ms,
-        serve_queue_depth=args.serve_queue_depth,
-        serve_max_evictions=args.serve_max_evictions,
-        serve_drain_ms=args.serve_drain_ms,
-        serve_failover_backoff_ms=args.serve_failover_backoff_ms,
-        serve_workload=args.serve_workload,
-        serve_slo_ms=args.serve_slo_ms,
-        serve_trace=args.serve_trace,
-        serve_trace_out=args.serve_trace_out,
         prefetch=args.prefetch, remat=args.remat,
         fused_steps=(args.fused_steps if args.fused_steps is not None
                      else (args.log_every if args.sync == "psum" else 1)),
@@ -422,150 +205,6 @@ def main(argv=None) -> int:
             f"--virtual-stages {args.virtual_stages} applies only with "
             f"--pp-schedule 1f1b_interleaved; schedule "
             f"{config.pp_schedule!r} would silently ignore it")
-    if config.serve_block_size < 1 or config.serve_pool_blocks < 2 \
-            or config.serve_max_slots < 1 or config.serve_max_seq_len < 1:
-        raise SystemExit(
-            f"bad --serve-* geometry: pool-blocks "
-            f"{config.serve_pool_blocks} (>= 2; block 0 is reserved), "
-            f"block-size {config.serve_block_size} (>= 1), max-slots "
-            f"{config.serve_max_slots} (>= 1), max-seq-len "
-            f"{config.serve_max_seq_len} (>= 1)")
-    if config.serve_kv_dtype not in ("fp32", "int8", "int4"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-kv-dtype {config.serve_kv_dtype!r}: "
-            f"must be fp32|int8|int4")
-    if config.serve_kv_group < 1:
-        raise SystemExit(
-            f"bad --serve-kv-group {config.serve_kv_group}: must be "
-            f">= 1 (one fp32 scale per group of head_dim channels)")
-    if config.serve_kv_tier not in ("off", "host"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-kv-tier {config.serve_kv_tier!r}: "
-            f"must be off|host")
-    if config.serve_kv_tier == "host" \
-            and config.serve_prefix_cache == "off":
-        raise SystemExit(
-            "--serve-kv-tier host demotes/promotes radix-trie blocks; "
-            "with --serve-prefix-cache off there are no trie paths to "
-            "key the host store by — turn the cache on or drop the tier")
-    if config.serve_prefix_cache not in ("off", "on"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-prefix-cache {config.serve_prefix_cache!r}: "
-            f"must be off|on")
-    if config.serve_prefix_gen not in ("off", "on"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-prefix-gen {config.serve_prefix_gen!r}: "
-            f"must be off|on")
-    if config.serve_prefix_route not in ("off", "on"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-prefix-route {config.serve_prefix_route!r}: "
-            f"must be off|on")
-    if config.serve_prefix_gen == "on" \
-            and config.serve_prefix_cache == "off":
-        raise SystemExit(
-            "--serve-prefix-gen on extends the radix prefix cache; with "
-            "--serve-prefix-cache off it would be silently ignored — "
-            "turn the cache on or drop it")
-    if config.serve_prefix_route == "on" \
-            and config.serve_prefix_cache == "off":
-        raise SystemExit(
-            "--serve-prefix-route on routes by cached prefixes; with "
-            "--serve-prefix-cache off there is nothing to route by — "
-            "turn the cache on or drop it")
-    if config.serve_kernel not in ("auto", "xla", "pallas"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-kernel {config.serve_kernel!r}: "
-            f"must be auto|xla|pallas")
-    if config.serve_speculative not in ("off", "ngram", "draft-model") \
-            or config.serve_draft_k < 1:
-        raise SystemExit(
-            f"bad --serve-speculative config: mode "
-            f"{config.serve_speculative!r} (off|ngram|draft-model), "
-            f"draft-k {config.serve_draft_k} (>= 1)")
-    if config.serve_draft_auto not in ("off", "on"):
-        raise SystemExit(
-            f"bad --serve-draft-auto {config.serve_draft_auto!r}: "
-            f"must be off|on")
-    if config.serve_draft_auto == "on" \
-            and config.serve_speculative == "off":
-        raise SystemExit(
-            "--serve-draft-auto on tunes the speculative draft window; "
-            "with --serve-speculative off it would be silently ignored "
-            "— pick a drafter or drop it")
-    if config.serve_mixed_batch not in ("off", "on"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-mixed-batch {config.serve_mixed_batch!r}: "
-            f"must be off|on")
-    if config.serve_prefill_budget < 1:
-        raise SystemExit(
-            f"bad --serve-prefill-budget {config.serve_prefill_budget}: "
-            f"the per-step fused prefill token budget must be >= 1")
-    if config.serve_mixed_batch == "on" \
-            and config.serve_speculative != "off":
-        raise SystemExit(
-            "--serve-mixed-batch on and --serve-speculative each replace "
-            "the decode dispatch with their own fused forward; they do "
-            "not compose — pick one")
-    if config.serve_tp < 1 or config.serve_replicas < 1:
-        # range guards only: head/mlp divisibility and the device-count
-        # bound need the model geometry and an initialized backend, so
-        # they live where both are known (serving/tp.check_geometry at
-        # engine construction)
-        raise SystemExit(
-            f"bad distributed-serving knobs: --serve-tp "
-            f"{config.serve_tp} (>= 1), --serve-replicas "
-            f"{config.serve_replicas} (>= 1)")
-    if (config.serve_deadline_ms is not None
-            and config.serve_deadline_ms <= 0) \
-            or (config.serve_queue_depth is not None
-                and config.serve_queue_depth < 1) \
-            or (config.serve_max_evictions is not None
-                and config.serve_max_evictions < 1) \
-            or (config.serve_drain_ms is not None
-                and config.serve_drain_ms < 0) \
-            or config.serve_failover_backoff_ms <= 0:
-        raise SystemExit(
-            f"bad --serve-* fault policy: deadline-ms "
-            f"{config.serve_deadline_ms} (> 0), queue-depth "
-            f"{config.serve_queue_depth} (>= 1), max-evictions "
-            f"{config.serve_max_evictions} (>= 1), drain-ms "
-            f"{config.serve_drain_ms} (>= 0), failover-backoff-ms "
-            f"{config.serve_failover_backoff_ms} (> 0)")
-    if config.serve_workload not in ("poisson", "bursty", "multi-tenant",
-                                     "diurnal"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-workload {config.serve_workload!r}: must be "
-            f"poisson|bursty|multi-tenant|diurnal")
-    if config.serve_slo_ms is not None and not config.serve_slo_ms > 0:
-        raise SystemExit(
-            f"bad --serve-slo-ms {config.serve_slo_ms}: the latency "
-            f"budget must be > 0 ms")
-    if config.serve_trace not in ("off", "on"):
-        # argparse choices guard the CLI path; this covers programmatic
-        # Config construction routed through main
-        raise SystemExit(
-            f"bad --serve-trace {config.serve_trace!r}: must be off|on")
-    if config.serve_trace_out is not None and config.serve_trace != "on":
-        raise SystemExit(
-            f"--serve-trace-out {config.serve_trace_out!r} requires "
-            f"--serve-trace on (there is no trace to write otherwise)")
-
     from mpi_tensorflow_tpu.parallel import mesh as meshlib
 
     meshlib.initialize_distributed()

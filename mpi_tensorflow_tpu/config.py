@@ -96,169 +96,6 @@ class Config:
     mesh_shape: Optional[dict] = None  # e.g. {"data": 8}; None = all devices
                                        # on one "data" axis
 
-    # --- serving (continuous-batching decode engine, serving/) ---
-    serve_pool_blocks: int = 128  # paged-KV pool size in blocks (block 0
-                                  # reserved as the null/scratch block);
-                                  # HBM cost = blocks * block_size * 2KV
-                                  # * heads * head_dim * layers * dtype
-    serve_block_size: int = 16    # cache entries per pool block
-    serve_max_slots: int = 8      # concurrent sequences (decode batch cap)
-    serve_max_seq_len: int = 512  # per-request prompt+output cap; also
-                                  # sizes the per-sequence block table
-    serve_kernel: str = "auto"    # paged-attention lowering: auto (fused
-                                  # Pallas kernel on TPU when the compile
-                                  # probe passes, else XLA gather), xla
-                                  # (force the exact gather fallback),
-                                  # pallas (force the kernel; interpret
-                                  # mode off TPU — the test path)
-    serve_kv_dtype: str = "fp32"  # paged-pool storage format: "fp32"
-                                  # (blocks in the model compute dtype —
-                                  # byte-for-byte the pre-quantization
-                                  # behavior) | "int8" (symmetric-absmax
-                                  # codes + per-(block, head, slot) fp32
-                                  # row scales: ~4x effective KV
-                                  # capacity, dequantized inside the
-                                  # attention consume paths; greedy
-                                  # outputs track fp32 at a token-match-
-                                  # rate gate, not token identity) |
-                                  # "int4" (two nibble-packed codes per
-                                  # byte + per-group fp32 scales along
-                                  # head_dim + a KIVI fp-residual self
-                                  # lane: the next capacity rung, same
-                                  # token-match-rate gate)
-    serve_kv_group: int = 32      # int4 scale-group size along head_dim
-                                  # (one fp32 scale per group; clamped
-                                  # to head_dim on tiny heads, must
-                                  # divide it).  Consumed only under
-                                  # serve_kv_dtype=int4
-    serve_kv_tier: str = "off"    # host-RAM block tier: "host" demotes
-                                  # cold prefix-cache blocks to host
-                                  # memory on eviction and promotes
-                                  # them back into fresh device blocks
-                                  # when a later prompt matches their
-                                  # trie path (multi-turn sessions stop
-                                  # re-paying prefill); requires
-                                  # serve_prefix_cache=on; "off" is
-                                  # byte-for-byte untiered
-    serve_prefix_cache: str = "off"  # radix prefix cache: "on" shares
-                                  # already-cached full prompt blocks
-                                  # across requests (refcounted, copy-
-                                  # on-write, LRU trie eviction under
-                                  # pool pressure); "off" preserves the
-                                  # unshared behavior byte-for-byte
-    serve_prefix_gen: str = "off"  # prefix cache v2 extensions: "on"
-                                  # additionally caches a finished
-                                  # request's GENERATED full blocks in
-                                  # the trie (follow-up turns that embed
-                                  # the prior answer hit them) and
-                                  # shares partial tail blocks via a
-                                  # one-compile row-prefix copy; "off"
-                                  # keeps prefix_cache=on behavior
-                                  # byte-for-byte; requires
-                                  # serve_prefix_cache=on
-    serve_prefix_route: str = "off"  # prefix-aware fleet routing: "on"
-                                  # biases sessionless placement toward
-                                  # the replica whose trie caches the
-                                  # prompt's leading full block (load-
-                                  # bounded, never overrides the health
-                                  # gate, never changes tokens); "off"
-                                  # keeps affinity+least-load routing;
-                                  # requires serve_prefix_cache=on
-    serve_speculative: str = "off"  # speculative decoding: "ngram"
-                                  # (n-gram self-draft, zero extra
-                                  # model), "draft-model" (tiny-model
-                                  # drafter over its own paged pool);
-                                  # drafts verify in ONE batched
-                                  # forward and only the argmax-
-                                  # matching prefix is emitted, so
-                                  # greedy outputs are token-identical
-                                  # to "off" (which preserves the one-
-                                  # token decode loop byte-for-byte)
-    serve_draft_k: int = 4        # draft window: tokens proposed per
-                                  # verify forward (dispatch width is
-                                  # draft_k + 1)
-    serve_draft_auto: str = "off"  # auto-tune the draft window: "on"
-                                  # adapts the effective k to an EWMA
-                                  # of the observed accepted length,
-                                  # clamped to [1, serve_draft_k] (the
-                                  # dispatch width never changes, so
-                                  # no recompiles); "off" drafts the
-                                  # configured k every step
-    serve_mixed_batch: str = "off"  # stall-free mixed batching: "on"
-                                  # fuses budget-capped prefill chunks
-                                  # from MULTIPLE mid-prefill sequences
-                                  # into the decode dispatch, so every
-                                  # step is ONE forward (chunked-prefill
-                                  # math; decode is the chunk=1 case)
-                                  # — lower dispatches per emitted
-                                  # token and lower TTFT under bursty
-                                  # admission; "off" preserves the
-                                  # two-dispatch prefill-then-decode
-                                  # loop byte-for-byte
-    serve_prefill_budget: int = 64  # mixed batching: max prefill
-                                  # tokens fused into one step across
-                                  # all mid-prefill sequences; bounds
-                                  # the decode-latency tax a step can
-                                  # pay for prompt ingestion (consumed
-                                  # only with serve_mixed_batch=on)
-    serve_tp: int = 1             # tensor-parallel shards for the
-                                  # decode engine: >1 partitions the
-                                  # paged pool's head axis, the QKV/O
-                                  # projections, and the MLP over a
-                                  # ``tp`` mesh axis (serving/tp) with
-                                  # one psum per row-parallel output;
-                                  # must divide the model's heads and
-                                  # mlp dims and fit the device count
-    serve_replicas: int = 1       # data-parallel engine replicas
-                                  # fronted by serving/router: each has
-                                  # its own pool/scheduler; requests
-                                  # place by session affinity then
-                                  # least-load (queue depth, occupancy,
-                                  # shed rate).  1 = no router layer
-    # fault-tolerance policy (serving/engine.ServeConfig; None = off)
-    serve_deadline_ms: Optional[float] = None  # default per-request TTL
-                                  # from arrival; expired work fails
-                                  # with deadline_exceeded instead of
-                                  # occupying a slot
-    serve_queue_depth: Optional[int] = None    # bound on the waiting
-                                  # queue; a full queue load-sheds the
-                                  # newest submit (backpressure)
-    serve_max_evictions: Optional[int] = None  # preemption-livelock
-                                  # guard: a request evicted more than
-                                  # this many times fails with
-                                  # evicted_too_often
-    serve_drain_ms: Optional[float] = None     # graceful-drain budget
-                                  # after SIGTERM; in-flight work past
-                                  # it is cut with status `drained`
-                                  # (None = finish all in-flight)
-    serve_failover_backoff_ms: float = 50.0    # replica circuit
-                                  # breaker (serving/router): base
-                                  # probe backoff after a transient
-                                  # replica fault, doubled per
-                                  # consecutive fault and capped at
-                                  # 64x before the replica is rebuilt
-                                  # and probed back into rotation
-    serve_workload: str = "poisson"  # synthetic trace shape for bench
-                                  # --mode serving (serving/loadgen):
-                                  # poisson | bursty | multi-tenant |
-                                  # diurnal; poisson replays the
-                                  # historical trace byte-for-byte
-    serve_slo_ms: Optional[float] = None       # per-request latency
-                                  # budget stamped as Request.deadline;
-                                  # the goodput metric (tokens/sec
-                                  # within budget) keys on it (None =
-                                  # no SLO)
-    serve_trace: str = "off"      # request-lifecycle + step-phase
-                                  # tracing (serving/tracing): off | on.
-                                  # off = byte-for-byte untraced
-                                  # behavior; on adds host-side span
-                                  # stamps (zero device syncs) and the
-                                  # `breakdown` block in bench detail
-    serve_trace_out: Optional[str] = None      # Chrome trace-event JSON
-                                  # path (open in Perfetto or
-                                  # chrome://tracing); requires
-                                  # serve_trace=on
-
     # --- checkpointing (absent from the reference; SURVEY.md §5) ---
     checkpoint_dir: Optional[str] = None   # None = checkpointing off
     resume: bool = False                   # resume from latest in the dir
@@ -291,8 +128,7 @@ class Config:
                                   # cleanly under GSPMD).  A BERT train step
                                   # runs 25 (B,S,E) mask generations, so the
                                   # generator choice is a first-order
-                                  # throughput knob (scripts/bert_diagnose.py
-                                  # measures the delta); parameter INIT always
+                                  # throughput knob; parameter INIT always
                                   # uses threefry so init is bit-identical
                                   # across prng arms
     seed: int = 1                 # the reference seeds everything with 1

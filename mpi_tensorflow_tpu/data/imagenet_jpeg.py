@@ -12,8 +12,8 @@ directory layout
 into the mmap ``.npy`` shard format ``data/imagenet.py`` already serves
 (``imagenet_npy/{train,val}_{images,labels}.npy``) — decode once, then
 every epoch streams straight from page-cache-backed mmap through the
-native/thread prefetcher with zero per-step decode cost (the bench mode
-``--mode hostio`` measures exactly that feed).
+native/thread prefetcher with zero per-step decode cost.
+
 
 Decode/preprocess is the standard eval transform: shorter side to
 ``resize_to`` (bilinear), center-crop ``image_size``, float32 in [0, 1],

@@ -142,7 +142,7 @@ class EncDecLm:
         h = params["tok_emb"][src] + params["pos_emb"][None, :S]
         h = _layernorm(h, params["emb_ln"])
         # embedding-site dropout on stream index 1, exactly as BertMlm
-        # applies it (ADVICE r3: this site was silently skipped, quietly
+        # applies it (round 3: this site was silently skipped, quietly
         # diverging the family's regularization from its siblings)
         if train and c.dropout > 0.0:
             if rng is None:
@@ -288,8 +288,8 @@ class EncDecLm:
         Matches CausalLm's loss shape so the gspmd step drives it
         unchanged.  The CE follows ``cfg.ce_impl`` like the sibling
         families: chunked online-logsumexp by default (every position
-        carries loss — (B, T, V) fp32 logits would cost ~1 GB at the
-        bench shape), dense on request."""
+        carries loss — (B, T, V) fp32 logits would cost ~1 GB at
+        64 x 128 x 30522), dense on request."""
         from mpi_tensorflow_tpu.utils import engagement
 
         tgt = batch["tgt"]
@@ -331,7 +331,7 @@ class EncDecLm:
             # _dec_embed's dynamic_slice clamps its start index, so
             # decoding past the learned dec_pos_emb table would silently
             # reuse the last row's embedding — mirror CausalLm.init_cache
-            # and raise instead (ADVICE r3)
+            # and raise instead
             raise ValueError(
                 f"max_new_tokens {max_new_tokens} exceeds max_positions "
                 f"{c.max_positions}")

@@ -172,7 +172,7 @@ class CausalLm(bert_lib.BertMlm):
         pools:        per-layer [{"k", "v"}] block pools, each
                       (num_blocks, block_size, H*D) — token-major,
                       ops/paged_attention's layout.  An int8 pool
-                      (--serve-kv-dtype int8) additionally carries
+                      (--kv-dtype int8) additionally carries
                       {"k_scale", "v_scale"} (num_blocks, block_size, H)
                       fp32 row scales (serving/paged_cache.init_pools);
                       writes then quantize on store and attention
@@ -248,7 +248,7 @@ class CausalLm(bert_lib.BertMlm):
             q = self._constrain(q, qkv_axes)
             mode = paged_ops.pool_mode(pl["k"], pl.get("k_scale"))
             if mode == "int4":
-                # int4 pool (--serve-kv-dtype int4, uint8 nibble codes):
+                # int4 pool (--kv-dtype int4, uint8 nibble codes):
                 # group-quantize on store, consume through attend's
                 # dequantizing paths WITH the fp-residual self lane —
                 # the in-register k/v of this step's own tokens give
@@ -265,7 +265,7 @@ class CausalLm(bert_lib.BertMlm):
                                      k_scale=ks, v_scale=vs,
                                      k_new=k, v_new=v, work=work)
             elif mode == "int8":
-                # int8 pool (--serve-kv-dtype int8): quantize on store —
+                # int8 pool (--kv-dtype int8): quantize on store —
                 # codes and per-row scales scatter through the same
                 # block/offset indexing — and consume through attend's
                 # dequantizing paths; the fp K/V never touch the pool
@@ -323,7 +323,7 @@ class CausalLm(bert_lib.BertMlm):
         (masked) cache buffer, so per-step cost scales with the CAPACITY,
         not the occupancy — benchmark arms comparing different generation
         lengths must pin the same cache_len or the comparison is
-        apples-to-oranges (bench.measure_decode does)."""
+        apples-to-oranges."""
         if temperature > 0.0 and rng is None:
             raise ValueError("temperature sampling needs an rng")
         if (top_k > 0 or top_p < 1.0) and temperature <= 0.0:

@@ -3,7 +3,7 @@
 Role: the loss of the reference is a mean softmax-CE over class logits
 (``/root/reference/mpipy.py:55-56``); BERT-MLM scales that to a 30k-class
 vocabulary, where the naive formulation materializes a (B, S, V) fp32 logits
-tensor (~1 GB at the bench shape 64x128x30522) that is written to HBM in the
+tensor (~1 GB at the shape 64x128x30522) that is written to HBM in the
 forward pass and re-read three times (logsumexp, label gather, backward).
 That HBM round-trip — not FLOPs — dominates the head's cost on TPU.
 

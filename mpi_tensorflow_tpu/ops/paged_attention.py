@@ -31,7 +31,7 @@ decode slots) so those lanes need no branching — garbage lands in
 scratch, reads of it are masked by the causal visibility test.
 
 ``attend`` is THE dispatcher behind the paged-attention seam: the
-``--serve-kernel`` knob (CLI -> Config -> ServeConfig -> engine)
+``--kernel`` knob (CLI -> Config -> ServeConfig -> engine)
 resolves through ``resolve_kernel`` to either
 
 - ``pallas`` — the fused kernel compiled by Mosaic, reading pool
@@ -219,7 +219,7 @@ def quantize_kv_int4(kv, group: int):
     (the KIVI recipe, arXiv:2402.02750: sub-8-bit KV needs finer scale
     granularity than a whole row).
 
-    kv: (B, H, S, D) fp K or V vectors.  ``group`` is the --serve-kv-
+    kv: (B, H, S, D) fp K or V vectors.  ``group`` is the --kv-
     group knob; the effective group is ``min(group, D)`` (so the
     default 32 stays valid on tiny test heads) and must divide D.
     Returns ``(packed, scales)``: packed (B, H, S, D//2) uint8
@@ -318,7 +318,7 @@ def paged_attention(q, ck, cv, q_positions, dt):
     row's own ``q_positions`` — nothing couples rows, so one batch may
     freely mix phases (decode rows querying a single position beside
     prefill rows querying a chunk at their own offsets, the
-    --serve-mixed-batch fused dispatch).  Each row attends to exactly
+    --mixed-batch fused dispatch).  Each row attends to exactly
     the prefix its positions admit, identical to what a single-phase
     dispatch would give it; tests/test_mixed_batch.py pins the fused
     and unfused paths token-identical in fp32 and int8.
@@ -441,9 +441,9 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
                  choice must be static and must not consult the backend.
     k/v_scale:   fp32 scales when the pools hold quantized codes; both
                  or neither.  ``(num_blocks, block_size, H)`` row
-                 scales beside int8 codes (--serve-kv-dtype int8);
+                 scales beside int8 codes (--kv-dtype int8);
                  ``(num_blocks, block_size, H*G)`` group scales beside
-                 uint8 nibble-packed codes (--serve-kv-dtype int4) —
+                 uint8 nibble-packed codes (--kv-dtype int4) —
                  the CODE DTYPE is the discriminator (``pool_mode``), so
                  no new pool leaf key is needed and CoW/TP/partial-copy
                  stay generic.  Dequantization happens INSIDE the consume
@@ -463,7 +463,7 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
     built per row from it (``pos = lengths[:, None] + arange(S)``), so
     rows of ONE dispatch may sit at different phases — a decode row
     (one real lane) beside prefill rows carrying chunks at their own
-    offsets, as the --serve-mixed-batch fused step packs them.  Rows
+    offsets, as the --mixed-batch fused step packs them.  Rows
     with fewer than S real lanes are the CALLER'S job to mask: slack
     lanes must be marked invalid upstream so write_kv lands them in
     the null block, and their attention output is garbage to be
@@ -556,7 +556,7 @@ def resolve_kernel(choice: str, cfg, block_size: int,
                    kv_dtype: str = "fp32",
                    kv_group: int = 32, max_slots: int = 8,
                    max_blocks: int = 4) -> str:
-    """Resolve the ``--serve-kernel`` knob to a static lowering literal.
+    """Resolve the ``--kernel`` knob to a static lowering literal.
 
     - "xla"    -> "xla"
     - "pallas" -> "pallas" on TPU, "pallas-interpret" elsewhere (the

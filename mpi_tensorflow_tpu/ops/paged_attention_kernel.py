@@ -140,7 +140,7 @@ def _paged_kernel(*refs, scale: float, block_size: int,
 
     ``mode`` selects the pool storage format the step consumes:
 
-    - "int8" (--serve-kv-dtype int8): k/v_ref hold int8 codes and two
+    - "int8" (--kv-dtype int8): k/v_ref hold int8 codes and two
       extra refs ride between them — ks_ref/vs_ref, the ``(1, bs, H)``
       fp32 row scales of the SAME pool block (their BlockSpec shares
       the kv index map, so code block and scale block can never skew).
@@ -149,7 +149,7 @@ def _paged_kernel(*refs, scale: float, block_size: int,
       dequantize_kv contract the XLA gather path applies elementwise —
       before the unchanged fp32 matmul/softmax; no fp pool ever
       materializes.
-    - "int4" (--serve-kv-dtype int4): k/v_ref hold ``(1, bs, H*D//2)``
+    - "int4" (--kv-dtype int4): k/v_ref hold ``(1, bs, H*D//2)``
       nibble-packed uint8 codes, ks/vs_ref the ``(1, bs, H*G)`` fp32
       GROUP scales; ``_dequant_int4_block`` unpacks + dequantizes in
       register (the dequantize_kv_int4 contract).
@@ -418,7 +418,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     grid walks (row, kv block) pairs and every visibility test uses that
     row's own ``lengths[b]``, so one dispatch may mix decode rows
     (one real lane) with prefill rows carrying chunks at different
-    offsets — the --serve-mixed-batch fused step.  Slack lanes past a
+    offsets — the --mixed-batch fused step.  Slack lanes past a
     row's real count are the caller's to mask upstream (their K/V
     scatters to the null block); their output lanes are discarded on
     host.  tests/test_mixed_batch.py pins kernel-vs-XLA agreement on

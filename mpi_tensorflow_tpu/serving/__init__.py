@@ -60,7 +60,7 @@ Six cooperating layers, host-side policy over device-side math:
                      folds the scheduler/router load signals (queue
                      depth, occupancy, shed rate) into per-tick
                      scale-up/down advice under hysteresis + cooldown,
-                     recorded in bench detail; with tracing on it
+                     recorded in the run's result; with tracing on it
                      consumes the SAME ``TraceBuffer`` step records the
                      trace exports, so advice is explainable from the
                      trace.
@@ -70,7 +70,7 @@ Six cooperating layers, host-side policy over device-side math:
                      transitions) and a bounded per-step phase timeline
                      (``TraceBuffer``), fleet-merged across replicas
                      and incarnations; exports Chrome trace-event JSON
-                     and the bench ``breakdown`` block.  Off = no
+                     and the ``breakdown`` block.  Off = no
                      tracer object, byte-for-byte untraced; on = host
                      clocks only, zero device syncs.
 - ``router``       — data-parallel scale-out WITH fleet fault

@@ -10,7 +10,7 @@ consecutive observations) and a post-decision cooldown, so a bursty
 trace can't flap the advice every tick.
 
 Advisory on purpose: nothing here spawns or kills replicas.  The
-decision log is recorded in bench detail as the acceptance signal a
+decision log rides the run's result as the acceptance signal a
 real replica auto-scaler (ROADMAP item 1's remaining extension) will
 later act on through ``ReplicaRouter``'s existing probe/rebuild seam.
 
@@ -149,7 +149,7 @@ class ScaleAdvisor:
         return decision
 
     def report(self) -> dict:
-        """The canonical ``autoscale`` result block bench detail
+        """The canonical ``autoscale`` result block a run's result
         carries: the decision log plus the final advice and enough
         policy echo to read the decisions against."""
         return {

@@ -14,7 +14,7 @@ bucketed shapes —
   most ``(log2 max_slots + 1) * (log2 max_blocks_per_seq + 1)`` shapes;
 - prefill: (1, chunk bucket) with the full table width, at most
   ``log2 prefill_chunk + 1`` shapes;
-- mixed (``--serve-mixed-batch on``): (slot bucket, chunk bucket,
+- mixed (``--mixed-batch on``): (slot bucket, chunk bucket,
   table-width bucket) for the ONE fused prefill+decode forward per
   step — every triple pre-warmed at build, like speculative verify,
   because which buckets a mixed step hits depends on arrival timing
@@ -24,7 +24,7 @@ bucketed shapes —
 block pools are donated through every dispatch on TPU, so the cache
 updates in place instead of ping-ponging two pool-sized buffers.
 
-Tensor parallelism (``--serve-tp N``): the jitted steps below run the
+Tensor parallelism (``--tp N``): the jitted steps below run the
 forward through a shard_map seam (serving/tp) that partitions the
 pool (by head), QKV/O, and MLP over a ``tp`` mesh axis with one psum
 per row-parallel projection.  Block tables index blocks, not heads, so
@@ -33,13 +33,13 @@ once at construction, so TP adds no dispatch shapes and the
 zero-recompile contract holds unchanged.  Scale-OUT (whole-engine
 replicas) lives above this file in serving/router.
 
-Prefix sharing (``--serve-prefix-cache on``): admission walks each
+Prefix sharing (``--prefix-cache on``): admission walks each
 prompt through a radix trie of cached full blocks
 (serving/prefix_cache) and maps hits to EXISTING physical blocks, so
 prefill computes only the unique suffix; the engine contributes the
 device half — a copy-on-write block copy before any dispatch would
 write into a shared block, and trie registration when a prompt finishes
-prefill.  Prefix sharing v2 (``--serve-prefix-gen on``) extends the
+prefill.  Prefix sharing v2 (``--prefix-gen on``) extends the
 trie with a finishing request's generated blocks (multi-turn reuse)
 and serves mid-block misses through a pre-warmed one-compile partial
 tail-block copy (``_partial_fn``, the ``_cow_fn`` discipline), applied
@@ -63,225 +63,51 @@ from mpi_tensorflow_tpu.serving import paged_cache, \
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
-    """Serving-pool geometry + fault-tolerance policy (the --serve-*
-    CLI knobs)."""
-    num_blocks: int = 128         # pool blocks, block 0 reserved as null
-    block_size: int = 16          # cache entries per block
-    max_slots: int = 8            # concurrent sequences (decode batch cap)
-    max_seq_len: int = 512        # per-sequence prompt+output cap
-    prefill_chunk: int = 64       # max prompt tokens per prefill dispatch
-    eos_id: Optional[int] = None  # emit-EOS slot recycling (None: budget
-                                  # exhaustion only — the LM families
-                                  # train on streams with no terminator)
-    kernel: str = "auto"          # paged-attention lowering: auto | xla
-                                  # | pallas (--serve-kernel; resolved
-                                  # ONCE at engine construction via
-                                  # ops/paged_attention.resolve_kernel,
-                                  # so the choice is static under jit)
-    prefix_cache: str = "off"     # radix prefix cache (--serve-prefix-
-                                  # cache): "on" maps cached full prompt
-                                  # blocks into new sequences (shared,
-                                  # copy-on-write on divergence, LRU
-                                  # trie eviction under pressure);
-                                  # "off" preserves byte-for-byte the
-                                  # unshared behavior
-    prefix_gen: str = "off"       # prefix sharing v2 (--serve-prefix-
-                                  # gen): "on" additionally (a) inserts
-                                  # a finishing request's full blocks
-                                  # spanning prompt + generated output
-                                  # into the trie, so follow-up turns
-                                  # embedding the prior answer map them
-                                  # instead of re-prefilling, and (b)
-                                  # serves a mid-block miss's matched
-                                  # row prefix via the one-compile
-                                  # partial-copy dispatch.  Requires
-                                  # prefix_cache on; "off" keeps the
-                                  # trie prompt-blocks-only (v1),
-                                  # byte-for-byte
-    prefix_route: str = "off"     # prefix-aware fleet routing (--serve-
-                                  # prefix-route): "on" lets the
-                                  # replica router (serving/router)
-                                  # bias placement toward the replica
-                                  # whose trie already caches a
-                                  # request's leading full block, when
-                                  # load permits — never overriding
-                                  # health gating, never changing
-                                  # tokens.  Requires prefix_cache on;
-                                  # consumed by ReplicaRouter, carried
-                                  # here so the fleet's engines and the
-                                  # router agree through ONE config
-    speculative: str = "off"      # speculative decoding (--serve-
-                                  # speculative): "ngram" = n-gram
-                                  # self-draft, "draft-model" = tiny-
-                                  # model drafter over its own paged
-                                  # pool (serving/speculative); "off"
-                                  # keeps the one-token decode loop
-                                  # byte-for-byte
-    draft_k: int = 4              # draft window (--serve-draft-k):
-                                  # tokens proposed per verify forward;
-                                  # the verify dispatch width is k+1
-                                  # and a step emits 1..k+1 tokens
-    draft_auto: str = "off"       # auto-tune the draft window (--serve-
-                                  # draft-auto): "on" shrinks/grows the
-                                  # EFFECTIVE k with an EWMA of the
-                                  # accepted length per verify step,
-                                  # clamped to [1, draft_k] (the floor
-                                  # keeps a 1-token probe alive so a
-                                  # recovering accept rate can re-grow
-                                  # it); dispatch width stays draft_k+1
-                                  # so the zero-recompile contract is
-                                  # untouched.  "off" drafts the full
-                                  # configured k every step
-    mixed_batch: str = "off"      # stall-free mixed batching (--serve-
-                                  # mixed-batch): "on" fuses budget-
-                                  # capped prefill chunks from MULTIPLE
-                                  # mid-prefill sequences into the
-                                  # decode dispatch, so every step is
-                                  # ONE forward — the chunked-prefill
-                                  # math already masks per-row lengths,
-                                  # and decode is its chunk=1
-                                  # degenerate case, so greedy outputs
-                                  # are token-identical to "off" by
-                                  # construction; "off" preserves the
-                                  # two-dispatch prefill-then-decode
-                                  # loop byte-for-byte.  Replaces the
-                                  # decode dispatch like speculative
-                                  # verify does, so the two do not
-                                  # compose
-    prefill_budget: int = 64      # mixed batching (--serve-prefill-
-                                  # budget): max prefill tokens fused
-                                  # into one step across all mid-
-                                  # prefill sequences — bounds the
-                                  # decode-latency tax a step pays for
-                                  # prompt ingestion (consumed only
-                                  # with mixed_batch on)
-    kv_dtype: str = "fp32"        # pool storage format (--serve-kv-
-                                  # dtype): "fp32" keeps blocks in the
-                                  # model compute dtype — byte-for-byte
-                                  # the pre-quantization pool, the
-                                  # parity reference; "int8" stores
-                                  # symmetric-absmax codes with per-
-                                  # (block, head, slot) fp32 row scales
-                                  # (serving/paged_cache.init_pools):
-                                  # ~4x the tokens per pool byte, write
-                                  # paths quantize on store, consume
-                                  # paths dequantize in place (kernel:
-                                  # in register; XLA: on the gathered
-                                  # view), and greedy outputs track the
-                                  # fp32 pool at a token-match-rate
-                                  # gate rather than token identity;
-                                  # "int4" nibble-packs two codes per
-                                  # byte with per-group fp32 scales
-                                  # (kv_group) plus a KIVI fp-residual
-                                  # self lane — the next capacity rung
-                                  # (~6-8x the tokens per pool byte)
-    kv_group: int = 32            # int4 scale-group size along head_dim
-                                  # (--serve-kv-group): one fp32 scale
-                                  # per ``min(kv_group, head_dim)``
-                                  # channels (clamped so the default
-                                  # stays valid on tiny heads; must
-                                  # divide head_dim).  Smaller groups =
-                                  # tighter quantization, more scale
-                                  # bytes.  Consumed only under
-                                  # kv_dtype=int4
-    kv_tier: str = "off"          # host-RAM block tier (--serve-kv-
-                                  # tier): "host" demotes cold prefix-
-                                  # cache blocks to a HostBlockStore on
-                                  # eviction instead of discarding
-                                  # them, and promotes them back into
-                                  # fresh device blocks when a later
-                                  # prompt walks the same trie path —
-                                  # multi-turn sessions stop re-paying
-                                  # prefill after their prefix ages out
-                                  # of the device pool.  Requires
-                                  # prefix_cache on (the trie's token
-                                  # paths are the tier's keys); "off"
-                                  # is byte-for-byte untiered
-    tp: int = 1                   # tensor-parallel shards (--serve-tp):
-                                  # >1 partitions the pool by head,
-                                  # QKV/O projections, and MLP over a
-                                  # ``tp`` mesh axis via shard_map
-                                  # (serving/tp), psum-combining the
-                                  # row-parallel outputs; 1 keeps the
-                                  # single-device path byte-for-byte.
-                                  # Must divide the model's heads and
-                                  # mlp dims and fit the device count
-                                  # (checked at engine construction,
-                                  # where the model geometry is known)
+    """Serving-pool geometry, feature switches and fault-tolerance
+    policy: THE place a serving option lives.  One field here, its line
+    in ``SERVE_HELP`` below and, where it needs one, a rule in
+    ``__post_init__`` make an option; ``python -m
+    mpi_tensorflow_tpu.serving`` derives its flag from the field."""
+    num_blocks: int = 128
+    block_size: int = 16
+    max_slots: int = 8
+    max_seq_len: int = 512
+    prefill_chunk: int = 64
+    eos_id: Optional[int] = None
+    kernel: str = "auto"
+    prefix_cache: str = "off"
+    prefix_gen: str = "off"
+    prefix_route: str = "off"
+    speculative: str = "off"
+    draft_k: int = 4
+    draft_auto: str = "off"
+    mixed_batch: str = "off"
+    prefill_budget: int = 64
+    kv_dtype: str = "fp32"
+    kv_group: int = 32
+    kv_tier: str = "off"
+    tp: int = 1
     # --- fault-tolerance policy (None = feature off / unbounded) ---
-    deadline_ms: Optional[float] = None   # default per-request TTL from
-                                  # arrival; expired work fails with
-                                  # deadline_exceeded instead of
-                                  # occupying slots (an explicit
-                                  # Request.deadline wins)
-    queue_depth: Optional[int] = None     # bound on the waiting queue;
-                                  # a submit finding it full is load-
-                                  # shed (reject-newest, queue_full)
-    max_evictions: Optional[int] = None   # preemption-livelock guard: a
-                                  # request evicted more than this many
-                                  # times fails with evicted_too_often
-    drain_ms: Optional[float] = None      # graceful-drain budget after a
-                                  # stop request (SIGTERM): in-flight
-                                  # work past it is cut with status
-                                  # `drained` (None = finish in flight)
-    failover_backoff_ms: float = 50.0     # replica circuit breaker
-                                  # (serving/router): base probe backoff
-                                  # after a transient replica fault —
-                                  # doubled per consecutive fault, capped
-                                  # at 64x, before the router rebuilds
-                                  # the replica and probes it back in
-    trace: str = "off"            # request-lifecycle + step-phase
-                                  # tracing (serving/tracing): "on"
-                                  # builds an EngineTracer at reset and
-                                  # adds the `trace` result block; off
-                                  # is byte-for-byte untraced (the
-                                  # tracer is never constructed)
-    trace_out: Optional[str] = None       # Chrome trace-event JSON path
-                                  # (written by bench after the timed
-                                  # run); requires trace="on"
-
-    @classmethod
-    def from_config(cls, config, **overrides):
-        """Build from a run Config's ``--serve-*`` knobs (config.py) —
-        THE bridge from the CLI surface to the engine; bench and any
-        serve entry point construct their ServeConfig through here so
-        the knobs have exactly one meaning."""
-        base = dict(num_blocks=config.serve_pool_blocks,
-                    block_size=config.serve_block_size,
-                    max_slots=config.serve_max_slots,
-                    max_seq_len=config.serve_max_seq_len,
-                    kernel=config.serve_kernel,
-                    prefix_cache=config.serve_prefix_cache,
-                    prefix_gen=config.serve_prefix_gen,
-                    prefix_route=config.serve_prefix_route,
-                    speculative=config.serve_speculative,
-                    draft_k=config.serve_draft_k,
-                    draft_auto=config.serve_draft_auto,
-                    mixed_batch=config.serve_mixed_batch,
-                    prefill_budget=config.serve_prefill_budget,
-                    kv_dtype=config.serve_kv_dtype,
-                    kv_group=config.serve_kv_group,
-                    kv_tier=config.serve_kv_tier,
-                    tp=config.serve_tp,
-                    deadline_ms=config.serve_deadline_ms,
-                    queue_depth=config.serve_queue_depth,
-                    max_evictions=config.serve_max_evictions,
-                    drain_ms=config.serve_drain_ms,
-                    failover_backoff_ms=config.serve_failover_backoff_ms,
-                    trace=config.serve_trace,
-                    trace_out=config.serve_trace_out)
-        base.update({k: v for k, v in overrides.items() if v is not None})
-        return cls(**base)
+    deadline_ms: Optional[float] = None
+    queue_depth: Optional[int] = None
+    max_evictions: Optional[int] = None
+    drain_ms: Optional[float] = None
+    failover_backoff_ms: float = 50.0
+    trace: str = "off"
+    trace_out: Optional[str] = None
 
     @property
     def max_blocks_per_seq(self) -> int:
         return paged_cache.blocks_for(self.max_seq_len, self.block_size)
 
     def __post_init__(self):
-        if self.block_size < 1 or self.num_blocks < 2 \
-                or self.prefill_chunk < 1 or self.max_slots < 1 \
-                or self.max_seq_len < 1:
-            raise ValueError(f"bad pool geometry: {self}")
+        bad = [f"{k} {getattr(self, k)} ({rule})" for k, lo, rule in (
+            ("num_blocks", 2, ">= 2: block 0 is reserved"),
+            ("block_size", 1, ">= 1"), ("max_slots", 1, ">= 1"),
+            ("max_seq_len", 1, ">= 1"), ("prefill_chunk", 1, ">= 1"))
+            if getattr(self, k) < lo]
+        if bad:
+            raise ValueError("bad pool geometry: " + ", ".join(bad))
         if self.kernel not in ("auto", "xla", "pallas"):
             raise ValueError(
                 f"serve kernel must be auto|xla|pallas, "
@@ -353,13 +179,16 @@ class ServeConfig:
                 "host store by — turn the cache on or drop the tier")
         if self.tp < 1:
             raise ValueError(f"serve tp must be >= 1, got {self.tp}")
-        if (self.deadline_ms is not None and self.deadline_ms <= 0) \
-                or (self.queue_depth is not None and self.queue_depth < 1) \
-                or (self.max_evictions is not None
-                    and self.max_evictions < 1) \
-                or (self.drain_ms is not None and self.drain_ms < 0) \
-                or self.failover_backoff_ms <= 0:
-            raise ValueError(f"bad fault-tolerance policy: {self}")
+        bad = [f"{k} {getattr(self, k)} ({rule})" for k, ok, rule in (
+            ("deadline_ms", lambda v: v > 0, "> 0"),
+            ("queue_depth", lambda v: v >= 1, ">= 1"),
+            ("max_evictions", lambda v: v >= 1, ">= 1"),
+            ("drain_ms", lambda v: v >= 0, ">= 0"),
+            ("failover_backoff_ms", lambda v: v > 0, "> 0"))
+            if getattr(self, k) is not None and not ok(getattr(self, k))]
+        if bad:
+            raise ValueError("bad fault-tolerance policy: "
+                             + ", ".join(bad))
         if self.trace not in ("off", "on"):
             raise ValueError(
                 f"serve trace must be off|on, got {self.trace!r}")
@@ -377,10 +206,102 @@ class ServeConfig:
                 f"({self.max_blocks_per_seq} blocks of {self.block_size})")
 
 
+#: One line of help per ServeConfig field: what the option does and what
+#: it needs.  The serving entry point's ``--help`` and the options table
+#: of docs/SERVING.md are this table (tests/test_serve_entry.py).
+SERVE_HELP = {
+    "num_blocks": "paged KV pool size in blocks; block 0 is reserved as "
+                  "the null block, and one max_seq_len sequence must fit "
+                  "in the rest",
+    "block_size": "cache entries per pool block",
+    "max_slots": "concurrent sequences: the decode batch cap",
+    "max_seq_len": "per-request prompt + output cap; sizes the "
+                   "per-sequence block table",
+    "prefill_chunk": "most prompt tokens one prefill dispatch takes, so "
+                     "a long prompt cannot stall in-flight decodes",
+    "eos_id": "token id that ends a sequence and recycles its slot "
+              "(unset: the output budget alone ends it; the LM families "
+              "train on streams with no terminator)",
+    "kernel": "paged-attention lowering, auto|xla|pallas, resolved once "
+              "at engine construction: auto takes the Pallas kernel on "
+              "TPU when its compile probe passes and the XLA gather "
+              "path otherwise; pallas off TPU is the interpreter",
+    "prefix_cache": "off|on: radix prefix cache; on maps already-cached "
+                    "full prompt blocks into new sequences (refcounted, "
+                    "copy-on-write on divergence, LRU trie eviction "
+                    "under pool pressure); tokens equal off's",
+    "prefix_gen": "off|on: also cache a finished request's generated "
+                  "full blocks (multi-turn reuse) and share partial tail "
+                  "blocks through a one-compile row copy; needs "
+                  "prefix_cache on",
+    "prefix_route": "off|on: the replica router places a sessionless "
+                    "request on the replica whose trie caches its "
+                    "leading block when load permits; never overrides "
+                    "health gating, never changes tokens; needs "
+                    "prefix_cache on",
+    "speculative": "off|ngram|draft-model: draft draft_k tokens a "
+                   "sequence (from its own earlier tokens, or a tiny "
+                   "model over its own paged pool) and verify them in "
+                   "one forward; only the argmax-matching prefix is "
+                   "emitted, so tokens equal off's",
+    "draft_k": "speculative draft window: tokens proposed per verify "
+               "forward (the dispatch is draft_k + 1 wide)",
+    "draft_auto": "off|on: adapt the effective draft window to an EWMA "
+                  "of the accepted length, within [1, draft_k]; the "
+                  "dispatch width stays, so nothing recompiles; needs a "
+                  "drafter",
+    "mixed_batch": "off|on: fuse budget-capped prefill chunks of several "
+                   "mid-prefill sequences into the decode dispatch, one "
+                   "forward a step; tokens equal off's; replaces the "
+                   "decode dispatch as speculative verify does, so the "
+                   "two do not compose",
+    "prefill_budget": "mixed batching: most prefill tokens fused into "
+                      "one step across all mid-prefill sequences (read "
+                      "only with mixed_batch on)",
+    "kv_dtype": "pool storage format, fp32|int8|int4: fp32 keeps blocks "
+                "in the model's compute dtype; int8 stores "
+                "symmetric-absmax codes with a float32 scale per (block, "
+                "slot, head); int4 packs two codes a byte with a scale "
+                "per kv_group channels and a full-precision lane for "
+                "each step's own tokens; quantised outputs track fp32 at "
+                "a token-match rate, not token for token",
+    "kv_group": "int4 scale-group size along head_dim: one float32 scale "
+                "per min(kv_group, head_dim) channels, which must divide "
+                "head_dim (read only with kv_dtype int4)",
+    "kv_tier": "off|host: demote cold prefix-cache blocks to host memory "
+               "on eviction and promote them back when a later prompt "
+               "walks the same trie path; needs prefix_cache on",
+    "tp": "tensor-parallel shards: >1 splits the pool by head and the "
+          "QKV/O and MLP projections over a tp mesh axis (serving/tp); "
+          "must divide the model's heads and MLP width and fit the "
+          "visible devices (checked at engine construction)",
+    "deadline_ms": "default per-request time to live from arrival; late "
+                   "work fails with deadline_exceeded instead of holding "
+                   "a slot (a request's own deadline wins; unset: none)",
+    "queue_depth": "bound on the waiting queue; a submit that finds it "
+                   "full is shed with queue_full (unset: unbounded)",
+    "max_evictions": "a request preempted more often than this fails "
+                     "with evicted_too_often instead of requeueing "
+                     "forever (unset: unbounded)",
+    "drain_ms": "graceful-drain budget after SIGTERM: in-flight work "
+                "past it ends with status drained (unset: finish all "
+                "in-flight work)",
+    "failover_backoff_ms": "replica circuit breaker (serving/router): "
+                           "base probe back-off after a transient "
+                           "replica fault, doubled per consecutive fault "
+                           "and capped at 64x",
+    "trace": "off|on: request-lifecycle spans and a bounded step-phase "
+             "ring on host clocks (serving/tracing), reported in the "
+             "result's trace block; off builds no tracer",
+    "trace_out": "write the run's Chrome trace-event JSON here (open in "
+                 "Perfetto or chrome://tracing); needs trace on",
+}
+
+
 def pow2_ceil(n: int) -> int:
     """Smallest power of two >= ``n`` — THE bucketing rule the engine's
-    dispatch-shape / zero-recompile contract rests on; bench's trace
-    sizing reuses it so the two can never drift."""
+    dispatch-shape / zero-recompile contract rests on; the serving entry
+    point sizes an unset pool with it so the two can never drift."""
     b = 1
     while b < n:
         b *= 2
@@ -413,6 +334,23 @@ def _bucket(n: int, cap: int) -> int:
     return min(pow2_ceil(n), cap)
 
 
+def check_model(cfg, serve: ServeConfig) -> None:
+    """The refusals that need the MODEL's widths beside the options: the
+    position table against the sequence cap, and the tensor-parallel
+    geometry (serving/tp, where the head/mlp rule is stated).  The engine
+    calls it first thing; the entry point calls it before building
+    anything, so a flag is refused in these words and a fault further
+    down keeps its traceback."""
+    cap = serve.max_blocks_per_seq * serve.block_size
+    if cfg.pos_kind == "learned" and cap > cfg.max_positions:
+        raise ValueError(
+            f"max_seq_len {serve.max_seq_len} (table capacity {cap}) "
+            f"exceeds max_positions {cfg.max_positions}")
+    from mpi_tensorflow_tpu.serving import tp as tp_lib
+
+    tp_lib.check_geometry(cfg, serve.tp)
+
+
 class PagedDecodeEngine:
     """Greedy continuous-batching decode over a paged KV cache.
 
@@ -431,19 +369,12 @@ class PagedDecodeEngine:
 
         self.model = model
         self.serve = serve
-        cap = serve.max_blocks_per_seq * serve.block_size
-        if model.cfg.pos_kind == "learned" \
-                and cap > model.cfg.max_positions:
-            raise ValueError(
-                f"max_seq_len {serve.max_seq_len} (table capacity {cap}) "
-                f"exceeds max_positions {model.cfg.max_positions}")
-        # tensor parallelism (serving/tp): geometry checked HERE, where
-        # the model's head/mlp dims are known; the mesh, the sharded
+        check_model(model.cfg, serve)
+        # tensor parallelism (serving/tp): the mesh, the sharded
         # parameter placement, and the shard_map forward are all
         # resolved once so TP is static under the jitted steps below
         from mpi_tensorflow_tpu.serving import tp as tp_lib
 
-        tp_lib.check_geometry(model.cfg, serve.tp)
         self.tp_mesh = (tp_lib.make_tp_mesh(serve.tp)
                         if serve.tp > 1 else None)
         # resolve the knob -> xla|pallas|pallas-interpret ONCE,
@@ -490,7 +421,7 @@ class PagedDecodeEngine:
         # so every (src, dst, n) reuses the one compiled program
         self._partial_fn = jax.jit(
             _weakly(self._partial_impl), donate_argnums=(0,))
-        # host-tier promotion (--serve-kv-tier host): write a demoted
+        # host-tier promotion (--kv-tier host): write a demoted
         # block's host bytes into a freshly allocated device block —
         # same discipline as _cow_fn/_partial_fn: the destination id
         # rides as a traced scalar and the host leaves have one fixed
@@ -505,14 +436,14 @@ class PagedDecodeEngine:
         self._verify_fn = jax.jit(_weakly(self._verify_impl),
                                   donate_argnums=donate)
         # mixed batching: ONE fused prefill+decode forward per step
-        # (--serve-mixed-batch on); shares the verify dispatch's
+        # (--mixed-batch on); shares the verify dispatch's
         # masking math — decode rows are the chunk=1 degenerate case
         self._mixed_fn = jax.jit(_weakly(self._mixed_impl),
                                  donate_argnums=donate)
         self.drafter = spec_lib.make_drafter(
             serve.speculative, serve, model,
             draft_model=draft_model, draft_params=draft_params)
-        # draft-window auto-tuning (--serve-draft-auto on): EWMA of the
+        # draft-window auto-tuning (--draft-auto on): EWMA of the
         # accepted length per verify forward drives the EFFECTIVE k.
         # Initialized optimistic (full window) and NOT cleared by
         # reset(): like the jit caches, the learned window is warmed
@@ -566,8 +497,8 @@ class PagedDecodeEngine:
 
     def reset(self) -> None:
         """Fresh pools/scheduler; jit caches (and their warmed bucket
-        shapes) survive — the bench harness times a second trace replay
-        against exactly the compiles the first replay paid for."""
+        shapes) survive — the serving entry point serves its trace
+        against exactly the compiles the warm-up replay paid for."""
         from mpi_tensorflow_tpu.serving import prefix_cache as prefix_lib
 
         self.pools = paged_cache.init_pools(
@@ -593,7 +524,7 @@ class PagedDecodeEngine:
         self.prefix_cache = (
             prefix_lib.PrefixCache(self.allocator, self.serve.block_size)
             if self.serve.prefix_cache == "on" else None)
-        # host-RAM block tier (--serve-kv-tier host): resets WITH the
+        # host-RAM block tier (--kv-tier host): resets WITH the
         # pools/trie — stored bytes index device content that just went
         # away, and crash recovery rebuilds both from the journal
         self.tier = (paged_cache.HostBlockStore()
@@ -644,7 +575,7 @@ class PagedDecodeEngine:
         # model-forward dispatches this run (prefill + decode + verify
         # + mixed; CoW/partial copies excluded — they move cache rows,
         # not tokens): dispatches-per-emitted-token is THE CPU-visible
-        # win metric of mixed batching (bench --serve-mixed-ab)
+        # win metric of mixed batching
         self.forward_dispatches = 0
         # what the attention kernel's grid walks over the decode
         # dispatches of this run — each row's live blocks, a slack row's
@@ -728,7 +659,7 @@ class PagedDecodeEngine:
     def _demote_fetch(self, block: int) -> list:
         """Copy pool block ``block`` to host (per-layer dicts of
         np.ndarray rows) — the prefix cache calls this just before
-        eviction releases the device block (--serve-kv-tier host)."""
+        eviction releases the device block (--kv-tier host)."""
         return paged_cache.block_rows(
             self.pools,
             lambda leaf: np.asarray(leaf[block]))  # graft-lint: sync-ok(cold-block demotion off the dispatch path)
@@ -772,7 +703,7 @@ class PagedDecodeEngine:
 
     def _mixed_impl(self, params, pools, tokens, lengths, n_valid,
                     tables):
-        """The fused mixed prefill+decode dispatch (--serve-mixed-batch
+        """The fused mixed prefill+decode dispatch (--mixed-batch
         on): row ``b`` feeds ``n_valid[b]`` real lanes at positions
         ``lengths[b] + lane`` through ONE forward.  A decode row is the
         chunk=1 degenerate case (its pending token at position
@@ -838,14 +769,14 @@ class PagedDecodeEngine:
     def prewarm_decode(self) -> None:
         """Compile the decode dispatch at every (slot bucket, table
         bucket) pair it can ever run at — all-null tables, so nothing
-        real is touched.  NOT called at build: the normal engine pays
-        decode compiles in its first (warmup) replay.  Bench control
-        arms call this explicitly when their zero-recompile probe must
-        hold on a wall-clock arrival trace (--serve-mixed-ab's off
-        arm): which (occupancy, table-width) pair a decode step runs
+        real is touched.  NOT called at build: an engine built directly
+        pays decode compiles in its first run.  The serving entry point
+        calls this before its warm-up replay, because its zero-recompile
+        probe must hold on a wall-clock arrival trace:
+        which (occupancy, table-width) pair a decode step runs
         at tracks arrival TIMING, and a compile stall in the warmup
         replay slows it enough to visit different buckets than the
-        stall-free timed replay — the same argument that makes
+        stall-free served pass — the same argument that makes
         _prewarm_mixed a build-time obligation."""
         import jax.numpy as jnp
         import numpy as np
@@ -873,7 +804,7 @@ class PagedDecodeEngine:
         on acceptance — token content — so the contract is paid up
         front.  (Decode bucket visits also drift with arrival timing
         on wall-clock traces; ``prewarm_decode`` covers that for the
-        bench arms that need it.)"""
+        callers that need it.)"""
         import jax.numpy as jnp
         import numpy as np
 
@@ -1132,7 +1063,7 @@ class PagedDecodeEngine:
 
     def _step_mixed(self) -> List[Tuple[int, int]]:
         """The fused replacement for the prefill-then-decode phases
-        (--serve-mixed-batch on): pack the decode row of every live
+        (--mixed-batch on): pack the decode row of every live
         fully-prefilled slot PLUS budget-capped prefill chunks from
         every mid-prefill sequence the per-step token budget reaches
         into ONE forward, so decode ITL never stalls behind a long
@@ -1271,7 +1202,7 @@ class PagedDecodeEngine:
         draft prefix plus the model's own token at the first mismatch,
         then roll back the blocks the rejected tail was parked in.
 
-        Token identity with ``--serve-speculative off`` holds by
+        Token identity with ``--speculative off`` holds by
         construction: lane ``i`` of the verify output is the argmax
         over exactly the context vanilla decode would have at that
         position, and only argmax-chain-consistent tokens are emitted.
@@ -1283,7 +1214,7 @@ class PagedDecodeEngine:
         bs = serve.block_size
         cap = serve.max_blocks_per_seq * bs
         # the step's draft-window cap: the configured k, or — under
-        # --serve-draft-auto on — the EWMA-tuned effective k (floor 1
+        # --draft-auto on — the EWMA-tuned effective k (floor 1
         # keeps a cheap probe alive so a recovering accept rate can
         # re-grow the window; the verify dispatch width stays draft_k+1
         # either way, so auto-tuning can never add a compile)
@@ -1389,7 +1320,7 @@ class PagedDecodeEngine:
             counters["spec_emitted"] += len(emit)
             # effective-k accounting + EWMA update: the window the
             # policy would offer (k_cap) is what "effective k" means to
-            # the bench's speculation block; the EWMA tracks ACCEPTED
+            # the speculation block; the EWMA tracks ACCEPTED
             # length only over rows that drafted into a FULL window —
             # a row with no draft, or one truncated by budget/capacity/
             # pool pressure, says nothing about the drafter's accuracy
@@ -1661,7 +1592,7 @@ class PagedDecodeEngine:
     def prefix_block(self) -> dict:
         """Canonical prefix-cache accounting block for this engine's
         run (utils/metrics_writer.prefix_block — the ONE constructor
-        engine results, the recovery supervisor, and bench JSON
+        engine results, the recovery supervisor, and the entry point's JSON
         share)."""
         from mpi_tensorflow_tpu.utils.metrics_writer import prefix_block
 
@@ -1674,7 +1605,7 @@ class PagedDecodeEngine:
     def speculation_block(self) -> dict:
         """Canonical speculative-decoding accounting block
         (utils/metrics_writer.speculation_block — shared with the
-        recovery supervisor's cross-attempt merge and bench JSON)."""
+        recovery supervisor's cross-attempt merge and the entry point)."""
         from mpi_tensorflow_tpu.utils.metrics_writer import \
             speculation_block
 
@@ -1686,7 +1617,7 @@ class PagedDecodeEngine:
     def tier_block(self) -> dict:
         """Canonical host-tier accounting block
         (utils/metrics_writer.tier_block — the ONE constructor engine
-        results and bench JSON share); zero-safe with tiering off."""
+        results and the entry point share); zero-safe with tiering off."""
         from mpi_tensorflow_tpu.utils.metrics_writer import tier_block
 
         if self.tier is None:

@@ -1,8 +1,7 @@
-"""Trace-driven load generation + SLO metadata for the serving bench.
+"""Trace-driven load generation + SLO metadata for the serving entry
+point (``python -m mpi_tensorflow_tpu.serving``).
 
-Before this module, the serving trace was a hand-coded Poisson block
-inside ``bench.measure_serving`` — one arrival process, one length
-distribution, no deadlines, no tenants.  Real serving systems are
+Real serving systems are
 graded by GOODPUT UNDER SLO (requests completed within their latency
 deadline per second — DistServe, arXiv:2401.09670) and by behavior
 under realistic traffic: bursty arrivals, heavy-tailed lengths, and
@@ -14,11 +13,11 @@ multi-tenant mixes.  This module is the workload subsystem:
                        ServeConfig validates engine knobs;
 - ``build_trace``    — spec + seed -> ``Trace``: the SAME (spec, seed)
                        reproduces the exact same request list across
-                       runs, replicas, journal replay, and A/B arms.
+                       runs, replicas and journal replay.
                        ONE ``np.random.default_rng(seed)`` drives every
                        draw (no wall clock, no global RNG), and the
                        default Poisson path replays the historical
-                       bench draw order byte-for-byte (pinned by
+                       inline draw order byte-for-byte (pinned by
                        tests/test_loadgen.py);
 - per-request SLO deadlines — stamped as absolute ``Request.deadline``
                        values so they ride the scheduler's existing TTL
@@ -29,7 +28,7 @@ multi-tenant mixes.  This module is the workload subsystem:
                        times into the rows ``metrics_writer.
                        goodput_block`` aggregates.
 
-Workload matrix (``--serve-workload``):
+Workload matrix (``--workload``):
 
 ==============  ==========================  =========================
 workload        arrivals                    lengths / extras
@@ -60,7 +59,7 @@ import numpy as np
 
 from mpi_tensorflow_tpu.serving.scheduler import Request
 
-#: the --serve-workload enum (cli.py/bench.py mirror these choices)
+#: the ``workload`` enum
 WORKLOADS = ("poisson", "bursty", "multi-tenant", "diurnal")
 #: prompt/output length distributions ("uniform" is the historical one)
 LENGTH_DISTS = ("uniform", "lognormal", "zipf")
@@ -105,10 +104,11 @@ class WorkloadSpec:
     is the reproducibility key: the same pair builds the exact same
     request list (arrival stamps, token content, deadlines, sessions).
 
-    The defaults ARE the historical bench trace: ``poisson`` arrivals,
+    The defaults ARE the historical trace: ``poisson`` arrivals,
     ``uniform`` lengths, no prefix, no SLO — ``build_trace`` on a
-    default spec replays bench.py's original inline generator
-    byte-for-byte (the refactor pin)."""
+    default spec replays the original inline generator byte-for-byte
+    (tests/test_loadgen.py).  The fields ``WORKLOAD_HELP`` names are
+    the serving entry point's flags."""
     workload: str = "poisson"
     num_requests: int = 24
     rate_rps: float = 4.0
@@ -138,7 +138,7 @@ class WorkloadSpec:
     session_len: int = 1          # non-tenant workloads: mean multi-turn
                                   # session length (1 = no sessions)
     followup_turns: int = 0       # seeded follow-up-turn mode (prefix
-                                  # v2 bench): each extra turn replays
+                                  # v2): each extra turn replays
                                   # every request as prior prompt +
                                   # ANSWER + a pre-drawn unique suffix
                                   # (Trace.followup_requests); 0 draws
@@ -148,7 +148,7 @@ class WorkloadSpec:
     def __post_init__(self):
         if self.workload not in WORKLOADS:
             raise ValueError(
-                f"--serve-workload must be one of "
+                f"workload must be one of "
                 f"{'|'.join(WORKLOADS)}, got {self.workload!r}")
         if self.num_requests < 1 or self.prompt_max < 1 \
                 or self.output_max < 1:
@@ -164,14 +164,14 @@ class WorkloadSpec:
             raise ValueError(f"vocab_size must be >= 1, got "
                              f"{self.vocab_size}")
         if self.prefix_tokens < 0:
-            raise ValueError(f"--serve-prefix-tokens must be >= 0, got "
+            raise ValueError(f"prefix_tokens must be >= 0, got "
                              f"{self.prefix_tokens}")
         if self.length_dist not in LENGTH_DISTS:
             raise ValueError(
                 f"length_dist must be one of {'|'.join(LENGTH_DISTS)}, "
                 f"got {self.length_dist!r}")
         if self.slo_ms is not None and not self.slo_ms > 0:
-            raise ValueError(f"--serve-slo-ms must be > 0, got "
+            raise ValueError(f"slo_ms must be > 0, got "
                              f"{self.slo_ms}")
         if not self.burst_on_s > 0 or not self.burst_off_s > 0:
             raise ValueError(
@@ -199,6 +199,27 @@ class WorkloadSpec:
                              f"{self.followup_turns}")
 
 
+#: The WorkloadSpec fields the serving entry point exposes, one line of
+#: help each (its ``--help`` and docs/SERVING.md's options table).
+WORKLOAD_HELP = {
+    "workload": "arrival process, " + "|".join(WORKLOADS) + ": poisson "
+                "is the historical trace; bursty a 2-state MMPP; diurnal "
+                "a raised-cosine rate envelope; multi-tenant an "
+                "interactive-vs-batch mix with per-tenant SLOs and "
+                "sticky sessions over MMPP arrivals",
+    "num_requests": "requests in the trace",
+    "rate_rps": "mean arrival rate, requests per second",
+    "prompt_max": "longest prompt, tokens (lengths are drawn up to it)",
+    "output_max": "largest output budget, tokens",
+    "prefix_tokens": "shared system prompt prepended to every request "
+                     "(0: every prompt unique)",
+    "slo_ms": "per-request latency budget, stamped as each request's "
+              "deadline; the goodput block scores what finished inside "
+              "it (unset: no SLO)",
+    "seed": "seeds the trace, and the served model's weights",
+}
+
+
 def default_tenants(spec: WorkloadSpec) -> Tuple[TenantClass, ...]:
     """The built-in multi-tenant mix: a chatty interactive class (short
     outputs, tight SLO, 3-turn sticky sessions) against a batch class
@@ -220,8 +241,8 @@ def default_tenants(spec: WorkloadSpec) -> Tuple[TenantClass, ...]:
 class Trace:
     """A built trace: per-request content + the SLO/tenant metadata the
     goodput report joins against.  ``requests()`` materializes fresh
-    ``Request`` objects each call — bench replays the same trace
-    through warmup, timed, A/B, and routed arms."""
+    ``Request`` objects each call — the warm-up replay and the served
+    pass take the same trace."""
     spec: WorkloadSpec
     prompts: List[List[int]]
     outputs: List[int]
@@ -258,7 +279,7 @@ class Trace:
         ``outputs`` dict) + this turn's pre-drawn unique suffix.  The
         multi-turn regime generated-block caching exists for: everything
         up to the suffix re-prefills on a v1 cache but maps straight out
-        of the trie under --serve-prefix-gen.  Ids start at ``id_base``
+        of the trie under --prefix-gen.  Ids start at ``id_base``
         (distinct from every prior turn's); arrivals replay the turn's
         seeded exponential gaps from ``arrival_base``."""
         if not 1 <= turn <= len(self.followup_suffixes):
@@ -346,8 +367,8 @@ def build_trace(spec: WorkloadSpec) -> Trace:
     tenant assignment (only under a tenant mix), prompt lengths +
     tokens, output budgets, arrivals, then sessions.  On a default
     Poisson/uniform spec the first four stages are literally the
-    historical bench.measure_serving code, so the default trace is
-    byte-identical to the pre-loadgen inline generator."""
+    original inline generator, so the default trace is byte-identical
+    to it."""
     rng = np.random.default_rng(spec.seed)
     n = spec.num_requests
     p_lo = min(8, spec.prompt_max)
@@ -380,7 +401,7 @@ def build_trace(spec: WorkloadSpec) -> Trace:
         slos = [t.slo_ms if t.slo_ms is not None else spec.slo_ms
                 for t in assigned]
     elif spec.length_dist == "uniform":
-        # THE historical draw order (bench.measure_serving pre-loadgen):
+        # THE historical draw order (the pre-loadgen inline generator):
         # one vectorized length draw, per-prompt token draws in request
         # order, one vectorized output draw — byte-identical by test pin
         prompts = [shared + list(map(int, rng.integers(

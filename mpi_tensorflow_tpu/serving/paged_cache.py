@@ -212,7 +212,7 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     shape of models/gpt.init_cache so the engine threads them through
     jit the same way.
 
-    ``kv_dtype`` selects the pool storage format (--serve-kv-dtype):
+    ``kv_dtype`` selects the pool storage format (--kv-dtype):
 
     - "fp32": blocks in the model compute dtype — byte-for-byte the
       pre-quantization pool (the parity reference);
@@ -228,7 +228,7 @@ def init_pools(cfg, num_blocks: int, block_size: int,
       per byte within a head (ops/paged_attention.pack_int4) — and the
       scale siblings hold a head's groups side by side:
       ``(num_blocks, block_size, heads * head_dim // g)`` fp32 with
-      ``g = min(kv_group, head_dim)`` (the --serve-kv-group knob,
+      ``g = min(kv_group, head_dim)`` (the --kv-group knob,
       clamped so the default 32 stays valid on tiny heads; ``g`` must
       divide head_dim).  The uint8 code dtype is what the consume paths
       discriminate int4 on (ops/paged_attention.pool_mode) — no new leaf
@@ -274,7 +274,7 @@ def init_pools(cfg, num_blocks: int, block_size: int,
 
 
 class HostBlockStore:
-    """Host-RAM tier for demoted KV blocks (--serve-kv-tier host).
+    """Host-RAM tier for demoted KV blocks (--kv-tier host).
 
     When the prefix cache evicts an unreferenced trie leaf under pool
     pressure, the block's bytes are copied to host memory here instead

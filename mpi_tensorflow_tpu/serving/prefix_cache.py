@@ -22,7 +22,7 @@ BLOCK granularity:
 - ``insert`` runs when a sequence finishes prefill: the trie adopts the
   sequence's full-prompt blocks it has not seen before (its own
   ``share`` ref per node), making them matchable by later requests.
-  With generated-block caching on (--serve-prefix-gen, prefix v2) the
+  With generated-block caching on (--prefix-gen, prefix v2) the
   scheduler ALSO inserts a finished sequence's full blocks spanning
   prompt + generated output, so a follow-up turn that embeds the prior
   answer maps those blocks instead of re-prefilling them
@@ -97,7 +97,7 @@ class PrefixCache:
         # replica router's prefix-aware placement feeds its owner map
         # from this digest; None (the default) costs nothing.
         self.root_hook = None
-        # Host-RAM block tier (--serve-kv-tier host): the engine wires
+        # Host-RAM block tier (--kv-tier host): the engine wires
         # all three or none.  ``tier`` is a paged_cache.HostBlockStore;
         # ``demote_fetch(block) -> host leaves`` copies a pool block's
         # bytes to host (called just before eviction releases it);

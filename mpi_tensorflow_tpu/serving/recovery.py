@@ -13,7 +13,7 @@ and whose budget is the remaining tokens: chunked prefill re-ingests
 the concatenation, the prefill-final argmax emits exactly the token the
 lost process would have emitted next, and the delivered stream
 ``prefix + new_tokens`` is token-identical to an unfaulted run (pinned
-by tests/test_serving_recovery.py and the SIGKILL bench test).
+by tests/test_serving_recovery.py and tests/test_fault_injection.py).
 
 Layers:
 

@@ -1,6 +1,6 @@
 """Data-parallel replica serving: a fault-tolerant router over N engines.
 
-The engine (serving/engine) scales UP with ``--serve-tp`` — one logical
+The engine (serving/engine) scales UP with ``--tp`` — one logical
 pool, sharded over a mesh.  This layer scales OUT: ``N`` whole engine
 replicas, each with its own pool, scheduler, prefix trie, drafter, and
 — since fleet fault tolerance landed — its own ``ReplayJournal``,
@@ -17,7 +17,7 @@ Placement policy, in order:
    ejected re-homes on its next request.
 2. **Health gate** — only replicas the circuit breaker calls routable
    (``healthy`` or ``probing``) take work.
-3. **Prefix hint** (``--serve-prefix-route on``, prefix v2) — a
+3. **Prefix hint** (``--prefix-route on``, prefix v2) — a
    router-level map from leading full-block token keys to the replica
    whose trie cached them (fed by each trie's root-child digest via
    ``PrefixCache.root_hook``); a sessionless request whose first block
@@ -51,7 +51,7 @@ first, same as training) and:
   re-raises the last error rather than spinning.
 
 SIGTERM drains the WHOLE fleet: admission stops, queued work sheds,
-each replica finishes in-flight sequences within ``--serve-drain-ms``,
+each replica finishes in-flight sequences within ``--drain-ms``,
 and the budget's hard edge cuts the rest as ``drained`` — every request
 still leaves with exactly one terminal status, and
 ``Scheduler.check_quiescent`` is asserted on every surviving replica at
@@ -193,8 +193,8 @@ class ReplicaRouter:
         self.probe_ticks = probe_ticks
         self.max_sticky = max_sticky
         # prefix-aware placement (prefix v2): None resolves through the
-        # fleet's ServeConfig (--serve-prefix-route) — the explicit
-        # boolean exists for bench's hint-on-vs-off A/B over one fleet
+        # fleet's ServeConfig (--prefix-route) — the explicit
+        # boolean lets tests compare hint on and off over one fleet
         self._prefix_route = (engines[0].serve.prefix_route == "on"
                               if prefix_route is None
                               else bool(prefix_route))
@@ -658,8 +658,8 @@ class ReplicaRouter:
         crashed fleet — pair with ``recovery.fleet_replay_requests``
         and pass its ``pre`` map as ``replay_pre``); None = fresh
         memory-only journals, which is what arms in-process failover.
-        ``fault_plan`` injects deterministic replica faults (tests/
-        bench).  ``advisor`` (serving/autoscale.ScaleAdvisor) observes
+        ``fault_plan`` injects deterministic replica faults
+        (tests).  ``advisor`` (serving/autoscale.ScaleAdvisor) observes
         the FLEET-level load signals — router queue + summed replica
         queues, mean pool occupancy, fleet shed rate — once per router
         loop pass; its advisory decision log rides the result as the

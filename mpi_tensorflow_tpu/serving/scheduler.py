@@ -50,7 +50,7 @@ TERMINAL_STATUSES = ("ok", "rejected", "shed", "deadline_exceeded",
 class Request:
     """One generation request.  ``arrival`` is in seconds on the caller's
     clock; the engine admits a request only once the clock passes it
-    (the bench harness replays Poisson traces through this).
+    (the serving entry point replays its trace through this).
     ``deadline`` is an absolute stamp on the same clock: a request not
     COMPLETE by then fails with ``deadline_exceeded`` instead of
     occupying a slot (None = no deadline)."""
@@ -162,7 +162,7 @@ class Scheduler:
                           blocks are LRU-evicted BEFORE any live
                           sequence is preempted.  None = sharing off —
                           byte-for-byte today's behavior.
-    - ``prefix_gen``      prefix sharing v2 (--serve-prefix-gen): a
+    - ``prefix_gen``      prefix sharing v2 (--prefix-gen): a
                           finishing sequence inserts its full blocks
                           spanning prompt + generated output into the
                           trie (before its own release, so the blocks
@@ -272,7 +272,7 @@ class Scheduler:
         Returns the slot indices admitted this call (they need prefill).
         FIFO head-of-line: if the oldest request does not fit, nothing
         behind it jumps the queue — admission order stays arrival order
-        (the latency numbers the bench reports depend on it).
+        (the latency numbers a run reports depend on it).
 
         Aging guard: a head blocked on blocks for ``starvation_steps``
         consecutive admit calls preempts sequences YOUNGER than itself

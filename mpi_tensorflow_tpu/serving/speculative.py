@@ -17,7 +17,7 @@ would have used.  One KV-streaming pass is amortized over up to ``k+1``
 emitted tokens; the engine-side accounting reports the win as
 ``accept_rate`` / ``mean_accepted_len`` / ``steps_saved``.
 
-Two drafter backends behind one protocol (``--serve-speculative``):
+Two drafter backends behind one protocol (``--speculative``):
 
 - ``NgramDrafter``   — n-gram SELF-draft: match the sequence's current
                        suffix against its own earlier prompt+generated
@@ -327,7 +327,7 @@ class DraftModelDrafter(Drafter):
 
 def make_drafter(mode: str, serve, target_model, *, draft_model=None,
                  draft_params=None):
-    """Build the drafter the ``--serve-speculative`` mode names.
+    """Build the drafter the ``--speculative`` mode names.
 
     ``draft-model`` uses the supplied ``draft_model``/``draft_params``
     when given (the parity tests inject the TARGET model to pin the

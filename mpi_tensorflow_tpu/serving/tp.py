@@ -66,24 +66,24 @@ def _check_device_count(tp: int) -> None:
     ndev = len(jax.devices())
     if tp > ndev:
         raise ValueError(
-            f"--serve-tp {tp} exceeds the {ndev} visible device(s)")
+            f"--tp {tp} exceeds the {ndev} visible device(s)")
 
 
 def check_geometry(cfg, tp: int) -> None:
     """Reject a ``tp`` the model/mesh cannot honor — the one place the
     head/mlp divisibility and device-count rules are stated (engine
-    construction and bench both route through here)."""
+    construction routes through here)."""
     if tp < 1:
-        raise ValueError(f"--serve-tp must be >= 1, got {tp}")
+        raise ValueError(f"--tp must be >= 1, got {tp}")
     if tp == 1:
         return
     refusal = getattr(cfg, "tp_refusal", None)
     if refusal is not None:
-        raise ValueError(f"--serve-tp {tp}: {refusal}")
+        raise ValueError(f"--tp {tp}: {refusal}")
     _check_device_count(tp)
     if cfg.heads % tp or cfg.mlp % tp:
         raise ValueError(
-            f"--serve-tp {tp} must divide both heads ({cfg.heads}) and "
+            f"--tp {tp} must divide both heads ({cfg.heads}) and "
             f"mlp ({cfg.mlp}): the pool shards by head and the "
             f"MLP up-projection on its hidden axis")
 

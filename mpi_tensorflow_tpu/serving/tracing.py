@@ -1,6 +1,6 @@
 """Host-side structured tracing for the serving stack.
 
-Every serving claim the bench makes (goodput-under-SLO, TTFT p99,
+Every serving number a run reports (goodput-under-SLO, TTFT p99,
 dispatch reduction, failover token-identity) is an end-of-run
 aggregate; when a p99 regresses there was no way to see WHERE a
 request spent its time.  This module is the phase-attribution layer

@@ -57,7 +57,7 @@ def init_gspmd_state(model, tx: optax.GradientTransformation, rng,
     (``MasterOpt``).  When the model's COMPUTE dtype is bf16 this leaves
     compute numerics unchanged (the model casts weights to bf16 at use
     either way); pairing bf16 params with fp32 compute changes what the
-    matmuls see and is rejected by bench.py's flag validation.
+    matmuls see, so callers pair bf16 params with bf16 compute only.
     """
     params = model.init(rng)
     params = rules_lib.shard_tree(params, model.logical_axes(), mesh, rules)

@@ -120,7 +120,7 @@ def warmup_cosine(base_lr: float, warmup_steps: int, total_steps: int,
 # x? prefix: the enc-dec family's cross-attention biases (xbq/xbk/xbv/xbo,
 # models/encdec.py) are 2-D (heads, head_dim), so the ndim guard does not
 # exclude them either — without the prefix they silently weight-decayed
-# (ADVICE r3 medium)
+# (found in round 3's review)
 _BIAS_NAME = __import__("re").compile(r"^x?(b[a-z0-9]?|eb\d)$")
 
 

@@ -1,8 +1,8 @@
 """Where the persistent compilation cache lives, plus the XLA:CPU safety
 gates.
 
-``enable_compile_cache()`` is THE way an entry point (cli.main,
-bench.main, chip_smoke.py, tests/conftest.py) turns the persistent cache
+``enable_compile_cache()`` is THE way an entry point (cli.main, the
+serving main, chip_smoke.py, tests/conftest.py) turns the persistent cache
 on:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; the program

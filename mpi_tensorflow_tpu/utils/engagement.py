@@ -6,9 +6,9 @@ all-position MLM head; Mosaic-compiled vs interpreted vs XLA paged
 attention).  A benchmark number is meaningless if the artifact can't say
 which path it measured.
 
-Model code calls ``record(key, value)`` at each selection point; the bench
-harness calls ``reset()`` before tracing and ``snapshot()`` after, embedding
-the result in the JSON ``detail``.  Records fire during ``jax.jit`` tracing
+Model code calls ``record(key, value)`` at each selection point; an entry
+point calls ``reset()`` before tracing and ``snapshot()`` after, embedding
+the result in what it reports.  Records fire during ``jax.jit`` tracing
 (Python executes once per compilation), so a snapshot taken after the first
 call reflects exactly the paths baked into the compiled step.
 """

@@ -2,8 +2,8 @@
 
 NaN/Inf are not JSON: ``json.dumps`` happily writes literal ``NaN`` /
 ``Infinity`` tokens (``allow_nan`` defaults True) and strict consumers
-(jq, ``JSON.parse``) abort the whole stream on one bad line.  bench.py's
-output lines route through ``json_safe``; utils/metrics_writer.py applies
+(jq, ``JSON.parse``) abort the whole stream on one bad line.  The serving
+entry point's output line routes through ``json_safe``; utils/metrics_writer.py applies
 the same rule inline at
 its single scalar() write site (a scalar check, not a tree walk).
 """

@@ -85,7 +85,7 @@ def for_process(log_dir: Optional[str], process_index: int) -> MetricsWriter:
 
 #: canonical serving health-counter keys — THE shape of the ``faults``
 #: block every consumer sees (engine result dicts, the recovery
-#: supervisor's merged totals, bench.py --mode serving JSON).  One
+#: supervisor's merged totals, the serving entry point's JSON).  One
 #: definition so a dashboard keyed on these names never drifts from the
 #: engine's accounting.
 SERVING_FAULT_KEYS = ("rejected", "shed", "deadline_exceeded",
@@ -102,7 +102,7 @@ def faults_block(counters) -> dict:
 
 #: canonical fleet-level fault-tolerance counters (serving/router) —
 #: THE shape of the ``fleet_faults`` block every consumer sees (router
-#: result dicts, bench.py --serve-replicas JSON).  failovers = replica
+#: result dicts, the entry point's JSON).  failovers = replica
 #: faults handled; migrated_requests = live/queued requests re-homed to
 #: survivors; replay_tokens = prompt+prefix tokens re-ingested through
 #: chunked prefill to reconstruct migrated streams; ejections /
@@ -125,7 +125,7 @@ def prefix_block(counters, *, enabled: bool, trie_blocks: int = 0,
     """Normalize scheduler/supervisor counters into the canonical
     serving ``prefix`` (radix prefix cache) accounting block — one
     constructor shared by engine results, the recovery supervisor's
-    cross-attempt merge, router aggregation, and bench JSON, so the key
+    cross-attempt merge and router aggregation, so the key
     set and the hit-rate rounding can never drift between them.
 
     ``hit_rate`` counts FULL-BLOCK sharing only; partial tail-block
@@ -149,14 +149,14 @@ def prefix_block(counters, *, enabled: bool, trie_blocks: int = 0,
         # cached prefix made them fit (the scheduler's hit-aware
         # admission policy); 0 when the pool never came under pressure
         "hit_admissions": int(counters.get("prefix_hit_admissions", 0)),
-        # prefix v2 (--serve-prefix-gen): trie nodes adopted from
+        # prefix v2 (--prefix-gen): trie nodes adopted from
         # GENERATED output at request completion, and tail rows served
         # through the partial-copy dispatch instead of re-prefill
         "gen_inserted_blocks":
             int(counters.get("prefix_gen_inserted_blocks", 0)),
         "partial_copy_tokens": partial,
         "prefill_tokens_saved": hit + partial,
-        # prefix v2 (--serve-prefix-route): fleet placements the
+        # prefix v2 (--prefix-route): fleet placements the
         # router's prefix hint decided (always 0 for a single engine)
         "router_prefix_hits": int(router_prefix_hits),
     }
@@ -167,7 +167,7 @@ def speculation_block(counters, *, enabled: bool, mode: str = "off",
     """Normalize scheduler/supervisor counters into the canonical
     serving ``speculation`` (speculative decoding) accounting block —
     one constructor shared by engine results, the recovery
-    supervisor's cross-attempt merge, and bench JSON.
+    supervisor's cross-attempt merge, and the entry point's JSON.
 
     ``steps_saved`` is the bandwidth proxy the feature exists for:
     tokens emitted through the verify path minus verify forwards run —
@@ -185,7 +185,7 @@ def speculation_block(counters, *, enabled: bool, mode: str = "off",
         "mode": mode,
         "draft_k": int(draft_k),
         # the window the policy actually offered, averaged over verify
-        # steps: == draft_k with auto-tuning off; under --serve-draft-auto
+        # steps: == draft_k with auto-tuning off; under --draft-auto
         # on this is THE number the knob exists to report
         "draft_auto": draft_auto,
         "effective_k": (round(k_sum / k_steps, 2) if k_steps
@@ -201,57 +201,9 @@ def speculation_block(counters, *, enabled: bool, mode: str = "off",
     }
 
 
-def kv_quant_block(*, kv_dtype: str = "fp32", matched_tokens: int = 0,
-                   compared_tokens: int = 0, block_bytes_ref: int = 0,
-                   block_bytes: int = 0, num_blocks: int = 0,
-                   peak_live_blocks_ref: int = 0,
-                   peak_live_blocks: int = 0,
-                   bytes_per_decode_token_ref: float = 0.0,
-                   bytes_per_decode_token: float = 0.0) -> dict:
-    """Normalize KV-quantization A/B numbers into the canonical serving
-    ``kv_quant`` block (bench --serve-kv-ab JSON) — same discipline as
-    the blocks above: every key present, plain types, rounding here.
-
-    ``*_ref`` is the fp32 (unquantized) arm.  ``token_match_rate`` is
-    positionwise greedy-token agreement between the arms over the whole
-    trace (aligned positions; length mismatches count as mismatches) —
-    the quality gate quantization must clear.  ``capacity_multiplier``
-    / ``effective_capacity_blocks`` answer the question the feature
-    exists for: how many pool blocks the SAME HBM budget holds at the
-    quantized bytes-per-block (codes + scale siblings).
-    ``peak_live_blocks_delta`` pins the arms' block-accounting
-    equivalence (same trace => same block walk => 0), and the
-    bytes-per-decode-token pair is the decode bandwidth roofline at the
-    quantized element width (1 byte/elem for int8, plus scale
-    traffic)."""
-    return {
-        "enabled": True,
-        "kv_dtype": kv_dtype,
-        "matched_tokens": int(matched_tokens),
-        "compared_tokens": int(compared_tokens),
-        "token_match_rate": (round(matched_tokens / compared_tokens, 4)
-                             if compared_tokens else 0.0),
-        "block_bytes_ref": int(block_bytes_ref),
-        "block_bytes": int(block_bytes),
-        "capacity_multiplier": (round(block_bytes_ref / block_bytes, 4)
-                                if block_bytes else 0.0),
-        "effective_capacity_blocks": (
-            int(num_blocks * block_bytes_ref // block_bytes)
-            if block_bytes else 0),
-        "num_blocks": int(num_blocks),
-        "peak_live_blocks_ref": int(peak_live_blocks_ref),
-        "peak_live_blocks": int(peak_live_blocks),
-        "peak_live_blocks_delta": int(peak_live_blocks
-                                      - peak_live_blocks_ref),
-        "bytes_per_decode_token_ref": round(
-            float(bytes_per_decode_token_ref), 2),
-        "bytes_per_decode_token": round(float(bytes_per_decode_token), 2),
-    }
-
-
 #: canonical host-tier keys — THE shape of the ``tier`` block every
-#: consumer sees (engine results, bench --mode serving JSON).  Tiering
-#: (--serve-kv-tier host) demotes cold prefix-cache blocks to host RAM
+#: consumer sees (engine results, the entry point's JSON).  Tiering
+#: (--kv-tier host) demotes cold prefix-cache blocks to host RAM
 #: on eviction and promotes them back on a later trie match;
 #: prefill_tokens_saved_tier = promotions * block_size is the prefill
 #: work those re-admissions avoided re-paying.
@@ -309,8 +261,7 @@ def moe_block(*, enabled: bool = False, per_expert=(),
 
 
 #: canonical goodput-under-SLO keys — THE shape of the ``goodput``
-#: block every consumer sees (bench.py --mode serving JSON, the metric
-#: line's goodput_tokens_per_sec / slo_attainment fields).  Goodput =
+#: block the serving entry point prints.  Goodput =
 #: tokens (and requests) per second from requests that completed within
 #: their latency budget (DistServe, arXiv:2401.09670) — the serving
 #: number raw tokens/sec over-reports under load.
@@ -390,7 +341,7 @@ def goodput_block(rows, *, elapsed_s: float, enabled=None) -> dict:
 
 
 #: canonical phase-attribution keys — THE shape of the ``breakdown``
-#: block bench detail carries with --serve-trace on (serving/tracing
+#: block the entry point prints with --trace on (serving/tracing
 #: spans).  queue/prefill/decode percentiles are recomputed FROM SPANS
 #: (not from the engine's scalar stamps); the two ``*_max_delta_ms``
 #: keys are the cross-checks that pin the span clock to the stamped

@@ -11,18 +11,18 @@ tracing row).  Here measurement is a first-class utility:
 - ``device_memory_stats()``: per-device HBM usage snapshot, for finding the
   working-set the rematerialization knobs should target;
 - ``device_identity()``: platform / device_kind / device count as JAX
-  reports them — stamped on every bench row, printed by every entry point;
-- ``StepTimer`` / ``time_step_fn``: warmup-skipping wall-clock step
-  timers for the TRAIN loops and bench — JAX dispatch is asynchronous,
-  so both block on the final output (``block_until_ready``) and
-  amortize over many steps.  Measurement rule: evaluation stays OFF
+  reports them — printed by every entry point;
+- ``StepTimer``: the warmup-skipping wall-clock step timer of the TRAIN
+  loops — JAX dispatch is asynchronous, so it blocks on the final
+  output (``block_until_ready``) and amortizes over many steps.
+  Measurement rule: evaluation stays OFF
   the timed path (the reference's accidental every-step full-test eval
   at mpipy.py:86 is not replicated in what we time).
 
 The SERVING side has its own timing layer — ``serving/tracing``
 stamps request-lifecycle spans and per-step phase durations on the
 serve loop's existing host clocks (it must never block on device
-output the way ``time_step_fn`` deliberately does).  Train/bench time
+output the way ``StepTimer`` deliberately does).  Training times
 here; serving traces there; nothing else reads a clock.
 
 Wired into the CLI as ``--profile-dir`` (cli.py).
@@ -91,24 +91,6 @@ class StepTimer:
     def images_per_sec(self, batch_size: int) -> float:
         s = self.mean_step_seconds
         return batch_size / s if s == s and s > 0 else float("nan")
-
-
-def time_step_fn(step_fn, state, make_args, iters: int = 20, warmup: int = 3):
-    """Benchmark a train step that donates (and returns) its state.
-
-    ``make_args(i)`` supplies the per-call non-state arguments.  Returns
-    ``(mean_seconds_per_step, final_state)``.
-    """
-    import jax
-
-    for i in range(warmup):
-        state, metrics = step_fn(state, *make_args(i))
-    jax.block_until_ready(state)
-    t0 = time.perf_counter()
-    for i in range(iters):
-        state, metrics = step_fn(state, *make_args(i))
-    jax.block_until_ready(state)
-    return (time.perf_counter() - t0) / iters, state
 
 
 def device_identity() -> dict:

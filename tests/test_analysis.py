@@ -29,8 +29,7 @@ import json
 import textwrap
 
 from mpi_tensorflow_tpu.analysis import (core, host_sync, jit_stability,
-                                         knob_bridge, locks, names,
-                                         runner)
+                                         locks, names, runner)
 
 
 def _src(text):
@@ -39,487 +38,6 @@ def _src(text):
 
 def _ids(findings):
     return [f.pass_id for f in findings]
-
-
-# ---------------------------------------------------------------------
-# knob-bridge
-# ---------------------------------------------------------------------
-
-def _knob_tree(*, field="serve_knob: int = 1", flag_ok=True,
-               wire_ok=True, guard_ok=True, post_init_ok=True,
-               consume=True):
-    """A minimal three-layer knob bridge, breakable one layer at a
-    time."""
-    # continuation lines carry the RAW indent the insertion point
-    # needs, so textwrap.dedent sees a consistent block
-    flag = ('p.add_argument("--serve-knob", type=int, default=1)'
-            if flag_ok else
-            'p.add_argument("--serve-knob", default=1)')
-    wire = "serve_knob=args.serve_knob," if wire_ok else ""
-    guard = ("if config.serve_knob < 1:\n"
-             "                    raise SystemExit('bad')"
-             if guard_ok else "pass")
-    post = ("if self.knob < 1:\n"
-            "                        raise ValueError('bad')"
-            if post_init_ok else "pass")
-    consumer = ("def use(serve):\n                return serve.knob\n"
-                if consume else "")
-    return {
-        "pkg/cli.py": _src(f"""
-            import argparse
-            from pkg.config import Config
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                {flag}
-                return p
-
-            def config_from_args(args):
-                return Config({wire})
-
-            def main(argv=None):
-                args = build_parser().parse_args(argv)
-                config = config_from_args(args)
-                {guard}
-                return config
-            """),
-        "pkg/config.py": _src(f"""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class Config:
-                {field}
-            """),
-        "pkg/serve.py": _src(f"""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class ServeConfig:
-                knob: int = 1
-
-                def __post_init__(self):
-                    {post}
-
-                @classmethod
-                def from_config(cls, cfg):
-                    return cls(knob=cfg.serve_knob)
-            {consumer}
-            """),
-    }
-
-
-def test_knob_bridge_green():
-    tree = _knob_tree()
-    # guard against a vacuous pass: every fixture module must parse
-    # and the content-based cli discovery must bite
-    parsed = core.parse_sources(tree)
-    assert len(parsed) == len(tree) == 3
-    assert knob_bridge._find_cli(parsed) is not None
-    assert knob_bridge.run(tree) == []
-
-
-def test_knob_bridge_flag_without_field():
-    tree = _knob_tree(field="other: int = 0")
-    ids = _ids(knob_bridge.run(tree))
-    assert "KNOB-FLAG" in ids
-
-
-def test_knob_bridge_flag_not_wired():
-    found = knob_bridge.run(_knob_tree(wire_ok=False))
-    assert any(f.pass_id == "KNOB-FLAG" and "never wired" in f.message
-               for f in found)
-
-
-def test_knob_bridge_missing_main_guard():
-    found = knob_bridge.run(_knob_tree(guard_ok=False))
-    assert any(f.pass_id == "KNOB-GUARD" and "cli.main" in f.message
-               for f in found)
-
-
-def test_knob_bridge_missing_argparse_validation():
-    found = knob_bridge.run(_knob_tree(flag_ok=False))
-    assert any(f.pass_id == "KNOB-GUARD" and "argparse" in f.message
-               for f in found)
-
-
-def test_knob_bridge_missing_post_init_validation():
-    found = knob_bridge.run(_knob_tree(post_init_ok=False))
-    assert any(f.pass_id == "KNOB-GUARD"
-               and "__post_init__ never validates" in f.message
-               for f in found)
-
-
-def test_knob_bridge_dead_field():
-    tree = _knob_tree()
-    tree["pkg/config.py"] = _src("""
-        import dataclasses
-
-        @dataclasses.dataclass
-        class Config:
-            serve_knob: int = 1
-            serve_orphan: int = 0
-        """)
-    found = knob_bridge.run(tree)
-    assert any(f.pass_id == "KNOB-DEAD" and "serve_orphan" in f.message
-               for f in found)
-    # the orphan also has no flag and no downstream layer
-    assert any(f.pass_id == "KNOB-FLAG" and "serve_orphan" in f.message
-               for f in found)
-
-
-def _prefix_v2_tree(*, route_wired=True, gen_validated=True):
-    """The prefix-v2 knob pair (--serve-prefix-gen/-route) as a
-    minimal bridge fixture: two choices-validated string knobs with
-    cli.main coupling guards, breakable one layer at a time."""
-    route_wire = ("serve_prefix_route=args.serve_prefix_route,"
-                  if route_wired else "")
-    gen_post = ('if self.prefix_gen not in ("off", "on"):\n'
-                '                        raise ValueError("bad")'
-                if gen_validated else "pass")
-    return {
-        "pkg/cli.py": _src(f"""
-            import argparse
-            from pkg.config import Config
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--serve-prefix-gen",
-                               choices=["off", "on"], default="off")
-                p.add_argument("--serve-prefix-route",
-                               choices=["off", "on"], default="off")
-                return p
-
-            def config_from_args(args):
-                return Config(
-                    serve_prefix_gen=args.serve_prefix_gen,
-                    {route_wire})
-
-            def main(argv=None):
-                args = build_parser().parse_args(argv)
-                config = config_from_args(args)
-                if config.serve_prefix_gen not in ("off", "on"):
-                    raise SystemExit("bad gen")
-                if config.serve_prefix_route not in ("off", "on"):
-                    raise SystemExit("bad route")
-                return config
-            """),
-        "pkg/config.py": _src("""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class Config:
-                serve_prefix_gen: str = "off"
-                serve_prefix_route: str = "off"
-            """),
-        "pkg/serve.py": _src(f"""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class ServeConfig:
-                prefix_gen: str = "off"
-                prefix_route: str = "off"
-
-                def __post_init__(self):
-                    {gen_post}
-                    if self.prefix_route not in ("off", "on"):
-                        raise ValueError("bad")
-
-                @classmethod
-                def from_config(cls, cfg):
-                    return cls(prefix_gen=cfg.serve_prefix_gen,
-                               prefix_route=cfg.serve_prefix_route)
-
-            def use(serve):
-                return (serve.prefix_gen, serve.prefix_route)
-            """),
-    }
-
-
-def test_prefix_v2_knob_pair_green():
-    tree = _prefix_v2_tree()
-    assert knob_bridge._find_cli(core.parse_sources(tree)) is not None
-    assert knob_bridge.run(tree) == []
-
-
-def test_prefix_v2_route_not_wired_red():
-    found = knob_bridge.run(_prefix_v2_tree(route_wired=False))
-    assert any(f.pass_id == "KNOB-FLAG"
-               and "serve-prefix-route" in f.message for f in found)
-
-
-def test_prefix_v2_gen_post_init_missing_red():
-    found = knob_bridge.run(_prefix_v2_tree(gen_validated=False))
-    assert any(f.pass_id == "KNOB-GUARD"
-               and "__post_init__ never validates" in f.message
-               and "prefix_gen" in f.message for f in found)
-
-
-def _mixed_batch_tree(*, budget_wired=True, mixed_validated=True):
-    """The mixed-batch knob pair (--serve-mixed-batch/-prefill-budget)
-    as a minimal bridge fixture: one choices-validated string knob plus
-    one range-guarded int knob, breakable one layer at a time."""
-    budget_wire = ("serve_prefill_budget=args.serve_prefill_budget,"
-                   if budget_wired else "")
-    mixed_post = ('if self.mixed_batch not in ("off", "on"):\n'
-                  '                        raise ValueError("bad")'
-                  if mixed_validated else "pass")
-    return {
-        "pkg/cli.py": _src(f"""
-            import argparse
-            from pkg.config import Config
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--serve-mixed-batch",
-                               choices=["off", "on"], default="off")
-                p.add_argument("--serve-prefill-budget",
-                               type=int, default=8)
-                return p
-
-            def config_from_args(args):
-                return Config(
-                    serve_mixed_batch=args.serve_mixed_batch,
-                    {budget_wire})
-
-            def main(argv=None):
-                args = build_parser().parse_args(argv)
-                config = config_from_args(args)
-                if config.serve_mixed_batch not in ("off", "on"):
-                    raise SystemExit("bad mixed")
-                if config.serve_prefill_budget < 1:
-                    raise SystemExit("bad budget")
-                return config
-            """),
-        "pkg/config.py": _src("""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class Config:
-                serve_mixed_batch: str = "off"
-                serve_prefill_budget: int = 8
-            """),
-        "pkg/serve.py": _src(f"""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class ServeConfig:
-                mixed_batch: str = "off"
-                prefill_budget: int = 8
-
-                def __post_init__(self):
-                    {mixed_post}
-                    if self.prefill_budget < 1:
-                        raise ValueError("bad")
-
-                @classmethod
-                def from_config(cls, cfg):
-                    return cls(mixed_batch=cfg.serve_mixed_batch,
-                               prefill_budget=cfg.serve_prefill_budget)
-
-            def use(serve):
-                return (serve.mixed_batch, serve.prefill_budget)
-            """),
-    }
-
-
-def test_mixed_batch_knob_pair_green():
-    tree = _mixed_batch_tree()
-    assert knob_bridge._find_cli(core.parse_sources(tree)) is not None
-    assert knob_bridge.run(tree) == []
-
-
-def test_mixed_batch_budget_not_wired_red():
-    found = knob_bridge.run(_mixed_batch_tree(budget_wired=False))
-    assert any(f.pass_id == "KNOB-FLAG"
-               and "serve-prefill-budget" in f.message for f in found)
-
-
-def test_mixed_batch_post_init_missing_red():
-    found = knob_bridge.run(_mixed_batch_tree(mixed_validated=False))
-    assert any(f.pass_id == "KNOB-GUARD"
-               and "__post_init__ never validates" in f.message
-               and "mixed_batch" in f.message for f in found)
-
-
-def _trace_knob_tree(*, out_wired=True, out_validated=True):
-    """The tracing knob pair (--serve-trace/--serve-trace-out) as a
-    minimal bridge fixture: one choices-validated mode knob plus one
-    path knob whose only semantic guard is the coupling check
-    (trace_out requires trace on), breakable one layer at a time."""
-    out_wire = ("serve_trace_out=args.serve_trace_out,"
-                if out_wired else "")
-    out_post = ('if self.trace_out is not None and self.trace != "on":\n'
-                '                        raise ValueError("bad")'
-                if out_validated else "pass")
-    return {
-        "pkg/cli.py": _src(f"""
-            import argparse
-            from pkg.config import Config
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--serve-trace",
-                               choices=["off", "on"], default="off")
-                p.add_argument("--serve-trace-out",
-                               type=str, default=None)
-                return p
-
-            def config_from_args(args):
-                return Config(
-                    serve_trace=args.serve_trace,
-                    {out_wire})
-
-            def main(argv=None):
-                args = build_parser().parse_args(argv)
-                config = config_from_args(args)
-                if config.serve_trace not in ("off", "on"):
-                    raise SystemExit("bad trace")
-                if config.serve_trace_out is not None:
-                    if config.serve_trace != "on":
-                        raise SystemExit("out needs trace on")
-                return config
-            """),
-        "pkg/config.py": _src("""
-            import dataclasses
-            from typing import Optional
-
-            @dataclasses.dataclass
-            class Config:
-                serve_trace: str = "off"
-                serve_trace_out: Optional[str] = None
-            """),
-        "pkg/serve.py": _src(f"""
-            import dataclasses
-            from typing import Optional
-
-            @dataclasses.dataclass
-            class ServeConfig:
-                trace: str = "off"
-                trace_out: Optional[str] = None
-
-                def __post_init__(self):
-                    if self.trace not in ("off", "on"):
-                        raise ValueError("bad")
-                    {out_post}
-
-                @classmethod
-                def from_config(cls, cfg):
-                    return cls(trace=cfg.serve_trace,
-                               trace_out=cfg.serve_trace_out)
-
-            def use(serve):
-                return (serve.trace, serve.trace_out)
-            """),
-    }
-
-
-def test_trace_knob_pair_green():
-    tree = _trace_knob_tree()
-    assert knob_bridge._find_cli(core.parse_sources(tree)) is not None
-    assert knob_bridge.run(tree) == []
-
-
-def test_trace_out_not_wired_red():
-    found = knob_bridge.run(_trace_knob_tree(out_wired=False))
-    assert any(f.pass_id == "KNOB-FLAG"
-               and "serve-trace-out" in f.message for f in found)
-
-
-def test_trace_out_post_init_missing_red():
-    found = knob_bridge.run(_trace_knob_tree(out_validated=False))
-    assert any(f.pass_id == "KNOB-GUARD"
-               and "__post_init__ never validates" in f.message
-               and "trace_out" in f.message for f in found)
-
-
-def _kv_ladder_tree(*, group_wired=True, tier_validated=True):
-    """The KV capacity-ladder knob pair (--serve-kv-tier/-group) as a
-    minimal bridge fixture: one choices-validated mode knob whose only
-    semantic guard is the coupling check (tiering rides the prefix
-    cache's eviction/match hooks) plus one range-guarded int knob,
-    breakable one layer at a time."""
-    group_wire = ("serve_kv_group=args.serve_kv_group,"
-                  if group_wired else "")
-    tier_post = ('if self.kv_tier == "host" and self.prefix == "off":\n'
-                 '                        raise ValueError("bad")'
-                 if tier_validated else "pass")
-    return {
-        "pkg/cli.py": _src(f"""
-            import argparse
-            from pkg.config import Config
-
-            def build_parser():
-                p = argparse.ArgumentParser()
-                p.add_argument("--serve-kv-tier",
-                               choices=["off", "host"], default="off")
-                p.add_argument("--serve-kv-group",
-                               type=int, default=32)
-                return p
-
-            def config_from_args(args):
-                return Config(
-                    serve_kv_tier=args.serve_kv_tier,
-                    {group_wire})
-
-            def main(argv=None):
-                args = build_parser().parse_args(argv)
-                config = config_from_args(args)
-                if config.serve_kv_tier not in ("off", "host"):
-                    raise SystemExit("bad tier")
-                if config.serve_kv_group < 1:
-                    raise SystemExit("bad group")
-                return config
-            """),
-        "pkg/config.py": _src("""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class Config:
-                serve_kv_tier: str = "off"
-                serve_kv_group: int = 32
-            """),
-        "pkg/serve.py": _src(f"""
-            import dataclasses
-
-            @dataclasses.dataclass
-            class ServeConfig:
-                kv_tier: str = "off"
-                kv_group: int = 32
-                prefix: str = "off"
-
-                def __post_init__(self):
-                    {tier_post}
-                    if self.kv_group < 1:
-                        raise ValueError("bad")
-
-                @classmethod
-                def from_config(cls, cfg):
-                    return cls(kv_tier=cfg.serve_kv_tier,
-                               kv_group=cfg.serve_kv_group)
-
-            def use(serve):
-                return (serve.kv_tier, serve.kv_group)
-            """),
-    }
-
-
-def test_kv_ladder_knob_pair_green():
-    tree = _kv_ladder_tree()
-    assert knob_bridge._find_cli(core.parse_sources(tree)) is not None
-    assert knob_bridge.run(tree) == []
-
-
-def test_kv_group_not_wired_red():
-    found = knob_bridge.run(_kv_ladder_tree(group_wired=False))
-    assert any(f.pass_id == "KNOB-FLAG"
-               and "serve-kv-group" in f.message for f in found)
-
-
-def test_kv_tier_post_init_missing_red():
-    found = knob_bridge.run(_kv_ladder_tree(tier_validated=False))
-    assert any(f.pass_id == "KNOB-GUARD"
-               and "__post_init__ never validates" in f.message
-               and "kv_tier" in f.message for f in found)
 
 
 # ---------------------------------------------------------------------
@@ -935,10 +453,9 @@ def test_runner_exit_codes_and_ratchet(tmp_path, capsys):
 
 def test_runner_all_passes_registered():
     mods = {m.__name__.rsplit(".", 1)[-1] for m in runner.PASSES}
-    assert mods == {"knob_bridge", "jit_stability", "host_sync",
-                    "locks", "names"}
+    assert mods == {"jit_stability", "host_sync", "locks", "names"}
     ids = [pid for m in runner.PASSES for pid in m.PASS_IDS]
-    assert len(ids) == len(set(ids)) == 10
+    assert len(ids) == len(set(ids)) == 7
 
 
 def test_live_repo_scans_clean():
@@ -947,7 +464,7 @@ def test_live_repo_scans_clean():
     bar, pinned."""
     sources = core.load_sources(core.repo_root())
     assert "mpi_tensorflow_tpu/serving/router.py" in sources
-    assert "bench.py" in sources
+    assert "mpi_tensorflow_tpu/serving/__main__.py" in sources
     findings = runner.run_all(sources)
     baseline = runner.load_baseline(runner._DEFAULT_BASELINE)
     assert sum(baseline.values()) <= 5, \

@@ -163,7 +163,7 @@ class TestLoopResume:
 
 
 class TestCommitSemantics:
-    """ADVICE r2: commit markers and the async-commit threading contract."""
+    """Commit markers and the async-commit threading contract."""
 
     def test_bare_npz_is_not_committed(self, tmp_path):
         """A kill between the .npz replace and the .json sidecar write must

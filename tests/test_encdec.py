@@ -76,7 +76,7 @@ class TestForward:
                                       np.asarray(m.apply(params, b)))
 
     def test_dropout_fires_at_both_embedding_sites(self, monkeypatch):
-        """ADVICE r3: the encoder-embed mask (stream 1, as BertMlm applies
+        """The encoder-embed mask (stream 1, as BertMlm applies
         it) and a reserved decoder-embed site must both fire in train
         mode.  Counted via the shared dropout_mask: 1 enc embed +
         2/enc-layer + 1 dec embed + 3/dec-layer."""
@@ -99,7 +99,7 @@ class TestForward:
         assert calls == []
 
     def test_generate_rejects_beyond_position_table(self):
-        """ADVICE r3: _dec_embed's dynamic_slice clamps, so decoding past
+        """_dec_embed's dynamic_slice clamps, so decoding past
         dec_pos_emb would silently reuse the last row — must raise like
         CausalLm.init_cache."""
         m = _model()

@@ -1,6 +1,6 @@
 """Path-engagement recording (utils/engagement.py).
 
-A bench number must say which attention/CE implementation actually
+A reported number must say which attention/CE implementation actually
 compiled into the step.  These tests pin that the records follow the
 selection (platform, sequence length, operator switch) and that a kernel
 that fails to compile raises instead of becoming an XLA row.

@@ -97,18 +97,18 @@ def _read_until(proc, pred, deadline_s):
 
 class TestServingSigkillReplay:
     """The serving analogue of TestSigkillResume: SIGKILL a real
-    ``bench.py --mode serving`` process mid-decode (no grace, no signal
-    handler), relaunch with the same replay journal, and require the
+    ``python -m mpi_tensorflow_tpu.serving`` process mid-decode (no grace,
+    no signal handler), relaunch with the same replay journal, and require the
     recovered outputs to be TOKEN-IDENTICAL to an unfaulted run —
     greedy decode is deterministic, so the journal's prompt+prefix
     replay is exact."""
 
-    def _bench(self, env, journal, extra=()):
-        args = ["bench.py", "--mode", "serving", "--serve-tiny",
-                "--precision", "fp32", "--requests", "6",
-                "--prompt-len", "12", "--new-tokens", "80",
-                "--arrival-rate", "1000",
-                "--serve-journal", journal] + list(extra)
+    def _serve(self, env, journal, extra=()):
+        args = ["-m", "mpi_tensorflow_tpu.serving", "--tiny",
+                "--precision", "fp32", "--num-requests", "6",
+                "--prompt-max", "12", "--output-max", "80",
+                "--rate-rps", "1000",
+                "--journal", journal] + list(extra)
         return subprocess.Popen([sys.executable] + args, cwd=REPO, env=env,
                                 stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True)
@@ -118,7 +118,7 @@ class TestServingSigkillReplay:
         import json
 
         rec = json.loads(proc_stdout.strip().splitlines()[-1])
-        return rec["detail"]["outputs"], rec["detail"]["statuses"]
+        return rec["outputs"], rec["statuses"]
 
     def test_sigkill_mid_decode_then_replay_token_identical(self, tmp_path):
         env = _cli_env()
@@ -126,7 +126,7 @@ class TestServingSigkillReplay:
 
         # run 1: SIGKILL once the journal shows live mid-decode work
         # (tokens recorded, nothing near the ~460-token completion)
-        proc = self._bench(env, journal)
+        proc = self._serve(env, journal)
         try:
             t0 = time.time()
             killed = False
@@ -144,7 +144,7 @@ class TestServingSigkillReplay:
                     killed = True
                     break
                 time.sleep(0.005)
-            assert killed, "bench run never reached mid-decode state"
+            assert killed, "serving run never reached mid-decode state"
         finally:
             if proc.poll() is None:
                 proc.kill()
@@ -158,14 +158,14 @@ class TestServingSigkillReplay:
         assert live, "SIGKILL landed after completion; nothing to replay"
 
         # run 2: same journal — resumes and completes
-        proc2 = self._bench(env, journal)
+        proc2 = self._serve(env, journal)
         out2, _ = proc2.communicate(timeout=150)
         assert proc2.returncode == 0, out2
         got, statuses = self._outputs(out2)
         assert set(statuses.values()) == {"ok"}, statuses
 
         # run 3: unfaulted reference with a fresh journal
-        proc3 = self._bench(env, str(tmp_path / "clean.jsonl"))
+        proc3 = self._serve(env, str(tmp_path / "clean.jsonl"))
         out3, _ = proc3.communicate(timeout=150)
         assert proc3.returncode == 0, out3
         want, _ = self._outputs(out3)
@@ -174,7 +174,7 @@ class TestServingSigkillReplay:
 
 class TestFleetSigkillReplay:
     """The FLEET analogue of TestServingSigkillReplay (ISSUE 9): SIGKILL
-    a real ``bench.py --mode serving --serve-replicas 2 --serve-journal``
+    a real ``python -m mpi_tensorflow_tpu.serving --replicas 2 --journal``
     process mid-decode — journaling is per-replica (``<path>.r0`` /
     ``<path>.r1``) — relaunch with the same arguments, and require the
     merged recovered outputs to be TOKEN-IDENTICAL to an unfaulted
@@ -184,13 +184,13 @@ class TestFleetSigkillReplay:
 
     N_REPLICAS = 2
 
-    def _bench(self, env, journal):
-        args = ["bench.py", "--mode", "serving", "--serve-tiny",
-                "--precision", "fp32", "--requests", "6",
-                "--prompt-len", "12", "--new-tokens", "80",
-                "--arrival-rate", "1000",
-                "--serve-replicas", str(self.N_REPLICAS),
-                "--serve-journal", journal]
+    def _serve(self, env, journal):
+        args = ["-m", "mpi_tensorflow_tpu.serving", "--tiny",
+                "--precision", "fp32", "--num-requests", "6",
+                "--prompt-max", "12", "--output-max", "80",
+                "--rate-rps", "1000",
+                "--replicas", str(self.N_REPLICAS),
+                "--journal", journal]
         return subprocess.Popen([sys.executable] + args, cwd=REPO, env=env,
                                 stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True)
@@ -210,7 +210,7 @@ class TestFleetSigkillReplay:
         import json
 
         rec = json.loads(proc_stdout.strip().splitlines()[-1])
-        return rec["detail"]["outputs"], rec["detail"]["statuses"]
+        return rec["outputs"], rec["statuses"]
 
     def test_sigkill_fleet_then_replay_token_identical(self, tmp_path):
         env = _cli_env()
@@ -219,7 +219,7 @@ class TestFleetSigkillReplay:
         # run 1: SIGKILL once the per-replica journals show live
         # mid-decode work (tokens recorded, far from the ~460-token
         # completion)
-        proc = self._bench(env, journal)
+        proc = self._serve(env, journal)
         try:
             t0 = time.time()
             killed = False
@@ -232,7 +232,7 @@ class TestFleetSigkillReplay:
                     killed = True
                     break
                 time.sleep(0.005)
-            assert killed, "fleet bench never reached mid-decode state"
+            assert killed, "fleet never reached mid-decode state"
         finally:
             if proc.poll() is None:
                 proc.kill()
@@ -253,7 +253,7 @@ class TestFleetSigkillReplay:
         assert live, "SIGKILL landed after completion; nothing to replay"
 
         # run 2: same journals — the fleet resumes and completes
-        proc2 = self._bench(env, journal)
+        proc2 = self._serve(env, journal)
         out2, _ = proc2.communicate(timeout=150)
         assert proc2.returncode == 0, out2
         got, statuses = self._outputs(out2)
@@ -261,7 +261,7 @@ class TestFleetSigkillReplay:
         assert len(statuses) == 6, statuses
 
         # run 3: unfaulted fleet reference with fresh journals
-        proc3 = self._bench(env, str(tmp_path / "clean.jsonl"))
+        proc3 = self._serve(env, str(tmp_path / "clean.jsonl"))
         out3, _ = proc3.communicate(timeout=150)
         assert proc3.returncode == 0, out3
         want, _ = self._outputs(out3)

@@ -151,8 +151,7 @@ class TestDecode:
 
     def test_cache_len_override_is_output_invariant(self):
         """Extra cache capacity only pads the masked region — greedy
-        tokens must be identical (bench.measure_decode relies on this to
-        pin both timing arms to one capacity)."""
+        tokens must be identical, so comparisons may pin one capacity."""
         model, params, toks = self._setup(b=2, s=8)
         want = np.asarray(model.generate(params, toks, 6))
         got = np.asarray(model.generate(params, toks, 6, cache_len=40))

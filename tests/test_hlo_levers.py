@@ -3,9 +3,9 @@
 Chip runs of the benchmark measure the levers' throughput deltas; these
 tests pin the STRUCTURAL property each lever claims, from the
 lowered/compiled program alone — so the perf knowledge holds between
-chip runs (docs/LEVERS.md).
+chip runs (ROADMAP S3 names the two levers as candidates).
 
-Levers and their claims (docs/LEVERS.md holds the prediction table):
+Levers and their claims:
 
 - ``prng_impl="rbg"``: dropout masks come from one XLA RngBitGenerator
   instead of a threefry program — fewer ALU ops and fewer bytes for the
@@ -184,9 +184,9 @@ class TestDenseAttentionByteScaling:
 
 
 class TestDecodeRooflineModel:
-    """The decode roofline guard (bench.measure_decode) rejects slopes
-    implying less than one full parameter read per token-step.  Pin the
-    premise from the compiled program: the one-token KV-cache decode
+    """A decode rate implying less than one full parameter read per
+    token-step is an artifact, never a speed.  Pin the premise from the
+    compiled program: the one-token KV-cache decode
     step's bytes-accessed covers the parameters AND the cache at least
     once — XLA cannot elide the weight stream.  Deep tier: one CPU
     compile of the flagship-geometry decode step."""

@@ -14,9 +14,9 @@ Token identity in this file is WITHIN int8 mode (int8-with-feature vs
 int8-without-feature): greedy decode over the same quantized pool is
 deterministic, so every composition must be exact.  Int8 vs fp32 is a
 token-match-RATE gate and lives in tests/test_paged_kernel.py and the
-bench --serve-kv-ab arm.
+int8-vs-fp32 comparison of this file.
 
-Host-RAM block tiering (--serve-kv-tier host) rides the same
+Host-RAM block tiering (--kv-tier host) rides the same
 determinism contract: a demoted block's host bytes equal what a fresh
 prefill of its token path would write, so promotion is byte-exact
 re-admission — pinned below for both quantized rungs, under CoW, and

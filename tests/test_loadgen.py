@@ -1,6 +1,6 @@
 """Trace-driven load generation (serving/loadgen) + the autoscale
 advisor (serving/autoscale): spec validation, the byte-identity pin
-against bench's historical inline generator, arrival-process statistics
+against the historical inline generator, arrival-process statistics
 at a fixed seed, heavy-tail bounds, tenant mixes/SLOs/sessions, the
 per-request goodput join, and ScaleAdvisor hysteresis/cooldown.
 
@@ -18,7 +18,7 @@ from mpi_tensorflow_tpu.serving import autoscale, loadgen
 def legacy_inline_trace(num_requests=24, rate_rps=4.0, prompt_max=32,
                         output_max=128, vocab=32000, prefix_tokens=0,
                         seed=0):
-    """bench.measure_serving's pre-loadgen inline generator, verbatim —
+    """The pre-loadgen inline generator, verbatim —
     THE reference the refactor must replay byte-for-byte (same rng,
     same draw order, prefix drawn only when non-zero)."""
     rng = np.random.default_rng(seed)
@@ -44,16 +44,16 @@ class TestWorkloadSpec:
         assert spec.tenants == () and spec.session_len == 1
 
     @pytest.mark.parametrize("kwargs,match", [
-        (dict(workload="sinusoidal"), "serve-workload"),
+        (dict(workload="sinusoidal"), "workload must be"),
         (dict(num_requests=0), "serving trace needs"),
         (dict(prompt_max=0), "serving trace needs"),
         (dict(output_max=-1), "serving trace needs"),
         (dict(rate_rps=0.0), "arrival rate"),
         (dict(vocab_size=0), "vocab_size"),
-        (dict(prefix_tokens=-1), "serve-prefix-tokens"),
+        (dict(prefix_tokens=-1), "prefix_tokens must be"),
         (dict(length_dist="pareto"), "length_dist"),
-        (dict(slo_ms=0.0), "serve-slo-ms"),
-        (dict(slo_ms=-5.0), "serve-slo-ms"),
+        (dict(slo_ms=0.0), "slo_ms must be"),
+        (dict(slo_ms=-5.0), "slo_ms must be"),
         (dict(burst_on_s=0.0), "dwell"),
         (dict(burst_boost=0.5), "burst_boost"),
         (dict(diurnal_period_s=0.0), "diurnal_period_s"),
@@ -88,7 +88,7 @@ class TestWorkloadSpec:
 class TestBuildTrace:
     def test_default_trace_byte_identical_to_legacy(self):
         """THE refactor pin: a default (poisson/uniform) spec replays
-        bench's historical inline generator exactly — prompts, output
+        the historical inline generator exactly — prompts, output
         budgets, and arrival stamps all byte-for-byte."""
         t = loadgen.build_trace(loadgen.WorkloadSpec())
         lp, lo, la = legacy_inline_trace()

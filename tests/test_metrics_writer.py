@@ -48,7 +48,7 @@ class TestMetricsWriter:
     def test_faults_block_normalizes_counters(self):
         """The canonical serving faults block: every key present (0 when
         the counter never fired), plain ints — the one shape engine
-        results, the recovery supervisor, and bench JSON all share."""
+        results, the recovery supervisor, and the entry point's JSON share."""
         from collections import Counter
 
         block = metrics_writer.faults_block(Counter(shed=2, evictions=5))
@@ -62,7 +62,7 @@ class TestMetricsWriter:
         the raw spec_* counters, steps_saved = emitted - forwards (full
         KV-streaming passes avoided), zero-safe when nothing drafted —
         the one shape engine results, the recovery supervisor's
-        cross-attempt merge, and bench JSON all share."""
+        cross-attempt merge, and the entry point's JSON share."""
         from collections import Counter
 
         block = metrics_writer.speculation_block(
@@ -82,7 +82,7 @@ class TestMetricsWriter:
     def test_goodput_block_normalizes_rows(self):
         """The canonical SLO-goodput block: attainment and within-budget
         tokens/sec from per-request rows, with a per-tenant breakdown —
-        the one shape bench JSON and the metric line share."""
+        the one shape the serving entry point prints."""
         rows = [
             # met: ok within budget
             {"tenant": "interactive", "status": "ok", "tokens": 10,
@@ -116,35 +116,11 @@ class TestMetricsWriter:
         assert not z["enabled"] and z["slo_attainment"] == 0.0
         assert z["goodput_tokens_per_sec"] == 0.0 and z["per_tenant"] == {}
 
-    def test_kv_quant_block_normalizes_ab_numbers(self):
-        """The canonical KV-quantization A/B block: token-match rate,
-        effective-capacity multiplier from bytes-per-block, the
-        peak-live-blocks delta, and the decode-bandwidth roofline pair
-        — the one shape bench --serve-kv-ab JSON carries."""
-        block = metrics_writer.kv_quant_block(
-            kv_dtype="int8", matched_tokens=99, compared_tokens=100,
-            block_bytes_ref=4096, block_bytes=1280, num_blocks=25,
-            peak_live_blocks_ref=7, peak_live_blocks=7,
-            bytes_per_decode_token_ref=19136.834,
-            bytes_per_decode_token=5980.259)
-        assert block["enabled"] and block["kv_dtype"] == "int8"
-        assert block["token_match_rate"] == 0.99
-        assert block["capacity_multiplier"] == 3.2
-        assert block["effective_capacity_blocks"] == 80   # 25 * 4096//1280
-        assert block["peak_live_blocks_delta"] == 0
-        assert block["bytes_per_decode_token_ref"] == 19136.83
-        assert block["bytes_per_decode_token"] == 5980.26
-        # zero-safe: fp32-only run, nothing compared, no division blowups
-        z = metrics_writer.kv_quant_block()
-        assert z["token_match_rate"] == 0.0
-        assert z["capacity_multiplier"] == 0.0
-        assert z["effective_capacity_blocks"] == 0
-
     def test_tier_block_normalizes_counters(self):
         """The canonical host-tiering block: lifecycle counters, the
         derived prefill-tokens-saved line (promotions * block_size),
         and the zero-safe mean promote latency — the one shape the
-        engine result and bench JSON carry under ``tier``."""
+        engine result and the entry point's JSON carry under ``tier``."""
         block = metrics_writer.tier_block(
             enabled=True, mode="host", demotions=5, promotions=3,
             host_blocks=2, host_blocks_peak=4,

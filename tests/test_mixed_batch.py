@@ -1,6 +1,6 @@
 """Stall-free mixed batching: fused prefill+decode dispatch.
 
-The acceptance pins for --serve-mixed-batch:
+The acceptance pins for --mixed-batch:
 
 - mixed-on greedy outputs are TOKEN-IDENTICAL to mixed-off and to
   ``generate()`` — across prefill budgets, prefix cache v2 (generated
@@ -33,7 +33,7 @@ TINY = dataclasses.replace(bert.BERT_TINY, ce_positions="all")
 # build-time pre-warm over the full (slot, chunk, table) bucket grid,
 # so tier-1 wall-clock scales with the grid size — 2 slot buckets x
 # <=3 chunk buckets x 3 table buckets here, vs 48 triples at the
-# bench-default geometry.
+# default geometry.
 BASE = dict(num_blocks=24, block_size=4, max_slots=2, max_seq_len=16,
             prefill_chunk=4)
 
@@ -61,8 +61,8 @@ def _trace(n=6, seed=2, lo=3, hi=9, budget_hi=7):
 
 # Engine cache: construction pays the pre-warm grid, so tests sharing a
 # config share ONE engine — reset() restores fresh pools/scheduler/trie
-# while the warmed jit caches survive (the same contract bench's A/B
-# arms lean on between warmup and timed replays).
+# while the warmed jit caches survive (the contract the serving entry
+# point leans on between its warm-up replay and the served pass).
 _ENGINES = {}
 
 
@@ -95,19 +95,6 @@ class TestMixedConfig:
         # forward; composing them is a contradiction, not a feature
         with pytest.raises(ValueError, match="do not compose"):
             ServeConfig(**BASE, mixed_batch="on", speculative="ngram")
-
-    def test_cli_guard_rejects_bad_budget(self):
-        from mpi_tensorflow_tpu import cli
-
-        with pytest.raises(SystemExit, match="prefill-budget"):
-            cli.main(["--serve-prefill-budget", "0"])
-
-    def test_cli_guard_rejects_mixed_plus_speculative(self):
-        from mpi_tensorflow_tpu import cli
-
-        with pytest.raises(SystemExit, match="do not compose"):
-            cli.main(["--serve-mixed-batch", "on",
-                      "--serve-speculative", "ngram"])
 
 
 # ----------------------------------------------------- token identity

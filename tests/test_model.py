@@ -181,7 +181,7 @@ class TestOptimizer:
         params = {"w": jnp.ones((3, 3)), "ln": {"scale": jnp.ones((3,))},
                   "b": jnp.ones((3,)),
                   # MoE per-expert biases and enc-dec cross-attention
-                  # biases (xbq, ADVICE r3) are 2-D — the mask must catch
+                  # biases (xbq) are 2-D — the mask must catch
                   # them by NAME, a structural ndim rule would decay them
                   "eb1": jnp.ones((2, 3)), "out_b": jnp.ones((3,)),
                   "layers": [{"bq": jnp.ones((2, 2)),

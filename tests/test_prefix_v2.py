@@ -482,46 +482,3 @@ class TestPrefixRouting:
         got2 = r.route(Request(1, prompt, 2))
         assert got2 == 0                  # sessionless follows the hint
         assert r.fleet_counters["router_prefix_hits"] == 1
-
-
-# ------------------------------------------------------------ knob bridge
-
-@pytest.mark.quick
-class TestPrefixV2Knobs:
-    def test_knobs_bridge_cli_to_serve_config(self):
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(
-            ["--serve-prefix-cache", "on", "--serve-prefix-gen", "on",
-             "--serve-prefix-route", "on"])
-        c = cli.config_from_args(args)
-        assert (c.serve_prefix_gen, c.serve_prefix_route) == ("on", "on")
-        s = ServeConfig.from_config(c)
-        assert (s.prefix_gen, s.prefix_route) == ("on", "on")
-        c0 = cli.config_from_args(cli.build_parser().parse_args([]))
-        s0 = ServeConfig.from_config(c0)
-        assert (s0.prefix_gen, s0.prefix_route) == ("off", "off")
-
-    def test_bad_values_rejected_at_both_layers(self):
-        from mpi_tensorflow_tpu import cli
-        from mpi_tensorflow_tpu.config import Config
-
-        for flag in ("--serve-prefix-gen", "--serve-prefix-route"):
-            with pytest.raises(SystemExit):
-                cli.main([flag, "maybe"])
-        with pytest.raises(ValueError, match="prefix"):
-            ServeConfig.from_config(Config(serve_prefix_gen="maybe"))
-        with pytest.raises(ValueError, match="prefix"):
-            ServeConfig.from_config(Config(serve_prefix_route="maybe"))
-
-    def test_coupling_requires_prefix_cache_on(self):
-        from mpi_tensorflow_tpu import cli
-
-        with pytest.raises(SystemExit, match="prefix-gen"):
-            cli.main(["--serve-prefix-gen", "on"])
-        with pytest.raises(SystemExit, match="prefix-route"):
-            cli.main(["--serve-prefix-route", "on"])
-        with pytest.raises(ValueError, match="prefix"):
-            ServeConfig(prefix_cache="off", prefix_gen="on")
-        with pytest.raises(ValueError, match="prefix"):
-            ServeConfig(prefix_cache="off", prefix_route="on")

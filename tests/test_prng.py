@@ -1,12 +1,11 @@
-"""The dropout-PRNG knob (Config.prng_impl / --prng / bench --prng).
+"""The dropout-PRNG knob (Config.prng_impl / --prng).
 
 A BERT-base train step generates 25 (B, S, E) dropout masks; the generator
 choice (threefry vs XLA RngBitGenerator) is a first-order throughput knob
-on TPU (scripts/bert_diagnose.py measures the delta).  These tests pin the
-hardware-independent contract: the impl travels with the key from the one
-loop-level call site through every fold_in inside the jitted step, every
-surface (CLI, bench, loops) threads it, and parameter init stays threefry
-(bit-identical across prng arms).
+on TPU.  These tests pin the hardware-independent contract: the impl
+travels with the key from the one loop-level call site through every
+fold_in inside the jitted step, every surface (CLI, loops) threads it, and
+parameter init stays threefry (bit-identical across prng arms).
 """
 
 import dataclasses as dc
@@ -104,17 +103,6 @@ def test_cli_threads_prng():
     # default stays the JAX default
     args = cli.build_parser().parse_args([])
     assert cli.config_from_args(args).prng_impl == "threefry"
-
-
-def test_bench_flag_guards():
-    import bench
-
-    with pytest.raises(SystemExit):
-        bench.main(["--prng", "rbg", "--mode", "decode"])
-    with pytest.raises(SystemExit):
-        bench.main(["--prng", "rbg", "--record-baseline"])
-    with pytest.raises(SystemExit):
-        bench.main(["--fused-qkv", "--model", "resnet50"])
 
 
 def test_mlm_loop_runs_under_rbg():

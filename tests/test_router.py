@@ -485,7 +485,7 @@ class TestStickyHygiene:
 
 class TestFleetReplayHelpers:
     """Host-side pins of the recovery fleet helpers the failover and
-    the bench resume path are built on."""
+    the entry point's resume path are built on."""
 
     def test_replay_one_no_double_embed_for_replayed_request(self):
         """A fault during a journal-RESUMED run re-roots from an entry

@@ -798,7 +798,7 @@ class TestEngine:
 
     def test_default_ttl_from_serve_config(self):
         """serve.deadline_ms stamps arrival+TTL on every request that
-        has no explicit deadline — the --serve-deadline-ms knob."""
+        has no explicit deadline — the --deadline-ms knob."""
         _, _, engine = self._engine(deadline_ms=50.0)
         clock = {"t": 0.0}
 
@@ -1026,7 +1026,7 @@ class TestPrefixCacheEngine:
             "prefix cache added steady-state recompiles"
 
     def test_off_mode_reports_disabled_and_shares_nothing(self):
-        """--serve-prefix-cache off (the default) must be byte-for-byte
+        """--prefix-cache off (the default) must be byte-for-byte
         today's behavior: no trie, no sharing, no CoW dispatch use."""
         model, params, engine = self._engine(prefix_cache="off")
         rng = np.random.default_rng(24)
@@ -1048,7 +1048,7 @@ class TestPrefixCacheEngine:
 # ------------------------------------------------------------ cli guards
 
 @pytest.mark.quick
-class TestServeCliGuards:
+class TestTrainingCliGuards:
     def test_virtual_stages_requires_interleaved_schedule(self):
         from mpi_tensorflow_tpu import cli
 
@@ -1061,126 +1061,3 @@ class TestServeCliGuards:
         args = cli.build_parser().parse_args(
             ["--virtual-stages", "3", "--pp-schedule", "1f1b_interleaved"])
         assert cli.config_from_args(args).virtual_stages == 3
-
-    def test_bad_serve_geometry_rejected(self):
-        from mpi_tensorflow_tpu import cli
-
-        with pytest.raises(SystemExit, match="serve"):
-            cli.main(["--serve-block-size", "0"])
-
-    def test_serve_knobs_reach_config(self):
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(
-            ["--serve-pool-blocks", "64", "--serve-block-size", "8",
-             "--serve-max-slots", "4", "--serve-max-seq-len", "256"])
-        c = cli.config_from_args(args)
-        assert (c.serve_pool_blocks, c.serve_block_size,
-                c.serve_max_slots, c.serve_max_seq_len) == (64, 8, 4, 256)
-
-    def test_serve_config_bridges_from_run_config(self):
-        """Config.serve_* knobs are consumed through ServeConfig.
-        from_config — the knobs must not be parse-only decoration."""
-        from mpi_tensorflow_tpu.config import Config
-
-        c = Config(serve_pool_blocks=64, serve_block_size=8,
-                   serve_max_slots=4, serve_max_seq_len=256)
-        s = ServeConfig.from_config(c)
-        assert (s.num_blocks, s.block_size, s.max_slots,
-                s.max_seq_len) == (64, 8, 4, 256)
-        # explicit overrides win; None means "use the Config value"
-        s2 = ServeConfig.from_config(c, max_slots=2, block_size=None)
-        assert s2.max_slots == 2 and s2.block_size == 8
-
-    def test_bad_serve_fault_policy_rejected(self):
-        from mpi_tensorflow_tpu import cli
-
-        for flags in (["--serve-deadline-ms", "0"],
-                      ["--serve-queue-depth", "0"],
-                      ["--serve-max-evictions", "0"],
-                      ["--serve-drain-ms", "-1"]):
-            with pytest.raises(SystemExit, match="fault policy"):
-                cli.main(flags)
-
-    def test_serve_prefix_cache_knob_bridges(self):
-        """--serve-prefix-cache flows CLI -> Config -> ServeConfig,
-        defaulting to off (today's behavior byte-for-byte)."""
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(["--serve-prefix-cache", "on"])
-        c = cli.config_from_args(args)
-        assert c.serve_prefix_cache == "on"
-        assert ServeConfig.from_config(c).prefix_cache == "on"
-        c0 = cli.config_from_args(cli.build_parser().parse_args([]))
-        assert ServeConfig.from_config(c0).prefix_cache == "off"
-
-    def test_bad_serve_prefix_cache_rejected(self):
-        """Invalid values die at both layers: argparse choices on the
-        CLI path, ServeConfig validation on the programmatic path."""
-        from mpi_tensorflow_tpu import cli
-        from mpi_tensorflow_tpu.config import Config
-
-        with pytest.raises(SystemExit):
-            cli.main(["--serve-prefix-cache", "maybe"])
-        with pytest.raises(ValueError, match="prefix cache"):
-            ServeConfig.from_config(Config(serve_prefix_cache="maybe"))
-        with pytest.raises(ValueError, match="prefix cache"):
-            ServeConfig(prefix_cache="auto")
-
-    def test_distributed_serve_knobs_bridge(self):
-        """--serve-tp/--serve-replicas/--serve-draft-auto flow CLI ->
-        Config -> ServeConfig (replicas is a router-layer knob: it
-        bridges to Config and the bench, not the engine's config)."""
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(
-            ["--serve-tp", "2", "--serve-replicas", "3",
-             "--serve-draft-auto", "on",
-             "--serve-speculative", "ngram"])
-        c = cli.config_from_args(args)
-        assert (c.serve_tp, c.serve_replicas,
-                c.serve_draft_auto) == (2, 3, "on")
-        s = ServeConfig.from_config(c)
-        assert s.tp == 2 and s.draft_auto == "on"
-        s0 = ServeConfig.from_config(
-            cli.config_from_args(cli.build_parser().parse_args([])))
-        assert s0.tp == 1 and s0.draft_auto == "off"
-
-    def test_bad_distributed_serve_knobs_rejected(self):
-        """Range guards at cli.main and ServeConfig; the geometry
-        (heads/mlp divisibility, device bound) rejects at engine
-        construction where the model is known
-        (tests/test_serving_tp.py pins those)."""
-        from mpi_tensorflow_tpu import cli
-
-        with pytest.raises(SystemExit, match="serve-tp"):
-            cli.main(["--serve-tp", "0"])
-        with pytest.raises(SystemExit, match="serve-replicas"):
-            cli.main(["--serve-replicas", "0"])
-        with pytest.raises(ValueError, match="tp"):
-            ServeConfig(tp=0)
-        with pytest.raises(SystemExit):
-            cli.main(["--serve-draft-auto", "sometimes"])
-        # auto-tuning without a drafter would be silently ignored
-        with pytest.raises(SystemExit, match="draft-auto"):
-            cli.main(["--serve-draft-auto", "on"])
-        with pytest.raises(ValueError, match="draft_auto"):
-            ServeConfig(draft_auto="on", speculative="off")
-
-    def test_serve_fault_knobs_bridge_to_serve_config(self):
-        """The four fault-tolerance knobs flow CLI -> Config ->
-        ServeConfig.from_config, like the geometry knobs."""
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(
-            ["--serve-deadline-ms", "250", "--serve-queue-depth", "16",
-             "--serve-max-evictions", "3", "--serve-drain-ms", "500"])
-        c = cli.config_from_args(args)
-        s = ServeConfig.from_config(c)
-        assert (s.deadline_ms, s.queue_depth, s.max_evictions,
-                s.drain_ms) == (250.0, 16, 3, 500.0)
-        # defaults: every guard off, preserving pre-fault-layer behavior
-        s0 = ServeConfig.from_config(cli.config_from_args(
-            cli.build_parser().parse_args([])))
-        assert (s0.deadline_ms, s0.queue_depth, s0.max_evictions,
-                s0.drain_ms) == (None, None, None, None)

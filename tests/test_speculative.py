@@ -2,7 +2,7 @@
 
 The tier-1 anchors the ISSUE acceptance names:
 - greedy outputs in ``ngram`` and ``draft-model`` modes are
-  TOKEN-IDENTICAL to ``--serve-speculative off`` and to
+  TOKEN-IDENTICAL to ``--speculative off`` and to
   ``CausalLm.generate`` — across shared-prefix batches, prefix-cache
   on/off, copy-on-write inside a draft window, eviction mid-draft,
   deadline expiry mid-draft, and SIGKILL journal replay;
@@ -33,8 +33,7 @@ from _jitted import generate_ref as _generate_ref
 from _speculative_common import ROPE, SERVE, TINY, _pair, _shared_trace
 from mpi_tensorflow_tpu.models import gpt
 from mpi_tensorflow_tpu.serving import (BlockAllocator, NgramDrafter,
-                                        PagedDecodeEngine, Request, Scheduler,
-                                        ServeConfig)
+                                        PagedDecodeEngine, Request, Scheduler)
 
 
 # ------------------------------------------------------------- drafters
@@ -243,40 +242,10 @@ class TestSpeculativeParity:
         spec.sched.check_quiescent()
 
 
-# ------------------------------------------------------------ cli guards
+# ---------------------------------------------------------------- drafter
 
 @pytest.mark.quick
-class TestSpeculativeCliGuards:
-    def test_knobs_bridge_cli_config_serveconfig(self):
-        from mpi_tensorflow_tpu import cli
-
-        args = cli.build_parser().parse_args(
-            ["--serve-speculative", "ngram", "--serve-draft-k", "6"])
-        c = cli.config_from_args(args)
-        assert (c.serve_speculative, c.serve_draft_k) == ("ngram", 6)
-        s = ServeConfig.from_config(c)
-        assert (s.speculative, s.draft_k) == ("ngram", 6)
-        # defaults: off, byte-for-byte today's one-token loop
-        s0 = ServeConfig.from_config(cli.config_from_args(
-            cli.build_parser().parse_args([])))
-        assert s0.speculative == "off" and s0.draft_k == 4
-
-    def test_bad_values_rejected_at_every_layer(self):
-        from mpi_tensorflow_tpu import cli
-        from mpi_tensorflow_tpu.config import Config
-
-        with pytest.raises(SystemExit):
-            cli.main(["--serve-speculative", "maybe"])     # argparse
-        with pytest.raises(SystemExit, match="draft-k"):
-            cli.main(["--serve-draft-k", "0"])             # cli.main
-        with pytest.raises(ValueError, match="speculative"):
-            ServeConfig(speculative="auto")
-        with pytest.raises(ValueError, match="draft_k"):
-            ServeConfig(draft_k=0)
-        # programmatic Config path dies at cli.main's own guard
-        with pytest.raises(ValueError, match="speculative"):
-            ServeConfig.from_config(Config(serve_speculative="maybe"))
-
+class TestMakeDrafter:
     def test_make_drafter_rejects_unknown_mode(self):
         from mpi_tensorflow_tpu.serving import make_drafter
 

@@ -1,7 +1,7 @@
 """Speculative decoding under the engine's fault paths: prefix cache, CoW
 inside a draft window, eviction and deadline expiry mid-draft, transient
 device loss and SIGKILL journal replay — outputs stay TOKEN-IDENTICAL to
-``--serve-speculative off`` and ``CausalLm.generate``.  See
+``--speculative off`` and ``CausalLm.generate``.  See
 tests/test_speculative.py's docstring for the geometries."""
 
 import dataclasses

@@ -17,7 +17,7 @@ from mpi_tensorflow_tpu.serving import PagedDecodeEngine, Request
 # ---------------------------------------------------- draft-window auto-tune
 
 class TestDraftAutoTune:
-    """--serve-draft-auto on: the EFFECTIVE draft window follows the
+    """--draft-auto on: the EFFECTIVE draft window follows the
     observed accept rate (EWMA, clamped to [1, draft_k]) while the
     verify dispatch width — and therefore the compile set — never
     changes, and emitted tokens never move."""

@@ -112,23 +112,3 @@ class TestTraining:
         args = cli.build_parser().parse_args(
             ["--model", "vit", "--dataset", "cifar10"])
         assert args.model == "vit"
-
-
-def test_bench_names_cover_every_image_model():
-    import bench
-
-    image = {k for k, v in bench.MODEL_SPECS.items() if "shape" in v}
-    assert image <= set(bench.IMAGE_MODEL_NAMES), \
-        image - set(bench.IMAGE_MODEL_NAMES)
-
-
-def test_vit_flops_accounting():
-    from mpi_tensorflow_tpu.utils import flops as fl
-
-    c = vit.VIT_TINY_CIFAR
-    f = fl.vit_train_flops(c, 8)
-    N, E, L, M = c.num_patches + 1, c.hidden, c.layers, c.mlp
-    want = 6 * 8 * N * L * (4 * E * E + 2 * E * M) \
-        + 12 * L * 8 * N * N * E \
-        + 6 * 8 * c.num_patches * (c.patch ** 2 * c.channels) * E
-    assert f == pytest.approx(want)
