@@ -13,6 +13,16 @@ conversions applied to tainted values inside the hot namespace.
 Intended syncs carry ``# graft-lint: sync-ok(<reason>)`` on the line
 or the line above; ``.item()`` is flagged unconditionally (the
 per-element sync pattern has no place in the hot loop).
+
+The taint follows a value through the object that keeps it: an
+attribute a hot function assigns a tainted value to, or appends one to
+(``self._unread.append((nxt, rows))``), HOLDS device values, and what
+another hot function binds from it (``for nxt, rows in
+self._unread[:n]``) is tainted there; a tainted argument of a call to
+another hot method taints that method's parameter.  That is what lets
+the engine's one-step lookahead be pinned: the dispatching functions
+carry no ``sync-ok`` at all, and the one read a dispatch sits where the
+previous dispatch is delivered.
 """
 
 from __future__ import annotations
@@ -28,7 +38,10 @@ JIT_CTORS = {"jax.jit", "jit", "pjit", "jax.pjit"}
 #: hot namespace: path suffix -> function names (None = whole file)
 HOT: Dict[str, Optional[Set[str]]] = {
     "serving/iteration.py": None,
-    "serving/engine.py": {"step", "_advance_prefill", "_step_verify",
+    "serving/engine.py": {"step", "_advance_prefill", "_dispatch_decode",
+                          "_count_dispatch", "_hold", "_deliver",
+                          "_log_dispatch", "_read_counters",
+                          "_step_verify",
                           "_ensure_private", "_track_occupancy"},
     "serving/router.py": {"route", "load_score", "_tick", "_route_due",
                           "_observe_fleet"},
@@ -61,14 +74,22 @@ def _hot_functions(rel: str, tree: ast.Module):
 
 
 class _FnChecker:
-    """Statement-ordered taint walk of one hot function."""
+    """Statement-ordered taint walk of one hot function.  ``holding``
+    (attribute names that keep device values) and ``params`` (hot
+    function name -> parameter names a caller passed a tainted value
+    for) are the module's, shared by its hot functions and grown here."""
 
     def __init__(self, rel: str, src: str, device_attrs: Set[str],
-                 findings: List[core.Finding]):
+                 findings: List[core.Finding], holding: Set[str],
+                 params: Dict[str, Set[str]],
+                 hot: Dict[str, ast.AST]):
         self.rel = rel
         self.src = src
         self.device_attrs = device_attrs
         self.findings = findings
+        self.holding = holding
+        self.params = params
+        self.hot = hot
         self.tainted: Set[str] = set()
 
     # -- taint helpers --
@@ -81,9 +102,22 @@ class _FnChecker:
     def _is_tainted(self, node: ast.AST) -> bool:
         if isinstance(node, ast.Name):
             return node.id in self.tainted
+        if isinstance(node, ast.Attribute):
+            return node.attr in self.holding
         if isinstance(node, ast.Subscript):
             return self._is_tainted(node.value)
+        if isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr == "pop":
+            return self._is_tainted(node.func.value)    # self._held.pop(0)
         return False
+
+    def _carries(self, node: ast.AST) -> bool:
+        """``node`` is, or is a tuple/list display that mentions, a
+        tainted value."""
+        if isinstance(node, (ast.Tuple, ast.List)):
+            return any(self._carries(e) for e in node.elts)
+        return self._is_tainted(node) or self._is_device_call(node)
 
     def _flag(self, node: ast.AST, what: str) -> None:
         if core.allowlist_reason(self.src, node.lineno, "sync"):
@@ -110,10 +144,24 @@ class _FnChecker:
             elif isinstance(sub.func, ast.Attribute) \
                     and sub.func.attr == "item" and not sub.args:
                 self._flag(sub, ".item()")
+            elif isinstance(sub.func, ast.Attribute) \
+                    and sub.func.attr == "append" \
+                    and isinstance(sub.func.value, ast.Attribute) \
+                    and any(self._carries(a) for a in sub.args):
+                self.holding.add(sub.func.value.attr)
+            elif isinstance(sub.func, ast.Attribute) \
+                    and sub.func.attr in self.hot:
+                # a tainted argument taints the hot callee's parameter
+                names = core.arg_names(self.hot[sub.func.attr])
+                for name, arg in zip(names, sub.args):
+                    if self._carries(arg):
+                        self.params.setdefault(sub.func.attr,
+                                               set()).add(name)
 
     # -- statement walk (flow order: check uses, then bind) --
 
     def run(self, fn: ast.AST) -> None:
+        self.tainted = set(self.params.get(fn.name, ()))
         self.visit_body(fn.body)
 
     def visit_body(self, body) -> None:
@@ -121,15 +169,17 @@ class _FnChecker:
             self.visit_stmt(stmt)
 
     def _bind(self, targets, value) -> None:
-        names = []
+        names, attrs = [], []
         for t in targets:
-            if isinstance(t, ast.Name):
-                names.append(t.id)
-            elif isinstance(t, (ast.Tuple, ast.List)):
-                names.extend(e.id for e in t.elts
-                             if isinstance(e, ast.Name))
-        if self._is_device_call(value):
+            for e in (t.elts if isinstance(t, (ast.Tuple, ast.List))
+                      else [t]):
+                if isinstance(e, ast.Name):
+                    names.append(e.id)
+                elif isinstance(e, ast.Attribute):
+                    attrs.append(e.attr)
+        if self._carries(value):
             self.tainted |= set(names)
+            self.holding |= set(attrs)
         else:
             self.tainted -= set(names)
 
@@ -152,6 +202,7 @@ class _FnChecker:
             self.visit_body(stmt.orelse)
         elif isinstance(stmt, ast.For):
             self.check_expr(stmt.iter)
+            self._bind([stmt.target], stmt.iter)
             self.visit_body(stmt.body)
             self.visit_body(stmt.orelse)
         elif isinstance(stmt, ast.With):
@@ -179,8 +230,17 @@ def run(sources: Dict[str, str]) -> List[core.Finding]:
     trees = core.parse_sources(sources)
     for rel, tree in trees.items():
         device_attrs = _device_attrs(tree)
-        for fn in _hot_functions(rel, tree):
-            checker = _FnChecker(rel, sources[rel], device_attrs,
-                                 findings)
-            checker.run(fn)
+        hot = {fn.name: fn for fn in _hot_functions(rel, tree)}
+        holding: Set[str] = set()
+        params: Dict[str, Set[str]] = {}
+        while True:
+            # to a fixed point: what one function stores, another reads
+            before = (len(holding), sum(map(len, params.values())))
+            found: List[core.Finding] = []
+            for fn in hot.values():
+                _FnChecker(rel, sources[rel], device_attrs, found,
+                           holding, params, hot).run(fn)
+            if before == (len(holding), sum(map(len, params.values()))):
+                break
+        findings.extend(found)
     return findings

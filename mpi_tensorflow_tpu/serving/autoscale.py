@@ -94,10 +94,13 @@ class ScaleAdvisor:
     def observe(self, now_s: float, *, queue_depth: float,
                 occupancy: float, shed_rate: float = 0.0,
                 live_fraction: float = 0.0,
-                prefill_backlog: float = 0.0) -> Optional[dict]:
+                prefill_backlog: float = 0.0,
+                **_counts) -> Optional[dict]:
         """One tick: fold the signals into the load score, advance the
         hysteresis counters, and return the decision dict if one fired
-        this tick (None otherwise — the common case)."""
+        this tick (None otherwise — the common case).  The running
+        counts that ``engine.load_signals()`` carries beside the load
+        (dispatches, lookahead) are no load and are passed by."""
         load = self.load(queue_depth=queue_depth, occupancy=occupancy,
                          shed_rate=shed_rate, live_fraction=live_fraction,
                          prefill_backlog=prefill_backlog)

@@ -7,6 +7,21 @@ sequence.  Chunked prefill keeps a long new prompt from stalling
 in-flight decodes; slot recycling keeps finished sequences from burning
 device cycles on masked rows.
 
+One-step lookahead: ``step()`` issues iteration n+1's dispatches
+before it reads iteration n's tokens.  Assembling a batch needs counts
+(which rows decode, their lengths and tables, the budget test), never a
+token's value: each slot's last token stays in a device array that the
+dispatches scatter into and gather from, ``Scheduler.advance`` counts a
+token when it is dispatched and ``Scheduler.deliver`` takes its value a
+dispatch later, so admission, table assembly, token accounting and the
+caller's work between two ``iterate`` calls run while the device works.
+What only the value can say (EOS) and what the host decides meanwhile
+(eviction, a deadline, a failure, a drain cut) reach a sequence one
+dispatch late: the row it still has in flight is dropped at delivery
+(``lookahead_discarded_rows``).  Where the next dispatch's SHAPE hangs
+on the values — a drafter's accepted count, the mixed dispatch's — the
+step reads at once (docs/SERVING.md, "The iteration's contract").
+
 Compile discipline: device dispatches run at a SMALL FIXED SET of
 bucketed shapes —
 
@@ -334,6 +349,22 @@ def _bucket(n: int, cap: int) -> int:
     return min(pow2_ceil(n), cap)
 
 
+def _take_slot_tokens(last, slots):
+    """The tokens a dispatch feeds: ``last`` (a token a slot) at its
+    rows' ``slots``."""
+    return last[slots]
+
+
+def _keep_slot_tokens(last, slots, nxt):
+    """``last`` with a dispatch's next tokens put at its rows' slots."""
+    return last.at[slots].set(nxt)
+
+
+def _sum_counters(leaves):
+    """The model's device counters summed over its layers."""
+    return sum(leaves)
+
+
 def check_model(cfg, serve: ServeConfig) -> None:
     """The refusals that need the MODEL's widths beside the options: the
     position table against the sequence cap, and the tensor-parallel
@@ -440,6 +471,16 @@ class PagedDecodeEngine:
         # masking math — decode rows are the chunk=1 degenerate case
         self._mixed_fn = jax.jit(_weakly(self._mixed_impl),
                                  donate_argnums=donate)
+        # the lookahead's two small programs over ``_slot_tokens`` (each
+        # slot's last token, kept on the device): a dispatch's next
+        # tokens are scattered in by slot, the following dispatch's
+        # input gathered out, so a token's value is fed back without
+        # ever visiting the host.  One shape a slot bucket (and the
+        # prefill's scalar), all built below, before any window opens
+        self._take_fn = jax.jit(_take_slot_tokens)
+        self._keep_fn = jax.jit(_keep_slot_tokens)
+        # a traced run's snapshot of the model's device counters
+        self._sum_fn = jax.jit(_sum_counters)
         self.drafter = spec_lib.make_drafter(
             serve.speculative, serve, model,
             draft_model=draft_model, draft_params=draft_params)
@@ -452,6 +493,7 @@ class PagedDecodeEngine:
         self._accept_ewma = float(serve.draft_k)
         self._draft_k_eff = serve.draft_k
         self.reset()
+        self._prewarm_slot_tokens()
         if self.prefix_cache is not None:
             # pre-pay the CoW copy's single compile with a null-block
             # self-copy (a no-op write), so the first real CoW inside a
@@ -499,6 +541,8 @@ class PagedDecodeEngine:
         """Fresh pools/scheduler; jit caches (and their warmed bucket
         shapes) survive — the serving entry point serves its trace
         against exactly the compiles the warm-up replay paid for."""
+        import jax.numpy as jnp
+
         from mpi_tensorflow_tpu.serving import prefix_cache as prefix_lib
 
         self.pools = paged_cache.init_pools(
@@ -509,7 +553,19 @@ class PagedDecodeEngine:
         self._counters = ({} if any(
             paged_cache.is_counter(k) for p in self.pools for k in p)
             else None)
-        self._unread: list = []         # dispatch records awaiting a read
+        # traced runs: (dispatch-log record, the counters' sum as a
+        # device array taken right after that dispatch), oldest first
+        self._snapshots: list = []
+        # dispatches whose tokens the host has not read, oldest first:
+        # (next tokens on the device, the (slot, Sequence) of each row,
+        # a traced run's dispatch-log record).  The sequence identity is
+        # what a late delivery checks: a row whose slot no longer holds
+        # it is dropped
+        self._unread: list = []
+        # each slot's last token, on the device (index max_slots takes
+        # the bucket slack's rows)
+        self._slot_tokens = jnp.zeros((self.serve.max_slots + 1,),
+                                      jnp.int32)
         if self.tp_mesh is not None:
             # head-axis sharding (serving/tp): one block id addresses
             # the same slot of every shard's local-heads pool, so the
@@ -566,7 +622,11 @@ class PagedDecodeEngine:
                                         # end (an end-ok preceding its own
                                         # finishing token would replay a
                                         # truncated stream as complete)
-        self._last_token: dict = {}     # slot -> next token to feed
+        self._last_token: dict = {}     # slot -> next token to feed, as
+                                        # delivered: what the verify and
+                                        # mixed steps assemble from (the
+                                        # plain step feeds the device's
+                                        # own copy, ``_slot_tokens``)
         # admitted (slot, Sequence) pairs awaiting prefill: the sequence
         # identity guards against a slot being evicted and re-admitted
         # while queued — a stale entry must not prefill the NEW occupant
@@ -577,6 +637,13 @@ class PagedDecodeEngine:
         # not tokens): dispatches-per-emitted-token is THE CPU-visible
         # win metric of mixed batching
         self.forward_dispatches = 0
+        # of those, the ones issued while an earlier dispatch's tokens
+        # were still unread — over ``forward_dispatches`` ~1.0 on the
+        # plain path, 0 where every dispatch is read at once — and the
+        # rows that were computed for a sequence which had left its slot
+        # by the time they were read (EOS, eviction, deadline, failure)
+        self.lookahead_dispatches = 0
+        self.lookahead_discarded_rows = 0
         # what the attention kernel's grid walks over the decode
         # dispatches of this run — each row's live blocks, a slack row's
         # one (ops/paged_attention.work_list) — beside the rows x table
@@ -766,6 +833,28 @@ class PagedDecodeEngine:
                 break
             Bb = min(Bb * 2, serve.max_slots)
 
+    def _prewarm_slot_tokens(self) -> None:
+        """Compile the two slot-token programs at every slot bucket, and
+        the scatter at the prefill's scalar: a few dozen bytes each, so
+        they are built with the engine and never first met in a window.
+        Everything lands in the slack entry."""
+        import jax.numpy as jnp
+
+        slack = self.serve.max_slots
+        last = self._keep_fn(self._slot_tokens,
+                             jnp.asarray(slack, jnp.int32),
+                             jnp.asarray(0, jnp.int32))
+        Bb = 1
+        while True:
+            slots = jnp.asarray(np.full((Bb,), slack, np.int32))
+            last = self._keep_fn(last, slots, self._take_fn(last, slots))
+            if Bb >= slack:
+                break
+            Bb = min(Bb * 2, slack)
+        self._slot_tokens = last
+        if self.tracer is not None and self._counters is not None:
+            self._sum_fn(self._counter_leaves())
+
     def prewarm_decode(self) -> None:
         """Compile the decode dispatch at every (slot bucket, table
         bucket) pair it can ever run at — all-null tables, so nothing
@@ -900,11 +989,11 @@ class PagedDecodeEngine:
         row[:len(ids)] = ids
         return row
 
-    def _advance_prefill(self) -> List[Tuple[int, int]]:
+    def _advance_prefill(self) -> None:
         """Advance the oldest mid-prefill sequence by ONE chunk (chunked
         prefill: new prompts trickle into the pool between decode steps
-        instead of stalling them for a whole long prompt).  Returns the
-        ``(request id, token)`` the final chunk emits, if any."""
+        instead of stalling them for a whole long prompt).  The final
+        chunk's token stays on the device, unread (``_hold``)."""
         import jax.numpy as jnp
 
         while self._prefill_queue:
@@ -917,7 +1006,7 @@ class PagedDecodeEngine:
                 continue
             break
         else:
-            return []
+            return
         prompt = seq.request.prompt
         self._progressed = True          # a chunk enters the pool
         chunk = prompt[seq.prefilled:seq.prefilled + self.serve.prefill_chunk]
@@ -927,13 +1016,13 @@ class PagedDecodeEngine:
             # chunk writes into: fail this one request, keep serving
             self._prefill_queue.pop(0)
             self.sched.fail_live(slot, "rejected")
-            return []
+            return
         sb = _bucket(len(chunk), self.serve.prefill_chunk)
         toks = np.zeros((1, sb), np.int32)
         toks[0, :len(chunk)] = chunk
         tables = self._table_row(seq, self.serve.max_blocks_per_seq)[None]
         self.dispatch_shapes.add(("prefill", sb))
-        self.forward_dispatches += 1
+        self._count_dispatch()
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
@@ -941,41 +1030,96 @@ class PagedDecodeEngine:
             self.params, self.pools, jnp.asarray(toks),
             jnp.asarray(seq.prefilled, jnp.int32),
             jnp.asarray(len(chunk), jnp.int32), jnp.asarray(tables))
+        rec = None
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
             n, at = len(chunk), seq.prefilled
-            self._log_dispatch("prefill", n, n * at + n * (n + 1) // 2)
+            rec = self._log_dispatch("prefill", n,
+                                     n * at + n * (n + 1) // 2)
         seq.prefilled += len(chunk)
         if seq.prefilled < len(prompt):
-            return []
+            return
         self._prefill_queue.pop(0)
         if self.prefix_cache is not None:
             # register the fully prefilled prompt's full blocks BEFORE
-            # record_token can finish the request and release them: the
+            # a delivery can finish the request and release them: the
             # trie's own reference is what keeps a cached block alive
             # past its donor sequence
             self.prefix_cache.insert(prompt, seq.block_ids)
         # the prompt's last position already yields the first output
         # token (exactly generate()'s prefill-argmax), so the slot
         # enters the decode pool one token ahead
-        if tr is not None:
-            _m0 = time.monotonic()
-        tok = int(nxt)  # graft-lint: sync-ok(one scalar per admission, not per step)
-        if tr is not None:
-            self._read_counters()
-            tr.consume_s += time.monotonic() - _m0
-        self._last_token[slot] = tok
-        if self._journal is not None:
-            self._journal.record_token(seq.request.id, tok)
-        self.sched.record_token(slot, tok, self.serve.eos_id)
-        return [(seq.request.id, tok)]
+        self._hold(nxt, jnp.asarray(slot, jnp.int32), [(slot, seq)], rec)
+
+    def _count_dispatch(self) -> None:
+        """One model-forward dispatch is about to be issued; it looks
+        ahead when an earlier dispatch's tokens are still unread."""
+        self.forward_dispatches += 1
+        if self._unread:
+            self.lookahead_dispatches += 1
+
+    def _hold(self, nxt, slots, rows, rec) -> None:
+        """A dispatch that computes a token for each of ``rows`` (its
+        ``(slot, Sequence)`` pairs; ``slots`` the same on the device,
+        bucket slack pointing at the slack entry) has been issued: put
+        its ``nxt`` where the next dispatch gathers from, count a token
+        for every row (``Scheduler.advance``) and keep the values unread
+        until ``_deliver`` — nothing here waits for the device."""
+        self._slot_tokens = self._keep_fn(self._slot_tokens, slots, nxt)
+        # start the few bytes' way to the host as soon as they exist, so
+        # the read a dispatch later finds them there
+        nxt.copy_to_host_async()
+        for slot, _seq in rows:
+            self.sched.advance(slot)
+        self._unread.append((nxt, rows, rec))
+
+    def _deliver(self, n: int) -> List[Tuple[int, int]]:
+        """Read the tokens of the ``n`` oldest unread dispatches and
+        deliver them, in dispatch order and in the order the
+        synchronous step had: journal the token, THEN account it (the
+        terminal hook may fire: tok-then-end).  A row whose sequence has
+        left its slot since the dispatch — it ended on EOS a dispatch
+        ago, was evicted, failed, expired or cut — is dropped: its token
+        was computed for nobody, and its one K/V write landed in a block
+        the sequence still owned when the dispatch was issued, ahead (in
+        the device's one stream) of any later owner's writes."""
+        emitted: List[Tuple[int, int]] = []
+        tr = self.tracer
+        for nxt, rows, rec in self._unread[:n]:
+            if tr is not None:
+                _m0 = time.monotonic()
+            toks = np.asarray(nxt).reshape(-1).tolist()  # graft-lint: sync-ok(the one bulk read a dispatch, taken after the next dispatch is issued)
+            if tr is not None:
+                self._read_counters(rec)
+                # the time the host stood waiting for the device: next
+                # to the step's length it says which side sets the pace
+                tr.consume_s += time.monotonic() - _m0
+            for (slot, seq), tok in zip(rows, toks):
+                if self.sched.slots[slot] is not seq:
+                    self.lookahead_discarded_rows += 1
+                    continue
+                self._last_token[slot] = tok
+                rid = seq.request.id
+                emitted.append((rid, tok))
+                if self._journal is not None:
+                    self._journal.record_token(rid, tok)
+                self.sched.deliver(slot, tok, self.serve.eos_id)
+        if n:
+            del self._unread[:n]
+            self._progressed = True      # tokens reached the host
+        return emitted
+
+    def all_done(self) -> bool:
+        """Nothing waiting, nothing live and no dispatch unread: what a
+        serve loop ends on (the scheduler's own ``all_done`` can read
+        True with rows of ended sequences still in flight)."""
+        return self.sched.all_done() and not self._unread
 
     def step(self) -> List[Tuple[int, int]]:
-        """One engine iteration: admit, advance one prefill chunk, decode
-        every live slot once.  Returns the ``(request id, token)`` pairs
-        emitted."""
-        import jax.numpy as jnp
-
+        """One engine iteration: admit, advance one prefill chunk,
+        decode every live slot once, THEN read and deliver the tokens of
+        the previous iteration's dispatches.  Returns the ``(request
+        id, token)`` pairs delivered."""
         self._progressed = False
         admitted = self.sched.admit()
         self._track_occupancy()
@@ -986,18 +1130,32 @@ class PagedDecodeEngine:
         self._apply_partial_copies()
         if self.serve.mixed_batch == "on":
             # the fused path replaces BOTH the prefill and the decode
-            # phases below; mixed off leaves them byte-for-byte
+            # phases below, and reads its output at once
             return self._step_mixed()
-        emitted = self._advance_prefill()
-
+        held = len(self._unread)         # the previous iteration's
+        self._advance_prefill()
         if self.drafter is not None:
-            return self._step_verify(emitted)
+            # the accepted count decides every row's next length, and
+            # the drafter reads the delivered stream: the next
+            # dispatch's shape hangs on the values, so read at once
+            return self._step_verify(self._deliver(len(self._unread)))
+        self._dispatch_decode()
+        return self._deliver(held)
+
+    def _dispatch_decode(self) -> None:
+        """Assemble and issue one decode dispatch over every live,
+        fully prefilled slot whose budget is not already spent by the
+        tokens in flight.  Counts only: no token's value is read."""
+        import jax.numpy as jnp
 
         live = []
         for slot in self.sched.live_slots():
             seq = self.sched.slots[slot]
-            if seq is None or seq.prefilled < len(seq.request.prompt):
-                continue            # mid-prefill: not in the decode pool
+            if seq is None or seq.prefilled < len(seq.request.prompt) \
+                    or seq.spent:
+                # mid-prefill: not in the decode pool; spent: its last
+                # token is computed, it leaves when that is delivered
+                continue
             if not self.sched.ensure_block(slot):
                 # pool exhausted with nothing left to evict: THIS request
                 # cannot grow — fail it alone (blocks freed, terminal
@@ -1015,51 +1173,44 @@ class PagedDecodeEngine:
         live = [s for s in live if self.sched.slots[s] is not None]
         self._track_occupancy()
         if not live:
-            return emitted
+            return
         self._progressed = True
 
         Bb = _bucket(len(live), self.serve.max_slots)
         nb = max(len(self.sched.slots[s].block_ids) for s in live)
         NBb = _bucket(nb, self.serve.max_blocks_per_seq)
-        tokens = np.zeros((Bb,), np.int32)
+        slots = np.full((Bb,), self.serve.max_slots, np.int32)
+        slots[:len(live)] = live
         lengths = np.zeros((Bb,), np.int32)
         tables = np.zeros((Bb, NBb), np.int32)
+        rows = []
         for j, slot in enumerate(live):
             seq = self.sched.slots[slot]
-            tokens[j] = self._last_token[slot]
+            rows.append((slot, seq))
             # the pending token writes at position length-1: the cache
             # holds length-1 entries until this step lands it
             lengths[j] = seq.length - 1
             tables[j] = self._table_row(seq, NBb)
         self.dispatch_shapes.add(("decode", Bb, NBb))
-        self.forward_dispatches += 1
+        self._count_dispatch()
         self.paged_grid_steps += int(np.minimum(
             lengths // self.serve.block_size + 1, NBb).sum())
         self.paged_grid_bound += Bb * NBb
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
+        slots = jnp.asarray(slots)
         nxt, self.pools = self._decode_fn(
-            self.params, self.pools, jnp.asarray(tokens),
+            self.params, self.pools,
+            self._take_fn(self._slot_tokens, slots),
             jnp.asarray(lengths), jnp.asarray(tables))
+        rec = None
         if tr is not None:
-            _m1 = time.monotonic()
-            tr.dispatch_s += _m1 - _m0
-        nxt = np.asarray(nxt)  # graft-lint: sync-ok(the one budgeted bulk sync per decode dispatch)
+            rec = self._log_dispatch("decode", len(live),
+                                     int(lengths.sum()) + len(live))
+        self._hold(nxt, slots, rows, rec)
         if tr is not None:
-            self._log_dispatch("decode", len(live),
-                               int(lengths.sum()) + len(live))
-            self._read_counters()
-            tr.consume_s += time.monotonic() - _m1
-        for j, slot in enumerate(live):
-            tok = int(nxt[j])
-            self._last_token[slot] = tok
-            rid = self.sched.slots[slot].request.id
-            emitted.append((rid, tok))
-            if self._journal is not None:
-                self._journal.record_token(rid, tok)
-            self.sched.record_token(slot, tok, self.serve.eos_id)
-        return emitted
+            tr.dispatch_s += time.monotonic() - _m0
 
     def _step_mixed(self) -> List[Tuple[int, int]]:
         """The fused replacement for the prefill-then-decode phases
@@ -1397,7 +1548,7 @@ class PagedDecodeEngine:
         drain = DrainTracker(serve.drain_ms)
         pending = sorted(requests, key=lambda r: r.arrival)
         t0 = time_fn()
-        while pending or not self.sched.all_done():
+        while pending or not self.all_done():
             now = time_fn() - t0
             if guard is not None and guard.should_stop \
                     and not drain.draining:
@@ -1440,6 +1591,10 @@ class PagedDecodeEngine:
                 if delay > 0:
                     time.sleep(delay)
         elapsed = time_fn() - t0
+        # a drain cut leaves its last dispatch unread: every row of it
+        # belongs to a sequence that was just cut, so reading it only
+        # counts them
+        self._deliver(len(self._unread))
         # pool-leak invariant: every terminal request released its
         # blocks; only the prefix trie's own references may remain —
         # and the draft pool (every request terminal => every draft
@@ -1484,6 +1639,11 @@ class PagedDecodeEngine:
             # beside the rows x table bucket they were cut from
             "paged_grid_steps": self.paged_grid_steps,
             "paged_grid_bound": self.paged_grid_bound,
+            # the one-step lookahead: forward dispatches issued while an
+            # earlier one's tokens were unread, and rows computed for a
+            # sequence that had left its slot by the time they were read
+            "lookahead_dispatches": self.lookahead_dispatches,
+            "lookahead_discarded_rows": self.lookahead_discarded_rows,
             # final-token emit time per request on the run clock (the
             # same clock as Request.arrival): attained whole-request
             # latency = finish - arrival (serving/loadgen goodput join)
@@ -1508,45 +1668,60 @@ class PagedDecodeEngine:
             }
         return res
 
-    def _log_dispatch(self, kind: str, rows: int, attended: int) -> None:
+    def _log_dispatch(self, kind: str, rows: int, attended: int) -> list:
         """Traced runs only: one record per model dispatch in the
         process-wide registry (utils/dispatch_log) — when, what, how many
         rows (decode) or chunk tokens (prefill), and how many cached
         tokens its queries attended, from the scheduler's host state.
-        The routed experts' share of the record is filled by the next
-        ``_read_counters``."""
+        The routed experts' share of the record is what the model's
+        device counters read right after THIS dispatch: a snapshot of
+        their few dozen bytes is taken on the device here (the next
+        dispatch donates the leaves themselves) and read by
+        ``_read_counters`` once the dispatch's tokens are."""
         from mpi_tensorflow_tpu.utils import dispatch_log
 
-        self._unread.append(dispatch_log.record(
-            time.perf_counter(), kind, rows, attended))
+        rec = dispatch_log.record(time.perf_counter(), kind, rows,
+                                  attended)
+        if self._counters is not None:
+            self._snapshots.append(
+                (rec, self._sum_fn(self._counter_leaves())))
+        return rec
 
-    def _read_counters(self) -> None:
-        """Traced runs only, and only once a dispatch's tokens are on
-        the host (the counter leaves of the same program are then ready
-        buffers of a few dozen bytes): read the model's device counters
-        and give every dispatch logged since the last read its share —
-        the counter keeps decode calls and the rest apart, and an
-        iteration holds at most one of each."""
+    def _counter_leaves(self) -> list:
+        """The model's device counters as they stand in ``self.pools``
+        (the last dispatch's output), one leaf a layer that has one."""
+        return [leaf for p in self.pools for key, leaf in p.items()
+                if paged_cache.is_counter(key)]
+
+    def _read_counters(self, upto: list) -> None:
+        """Traced runs only, and only once the tokens of the dispatch
+        that ``upto`` records are on the host (every snapshot taken up
+        to it is then a ready buffer of a few dozen bytes): give each
+        dispatch logged so far its own share — what its snapshot reads
+        over the one before.  The counter keeps decode calls and the
+        rest apart."""
         if self._counters is None:
             return
         from mpi_tensorflow_tpu.utils import dispatch_log
 
-        tot = self._counter_totals()
-        prev = self._counters.get("totals", np.zeros_like(tot))
-        delta = tot - prev
-        self._counters["totals"] = tot
-        for rec in self._unread:
-            row = delta[0 if rec[1] == "decode" else 1]
+        tot = None
+        while self._snapshots:
+            rec, snap = self._snapshots.pop(0)
+            tot = np.asarray(snap)  # graft-lint: sync-ok(a ready buffer: taken before the dispatch whose tokens were just read)
+            row = (tot - self._counters.get("totals", 0))[
+                0 if rec[1] == "decode" else 1]
             rec[4], rec[5] = int(row[:-1].sum()), int(row[-1])
-            row[:] = 0              # one dispatch of a kind per read
-        self._unread.clear()
-        dispatch_log.set_totals(tot[:, :-1].sum(axis=0).tolist())
-        if self.tracer is not None:
-            self.tracer.moe = self.moe_block(tot)
+            self._counters["totals"] = tot
+            if rec is upto:
+                break
+        if tot is not None:
+            dispatch_log.set_totals(tot[:, :-1].sum(axis=0).tolist())
+            if self.tracer is not None:
+                self.tracer.moe = self.moe_block(tot)
 
     def _counter_totals(self):
-        """The counter leaves summed over layers, on the host (a
-        sync)."""
+        """The counter leaves summed over layers, on the host (a sync
+        that waits for whatever dispatch is in flight)."""
         return np.sum([leaf for layer in paged_cache.read_counters(
             self.pools) for leaf in layer.values()], axis=0)
 
@@ -1587,6 +1762,12 @@ class PagedDecodeEngine:
             # yet serving (Scheduler.prefill_backlog_tokens)
             "prefill_backlog": (self.sched.prefill_backlog_tokens
                                 / max(1, self.serve.prefill_chunk)),
+            # running counts, not load (the advisor passes them by):
+            # with ``forward_dispatches`` a step record says how much of
+            # the host's work the lookahead hid, and at what waste
+            "forward_dispatches": self.forward_dispatches,
+            "lookahead_dispatches": self.lookahead_dispatches,
+            "lookahead_discarded_rows": self.lookahead_discarded_rows,
         }
 
     def prefix_block(self) -> dict:
@@ -1649,7 +1830,11 @@ class PagedDecodeEngine:
                "partial": size(self._partial_fn),
                "verify": size(self._verify_fn),
                "mixed": size(self._mixed_fn),
-               "promote": size(self._promote_fn)}
+               "promote": size(self._promote_fn),
+               # the lookahead's slot-token programs, all built with the
+               # engine: a window must not grow them either
+               "slot_take": size(self._take_fn),
+               "slot_keep": size(self._keep_fn)}
         if self.drafter is not None:
             # a drafter's own jitted dispatches are inside the steady-
             # state loop too — the contract covers them like the

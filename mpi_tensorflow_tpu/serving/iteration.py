@@ -44,9 +44,16 @@ class EngineLoop:
 
     Owns the latency bookkeeping for one engine and wires the engine's
     token stream into ``journal`` (``engine.step()`` journals each token
-    at emission, BEFORE the terminal hook can fire — the durable order
+    at delivery, BEFORE the terminal hook can fire — the durable order
     is tok-then-end).  Single-owner like the scheduler: only the thread
     driving the engine may touch a loop.
+
+    ``engine.step()`` looks one dispatch ahead: the tokens an
+    ``iterate`` call returns are those of the PREVIOUS call's dispatches,
+    read after this call's were issued, and everything below that
+    stamps, journals or voids does so at that delivery, in the order it
+    always had.  A token is stamped when the host has it, so a gap or a
+    time to first token counts the dispatch it waited.
     """
 
     def __init__(self, engine, journal=None):
@@ -104,7 +111,7 @@ class EngineLoop:
         """One engine iteration: deadline sweep BEFORE the step (expired
         work must not buy another dispatch's worth of pool time), one
         ``engine.step()``, then the emit/eviction accounting.  Returns
-        the ``(request id, token)`` pairs emitted."""
+        the ``(request id, token)`` pairs delivered in this call."""
         eng = self.engine
         tr = self.tracer
         if tr is not None:

@@ -850,7 +850,7 @@ class ReplicaRouter:
                         if stop.is_set():
                             with self._inbox_locks[i]:
                                 empty = not self._inboxes[i]
-                            if empty and self.engines[i].sched.all_done():
+                            if empty and self.engines[i].all_done():
                                 return
                         time.sleep(1e-3)
             except BaseException as e:   # noqa: BLE001 — handed to the
@@ -1005,6 +1005,10 @@ class ReplicaRouter:
             "dispatches_per_token": (
                 sum(e.forward_dispatches for e in self.engines)
                 / max(1, total)),
+            "lookahead_dispatches": sum(e.lookahead_dispatches
+                                        for e in self.engines),
+            "lookahead_discarded_rows": sum(e.lookahead_discarded_rows
+                                            for e in self.engines),
             "autoscale": (self._advisor.report()
                           if self._advisor is not None else None),
         }

@@ -111,15 +111,30 @@ class Sequence:
     partial_src: Optional[int] = None
     partial_dst: int = 0
     partial_rows: int = 0
+    # Tokens a dispatch has computed for this sequence whose VALUES the
+    # host has not read yet (one-step lookahead: ``Scheduler.advance``
+    # counts one at dispatch, ``deliver`` appends its value to
+    # ``generated`` a dispatch later).  Everything the next dispatch's
+    # assembly needs — position, table growth, the budget test — reads
+    # counts, so it never waits for a value.
+    unread: int = 0
 
     @property
     def length(self) -> int:
-        """Prompt tokens prefilled + tokens generated.  The LAST
-        generated token is pending — emitted but not yet written to the
-        cache (the next decode step writes it at position length-1 as it
-        reads it), so the cache holds ``length - 1`` entries between
-        steps."""
-        return self.prefilled + len(self.generated)
+        """Prompt tokens prefilled + tokens generated, the unread ones
+        included.  The LAST generated token is pending — computed but
+        not yet written to the cache (the next decode step writes it at
+        position length-1 as it reads it), so the cache holds
+        ``length - 1`` entries between dispatches."""
+        return self.prefilled + len(self.generated) + self.unread
+
+    @property
+    def spent(self) -> bool:
+        """The output budget is used up by the tokens delivered plus
+        those dispatched and unread: no further dispatch may carry this
+        sequence (it finishes when its last value is delivered)."""
+        return (len(self.generated) + self.unread
+                >= self.request.max_new_tokens)
 
 
 class Scheduler:
@@ -572,11 +587,26 @@ class Scheduler:
             self.waiting.insert(requeue_pos, seq.request)
         return True
 
+    def advance(self, slot: int) -> None:
+        """A dispatch now computes this slot's next token: count it
+        (``Sequence.unread``) without its value.  The first half of
+        ``record_token``; ``deliver`` is the second."""
+        self.slots[slot].unread += 1
+
     def record_token(self, slot: int, token: int,
                      eos_id: Optional[int] = None) -> None:
-        """Account one generated token; finish + recycle the slot when
-        the sequence hits EOS or its budget."""
+        """``advance`` and ``deliver`` at once, for a token whose value
+        is on the host as soon as it is counted (the verify and mixed
+        dispatches, which read their output before anything else)."""
+        self.advance(slot)
+        self.deliver(slot, token, eos_id)
+
+    def deliver(self, slot: int, token: int,
+                eos_id: Optional[int] = None) -> None:
+        """Account the VALUE of one token counted by ``advance``; finish
+        + recycle the slot when the sequence hits EOS or its budget."""
         seq = self.slots[slot]
+        seq.unread -= 1
         seq.generated.append(token)
         if (len(seq.generated) >= seq.request.max_new_tokens
                 or (eos_id is not None and token == eos_id)):
@@ -587,13 +617,17 @@ class Scheduler:
                 # blocks spanning prompt + generated BEFORE this
                 # sequence's release below, so they survive by the
                 # trie's own share refs (check_quiescent's
-                # trie-only-refs rule).  Only the ``length - 1`` cache
-                # entries actually WRITTEN are insertable — the final
-                # token is pending, and under speculation positions
-                # past it hold rejected phantom writes.
+                # trie-only-refs rule).  Only the cache entries WRITTEN
+                # FOR DELIVERED TOKENS are insertable, one fewer than
+                # the stream holds — the final token is pending, under
+                # speculation positions past it hold rejected phantom
+                # writes, and a lookahead dispatch that still carries
+                # this sequence (``unread`` > 0: it ended on EOS) writes
+                # exactly that next position, in a block this stream
+                # does not fill and the trie so never adopts.
                 stream = list(seq.request.prompt) + seq.generated
                 added = self.prefix_cache.insert(
-                    stream[:seq.length - 1], seq.block_ids)
+                    stream[:len(stream) - 1], seq.block_ids)
                 self.counters["prefix_gen_inserted_blocks"] += added
             self.allocator.release(seq.block_ids)
             seq.block_ids = []

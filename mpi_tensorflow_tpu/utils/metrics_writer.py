@@ -351,7 +351,8 @@ BREAKDOWN_KEYS = ("enabled", "requests", "queue_ms_p50", "queue_ms_p99",
                   "prefill_ms_p50", "prefill_ms_p99", "decode_ms_p50",
                   "decode_ms_p99", "ttft_ms_p50", "ttft_ms_p99",
                   "phase_sum_vs_attained_max_delta_ms",
-                  "ttft_vs_stamp_max_delta_ms", "steps", "steps_dropped")
+                  "ttft_vs_stamp_max_delta_ms", "steps", "steps_dropped",
+                  "step_ms_p50", "device_wait_ms_p50", "lookahead_share")
 
 
 def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
@@ -365,8 +366,17 @@ def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
     when given, ``ttft_vs_stamp_max_delta_ms`` reports the worst
     disagreement between a span's first-token stamp and the loop's —
     the loop stamps both from the same post-step clock read, so this
-    should be ~0 and a drift means an instrumentation bug.  Keys are
-    always exactly ``BREAKDOWN_KEYS`` (zeros when disabled/empty)."""
+    should be ~0 and a drift means an instrumentation bug.
+
+    The last three keys read the step ring and say which side sets the
+    pace: ``step_ms_p50`` is an iteration's length, ``device_wait_ms_p50``
+    the part of it the host stood waiting for the device's tokens (the
+    step record's ``consume_s``: ~0 means the host is the slower side,
+    ~the step's length the device), and ``lookahead_share`` the share of
+    model dispatches issued while an earlier one's tokens were unread
+    (the newest record's running counts; ~1 on the plain path, 0 where
+    every dispatch is read at once).  Keys are always exactly
+    ``BREAKDOWN_KEYS`` (zeros when disabled/empty)."""
     if enabled is None:
         enabled = bool(trace) and bool(trace.get("enabled"))
     out = {k: 0.0 for k in BREAKDOWN_KEYS}
@@ -391,6 +401,9 @@ def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
                    # deliberately exclude — the sum contract holds per
                    # incarnation, so check single-incarnation spans
                    if d.get("incarnations", 1) == 1 and not d["replays"]]
+    steps = [r for rep in trace.get("replicas", ())
+             for r in rep.get("steps", ())]
+    newest = max(steps, key=lambda r: r["t1"])["signals"] if steps else {}
     stamp_delta = [abs(d["first_token"] - stamped_first_s[d["rid"]]) * 1e3
                    for d in ok
                    if stamped_first_s is not None
@@ -412,6 +425,13 @@ def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
             max(stamp_delta), 3) if stamp_delta else 0.0,
         "steps": int(trace.get("steps", 0)),
         "steps_dropped": int(trace.get("steps_dropped", 0)),
+        "step_ms_p50": round(_percentile(
+            [(r["t1"] - r["t0"]) * 1e3 for r in steps], 0.5), 3),
+        "device_wait_ms_p50": round(_percentile(
+            [r["consume_s"] * 1e3 for r in steps], 0.5), 3),
+        "lookahead_share": round(
+            newest.get("lookahead_dispatches", 0)
+            / max(1, newest.get("forward_dispatches", 0)), 4),
     })
     return out
 

@@ -222,6 +222,84 @@ def test_host_sync_trace_stamp_red():
     assert "float()" in found[0].message
 
 
+def _two_step_module(hold_body, read_body):
+    return {"pkg/serving/iteration.py": _src(f"""
+        import jax
+        import numpy as np
+
+        class Loop:
+            def __init__(self, impl):
+                self._decode_fn = jax.jit(impl)
+                self._unread = []
+
+            def step(self, tokens):
+                nxt = self._decode_fn(tokens)
+                {hold_body}
+
+            def _hold(self, nxt, rows):
+                self._unread.append((nxt, rows))
+
+            def _deliver(self, n):
+                {read_body}
+        """)}
+
+
+def test_host_sync_follows_a_kept_value_red():
+    # the lookahead's shape: one function keeps the dispatch's result,
+    # another reads it later — the read is still a sync
+    tree = _two_step_module(
+        "self._unread.append((nxt, tokens))",
+        """for nxt, rows in self._unread[:n]:
+                    toks = np.asarray(nxt)""")
+    found = host_sync.run(tree)
+    assert _ids(found) == ["HOST-SYNC"]
+    assert "np.asarray()" in found[0].message
+
+
+def test_host_sync_follows_a_tainted_argument_red():
+    tree = _two_step_module(
+        "self._hold(nxt, tokens)",
+        """nxt, rows = self._unread.pop(0)
+                return int(nxt[0])""")
+    found = host_sync.run(tree)
+    assert _ids(found) == ["HOST-SYNC"]
+    assert "int()" in found[0].message
+
+
+def test_host_sync_kept_value_allowlist_green():
+    tree = _two_step_module(
+        "self._hold(nxt, tokens)",
+        """for nxt, rows in self._unread[:n]:
+                    # graft-lint: sync-ok(read after the next dispatch)
+                    toks = np.asarray(nxt)""")
+    assert host_sync.run(tree) == []
+
+
+def test_engine_dispatch_path_reads_no_device_value():
+    """The static half of the lookahead's pin (tests/test_lookahead.py
+    holds the run-time half): with every ``sync-ok`` mark of the engine
+    struck, the pass finds a read of a device value in ``_deliver`` (the
+    one bulk read a dispatch, taken after the next dispatch is issued),
+    in the traced run's counter read beside it and in the verify step
+    (which reads at once, by design) — and NONE in the functions that
+    assemble and issue the plain path's dispatches."""
+    import ast
+
+    sources = core.load_sources()
+    rel = next(r for r in sources if r.endswith("serving/engine.py"))
+    struck = {rel: sources[rel].replace("sync-ok", "sync-struck")}
+    spans = {fn.name: (fn.lineno, fn.end_lineno)
+             for fn in core.iter_functions(ast.parse(sources[rel]))}
+    where = sorted(name for f in host_sync.run(struck)
+                   for name, (a, b) in spans.items() if a <= f.line <= b)
+    assert where == ["_deliver", "_read_counters", "_step_verify"]
+    for name in ("step", "_advance_prefill", "_dispatch_decode", "_hold",
+                 "_count_dispatch", "_log_dispatch"):
+        a, b = spans[name]
+        body = sources[rel].splitlines()[a - 1:b]
+        assert not any("sync-ok" in line for line in body), name
+
+
 def test_host_sync_cold_namespace_green():
     # same code outside the hot namespace: not this pass's business
     tree = {"pkg/serving/other.py": _src("""

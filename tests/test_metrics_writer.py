@@ -205,3 +205,33 @@ class TestPrefixBlockV2:
         assert empty["prefill_tokens_saved"] == 0
         assert empty["gen_inserted_blocks"] == 0
         assert empty["router_prefix_hits"] == 0
+
+
+@pytest.mark.quick
+class TestBreakdownPace:
+    def test_step_ring_keys_say_which_side_sets_the_pace(self):
+        """``step_ms_p50`` / ``device_wait_ms_p50`` / ``lookahead_share``
+        come from the step records (serving/tracing): the iteration's
+        length, the host's wait for the device inside it, and the
+        newest record's lookahead count over its dispatch count."""
+        def step(t0, t1, wait, ahead, total, discarded=0):
+            return {"t0": t0, "t1": t1, "sweep_s": 0.0, "dispatch_s": 1e-3,
+                    "consume_s": wait, "emitted": 1,
+                    "signals": {"queue_depth": 0, "occupancy": 0.5,
+                                "forward_dispatches": total,
+                                "lookahead_dispatches": ahead,
+                                "lookahead_discarded_rows": discarded}}
+        trace = {"enabled": True, "spans": {}, "steps": 3,
+                 "steps_dropped": 0,
+                 "replicas": [{"steps": [step(0.00, 0.01, 0.008, 1, 2),
+                                         step(0.01, 0.02, 0.006, 3, 4),
+                                         step(0.02, 0.03, 0.004, 5, 6)]}]}
+        bd = metrics_writer.breakdown_block(trace)
+        assert tuple(bd) == metrics_writer.BREAKDOWN_KEYS
+        assert bd["step_ms_p50"] == pytest.approx(10.0)
+        assert bd["device_wait_ms_p50"] == pytest.approx(6.0)
+        assert bd["lookahead_share"] == pytest.approx(5 / 6, abs=1e-4)
+        # no step ring (a fleet harvest without records): zeros
+        empty = metrics_writer.breakdown_block(
+            {"enabled": True, "spans": {}, "steps": 0, "steps_dropped": 0})
+        assert empty["step_ms_p50"] == empty["lookahead_share"] == 0

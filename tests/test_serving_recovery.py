@@ -219,6 +219,51 @@ class TestTransientReplay:
         assert res["outputs"] == want["outputs"]
         assert all(s == "ok" for s in res["statuses"].values())
 
+    def test_fault_with_a_dispatch_unread_replays_whole(
+            self, model_params, tmp_path):
+        """The engine looks one dispatch ahead, so a fault at a decode
+        dispatch finds the previous one's tokens unread: they were
+        computed and are in no journal.  The replay regenerates them —
+        the journal holds delivered tokens only, tok before end — and
+        the streams come out whole."""
+        import json
+
+        model, params = model_params
+        want = PagedDecodeEngine(model, params, SERVE).run(_trace())
+        seen = {}
+        inner = self._flaky_factory(model, params)
+
+        def make_engine():
+            engine = inner()
+            if not seen:
+                flaky = engine._decode_fn
+
+                def watched(*a, **k):
+                    try:
+                        return flaky(*a, **k)
+                    except RuntimeError:
+                        seen["unread"] = sum(
+                            len(rows) for _nxt, rows, _rec
+                            in engine._unread)
+                        raise
+                engine._decode_fn = watched
+                seen["engine"] = engine
+            return engine
+
+        path = str(tmp_path / "unread.jsonl")
+        res = run_with_replay(make_engine, _trace(), journal_path=path)
+        assert res["replays"] == 1 and seen["unread"] > 0
+        assert res["outputs"] == want["outputs"]
+        toks, ended = {}, set()
+        for line in open(path):
+            rec = json.loads(line)
+            if rec["kind"] == "tok":
+                assert rec["id"] not in ended    # tok-then-end
+                toks[rec["id"]] = toks.get(rec["id"], 0) + 1
+            elif rec["kind"] == "end":
+                ended.add(rec["id"])
+        assert ended == set(want["outputs"])
+
     def test_repeated_faults_within_budget_still_identical(
             self, model_params):
         model, params = model_params

@@ -182,6 +182,32 @@ class TestTraceBuffer:
         assert rec["dispatch_s"] >= 0 and rec["consume_s"] >= 0
         assert set(rec["signals"]) >= {"queue_depth", "occupancy"}
 
+    def test_step_record_says_how_far_the_host_looked_ahead(self):
+        """Every step record carries the lookahead's running counts, and
+        ``consume_s`` (the host waiting for the device's tokens) beside
+        the step's length: together they say which side set the pace.
+        The counts in the newest record are the run's."""
+        eng = _engine("on")
+        rng = np.random.default_rng(23)
+        res = eng.run(_reqs(rng, 6))
+        steps = res["trace"]["replicas"][0]["steps"]
+        for rec in steps:
+            sig = rec["signals"]
+            assert 0 <= sig["lookahead_dispatches"] \
+                <= sig["forward_dispatches"]
+            assert sig["lookahead_discarded_rows"] >= 0
+            assert rec["consume_s"] <= rec["t1"] - rec["t0"] + 1e-6
+        counts = [r["signals"]["lookahead_dispatches"] for r in steps]
+        assert counts == sorted(counts) and counts[-1] > 0
+        assert counts[-1] == res["lookahead_dispatches"]
+        assert steps[-1]["signals"]["forward_dispatches"] \
+            == res["forward_dispatches"]
+        bd = breakdown_block(res["trace"])
+        assert bd["lookahead_share"] == pytest.approx(
+            res["lookahead_dispatches"] / res["forward_dispatches"],
+            abs=1e-3)
+        assert 0 <= bd["device_wait_ms_p50"] <= bd["step_ms_p50"]
+
 
 # --------------------------------------------------- chrome export
 
