@@ -178,7 +178,7 @@ class MlaMoeLm:
     # ---------------- what the engine asks ----------------
 
     def pool_leaves(self, num_blocks: int, block_size: int,
-                    kv_dtype: str = "fp32") -> list:
+                    kv_dtype: str = "fp32", max_slots: int = 0) -> list:
         """Per layer, the leaves of its pool entry as
         ``{name: ShapeDtypeStruct}``: the latent rows, and the expert
         counter where the layer routes ([0] decode calls, [1] the rest;

@@ -80,8 +80,13 @@ def build_parser() -> argparse.ArgumentParser:
                     "continuous-batching engine; one JSON line out.")
     p.add_argument("--precision", default=Config.precision,
                    help="compute dtype of the served model: fp32 | bf16")
+    p.add_argument("--model", default="gpt",
+                   help="decoder family: gpt (CausalLm at gpt_base's "
+                        "widths) | phi4_flash (Phi-4-mini-flash-"
+                        "reasoning's: state-space, window, full and cross "
+                        "layers)")
     p.add_argument("--tiny", action="store_true",
-                   help="BERT_TINY widths, not gpt_base's: the CPU size")
+                   help="the family's CPU size, not its published widths")
     p.add_argument("--journal", default=None, metavar="PATH",
                    help="replay journal: serve through the crash-recovery "
                         "path (no warm-up); the same arguments resume it")
@@ -93,7 +98,19 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+#: family -> its served name at published widths
+FAMILIES = {"gpt": "gpt_base", "phi4_flash": "phi4_mini_flash_reasoning"}
+
+
 def _widths(args):
+    """The model's config at the family's published or tiny widths."""
+    if args.model not in FAMILIES:
+        raise ValueError(f"--model must be one of {sorted(FAMILIES)}, "
+                         f"got {args.model!r}")
+    if args.model == "phi4_flash":
+        from mpi_tensorflow_tpu.models import phi4_flash
+
+        return phi4_flash.TINY if args.tiny else phi4_flash.Phi4FlashConfig()
     from mpi_tensorflow_tpu.models import bert
 
     return bert.BERT_TINY if args.tiny else bert.BERT_BASE
@@ -121,10 +138,12 @@ def _build(args, cfg: ServeConfig, seed: int):
     """The model from the seed, and one engine or a router to serve it."""
     import jax
 
-    from mpi_tensorflow_tpu.models import gpt
+    from mpi_tensorflow_tpu.models import gpt, phi4_flash
     from mpi_tensorflow_tpu.serving import PagedDecodeEngine, ReplicaRouter
 
-    model = gpt.CausalLm(dataclasses.replace(
+    family = phi4_flash.Phi4FlashLm if args.model == "phi4_flash" \
+        else gpt.CausalLm
+    model = family(dataclasses.replace(
         _widths(args),
         dtype=Config(precision=args.precision).compute_dtype))
     params = model.init(jax.random.key(seed))
@@ -182,7 +201,8 @@ def _report(args, cfg, trace, front, res, warm, served) -> dict:
     from mpi_tensorflow_tpu.utils.profiling import device_identity
 
     out = {
-        "model": "gpt_tiny" if args.tiny else "gpt_base",
+        "model": (args.model + "_tiny" if args.tiny
+                  else FAMILIES[args.model]),
         "precision": args.precision, "journal": args.journal,
         "serve": dataclasses.asdict(cfg),
         "workload": {k: getattr(trace.spec, k)

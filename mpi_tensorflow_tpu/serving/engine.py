@@ -344,6 +344,25 @@ def _weakly(method):
     return call
 
 
+class _SlackFilled:
+    """A jitted step of a model that keeps state by slot
+    (``model.slot_state``), callable with or without its trailing row
+    arguments: left out (a prewarm on null tables, serve_driver's and
+    ``prewarm_decode``'s), ``fill`` supplies the slack slot, so that a
+    prewarm builds the very program the loop runs."""
+
+    def __init__(self, fn, arity: int, fill):
+        self.fn, self.arity, self.fill = fn, arity, fill
+
+    def __call__(self, *args):
+        if len(args) == self.arity:
+            args += self.fill(args)
+        return self.fn(*args)
+
+    def _cache_size(self):
+        return self.fn._cache_size()
+
+
 def _bucket(n: int, cap: int) -> int:
     """Round ``n`` up to a power of two, capped at ``cap``."""
     return min(pow2_ceil(n), cap)
@@ -372,6 +391,10 @@ def check_model(cfg, serve: ServeConfig) -> None:
     calls it first thing; the entry point calls it before building
     anything, so a flag is refused in these words and a fault further
     down keeps its traceback."""
+    refusal = getattr(cfg, "serve_refusal", lambda serve: None)(serve)
+    if refusal:
+        # what a family cannot do yet, in its own words
+        raise ValueError(refusal)
     cap = serve.max_blocks_per_seq * serve.block_size
     if cfg.pos_kind == "learned" and cap > cfg.max_positions:
         raise ValueError(
@@ -431,10 +454,14 @@ class PagedDecodeEngine:
         else:
             self.params = params
             kernel = self.kernel        # no ``self`` in the closure
+            # ``rows``: each row's slot and the lane whose logits are
+            # taken, for a model that keeps state by slot; empty else
             self._paged_forward = (
-                lambda params, tokens, pools, tables, lengths, valid:
+                lambda params, tokens, pools, tables, lengths, valid,
+                **rows:
                 model.forward_paged(params, tokens, pools, tables,
-                                    lengths, valid=valid, kernel=kernel))
+                                    lengths, valid=valid, kernel=kernel,
+                                    **rows))
         # donate the pools so the cache updates in place — on every
         # platform (XLA:CPU honours donation too), so tier-1 sees a
         # use-after-donate before the chip does
@@ -443,6 +470,24 @@ class PagedDecodeEngine:
                                   donate_argnums=donate)
         self._prefill_fn = jax.jit(_weakly(self._prefill_impl),
                                    donate_argnums=donate)
+        # a model with per-slot state (models/phi4_flash) is told each
+        # row's slot, and which lane of a prefill chunk it answers for
+        self._slot_state = bool(getattr(model, "slot_state", False))
+        # ... and may ask for its tables at full width always: where one
+        # layer in many reads the table and the kernel's grid follows the
+        # live blocks anyway, a narrower table buys only programs
+        self._full_tables = bool(getattr(model, "full_tables", False))
+        if self._slot_state:
+            import jax.numpy as jnp
+
+            slack = serve.max_slots
+            self._decode_fn = _SlackFilled(
+                self._decode_fn, 5,
+                lambda a: (jnp.full(a[2].shape, slack, jnp.int32),))
+            self._prefill_fn = _SlackFilled(
+                self._prefill_fn, 6,
+                lambda a: (jnp.asarray(slack, jnp.int32),
+                           jnp.asarray(1, jnp.int32)))
         # copy-on-write block copy: pools in, pools out, fixed shapes —
         # exactly ONE compile ever (block ids ride as traced scalars)
         self._cow_fn = jax.jit(
@@ -547,7 +592,15 @@ class PagedDecodeEngine:
 
         self.pools = paged_cache.init_pools(
             self.model.cfg, self.serve.num_blocks, self.serve.block_size,
-            self.serve.kv_dtype, self.serve.kv_group, model=self.model)
+            self.serve.kv_dtype, self.serve.kv_group, model=self.model,
+            max_slots=self.serve.max_slots)
+        self._cache_bytes = {"paged": 0, "window": 0, "state": 0}
+        for p in self.pools:
+            for key, leaf in p.items():
+                kind = paged_cache.cache_kind(key)
+                if kind != "counter":
+                    self._cache_bytes[kind] += leaf.size \
+                        * leaf.dtype.itemsize
         # device counters the model declared beside its pool leaves
         # (routed experts' load): totals as last read, None = none held
         self._counters = ({} if any(
@@ -652,6 +705,9 @@ class PagedDecodeEngine:
         # device time is what a step costs
         self.paged_grid_steps = 0
         self.paged_grid_bound = 0
+        # prefill chunks that began at position 0: each starts a slot's
+        # per-slot state from zero (a model without any counts them too)
+        self.state_resets = 0
 
     def _on_terminal(self, req, status: str) -> None:
         """THE per-request exit hook (installed on every scheduler this
@@ -672,29 +728,44 @@ class PagedDecodeEngine:
 
     # ---------------- jitted device steps ----------------
 
-    def _decode_impl(self, params, pools, tokens, lengths, tables):
+    def _decode_impl(self, params, pools, tokens, lengths, tables,
+                     slots=None):
         """(B,) tokens at per-row positions ``lengths`` -> (B,) greedy
         next tokens + updated pools.  Padding rows (bucket slack) carry
         all-null tables; their writes land in the null block and their
-        output is discarded on host."""
+        output is discarded on host.  ``slots`` (each row's slot, the
+        slack one for padding rows) goes to a model that keeps state by
+        slot and to no other."""
         import jax.numpy as jnp
 
         from mpi_tensorflow_tpu.ops.paged_attention import NULL_BLOCK
 
         live = (tables[:, 0] != NULL_BLOCK)[:, None]
+        rows = {} if slots is None else {"slots": slots}
         logits, pools = self._paged_forward(
-            params, tokens[:, None], pools, tables, lengths, live)
+            params, tokens[:, None], pools, tables, lengths, live, **rows)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return nxt, pools
 
-    def _prefill_impl(self, params, pools, tokens, length, n_real, tables):
+    def _prefill_impl(self, params, pools, tokens, length, n_real, tables,
+                      slot=None, final=None):
         """One (1, chunk) prefill dispatch: writes the chunk's KV into
         the row's blocks, returns the greedy token following the LAST
-        REAL lane (meaningful only on the final chunk) + updated pools."""
+        REAL lane (meaningful only on the final chunk) + updated pools.
+        A model that keeps state by slot is given the row's ``slot`` and
+        the one lane it answers for: the last real one where ``final``
+        says this chunk ends the prompt, none otherwise, and computes
+        logits for that lane alone."""
         import jax.numpy as jnp
 
         S = tokens.shape[1]
         valid = jnp.arange(S)[None] < n_real
+        if slot is not None:
+            take = jnp.where(final > 0, jnp.maximum(n_real - 1, 0), -1)
+            logits, pools = self._paged_forward(
+                params, tokens, pools, tables, length[None], valid,
+                slots=slot[None], take=take[None])
+            return jnp.argmax(logits[0, 0], axis=-1).astype(jnp.int32), pools
         logits, pools = self._paged_forward(
             params, tokens, pools, tables, length[None], valid)
         nxt = jnp.argmax(logits[0, jnp.maximum(n_real - 1, 0)], axis=-1)
@@ -872,7 +943,7 @@ class PagedDecodeEngine:
 
         Bb = 1
         while True:
-            NBb = 1
+            NBb = self._table_bucket(1)
             while True:
                 _, self.pools = self._decode_fn(
                     self.params, self.pools,
@@ -983,6 +1054,12 @@ class PagedDecodeEngine:
                 for b in s.block_ids}
         self.peak_live_blocks = max(self.peak_live_blocks, len(live))
 
+    def _table_bucket(self, blocks: int) -> int:
+        """The table width a decode dispatch whose longest row holds
+        ``blocks`` blocks runs at."""
+        cap = self.serve.max_blocks_per_seq
+        return cap if self._full_tables else _bucket(blocks, cap)
+
     def _table_row(self, seq, width: int) -> np.ndarray:
         row = np.zeros((width,), np.int32)
         ids = seq.block_ids[:width]
@@ -1023,19 +1100,24 @@ class PagedDecodeEngine:
         tables = self._table_row(seq, self.serve.max_blocks_per_seq)[None]
         self.dispatch_shapes.add(("prefill", sb))
         self._count_dispatch()
+        self.state_resets += seq.prefilled == 0
+        final = seq.prefilled + len(chunk) >= len(prompt)
+        rows = (jnp.asarray(slot, jnp.int32),
+                jnp.asarray(final, jnp.int32)) if self._slot_state else ()
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
         nxt, self.pools = self._prefill_fn(
             self.params, self.pools, jnp.asarray(toks),
             jnp.asarray(seq.prefilled, jnp.int32),
-            jnp.asarray(len(chunk), jnp.int32), jnp.asarray(tables))
+            jnp.asarray(len(chunk), jnp.int32), jnp.asarray(tables), *rows)
         rec = None
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
             n, at = len(chunk), seq.prefilled
             rec = self._log_dispatch("prefill", n,
-                                     n * at + n * (n + 1) // 2)
+                                     n * at + n * (n + 1) // 2,
+                                     [at], [n], int(final))
         seq.prefilled += len(chunk)
         if seq.prefilled < len(prompt):
             return
@@ -1178,7 +1260,7 @@ class PagedDecodeEngine:
 
         Bb = _bucket(len(live), self.serve.max_slots)
         nb = max(len(self.sched.slots[s].block_ids) for s in live)
-        NBb = _bucket(nb, self.serve.max_blocks_per_seq)
+        NBb = self._table_bucket(nb)
         slots = np.full((Bb,), self.serve.max_slots, np.int32)
         slots[:len(live)] = live
         lengths = np.zeros((Bb,), np.int32)
@@ -1203,11 +1285,13 @@ class PagedDecodeEngine:
         nxt, self.pools = self._decode_fn(
             self.params, self.pools,
             self._take_fn(self._slot_tokens, slots),
-            jnp.asarray(lengths), jnp.asarray(tables))
+            jnp.asarray(lengths), jnp.asarray(tables),
+            *((slots,) if self._slot_state else ()))
         rec = None
         if tr is not None:
-            rec = self._log_dispatch("decode", len(live),
-                                     int(lengths.sum()) + len(live))
+            n = len(live)
+            rec = self._log_dispatch("decode", n, int(lengths.sum()) + n,
+                                     lengths[:n], [1] * n, n)
         self._hold(nxt, slots, rows, rec)
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
@@ -1644,6 +1728,7 @@ class PagedDecodeEngine:
             # sequence that had left its slot by the time they were read
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
+            "caches": self.cache_block(),
             # final-token emit time per request on the run clock (the
             # same clock as Request.arrival): attained whole-request
             # latency = finish - arrival (serving/loadgen goodput join)
@@ -1668,11 +1753,16 @@ class PagedDecodeEngine:
             }
         return res
 
-    def _log_dispatch(self, kind: str, rows: int, attended: int) -> list:
+    def _log_dispatch(self, kind: str, rows: int, attended: int,
+                      starts, counts, taken: int) -> list:
         """Traced runs only: one record per model dispatch in the
         process-wide registry (utils/dispatch_log) — when, what, how many
         rows (decode) or chunk tokens (prefill), and how many cached
-        tokens its queries attended, from the scheduler's host state.
+        tokens its queries attended, from the scheduler's host state;
+        and, where the model says what else a dispatch obliges of its
+        caches (``dispatch_extra``: each row's position before the
+        dispatch, its real tokens, the lanes whose logits were taken),
+        that too.
         The routed experts' share of the record is what the model's
         device counters read right after THIS dispatch: a snapshot of
         their few dozen bytes is taken on the device here (the next
@@ -1680,8 +1770,10 @@ class PagedDecodeEngine:
         ``_read_counters`` once the dispatch's tokens are."""
         from mpi_tensorflow_tpu.utils import dispatch_log
 
-        rec = dispatch_log.record(time.perf_counter(), kind, rows,
-                                  attended)
+        extra = getattr(self.model, "dispatch_extra", None)
+        rec = dispatch_log.record(
+            time.perf_counter(), kind, rows, attended,
+            extra(kind, starts, counts, taken) if extra else None)
         if self._counters is not None:
             self._snapshots.append(
                 (rec, self._sum_fn(self._counter_leaves())))
@@ -1768,7 +1860,22 @@ class PagedDecodeEngine:
             "forward_dispatches": self.forward_dispatches,
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
+            # the caches by kind (static sizes; ``occupancy`` above is
+            # the paged pool's) and the prefill chunks that started a
+            # slot's state from zero
+            "cache_bytes": dict(self._cache_bytes),
+            "state_resets": int(self.state_resets),
         }
+
+    def cache_block(self) -> dict:
+        """Bytes the device holds of each kind of cache (``paged`` block
+        leaves, per-slot ``window`` rings, other per-slot ``state``:
+        serving/paged_cache.cache_kind), how full the paged pool is, and
+        how often a slot's state was started from zero."""
+        return {"cache_bytes": dict(self._cache_bytes),
+                "pool_occupancy": (self.allocator.num_used
+                                   / max(1, self.serve.num_blocks - 1)),
+                "state_resets": int(self.state_resets)}
 
     def prefix_block(self) -> dict:
         """Canonical prefix-cache accounting block for this engine's

@@ -35,8 +35,29 @@ accounting here and emits the same tokens.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Dict, List, Optional, Tuple
+
+_HEAP_SETTLED = False
+
+
+def settle_heap() -> None:
+    """Once a process, at a serving loop's first iteration: one full
+    collection, then ``gc.freeze()``.  By then the programs are traced
+    (a prewarm, a warm-up replay) and what tracing leaves alive, with the
+    imported modules, is millions of objects that live as long as the
+    process: every later full collection, which the loop's own garbage
+    triggers about once a minute, would walk them all (0.44 s inside a
+    50 s window with a 32-layer model's 18 programs: PERF.md, PR 32,
+    where it moved a p95 by 7%).  Frozen, they are passed by; what the
+    loop allocates from here on is collected as before, and reference
+    counts free what they always freed."""
+    global _HEAP_SETTLED
+    if not _HEAP_SETTLED:
+        _HEAP_SETTLED = True
+        gc.collect()
+        gc.freeze()
 
 
 class EngineLoop:
@@ -112,6 +133,7 @@ class EngineLoop:
         work must not buy another dispatch's worth of pool time), one
         ``engine.step()``, then the emit/eviction accounting.  Returns
         the ``(request id, token)`` pairs delivered in this call."""
+        settle_heap()
         eng = self.engine
         tr = self.tracer
         if tr is not None:

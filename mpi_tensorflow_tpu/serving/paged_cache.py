@@ -18,7 +18,14 @@ block id on axis 0 and the token slot on axis 1.  A leaf whose name ends
 in ``_count`` is a COUNTER the model accumulates on the device (routed
 experts' load): it has no block axis, block copies pass it through
 (``copy_block``), ``reset`` zeroes it with the rest, and the host reads
-it only when asked (``read_counters``).  The host side —
+it only when asked (``read_counters``).  A leaf whose name ends in
+``_slot`` is PER-SLOT state (models/phi4_flash: a state-space layer's
+recurrent state, a window layer's K/V ring): axis 0 is the engine's slot,
+``max_slots + 1`` rows, the last taking the writes of a dispatch's
+padding rows; it is no block either, so block copies pass it through,
+and only the model's forward reads or writes it (it is told each row's
+slot).  ``cache_kind`` names what a leaf is for the byte counts of the
+engine's result.  The host side —
 this module — owns WHICH block belongs to WHOM: a refcounted free-list
 allocator whose accounting the scheduler's admit/evict decisions hang
 off.
@@ -138,23 +145,44 @@ def is_counter(key: str) -> bool:
     return key.endswith("_count")
 
 
+def is_slot_state(key: str) -> bool:
+    """A pool entry's per-slot state (slot on axis 0), by its name."""
+    return key.endswith("_slot")
+
+
+def is_block(key: str) -> bool:
+    """A pool entry's block leaf (block id on axis 0, token slot on axis
+    1): neither a counter nor per-slot state."""
+    return not (is_counter(key) or is_slot_state(key))
+
+
+def cache_kind(key: str) -> str:
+    """``counter``, ``window`` (a per-slot K/V ring), ``state`` (other
+    per-slot state) or ``paged`` (a block leaf)."""
+    if is_counter(key):
+        return "counter"
+    if not is_slot_state(key):
+        return "paged"
+    return "window" if key.startswith("win_") else "state"
+
+
 def copy_block(pools: list, src, dst) -> list:
     """Copy pool block ``src`` onto ``dst`` in every block leaf of every
-    layer (codes and scale siblings alike); counters pass through."""
-    return [{key: leaf if is_counter(key) else leaf.at[dst].set(leaf[src])
+    layer (codes and scale siblings alike); the rest pass through."""
+    return [{key: leaf.at[dst].set(leaf[src]) if is_block(key) else leaf
              for key, leaf in p.items()} for p in pools]
 
 
 def block_rows(pools: list, pick) -> list:
     """Per layer ``{key: pick(leaf)}`` over the block leaves only."""
     return [{key: pick(leaf) for key, leaf in p.items()
-             if not is_counter(key)} for p in pools]
+             if is_block(key)} for p in pools]
 
 
 def write_block(pools: list, rows: list, dst) -> list:
     """Write one block's ``rows`` (``block_rows`` of some pool) into
-    block ``dst``; counters pass through."""
-    return [{key: leaf if is_counter(key) else leaf.at[dst].set(r[key])
+    block ``dst``; the rest pass through."""
+    return [{key: leaf.at[dst].set(r[key]) if is_block(key) else leaf
              for key, leaf in p.items()} for p, r in zip(pools, rows)]
 
 
@@ -191,7 +219,7 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
     for p in pools:
         layer = {}
         for key, leaf in p.items():
-            if is_counter(key):
+            if not is_block(key):
                 layer[key] = leaf
                 continue
             rows = jnp.arange(leaf.shape[1]) < n
@@ -204,10 +232,11 @@ def partial_copy_block(pools: list, src, dst, n) -> list:
 
 def init_pools(cfg, num_blocks: int, block_size: int,
                kv_dtype: str = "fp32", kv_group: int = 32,
-               model=None) -> list:
+               model=None, max_slots: int = 0) -> list:
     """Per-layer block pools (zeros).  A ``model`` with ``pool_leaves``
     declares its own (``{name: ShapeDtypeStruct}`` per layer, counters
-    included; it refuses a ``kv_dtype`` it has no form for); otherwise
+    and per-slot state of ``max_slots`` slots included; it refuses a
+    ``kv_dtype`` it has no form for); otherwise
     per-layer K/V pools, mirroring the per-layer ``{"k", "v"}`` pytree
     shape of models/gpt.init_cache so the engine threads them through
     jit the same way.
@@ -246,7 +275,8 @@ def init_pools(cfg, num_blocks: int, block_size: int,
     if declare is not None:
         return [{key: jnp.zeros(s.shape, s.dtype)
                  for key, s in layer.items()}
-                for layer in declare(num_blocks, block_size, kv_dtype)]
+                for layer in declare(num_blocks, block_size, kv_dtype,
+                                     max_slots)]
     width = cfg.heads * cfg.head_dim
     code_shape = (num_blocks, block_size, width)
     if kv_dtype == "fp32":

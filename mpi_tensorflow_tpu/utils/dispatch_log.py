@@ -13,8 +13,12 @@ A record is the list ``[time.perf_counter(), "decode" | "prefill", rows
 the dispatch's queries, from host scheduler state), expert assignments,
 experts touched]``; the last two are per dispatch, summed over the
 layers that route, and ``None`` until the device counter was next read
-(or for a model that routes nothing).  ``totals`` is the running
-assignment count per held expert.
+(or for a model that routes nothing).  A model with a
+``dispatch_extra`` method adds a seventh entry, a dict of what else the
+dispatch obliged of its caches (models/phi4_flash: tokens through the
+scans, keys its window layers and its full layer's cache were read for,
+prefill lanes the cross-decoder was skipped on).  ``totals`` is the
+running assignment count per held expert.
 """
 
 from __future__ import annotations
@@ -23,9 +27,12 @@ _DISPATCHES: list = []
 _TOTALS: list = []
 
 
-def record(at: float, kind: str, rows: int, attended: int) -> list:
+def record(at: float, kind: str, rows: int, attended: int,
+           extra: dict = None) -> list:
     """Append one dispatch and return its (mutable) record."""
     rec = [at, kind, int(rows), int(attended), None, None]
+    if extra is not None:
+        rec.append(extra)
     _DISPATCHES.append(rec)
     return rec
 

@@ -203,8 +203,8 @@ def test_every_flag_is_a_dataclass_field_or_a_deployment_setting():
         f.name for f in dataclasses.fields(loadgen.WorkloadSpec)}
     derived = {_flag(n) for n in fields | set(loadgen.WORKLOAD_HELP)}
     assert derived <= flags
-    assert flags - derived == {"--precision", "--tiny", "--journal",
-                               "--replicas"}
+    assert flags - derived == {"--precision", "--model", "--tiny",
+                               "--journal", "--replicas"}
     assert not [a for a in entry.build_parser()._actions if a.choices]
 
 
