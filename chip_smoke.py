@@ -22,7 +22,12 @@ points a user would call, at the full width of models the repo has
                Pallas kernel;
 - ``kernels``  every paged-attention variant (bf16/fp32/int8/int4 pools,
                decode + every prefill bucket) against
-               ``attend(kernel="xla")``, and the flash kernel fwd+bwd at
+               ``attend(kernel="xla")``; decode at the served geometry
+               (128 rows, bf16 pool) under each pre-warmed table width
+               (8, 16, 32, 64), where the decode body's hand-issued,
+               double-buffered group copies run for hundreds of steps —
+               a semaphore left unwaited or a slot reused too soon shows
+               only under Mosaic; and the flash kernel fwd+bwd at
                S=4096 against ``ring.dense_attention``.
 
 Each phase checks what came out (finite loss, expected step count, all
@@ -386,6 +391,34 @@ def _paged_case(S: int, q_dtype: str, variant: str):
     return (q, kp, vp, bt, lens), kw
 
 
+def _served_decode_case(rows: int, NB: int):
+    """Decode at the served geometry under an ``NB``-wide table: ragged
+    rows (one full table, null-block tails), the last quarter bucket
+    slack (length 0, all-null table), blocks scattered over a pool of
+    ``rows * NB`` random blocks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    H, D, bs = HEADS, HEAD_DIM, BLOCK
+    rng = np.random.default_rng(NB)
+    nblk = 1 + rows * NB
+    lens = rng.integers(0, NB * bs, rows).astype(np.int32)
+    lens[0] = NB * bs - 1
+    lens[rows - rows // 4:] = 0
+    bt = np.zeros((rows, NB), np.int32)
+    ids, nxt = rng.permutation(np.arange(1, nblk)), 0
+    for b in range(rows - rows // 4):
+        n = lens[b] // bs + 1
+        bt[b, :n] = ids[nxt:nxt + n]
+        nxt += n
+    kq, kk, kv = jax.random.split(jax.random.key(NB), 3)
+    q = jax.random.normal(kq, (rows, H, 1, D), jnp.bfloat16)
+    kp = jax.random.normal(kk, (nblk, bs, H * D), jnp.bfloat16)
+    vp = jax.random.normal(kv, (nblk, bs, H * D), jnp.bfloat16)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)
+
+
 def run_kernels(rehearsal: bool, platform: str, facts) -> None:
     import jax
     import jax.numpy as jnp
@@ -421,6 +454,22 @@ def run_kernels(rehearsal: bool, platform: str, facts) -> None:
                 worst.get(f"{q_dtype}/{variant}", 0.0), err)
     facts["paged_max_abs_err"] = {k: round(v, 5) for k, v in worst.items()}
     facts["paged_kernel"] = kernel
+
+    # decode at the served geometry, one call a pre-warmed table width
+    served = {}
+    for rows, NB in ((8, 3), (8, 8)) if rehearsal else (
+            (128, 8), (128, 16), (128, 32), (128, 64)):
+        args = _served_decode_case(rows, NB)
+        want, got = (jax.jit(functools.partial(
+            pa.attend, dt=jnp.bfloat16, kernel=k))(*args)
+            for k in ("xla", kernel))
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                    - want.astype(jnp.float32))))
+        require(np.isfinite(err) and err <= PAGED_ATOL,
+                f"paged decode, {rows} rows x {NB} table blocks: "
+                f"|kernel - xla| {err:.4g} <= {PAGED_ATOL}")
+        served[f"{rows}x{NB}"] = round(err, 5)
+    facts["paged_served_decode_max_abs_err"] = served
 
     # flash fwd+bwd at BERT-base head geometry where flash_min_seq engages
     shape = (1, 2, 256, HEAD_DIM) if rehearsal else (1, HEADS, 4096, HEAD_DIM)

@@ -230,13 +230,15 @@ class CausalLm(bert_lib.BertMlm):
 
         qkv_axes = ("batch", "heads", "seq", "head_dim")
         engagement.record("paged_attention", kernel)
-        # the kernel's live (row, block) pairs depend on nothing but the
-        # lengths and the table's width: one list for every layer
+        # the kernel's live (row, group of blocks) pairs depend on
+        # nothing but the lengths, the table's width and the pool's
+        # shape: one list for every layer
         work = None
         if kernel in (paged_ops.PALLAS, paged_ops.PALLAS_INTERPRET):
-            work = paged_ops.paged_work(lengths, S_in,
-                                        pools[0]["k"].shape[1],
-                                        block_tables.shape[1])
+            k0 = pools[0]["k"]
+            work = paged_ops.paged_work(
+                lengths, S_in, k0.shape[1], block_tables.shape[1],
+                paged_ops.step_blocks(S_in, k0, pools[0].get("k_scale")))
         new_pools = []
         for lp, pl in zip(params["layers"], pools):
             q, k, v = bert_lib.qkv_proj(lp, h, dt, fused=c.fused_qkv)

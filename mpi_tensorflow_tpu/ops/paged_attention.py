@@ -404,21 +404,58 @@ def work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int):
     return pair // NT, pair % NT, blk, n.astype(jnp.int32), ends[-1]
 
 
-def paged_work(lengths, S: int, bs: int, NB: int):
+def paged_work(lengths, S: int, bs: int, NB: int, G: int = 1):
     """``work_list`` for the K/V kernel, a row's ``S`` queries being one
-    tile: (row, block, blocks of that row, live count).  The same for
-    every layer of a forward, which builds it once and hands it down the
-    ``attend`` seam.
+    tile and a step attending ``G`` consecutive table entries
+    (``step_blocks``): (row, group, groups of that row, live count).
+    The same for every layer of a forward, which builds it once and
+    hands it down the ``attend`` seam.
 
-    Each array is one entry longer than the list's bound ``B * NB``: the
-    kernel's pipeline evaluates the index maps of the step AFTER the one
-    it runs, to fetch ahead, so with every table full (always so at
-    ``B = NB = 1``) it reads one entry past the list — out of the array,
-    whatever scalar memory holds there, as a row and a block of the
-    table; the chip halts on it."""
+    Each array is one entry longer than the list's bound ``B *
+    ceil(NB / G)``: the kernel's pipeline evaluates the index maps of
+    the step AFTER the one it runs, to fetch ahead, so with every table
+    full (always so at ``B = NB = 1``) it reads one entry past the list
+    — out of the array, whatever scalar memory holds there, as a row and
+    a block of the table; the chip halts on it."""
     row, _, blk, n, live = work_list(lengths.astype(jnp.int32), S, S, 1,
-                                     bs, NB)
+                                     bs * G, -(-NB // G))
     return tuple(jnp.pad(x, (0, 1)) for x in (row, blk, n)) + (live,)
+
+
+# keys a decode grid step attends: one 128-lane tile of scores
+DECODE_STEP_KEYS = 128
+# VMEM the decode body's K and V slots (two a pool) may take, of the
+# 16 MiB a Mosaic kernel has by default on a v5e
+DECODE_SLOT_BYTES = 8 << 20
+
+
+def step_blocks(S: int, pool, pool_scale=None) -> int:
+    """Table entries ONE grid step of the K/V kernel attends, from what
+    the kernel sees: a group on the decode body (one query token, an
+    unquantized pool: ops/paged_attention_kernel._decode_kernel), which
+    fetches its blocks itself, one wherever the BlockSpec pipeline
+    fetches them.  THE rule, called by whoever builds the list
+    (``forward_paged``, the kernel's own default) and by the kernel that
+    walks it, so the two cannot disagree.
+
+    A step's cost is its latency, not its bytes, until it moves some
+    hundreds of KB (PERF.md, PR 29 and PR 32), so a step takes
+    ``DECODE_STEP_KEYS`` keys, halved while the four VMEM slots they
+    need (K and V, double-buffered) pass ``DECODE_SLOT_BYTES``: blocks
+    of 128 tokens and up are a group of one."""
+    if S != 1 or pool_mode(pool, pool_scale) != "fp32":
+        return 1
+    bs, row_bytes = pool.shape[1], pool.shape[2] * pool.dtype.itemsize
+    G = max(1, DECODE_STEP_KEYS // bs)
+    while G > 1 and 4 * G * bs * row_bytes > DECODE_SLOT_BYTES:
+        G //= 2
+    if 4 * G * bs * row_bytes > DECODE_SLOT_BYTES:
+        raise ValueError(
+            f"the decode kernel's four VMEM slots (K and V, double-"
+            f"buffered) of one block each take 4 x block_size {bs} x "
+            f"{row_bytes} B a row = {4 * bs * row_bytes} B, over the "
+            f"{DECODE_SLOT_BYTES} B they may: serve a smaller block_size")
+    return G
 
 
 def attend(q, k_pool, v_pool, block_table, lengths, dt, *,

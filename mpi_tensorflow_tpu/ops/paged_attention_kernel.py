@@ -5,29 +5,49 @@ materializes the whole padded contiguous KV view — two pool-sized
 copies per layer per decode token, then a dense masked softmax over the
 full bucketed table width.  This kernel reads the pool **blocks in
 place** through the block table with an fp32 online softmax (the
-PagedAttention / Flash-Decoding recipe, PAPERS.md): per grid step one
-``(block_size, H*D)`` K block and V block stream HBM->VMEM, scores and
-the running (m, l, acc) statistics stay in VMEM scratch, and the
-``(B, H, NB*block_size, D)`` gathered view never exists.
+PagedAttention / Flash-Decoding recipe, PAPERS.md): K and V blocks
+stream HBM->VMEM a grid step at a time, scores and the running (m, l,
+acc) statistics stay in VMEM scratch, and the ``(B, H, NB*block_size,
+D)`` gathered view never exists.
 
-Grid: one dimension, the LIVE (row, kv-block) pairs of the dispatch.
-A row of length ``len_b`` has ``ceil((len_b + S) / block_size)`` live
-blocks (at least one, at most the table's width); the pairs, row-major
-with a row's blocks ascending, are built on the device from ``lengths``
-(``ops/paged_attention.work_list``, the list the latent kernel of
-ops/mla_attention walks too) and the grid's bound is their count, a
-traced value.  The list and the block table ride in as **scalar-
-prefetch** operands, so step ``w``'s BlockSpec index maps pick the
-row's query block (``row[w]``) and the pool block to DMA
-(``bt[row[w], blk[w]]``) before the kernel body runs — the Pallas
-pipeline turns the host-side block table into device-side streamed
-reads with no gather materialization, and a call costs its live blocks,
-not its slots x table bucket.  The pipeline evaluates the maps of the
-step after the one it runs, so the list holds one valid entry past its
-bound (``paged_work``).  The one axis carries each row's
-accumulators through its blocks in order (``"arbitrary"``): a v5e chip
-has one TensorCore, so nothing is lost; a two-core chip would want the
-rows split between the cores first.
+Grid: one dimension, the LIVE (row, step) pairs of the dispatch.  A
+row of length ``len_b`` has ``ceil((len_b + S) / block_size)`` live
+blocks (at least one, at most the table's width) and a step attends
+``G`` consecutive table entries of it (``ops/paged_attention.
+step_blocks``: a group on the decode body, one elsewhere); the pairs,
+row-major with a row's steps ascending, are built on the device from
+``lengths`` (``ops/paged_attention.work_list``, the list the latent
+kernel of ops/mla_attention walks too) and the grid's bound is their
+count, a traced value.  The list and the block table ride in as
+**scalar-prefetch** operands, so a call costs its live steps, not its
+slots x table bucket.  The one axis carries each row's accumulators
+through its steps in order (``"arbitrary"``): a v5e chip has one
+TensorCore, so nothing is lost; a two-core chip would want the rows
+split between the cores first.
+
+Two bodies fetch the pool in two ways:
+
+- ``_paged_kernel`` (prefill chunks, speculative verify, mixed rows,
+  int8 and int4 pools) rides the **BlockSpec pipeline**, one block a
+  step: step ``w``'s index maps pick the row's query block
+  (``row[w]``) and the pool block to DMA (``bt[row[w], blk[w]]``)
+  before the body runs.  The pipeline evaluates the maps of the step
+  after the one it runs, so the list holds one valid entry past its
+  bound (``paged_work``).
+- ``_decode_kernel`` (one query token, an unquantized pool: the hot
+  path) takes the pools in place in HBM (``memory_space=pl.ANY``) and
+  **issues its copies by hand**: a pipeline step costs ~0.5 us
+  whatever it moves, and a 16-token block is 49 KB, so a step takes a
+  GROUP of ``G`` blocks (128 keys: one lane tile of scores) into one
+  of two VMEM slots a pool, ``G`` async copies of K and ``G`` of V
+  addressed from the table (``_group_ids``, ``_group_copies``).  Step
+  ``w`` starts the copies of step ``w + 1`` before it waits for its
+  own, so a group's fetch hides behind the step before it; queries,
+  output and the statistics stay on their BlockSpecs.  A last group's
+  dead tail — null-block entries past the row's allocation, entries
+  clamped to the edge of a table ``G`` does not divide — is fetched
+  like any block: the visibility test rejects its lanes (not issuing
+  those copies measured slower: PERF.md, PR 33).
 
 Masking contract (kept in LOCKSTEP with ops/paged_attention.
 paged_attention — the parity suite in tests/test_paged_kernel.py pins
@@ -46,19 +66,18 @@ tiles, row-major, which is what a Mosaic operand must be AND what the
 runtime's default layout and ``write_kv``'s row scatter already are, so
 the pool reaches the kernel with no re-layout (a 64-wide minor ``D``
 made the runtime rotate ``num_blocks`` minor-most, and every program
-copied every leaf to and fro).  The kernel takes head ``h`` out of the
-row as the static lane slice ``[h*D, (h+1)*D)`` and runs the same
-online softmax per head; ``H`` and ``D`` come from ``q``.  Decode over
-an unquantized pool, the hot path, skips even the slicing: block-
-diagonal queries meet the whole row in one pair of matmuls
-(``_decode_kernel``).
+copied every leaf to and fro).  ``_paged_kernel`` takes head ``h`` out
+of the row as the static lane slice ``[h*D, (h+1)*D)`` and runs the
+same online softmax per head; ``H`` and ``D`` come from ``q``.  The
+decode body skips even the slicing: block-diagonal queries meet the
+whole row in one pair of matmuls.
 
 ``probe_compile()`` compiles the served geometry (decode + every
 prefill bucket, each at its largest dispatch: the table and the list
-must fit scalar memory) up front so a Mosaic refusal surfaces at engine
-build with the compiler's message — it never selects another lowering;
-``interpret=True`` runs the same kernel on CPU for the tier-1 parity
-suite.
+must fit scalar memory, the decode body's slots VMEM) up front so a
+refusal surfaces at engine build in words — it never selects another
+lowering; ``interpret=True`` runs the same kernel on CPU for the tier-1
+parity suite.
 """
 
 from __future__ import annotations
@@ -72,11 +91,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpi_tensorflow_tpu.ops.paged_attention import (paged_work,
-                                                    pool_mode)
+                                                    pool_mode,
+                                                    step_blocks)
 
 # stats rows are lane-broadcast to the f32 tile width, mirroring
 # ops/flash_attention's LSE_LANES treatment of per-row statistics
 STAT_LANES = 128
+# the two bodies' names in a device trace (``_paged_call`` is jitted, so
+# without them every call of either would read ``_paged_call.N``)
+DECODE_KERNEL, PAGED_KERNEL = "paged_decode_attention", "paged_attention"
 
 
 def _dequant_int4_block(codes, scales, dt):
@@ -237,18 +260,55 @@ def _paged_kernel(*refs, scale: float, block_size: int,
         o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel(bt_ref, len_ref, row_ref, blk_ref, n_ref,
-                   q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
-                   scale: float, block_size: int, head_dim: int):
-    """``_paged_kernel`` for a single query token over an unquantized
-    pool — the decode hot path — with ALL heads in one pair of matmuls.
+def _group_ids(bt_ref, b, j, G: int):
+    """The pool block ids of group ``j`` of row ``b``'s table — entries
+    ``[j*G, (j+1)*G)`` — as ``G`` scalars.  A table whose width ``G``
+    does not divide has its last group's entries clamped to the table's
+    edge: the lanes those fill lie past ``NB * bs``, which no length
+    reaches."""
+    NB = bt_ref.shape[1]
+    return [bt_ref[b, jnp.minimum(j * G + g, NB - 1) if NB % G
+                   else j * G + g] for g in range(G)]
+
+
+def _group_copies(ids, pool_ref, buf, sem):
+    """The async copies of pool blocks ``ids`` from HBM into the VMEM
+    slot ``buf`` ``(len(ids)*bs, lanes)``, block ``g`` at rows ``[g*bs,
+    (g+1)*bs)``, all on the one DMA semaphore ``sem``: the hand-issued
+    fetch of one grid step.  ``start()`` each to issue the group;
+    ``wait()`` each before the slot is read — a wait takes the
+    semaphore and the copy's size only, so the waiting side may rebuild
+    the list from any ids (block 0 will do)."""
+    bs = pool_ref.shape[1]
+    return [pltpu.make_async_copy(pool_ref.at[i],
+                                  buf.at[pl.ds(g * bs, bs)], sem)
+            for g, i in enumerate(ids)]
+
+
+def _decode_kernel(bt_ref, len_ref, row_ref, grp_ref, n_ref,
+                   q_ref, k_hbm, v_hbm, o_ref, acc, m_scr, l_scr,
+                   k_buf, v_buf, sems, *,
+                   scale: float, head_dim: int, group: int):
+    """A single query token over an unquantized pool — the decode hot
+    path: step ``w`` of the work list attends GROUP ``j = grp[w]`` of
+    row ``b = row[w]`` — ``group`` consecutive table entries, fetched by
+    hand — with ALL heads in one pair of matmuls.
 
     q_ref:  (1, H, H*D)  — the row's queries laid out block-diagonally:
             row ``h`` holds head ``h``'s query in lanes ``[h*D, (h+1)*D)``
             and exact zeros elsewhere (built by ``_paged_call``)
-    k_ref:  (1, bs, H*D), v_ref: idem — the pool block as stored
+    k_hbm:  (num_blocks, bs, H*D), v_hbm: idem — the pools, in place
     o_ref:  (1, 1, H*D)  — the heads' outputs side by side
-    scratch: acc (H, H*D) f32, m/l (H, STAT_LANES) f32
+    scratch: acc (H, H*D) f32, m/l (H, STAT_LANES) f32; k_buf / v_buf
+            (2, group*bs, H*D) — two slots a pool; sems (2, 2) DMA
+
+    The pools never pass through the BlockSpec pipeline, whose step
+    costs ~0.5 us whatever it moves (PERF.md, PR 29): step ``w`` starts
+    the copies of step ``w + 1`` (its row and group from the list) into
+    the other slot BEFORE it waits for its own, which step ``w - 1``
+    started (step 0 starts its own).  Entries past a row's allocation
+    are the null block and are fetched like any other: the visibility
+    test rejects their lanes.
 
     ``q @ k.T`` over all ``H*D`` lanes is head ``h``'s score in row
     ``h`` (the other heads' lanes meet zeros), and ``p @ v`` is head
@@ -257,14 +317,36 @@ def _decode_kernel(bt_ref, len_ref, row_ref, blk_ref, n_ref,
     and are dropped at the emit.  That spends H times the arithmetic on
     an idle MXU and saves the per-head loop: with one query row a head's
     tiles are a sublane each, and the loop's 2*H tiny matmuls and H
-    softmax updates cost three times this step (0.88 us against the
-    0.28 us a grid step costs at all: PERF.md, PR 25).  Same visibility
-    test, same fp32 online softmax, same work list as ``_paged_kernel``.
+    softmax updates cost three times this step (PERF.md, PR 25).  Same
+    visibility test and fp32 online softmax as ``_paged_kernel``.
     """
     w = pl.program_id(0)
-    b, j = row_ref[w], blk_ref[w]
+    b, j = row_ref[w], grp_ref[w]
     H, HD = q_ref.shape[1:]
-    bs = block_size
+    keys = k_buf.shape[1]                          # group * bs
+    slot = lax.rem(w, 2)
+    stores = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def start(step, slot):
+        ids = _group_ids(bt_ref, row_ref[step], grp_ref[step], group)
+        for i, (pool, buf) in enumerate(stores):
+            for c in _group_copies(ids, pool, buf.at[slot],
+                                   sems.at[i, slot]):
+                c.start()
+
+    def wait(i):
+        pool, buf = stores[i]
+        for c in _group_copies([0] * group, pool, buf.at[slot],
+                               sems.at[i, slot]):
+            c.wait()
+
+    @pl.when(w == 0)
+    def _first():
+        start(w, slot)
+
+    @pl.when(w + 1 < pl.num_programs(0))
+    def _ahead():
+        start(w + 1, 1 - slot)
 
     @pl.when(j == 0)
     def _init():
@@ -272,20 +354,22 @@ def _decode_kernel(bt_ref, len_ref, row_ref, blk_ref, n_ref,
         m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    v = v_ref[0]                                   # (bs, H*D)
+    wait(0)
     s = lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (H, bs)
+        q_ref[0], k_buf[slot], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (H, G*bs)
     # visibility: key position <= query position (= lengths[b])
-    col = j * bs + lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+    col = j * keys + lax.broadcasted_iota(jnp.int32, (H, keys), 1)
     s = jnp.where(col <= len_ref[b], s * scale,
                   jnp.finfo(jnp.float32).min)
     m_prev = m_scr[:, 0:1]                         # (H, 1)
     l_prev = l_scr[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                         # (H, bs)
+    p = jnp.exp(s - m_new)                         # (H, G*bs)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    wait(1)
+    v = v_buf[slot]                                # (G*bs, H*D)
     acc[:] = acc[:] * corr + lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (H, H*D)
@@ -302,6 +386,11 @@ def _decode_kernel(bt_ref, len_ref, row_ref, blk_ref, n_ref,
                            keepdims=True).astype(o_ref.dtype)
 
 
+# jitted so that a forward's layers, which call it on equal shapes,
+# trace it and lower its kernel to Mosaic once a program, not once a
+# layer: the decode body's 3 x 2G copy descriptors made a program's
+# lowering 0.2 s longer on the chip's host, forty programs a set-up
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "mode"))
 def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
                 scale: float, interpret: bool, mode: str,
                 k_scale=None, v_scale=None, k_new=None, v_new=None,
@@ -311,8 +400,9 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
     bs = k_pool.shape[1]
     residual = k_new is not None
     lengths = lengths.astype(jnp.int32)
+    G = step_blocks(S, k_pool, k_scale)
     if work is None:
-        work = paged_work(lengths, S, bs, NB)
+        work = paged_work(lengths, S, bs, NB, G)
     row, blk, n, live = work
 
     def kv_map(w, bt, lens, row, blk, n):
@@ -321,9 +411,9 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
     lane_dense = S == 1 and mode == "fp32"
     if lane_dense:
         # decode over an unquantized pool: block-diagonal queries in,
-        # (1, H*D) rows out (_decode_kernel)
+        # (1, H*D) rows out, the pools in place (_decode_kernel)
         kernel = functools.partial(_decode_kernel, scale=scale,
-                                   block_size=bs, head_dim=D)
+                                   head_dim=D, group=G)
         eye = jnp.eye(H, dtype=q.dtype)[None, :, :, None]
         q = (q[:, :, 0, None, :] * eye).reshape(B, H, H * D)
         q_block, out_block, lead = (1, H, H * D), (1, 1, H * D), (H,)
@@ -345,34 +435,46 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         # step of a row revisits the row's own (1, H, S, D) block
         in_specs += [pl.BlockSpec(q_block, row_map)] * 2
         operands += [k_new, v_new]
-    # every pool leaf is (num_blocks, bs, lanes): codes and, where the
-    # pool is quantized, the scale rows of the SAME block id ride in as
-    # whole (1, bs, lanes) blocks through the one index map
-    for leaf in (k_pool, k_scale, v_pool, v_scale):
-        if leaf is not None:
-            in_specs.append(pl.BlockSpec((1, bs, leaf.shape[-1]), kv_map))
-            operands.append(leaf)
+    scratch = [
+        pltpu.VMEM(acc_shape, jnp.float32),
+        pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
+        pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
+    ]
+    if lane_dense:
+        # the body copies its groups itself: two (G*bs, H*D) slots a pool
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands += [k_pool, v_pool]
+        scratch += [pltpu.VMEM((2, G * bs, H * D), k_pool.dtype),
+                    pltpu.VMEM((2, G * bs, H * D), v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2))]
+    else:
+        # every pool leaf is (num_blocks, bs, lanes): codes and, where
+        # the pool is quantized, the scale rows of the SAME block id ride
+        # in as whole (1, bs, lanes) blocks through the one index map
+        for leaf in (k_pool, k_scale, v_pool, v_scale):
+            if leaf is not None:
+                in_specs.append(
+                    pl.BlockSpec((1, bs, leaf.shape[-1]), kv_map))
+                operands.append(leaf)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(live,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(out_block, row_map),
-        scratch_shapes=[
-            pltpu.VMEM(acc_shape, jnp.float32),
-            pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
-            pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,) + out_block[1:], q.dtype),
         # the one axis carries each row's online-softmax accumulators
-        # through its blocks in order
+        # through its blocks in order (and the decode body's copies
+        # from one step to the next)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=DECODE_KERNEL if lane_dense else PAGED_KERNEL,
     )(block_table.astype(jnp.int32), lengths, row, blk, n, *operands)
     if lane_dense:
         # (B, 1, H*D) rows back to (B, H, 1, D)
@@ -405,9 +507,10 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
                  blocks and dequantizes in register (see _paged_kernel)
     k/v_new:     (B, H, S, D) fp K/V of the query tokens (int4 only,
                  both or neither) — enables the fp-residual self lane
-    work:        the dispatch's ``paged_attention.paged_work`` where the
-                 caller holds it (one list serves every layer of a
-                 forward); None builds it here
+    work:        the dispatch's ``paged_attention.paged_work`` at this
+                 call's ``step_blocks`` where the caller holds it (one
+                 list serves every layer of a forward); None builds it
+                 here
 
     Returns (B, H, S, D) in q.dtype.  Numerically this is the online-
     softmax evaluation of ops/paged_attention.paged_attention over the
@@ -446,8 +549,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                            k_new=None, v_new=None, work=None):
     """Single-token decode specialization (S must be 1) — the serving
     hot path.  Thin wrapper so call sites (and probes) name the phase
-    they are on; the grid is shared with chunked prefill, and so is the
-    kernel body unless the pool is unquantized (``_decode_kernel``)."""
+    they are on; the list's form is shared with chunked prefill, and so
+    is the kernel body unless the pool is unquantized
+    (``_decode_kernel``, a group of blocks a step)."""
     if q.shape[2] != 1:
         raise ValueError(f"decode takes one query token per row, got "
                          f"S={q.shape[2]} (use paged_prefill_attention)")
@@ -488,7 +592,9 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
     uint8 codes + group scales + the fp-residual k_new/v_new operands).
     The table and the work list live in scalar memory and grow with
     rows x table width, so a smaller dispatch fits wherever the largest
-    does, and a geometry too large for scalar memory is refused here.
+    does, and a geometry too large for scalar memory is refused here;
+    so is a block whose four decode slots pass their share of VMEM
+    (``paged_attention.step_blocks`` says so in words).
 
     Returns nothing; a refusal RAISES with the compiler's message, so a
     selected kernel that cannot compile stops the engine at build time

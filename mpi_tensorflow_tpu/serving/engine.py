@@ -586,8 +586,10 @@ class PagedDecodeEngine:
         """Fresh pools/scheduler; jit caches (and their warmed bucket
         shapes) survive — the serving entry point serves its trace
         against exactly the compiles the warm-up replay paid for."""
+        import jax
         import jax.numpy as jnp
 
+        from mpi_tensorflow_tpu.ops import paged_attention as paged_ops
         from mpi_tensorflow_tpu.serving import prefix_cache as prefix_lib
 
         self.pools = paged_cache.init_pools(
@@ -626,6 +628,17 @@ class PagedDecodeEngine:
             from mpi_tensorflow_tpu.serving import tp as tp_lib
 
             self.pools = tp_lib.shard_pools(self.pools, self.tp_mesh)
+        # table entries a decode grid step of the K/V kernel attends,
+        # by the kernel's own rule over the leaf a shard sees (a model
+        # that brings its kernel walks one block a step): what the
+        # ``paged_*`` counters reckon with
+        self._decode_group = 1
+        k = self.pools[0].get("k")
+        if k is not None and not hasattr(self.model, "resolve_kernel"):
+            self._decode_group = paged_ops.step_blocks(
+                1, jax.ShapeDtypeStruct(k.sharding.shard_shape(k.shape),
+                                        k.dtype),
+                self.pools[0].get("k_scale"))
         self.allocator = paged_cache.BlockAllocator(self.serve.num_blocks)
         # fresh trie with fresh pools: cached content lives in the pool,
         # so the two reset together (a stale trie would map new
@@ -699,12 +712,19 @@ class PagedDecodeEngine:
         self.lookahead_discarded_rows = 0
         # what the attention kernel's grid walks over the decode
         # dispatches of this run — each row's live blocks, a slack row's
-        # one (ops/paged_attention.work_list) — beside the rows x table
-        # bucket those dispatches span: their ratio is the share of the
+        # one, in groups of ``_decode_group`` (ops/paged_attention.
+        # work_list, step_blocks) — beside the bound of that list (rows x
+        # groups of the table bucket): their ratio is the share of the
         # bucketed table that is work, and steps over the kernel's
-        # device time is what a step costs
+        # device time is what a step costs.  ``paged_live_blocks`` is
+        # what the contexts oblige a step to read and
+        # ``paged_blocks_fetched`` what the steps copy, a last group's
+        # dead tail included: fetched / live is the waste, live / steps
+        # the blocks a step
         self.paged_grid_steps = 0
         self.paged_grid_bound = 0
+        self.paged_live_blocks = 0
+        self.paged_blocks_fetched = 0
         # prefill chunks that began at position 0: each starts a slot's
         # per-slot state from zero (a model without any counts them too)
         self.state_resets = 0
@@ -1275,9 +1295,13 @@ class PagedDecodeEngine:
             tables[j] = self._table_row(seq, NBb)
         self.dispatch_shapes.add(("decode", Bb, NBb))
         self._count_dispatch()
-        self.paged_grid_steps += int(np.minimum(
-            lengths // self.serve.block_size + 1, NBb).sum())
-        self.paged_grid_bound += Bb * NBb
+        G = self._decode_group
+        blocks = np.minimum(lengths // self.serve.block_size + 1, NBb)
+        steps = int((-(-blocks // G)).sum())
+        self.paged_grid_steps += steps
+        self.paged_grid_bound += Bb * -(-NBb // G)
+        self.paged_live_blocks += int(blocks.sum())
+        self.paged_blocks_fetched += steps * G
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
@@ -1719,10 +1743,13 @@ class PagedDecodeEngine:
             "forward_dispatches": self.forward_dispatches,
             "dispatches_per_token": (self.forward_dispatches
                                      / max(1, total)),
-            # decode dispatches: grid steps of live (row, block) pairs
-            # beside the rows x table bucket they were cut from
+            # decode dispatches: grid steps of live (row, group of
+            # blocks) pairs beside the bound they were cut from, and
+            # the blocks those steps copied beside the live ones
             "paged_grid_steps": self.paged_grid_steps,
             "paged_grid_bound": self.paged_grid_bound,
+            "paged_live_blocks": self.paged_live_blocks,
+            "paged_blocks_fetched": self.paged_blocks_fetched,
             # the one-step lookahead: forward dispatches issued while an
             # earlier one's tokens were unread, and rows computed for a
             # sequence that had left its slot by the time they were read
@@ -1860,6 +1887,11 @@ class PagedDecodeEngine:
             "forward_dispatches": self.forward_dispatches,
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
+            # the decode kernel's grid steps, the live blocks they were
+            # obliged to read and the blocks they copied
+            "paged_grid_steps": self.paged_grid_steps,
+            "paged_live_blocks": self.paged_live_blocks,
+            "paged_blocks_fetched": self.paged_blocks_fetched,
             # the caches by kind (static sizes; ``occupancy`` above is
             # the paged pool's) and the prefill chunks that started a
             # slot's state from zero

@@ -35,9 +35,11 @@ TINY = dataclasses.replace(bert.BERT_TINY, ce_positions="all")
 ROPE = dataclasses.replace(TINY, pos_kind="rope")
 
 
-def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0):
+def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0,
+          lens=None):
     """One randomized kernel-vs-XLA input set, the pools in the stored
-    geometry ``(nblocks, bs, H*D)``.
+    geometry ``(nblocks, bs, H*D)``.  ``lens`` gives every row's length
+    instead of the populations below.
 
     Rows cycle through the interesting populations: full table, ragged
     partial table (null-block tail), and — when B allows — a bucket-
@@ -54,9 +56,11 @@ def _case(rng, B, NB, bs, S, H=2, D=8, ragged=True, poison=0.0):
     lengths = np.zeros((B,), np.int32)
     nxt = 1
     for b in range(B):
-        if b == B - 1 and B > 2:
+        if lens is not None:
+            lengths[b] = lens[b]
+        elif b == B - 1 and B > 2:
             continue                     # bucket-slack row: all-null, len 0
-        if ragged and b % 2 == 1:
+        elif ragged and b % 2 == 1:
             # ragged: a partial allocation with a null-block tail
             lengths[b] = int(rng.integers(0, max(1, (NB - 1) * bs - S + 1)))
         else:
@@ -94,11 +98,34 @@ def _assert_parity(q, k_pool, v_pool, bt, lengths, dead_rows=()):
 class TestKernelParity:
     """Interpret-mode kernel vs the XLA gather path, elementwise."""
 
+    # block 16 is a group of 8 a step, block 4 of 32: table widths under
+    # a group (1, 3; B = NB = 1 fills the list to its bound), of one
+    # group, between groups (12: the last group's entries are clamped
+    # to the table's edge) and of eight
     @pytest.mark.parametrize("B,NB,bs", [(1, 1, 4), (2, 2, 4), (4, 4, 4),
-                                         (8, 2, 8), (2, 4, 16)])
+                                         (8, 2, 8), (2, 4, 16),
+                                         (1, 1, 16), (4, 3, 16),
+                                         (4, 8, 16), (4, 12, 16),
+                                         (3, 64, 16), (4, 40, 4)])
     def test_decode_parity_across_bucket_shapes(self, B, NB, bs):
         rng = np.random.default_rng(B * 100 + NB * 10 + bs)
-        _assert_parity(*_case(rng, B, NB, bs, S=1))
+        dead = (B - 1,) if B > 2 else ()
+        _assert_parity(*_case(rng, B, NB, bs, S=1), dead_rows=dead)
+
+    @pytest.mark.parametrize("live", [1, 7, 8, 9, 16, 17])
+    def test_decode_parity_at_group_edges(self, live):
+        """Rows of fewer live blocks than a group, exactly one group,
+        one block into the next: beside a full row, under a table the
+        group does not divide, every hidden lane poisoned."""
+        bs, NB = 16, 20
+        G = paged_ops.step_blocks(1, jnp.zeros((1, bs, 16), jnp.float32))
+        assert G == 8
+        rng = np.random.default_rng(live)
+        lens = [live * bs - 1, NB * bs - 1, (live - 1) * bs]
+        case = _case(rng, 3, NB, bs, S=1, lens=lens, poison=1e30)
+        steps = paged_ops.paged_work(case[4], 1, bs, NB, G)[3]
+        assert int(steps) == -(-live // G) * 2 + -(-NB // G)
+        _assert_parity(*case)
 
     @pytest.mark.parametrize("S", [2, 4, 8])
     def test_chunked_prefill_parity(self, S):
@@ -117,6 +144,17 @@ class TestKernelParity:
         outputs instead of shifting them by epsilon."""
         rng = np.random.default_rng(42)
         case = _case(rng, 4, 3, 4, S=1, poison=1e30)
+        _assert_parity(*case, dead_rows=(3,))
+        assert np.all(np.isfinite(np.asarray(
+            pk.paged_attention_kernel(*case, interpret=True))))
+
+    def test_a_groups_dead_tail_cannot_leak(self):
+        """The decode body copies a whole group: a last group's dead
+        tail comes from the null block and from a live block's lanes
+        past the row's length, all poisoned here."""
+        rng = np.random.default_rng(43)
+        case = _case(rng, 4, 16, 16, S=1, poison=1e30)
+        assert np.asarray(case[3])[1, -1] == 0      # a null-block tail
         _assert_parity(*case, dead_rows=(3,))
         assert np.all(np.isfinite(np.asarray(
             pk.paged_attention_kernel(*case, interpret=True))))
@@ -209,6 +247,63 @@ class TestWorkList:
                 # one valid entry more: the pipeline reads a step ahead
                 np.testing.assert_array_equal(a, np.append(b, 0))
 
+    @pytest.mark.parametrize("kind", ["zero", "ragged", "edge", "full"])
+    @pytest.mark.parametrize("NB", [1, 3, 8, 12, 64])
+    @pytest.mark.parametrize("G", [1, 4, 8])
+    def test_grouped_list_matches_brute_force(self, G, NB, kind):
+        """``paged_work`` with ``G`` table entries a step: a row's live
+        blocks in groups of ``G``, a slack row's one step, the list one
+        entry longer than its bound."""
+        B, bs = 5, 16
+        rng = np.random.default_rng([G, NB, len(kind)])
+        lens = _lengths_of(kind, rng, B, 1, bs, NB)
+        want = []
+        for b in range(B):
+            blocks = min(max(-(-(int(lens[b]) + 1) // bs), 1), NB)
+            groups = -(-blocks // G)
+            want += [(b, j, groups) for j in range(groups)]
+        row, grp, n, live = paged_ops.paged_work(jnp.asarray(lens), 1, bs,
+                                                 NB, G)
+        assert row.shape == (B * -(-NB // G) + 1,)
+        assert int(live) == len(want)
+        got = list(zip(*(np.asarray(x)[:len(want)].tolist()
+                         for x in (row, grp, n))))
+        assert got == want
+
+    @pytest.mark.parametrize("bs,NB", [(16, 64), (256, 40)])
+    def test_one_block_steps_are_the_list_as_it_was(self, bs, NB):
+        """``ops/diff_attention`` calls ``paged_work(lengths, 1, bs,
+        NB)`` and walks one block a step: entry for entry ``work_list``
+        with one entry more, whatever the decode body's group."""
+        from mpi_tensorflow_tpu.ops import diff_attention
+
+        lens = jnp.asarray(_lengths_of(
+            "ragged", np.random.default_rng(bs), 6, 1, bs, NB))
+        row, _, blk, n, live = paged_ops.work_list(lens, 1, 1, 1, bs, NB)
+        got = diff_attention.decode_work(lens, bs, NB)
+        assert int(got[3]) == int(live)
+        for a, b in zip(got[:3], (row, blk, n)):
+            np.testing.assert_array_equal(a, np.append(b, 0))
+
+    @pytest.mark.parametrize("bs,lanes,dtype,want", [
+        (4, 16, jnp.float32, 32), (16, 768, jnp.bfloat16, 8),
+        (64, 768, jnp.bfloat16, 2), (128, 768, jnp.bfloat16, 1),
+        (256, 768, jnp.bfloat16, 1),
+        # the four VMEM slots of 128 keys pass the budget: halved
+        (16, 16384, jnp.float32, 2)])
+    def test_step_blocks_follows_the_pool(self, bs, lanes, dtype, want):
+        pool = jax.ShapeDtypeStruct((9, bs, lanes), dtype)
+        assert paged_ops.step_blocks(1, pool) == want
+        # prefill chunks and quantized pools stay on the pipeline
+        assert paged_ops.step_blocks(4, pool) == 1
+        codes = jax.ShapeDtypeStruct((9, bs, lanes), jnp.int8)
+        assert paged_ops.step_blocks(1, codes, object()) == 1
+
+    def test_step_blocks_refuses_a_block_past_vmem(self):
+        pool = jax.ShapeDtypeStruct((9, 1024, 4096), jnp.bfloat16)
+        with pytest.raises(ValueError, match="VMEM"):
+            paged_ops.step_blocks(1, pool)
+
     def test_forward_builds_one_list_for_all_layers(self):
         """``forward_paged`` hands its one list down the ``attend``
         seam: one cumsum in the traced step, one kernel call a layer."""
@@ -225,6 +320,12 @@ class TestWorkList:
             "pjit", "jit", "closed_call"))]
         assert names.count("pallas_call") == TINY.layers > 1
         assert names.count("cumsum") == 1
+        # ... and the layers share ONE trace of the kernel's call, so a
+        # program lowers the kernel to Mosaic once, not once a layer
+        calls = [e for e in closed.jaxpr.eqns
+                 if e.params.get("name") == "_paged_call"]
+        assert len(calls) == TINY.layers
+        assert len({id(e.params["jaxpr"]) for e in calls}) == 1
 
 
 class TestPoolGeometry:
@@ -375,6 +476,46 @@ class TestEnginePallas:
         assert engine.sched.evictions >= 1
         assert res["outputs"][0] == _generate_ref(model, params, pa, 10)
         assert res["outputs"][1] == _generate_ref(model, params, pb, 1)
+
+    def test_paged_counters_against_a_hand_count(self):
+        """``paged_grid_steps`` / ``paged_live_blocks`` /
+        ``paged_blocks_fetched`` over a run's decode dispatches, counted
+        again by hand from the lengths and tables each dispatch was
+        given: a step a group of 4 (block 32), dead tails fetched."""
+        model = gpt.CausalLm(ROPE)          # no cap of 128 positions
+        params = model.init(jax.random.key(0))
+        bs = 32
+        engine = PagedDecodeEngine(model, params, ServeConfig(
+            num_blocks=1 + 2 * 8, block_size=bs, max_slots=4,
+            max_seq_len=8 * bs, prefill_chunk=64, kernel="xla"))
+        assert engine._decode_group == 4
+        seen, decode = [], engine._decode_fn
+
+        def spy(params, pools, tokens, lengths, tables, *rest):
+            seen.append((np.asarray(lengths), np.asarray(tables).shape))
+            return decode(params, pools, tokens, lengths, tables, *rest)
+
+        engine._decode_fn = spy
+        rng = np.random.default_rng(5)
+        res = engine.run([
+            Request(i, list(map(int, rng.integers(0, TINY.vocab_size, p))),
+                    n) for i, (p, n) in enumerate([(150, 12), (30, 6)])])
+        assert len(seen) > 10
+        steps = live = fetched = bound = 0
+        for lengths, (rows, width) in seen:
+            for length in lengths.tolist():      # slack rows: length 0
+                blocks = min(length // bs + 1, width)
+                groups = -(-blocks // 4)
+                live, steps = live + blocks, steps + groups
+                fetched += 4 * groups
+            bound += rows * -(-width // 4)
+        assert (res["paged_grid_steps"], res["paged_live_blocks"],
+                res["paged_blocks_fetched"], res["paged_grid_bound"]) \
+            == (steps, live, fetched, bound)
+        assert fetched > live > steps > 0
+        signals = engine.load_signals()
+        assert (signals["paged_grid_steps"], signals["paged_live_blocks"],
+                signals["paged_blocks_fetched"]) == (steps, live, fetched)
 
     def test_zero_recompiles_after_warmup_with_kernel(self):
         """The zero-recompile probe extended to the kernel path: the
@@ -1068,15 +1209,40 @@ class TestMosaicCompile:
                          max_slots=128, max_blocks=64,
                          sharding=tpu_topology_device)
 
-    def test_geometry_past_scalar_memory_is_refused(
-            self, tpu_topology_device):
-        """The table and the work list grow with slots x table width; a
-        geometry they cannot fit raises with the compiler's words and
-        the dispatch it was probed at."""
+    @pytest.mark.parametrize("block_size,table", [(128, 8), (256, 4),
+                                                  (16, 3)])
+    def test_decode_body_compiles_across_groups(
+            self, tpu_topology_device, block_size, table):
+        """The decode body at one-block groups (blocks of 128 and 256
+        tokens) and under a table its group of 8 does not divide."""
         pk.probe_compile.cache_clear()
-        with pytest.raises(RuntimeError, match="512 rows x 128 table"):
-            pk.probe_compile("bfloat16", 12, 64, 16, 1, "fp32", 32,
-                             max_slots=512, max_blocks=128,
+        pk.probe_compile("bfloat16", 12, 64, block_size, 1, "fp32", 32,
+                         max_slots=128, max_blocks=table,
+                         sharding=tpu_topology_device)
+
+    @pytest.mark.parametrize("kv_dtype,slots,table", [
+        ("int8", 512, 128), ("fp32", 2048, 128)])
+    def test_geometry_past_scalar_memory_is_refused(
+            self, tpu_topology_device, kv_dtype, slots, table):
+        """The table and the work list grow with slots x table width (the
+        decode body's list with its groups: an eighth of it at block
+        16); a geometry they cannot fit raises with the compiler's words
+        and the dispatch it was probed at."""
+        pk.probe_compile.cache_clear()
+        with pytest.raises(RuntimeError,
+                           match=f"{slots} rows x {table} table"):
+            pk.probe_compile("bfloat16", 12, 64, 16, 1, kv_dtype, 32,
+                             max_slots=slots, max_blocks=table,
+                             sharding=tpu_topology_device)
+
+    def test_block_past_the_vmem_slots_is_refused_in_words(
+            self, tpu_topology_device):
+        """The decode body holds two slots a pool in VMEM: a block they
+        cannot fit stops the engine's build with the sizes named."""
+        pk.probe_compile.cache_clear()
+        with pytest.raises(RuntimeError, match="VMEM slots.*block_size"):
+            pk.probe_compile("bfloat16", 32, 128, 1024, 1, "fp32", 32,
+                             max_slots=8, max_blocks=4,
                              sharding=tpu_topology_device)
 
 
