@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mpi_tensorflow_tpu.ops import diff_attention as da
+from mpi_tensorflow_tpu.ops import paged_attention as paged_ops
 from mpi_tensorflow_tpu.utils import engagement
 
 
@@ -434,8 +435,8 @@ class Phi4FlashLm:
         q, k, v = self._project(ap, x)
         S = x.shape[1]
         rk, rv = pool["win_k_slot"], pool["win_v_slot"]
-        new = {"win_k_slot": da.write_ring(rk, k, slots, pos, valid),
-               "win_v_slot": da.write_ring(rv, v, slots, pos, valid)}
+        new = {"win_k_slot": paged_ops.write_ring(rk, k, slots, pos, valid),
+               "win_v_slot": paged_ops.write_ring(rv, v, slots, pos, valid)}
         if S == 1 and kernel != "xla":
             # the token's own write first; the ring then holds exactly
             # the keys it may see

@@ -17,7 +17,8 @@ one of two stores that the decode kernel reads alike:
   block_size, Hkv * D)`` under the engine's block tables (written by
   ``write_paged``), which the cross layers read too;
 - a window layer's RING, ``(slots + 1, window, Hkv * D)``: position ``p``
-  of the sequence in slot ``s`` sits at ``[s, p % window]`` (``write_ring``),
+  of the sequence in slot ``s`` sits at ``[s, p % window]``
+  (``paged_attention.write_ring``),
   so after a token's own write the ring holds exactly the keys
   ``p - window < j <= p`` it may see.  With no positional encoding the
   order of keys inside the softmax is free, so a ring IS a pool of
@@ -64,18 +65,6 @@ def write_paged(pool, rows, block_table, positions, valid):
     null block (``paged_attention.write_kv``'s contract)."""
     blk, off = paged_ops._slots(pool, block_table, positions, valid)
     return pool.at[blk, off].set(rows.astype(pool.dtype))
-
-
-def write_ring(ring, rows, slots, positions, valid):
-    """Put token rows ``(B, S, Hkv * D)`` at ``[slot, position % W]``.
-    Of a chunk longer than the window only the last ``W`` valid lanes
-    are written (the others would be overwritten at once, in an order a
-    scatter does not promise); invalid lanes go to the slack row."""
-    W = ring.shape[1]
-    last = jnp.max(jnp.where(valid, positions, -1), axis=1, keepdims=True)
-    keep = valid & (positions > last - W)
-    where = jnp.where(keep, slots[:, None], ring.shape[0] - 1)
-    return ring.at[where, positions % W].set(rows.astype(ring.dtype))
 
 
 def ring_positions(start, W: int):

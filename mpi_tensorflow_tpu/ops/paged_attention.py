@@ -78,7 +78,9 @@ def masked_softmax_attention(q, k, v, vis, dt, scale=None):
     token-parity guarantee between them holds by construction.
 
     q:    (B, H, S, D) queries
-    k, v: (B, H, L, D) position-ordered keys/values
+    k, v: (B, Hkv, L, D) position-ordered keys/values; ``Hkv`` divides
+          ``H`` and KV head ``j`` serves query heads ``[j*G, (j+1)*G)``,
+          ``G = H / Hkv`` (grouped-query attention; 1 is plain MHA)
     vis:  bool, broadcastable to (B, S, L) — True where the key lane is
           visible to the query row
     dt:   compute dtype for the probability @ V contraction
@@ -89,10 +91,19 @@ def masked_softmax_attention(q, k, v, vis, dt, scale=None):
     exact 0.0.
     """
     scale = q.shape[-1] ** -0.5 if scale is None else scale
+    B, H, S, D = q.shape
+    G = H // k.shape[1]
+    if G > 1:
+        # grouped-query heads: KV head j serves query heads [jG, (j+1)G),
+        # whose S rows each stack into one (G*S)-row block of queries
+        q = q.reshape(B, H // G, G * S, D)
+        vis = jnp.tile(jnp.broadcast_to(vis, (B, 1, S, k.shape[2])),
+                       (1, 1, G, 1))
     s = jnp.einsum("bhsd,bhld->bhsl", q, k).astype(jnp.float32)
     s = jnp.where(vis, s * scale, jnp.finfo(jnp.float32).min)
     p = jax.nn.softmax(s, axis=-1).astype(dt)
-    return jnp.einsum("bhsl,bhld->bhsd", p, v)
+    out = jnp.einsum("bhsl,bhld->bhsd", p, v)
+    return out.reshape(B, H, S, D) if G > 1 else out
 
 
 def write_kv(pool, kv, block_table, positions, valid):
@@ -112,6 +123,21 @@ def write_kv(pool, kv, block_table, positions, valid):
     blk, off = _slots(pool, block_table, positions, valid)
     # one whole (H*D,) row per token: pool[blk[b,s], off[b,s], :]
     return pool.at[blk, off].set(_token_rows(kv).astype(pool.dtype))
+
+
+def write_ring(ring, rows, slots, positions, valid):
+    """Put token rows ``(B, S, lanes)`` of a sliding-window layer at
+    ``[slot, position % W]`` of its RING ``(slots + 1, W, lanes)``, so
+    that after a token's own write the ring holds exactly the keys
+    ``p - W < j <= p`` it may see.  Of a chunk longer than the window
+    only the last ``W`` valid lanes are written (the others would be
+    overwritten at once, in an order a scatter does not promise);
+    invalid lanes go to the slack row, the last."""
+    W = ring.shape[1]
+    last = jnp.max(jnp.where(valid, positions, -1), axis=1, keepdims=True)
+    keep = valid & (positions > last - W)
+    where = jnp.where(keep, slots[:, None], ring.shape[0] - 1)
+    return ring.at[where, positions % W].set(rows.astype(ring.dtype))
 
 
 def _slots(pool, block_table, positions, valid):
@@ -302,13 +328,15 @@ def _head_rows(g, heads: int):
     return jnp.moveaxis(g, 2, 1)
 
 
-def paged_attention(q, ck, cv, q_positions, dt):
+def paged_attention(q, ck, cv, q_positions, dt, window=None):
     """Masked causal attention over a gathered paged cache.
 
     q:           (B, H, S, D) query block (S=1 decode, S=chunk prefill)
-    ck, cv:      (B, H, L, D) gathered keys/values (gather_kv)
+    ck, cv:      (B, Hkv, L, D) gathered keys/values (gather_kv)
     q_positions: (B, S) absolute positions of the queries
     dt:          compute dtype for the probability @ V contraction
+    window:      None, or W: a key at ``col`` is visible to a query at
+                 ``p`` only while ``p - W < col`` (sliding window)
 
     The math IS models/gpt.forward_with_cache's attention
     (``masked_softmax_attention``): the greedy token-parity test pins
@@ -327,6 +355,8 @@ def paged_attention(q, ck, cv, q_positions, dt):
     col = jnp.arange(L)
     # (B, S, L): key position <= query position, per row
     vis = col[None, None, :] <= q_positions[:, :, None]
+    if window is not None:
+        vis = vis & (col[None, None, :] > q_positions[:, :, None] - window)
     return masked_softmax_attention(q, ck, cv, vis[:, None], dt)
 
 
@@ -375,27 +405,38 @@ def paged_attention_self_residual(q, ck, cv, q_positions, dt, k_new,
             + p_self[..., None].astype(dt) * v_new.astype(dt))
 
 
-def work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int):
+def work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int,
+              window=None):
     """The live (row, query tile, kv block) triples of one dispatch in
     execution order: THE work list of the Pallas attention kernels
-    (ops/paged_attention_kernel, whose rows are one tile; ops/
-    mla_attention), built on the device from ``lengths`` and passed to
-    the kernel as scalar-prefetch operands.
+    (ops/paged_attention_kernel; ops/mla_attention), built on the device
+    from ``lengths`` and passed to the kernel as scalar-prefetch
+    operands.
 
     Tile ``t`` of row ``b`` holds query tokens ``[t*tq, (t+1)*tq)`` and
     needs the blocks that hold positions up to its last real token: the
     step's own tokens were scattered into the pool before attention, so
     lanes up to ``length + S`` are real and everything past them is
     null-block padding.  A slack row (all-null table, length 0) keeps
-    one step, and the garbage the engine discards.
+    one step, and the garbage the engine discards.  Under a sliding
+    ``window`` W a tile starts at its FIRST block that holds a key its
+    first query sees (``first_block``): the blocks wholly below
+    ``p - W + 1`` are never walked.
     Returns int32 arrays of the static bound ``B * NT * NB`` (row, tile,
-    block, blocks of that tile) and the live count: entries past it are
-    never run."""
+    block, end of that tile's blocks) and the live count: entries past
+    it are never run.  Without a window a tile's blocks start at 0, so
+    its end is also its count."""
     B = lengths.shape[0]
     last = jnp.minimum((jnp.arange(NT, dtype=jnp.int32) + 1) * tq, S)
     need = jnp.clip((lengths[:, None] + last[None, :] + bs - 1) // bs,
                     1, NB).reshape(-1)                     # (B * NT,)
-    ends = jnp.cumsum(need)
+    count = need
+    if window is not None:
+        first = jnp.arange(NT, dtype=jnp.int32) * tq
+        count = need - jnp.minimum(
+            first_block(lengths[:, None] + first[None, :], window,
+                        bs).reshape(-1), need - 1)
+    ends = jnp.cumsum(count)
     w = jnp.arange(B * NT * NB, dtype=jnp.int32)
     pair = jnp.minimum(jnp.searchsorted(ends, w, side="right"),
                        B * NT - 1).astype(jnp.int32)
@@ -404,12 +445,22 @@ def work_list(lengths, S: int, tq: int, NT: int, bs: int, NB: int):
     return pair // NT, pair % NT, blk, n.astype(jnp.int32), ends[-1]
 
 
-def paged_work(lengths, S: int, bs: int, NB: int, G: int = 1):
+def first_block(p, window, keys: int):
+    """The first step of ``keys`` keys that holds a key visible to a
+    query at position ``p`` under a sliding ``window``: the one holding
+    ``p - window + 1``, or 0.  The work list starts a tile there, and
+    the kernels initialise a row's statistics there (``keys`` a power of
+    two: a shift on the chip's scalar unit)."""
+    return jax.lax.div(jnp.maximum(p - window + 1, 0), keys)
+
+
+def paged_work(lengths, S: int, bs: int, NB: int, G: int = 1,
+               window=None):
     """``work_list`` for the K/V kernel, a row's ``S`` queries being one
     tile and a step attending ``G`` consecutive table entries
-    (``step_blocks``): (row, group, groups of that row, live count).
-    The same for every layer of a forward, which builds it once and
-    hands it down the ``attend`` seam.
+    (``step_blocks``): (row, group, end of that row's groups, live
+    count).  The same for every layer of a forward, which builds it once
+    and hands it down the ``attend`` seam.
 
     Each array is one entry longer than the list's bound ``B *
     ceil(NB / G)``: the kernel's pipeline evaluates the index maps of
@@ -418,7 +469,7 @@ def paged_work(lengths, S: int, bs: int, NB: int, G: int = 1):
     — out of the array, whatever scalar memory holds there, as a row and
     a block of the table; the chip halts on it."""
     row, _, blk, n, live = work_list(lengths.astype(jnp.int32), S, S, 1,
-                                     bs * G, -(-NB // G))
+                                     bs * G, -(-NB // G), window)
     return tuple(jnp.pad(x, (0, 1)) for x in (row, blk, n)) + (live,)
 
 
@@ -460,14 +511,17 @@ def step_blocks(S: int, pool, pool_scale=None) -> int:
 
 def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
            kernel: str = "xla", k_scale=None, v_scale=None,
-           k_new=None, v_new=None, work=None):
+           k_new=None, v_new=None, work=None, window=None):
     """THE paged-attention dispatch seam: one entry point, two lowering
     strategies, identical greedy tokens (tests/test_paged_kernel.py).
 
     q:           (B, H, S, D) queries at positions [lengths[b],
                  lengths[b] + S) — their K/V already scattered into the
                  pools (write_kv runs first)
-    k/v_pool:    (num_blocks, block_size, H*D)
+    k/v_pool:    (num_blocks, block_size, Hkv*D): ``Hkv`` KV heads, read
+                 off an unquantized pool's width; KV head ``j`` serves
+                 query heads ``[j*G, (j+1)*G)``, ``G = H / Hkv``
+                 (grouped-query attention; quantized pools hold ``H``)
     block_table: (B, NB) int32
     lengths:     (B,) int32 cache entries already present per row
     kernel:      "xla" (gather + dense masked softmax), "pallas"
@@ -495,6 +549,9 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
                  (a forward builds one for all its layers); None lets a
                  Pallas lowering build its own.  The XLA path has no use
                  for it.
+    window:      None, or a sliding window W: a key at position ``col``
+                 is visible to a query at ``p`` iff ``p - W < col <= p``
+                 (the kernels' lists skip the blocks wholly below it)
 
     MIXED-ROW CONTRACT: ``lengths`` is per-row and the causal mask is
     built per row from it (``pos = lengths[:, None] + arange(S)``), so
@@ -524,7 +581,7 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
         return fused(q, k_pool, v_pool, block_table, lengths,
                      interpret=kernel == PALLAS_INTERPRET,
                      k_scale=k_scale, v_scale=v_scale,
-                     k_new=k_new, v_new=v_new, work=work)
+                     k_new=k_new, v_new=v_new, work=work, window=window)
     if kernel != "xla":
         raise ValueError(
             f"unresolved paged-attention kernel {kernel!r}: callers "
@@ -540,12 +597,29 @@ def attend(q, k_pool, v_pool, block_table, lengths, dt, *,
         ck = _gather_kv_dequant(k_pool, k_scale, block_table, H, q.dtype)
         cv = _gather_kv_dequant(v_pool, v_scale, block_table, H, q.dtype)
     else:
-        ck = gather_kv(k_pool, block_table, H)
-        cv = gather_kv(v_pool, block_table, H)
+        Hkv = kv_heads(q, k_pool, k_scale)
+        ck = gather_kv(k_pool, block_table, Hkv)
+        cv = gather_kv(v_pool, block_table, Hkv)
     if k_new is not None:
         return paged_attention_self_residual(q, ck, cv, pos, dt,
                                              k_new, v_new)
-    return paged_attention(q, ck, cv, pos, dt)
+    return paged_attention(q, ck, cv, pos, dt, window)
+
+
+def kv_heads(q, pool, pool_scale=None) -> int:
+    """The KV heads a pool holds for queries ``q`` (B, H, S, D): its
+    width over the head's for an unquantized pool, which may group the
+    query heads (``H`` a multiple of it), else ``H``.  Grouped heads over
+    quantized codes are refused: their scales are per query head."""
+    H, D = q.shape[1], q.shape[-1]
+    if pool_scale is not None:
+        return H
+    Hkv = pool.shape[-1] // D
+    if Hkv * D != pool.shape[-1] or H % Hkv:
+        raise ValueError(
+            f"a pool of {pool.shape[-1]} lanes holds no whole number of "
+            f"{D}-wide KV heads dividing the {H} query heads")
+    return Hkv
 
 
 def _gather_kv_dequant(pool, pool_scale, block_table, heads: int, dt):

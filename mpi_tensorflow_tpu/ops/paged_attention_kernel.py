@@ -50,10 +50,21 @@ Two bodies fetch the pool in two ways:
   those copies measured slower: PERF.md, PR 33).
 
 Masking contract (kept in LOCKSTEP with ops/paged_attention.
-paged_attention — the parity suite in tests/test_paged_kernel.py pins
-it): a key lane at absolute position ``col = j*block_size + offset`` is
-visible iff ``col <= q_position``; invisible lanes score
-``finfo(f32).min`` so their softmax weight underflows to exact 0.0.
+paged_attention — the parity suites in tests/test_paged_kernel.py and
+tests/test_cohere2_moe.py pin it): a key lane at absolute position ``col
+= j*block_size + offset`` is visible iff ``col <= q_position`` and,
+under a sliding ``window`` W, ``q_position - W < col`` (``_visible``; the
+list then starts a row's or tile's steps at its first block that holds
+a visible key); invisible lanes score ``finfo(f32).min`` so their
+softmax weight underflows to exact 0.0.
+
+Grouped-query attention: an unquantized pool may hold ``Hkv`` KV heads
+for ``H = G * Hkv`` query heads (KV head ``j`` serving ``[j*G,
+(j+1)*G)``).  Such a call is named apart in a device trace: decode runs
+``_decode_kernel`` with head-major queries, a KV head's ``G`` rows a
+matmul (``gqa_decode_attention``), and a prefill chunk runs
+``_grouped_kernel`` on a grid of (KV head, (row, query tile, block))
+(``gqa_prefill_attention``).  ``G = 1`` lowers as it did.
 Null-block (block 0) lanes and bucket-slack rows need no special
 branch: null blocks only back table entries past a row's allocation,
 whose positions the visibility test already rejects, and slack rows
@@ -90,16 +101,44 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_tensorflow_tpu.ops.paged_attention import (paged_work,
+from mpi_tensorflow_tpu.ops.paged_attention import (first_block,
+                                                    kv_heads, paged_work,
                                                     pool_mode,
-                                                    step_blocks)
+                                                    step_blocks,
+                                                    work_list)
 
 # stats rows are lane-broadcast to the f32 tile width, mirroring
 # ops/flash_attention's LSE_LANES treatment of per-row statistics
 STAT_LANES = 128
-# the two bodies' names in a device trace (``_paged_call`` is jitted, so
-# without them every call of either would read ``_paged_call.N``)
+# the bodies' names in a device trace (``_paged_call`` is jitted, so
+# without them every call of any would read ``_paged_call.N``); a
+# grouped-query call (fewer KV heads than query heads) is named apart
 DECODE_KERNEL, PAGED_KERNEL = "paged_decode_attention", "paged_attention"
+GQA_DECODE_KERNEL = "gqa_decode_attention"
+GQA_PREFILL_KERNEL = "gqa_prefill_attention"
+# query rows (query heads of a group x the tile's tokens) one step of the
+# grouped prefill body scores at once
+GQA_TILE_ROWS = 1024
+
+
+def _visible(col, qpos, window):
+    """The masking contract: key ``col`` is visible to a query at
+    ``qpos`` iff ``col <= qpos`` and, under a sliding window ``W``,
+    ``qpos - W < col``."""
+    vis = col <= qpos
+    if window is not None:
+        vis = vis & (col > qpos - window)
+    return vis
+
+
+def query_tile(S: int, G: int) -> int:
+    """Tokens of a chunk one step of the grouped prefill body takes: the
+    largest power of two dividing ``S`` whose ``G`` query heads fit
+    ``GQA_TILE_ROWS`` rows (one row a token and head)."""
+    tq = 1
+    while tq * 2 <= max(1, GQA_TILE_ROWS // G) and S % (tq * 2) == 0:
+        tq *= 2
+    return tq
 
 
 def _dequant_int4_block(codes, scales, dt):
@@ -146,7 +185,8 @@ def _head_block(ref, scale_ref, h: int, H: int, mode: str, dt):
 
 
 def _paged_kernel(*refs, scale: float, block_size: int,
-                  mode: str = "fp32", residual: bool = False):
+                  mode: str = "fp32", residual: bool = False,
+                  window=None):
     """One live (row, kv-block) pair of the online softmax: step ``w``
     of the work list is block ``j = blk[w]`` of row ``b = row[w]``, which
     has ``n[w]`` live blocks.
@@ -177,6 +217,10 @@ def _paged_kernel(*refs, scale: float, block_size: int,
       GROUP scales; ``_dequant_int4_block`` unpacks + dequantizes in
       register (the dequantize_kv_int4 contract).
 
+    ``window`` W (a sliding-window layer) hides keys at or below
+    ``qpos - W`` too; the list then starts a row at its first block that
+    holds a visible key, where the statistics start.
+
     ``residual`` (int4 only) adds the KIVI fp-residual self lane: two
     more refs kn_ref/vn_ref — ``(1, H, S, D)`` fp K/V of exactly the
     query tokens (q_map-indexed, revisited each step).  Where a score
@@ -203,14 +247,18 @@ def _paged_kernel(*refs, scale: float, block_size: int,
     H, S, D = q_ref.shape[1:]
     bs = block_size
 
-    @pl.when(j == 0)
+    # a row's first step: 0, or under a window the list's own start
+    first = 0 if window is None else first_block(len_ref[b], window, bs)
+
+    @pl.when(j == first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    # visibility: key position <= query position, exactly the XLA
-    # path's mask (q positions are lengths[b] + [0, S))
+    # visibility: key position <= query position (and inside the
+    # window), exactly the XLA path's mask (q positions are
+    # lengths[b] + [0, S))
     col = j * bs + lax.broadcasted_iota(jnp.int32, (S, bs), 1)
     qpos = len_ref[b] + lax.broadcasted_iota(jnp.int32, (S, bs), 0)
     self_m = col == qpos if residual else None     # (S, bs)
@@ -229,7 +277,7 @@ def _paged_kernel(*refs, scale: float, block_size: int,
                 * kn_ref[0, h].astype(jnp.float32),
                 axis=-1, keepdims=True)            # (S, 1)
             s = jnp.where(self_m, s_self, s)
-        s = jnp.where(col <= qpos, s * scale,
+        s = jnp.where(_visible(col, qpos, window), s * scale,
                       jnp.finfo(jnp.float32).min)
         m_prev = m_scr[h, :, 0:1]                  # (S, 1)
         l_prev = l_scr[h, :, 0:1]
@@ -288,11 +336,13 @@ def _group_copies(ids, pool_ref, buf, sem):
 def _decode_kernel(bt_ref, len_ref, row_ref, grp_ref, n_ref,
                    q_ref, k_hbm, v_hbm, o_ref, acc, m_scr, l_scr,
                    k_buf, v_buf, sems, *,
-                   scale: float, head_dim: int, group: int):
+                   scale: float, head_dim: int, group: int,
+                   heads_per_kv: int = 1, window=None):
     """A single query token over an unquantized pool — the decode hot
     path: step ``w`` of the work list attends GROUP ``j = grp[w]`` of
     row ``b = row[w]`` — ``group`` consecutive table entries, fetched by
-    hand — with ALL heads in one pair of matmuls.
+    hand — with ALL heads in one pair of matmuls, or with grouped-query
+    heads one pair a KV head (below).
 
     q_ref:  (1, H, H*D)  — the row's queries laid out block-diagonally:
             row ``h`` holds head ``h``'s query in lanes ``[h*D, (h+1)*D)``
@@ -319,10 +369,20 @@ def _decode_kernel(bt_ref, len_ref, row_ref, grp_ref, n_ref,
     tiles are a sublane each, and the loop's 2*H tiny matmuls and H
     softmax updates cost three times this step (PERF.md, PR 25).  Same
     visibility test and fp32 online softmax as ``_paged_kernel``.
+
+    Grouped-query heads (``heads_per_kv`` G > 1: the pool holds ``Hkv =
+    H / G`` heads): q_ref is ``(1, H, D)`` head-major, o_ref ``(1, H,
+    D)``, acc ``(H, D)``, and KV head ``k``'s lanes ``[k*D, (k+1)*D)``
+    of the slot are scored against ITS G query rows ``[k*G, (k+1)*G)``
+    as one ``(G, D) x (D, keys)`` matmul, then weigh its values in
+    another: no arithmetic spent on other heads' lanes.  ``window``
+    (only where the pool is not already a window's ring) hides keys at
+    or below ``p - W``; the list starts a row at its first group that
+    holds a visible key.
     """
     w = pl.program_id(0)
     b, j = row_ref[w], grp_ref[w]
-    H, HD = q_ref.shape[1:]
+    H = q_ref.shape[1]
     keys = k_buf.shape[1]                          # group * bs
     slot = lax.rem(w, 2)
     stores = ((k_hbm, k_buf), (v_hbm, v_buf))
@@ -348,19 +408,29 @@ def _decode_kernel(bt_ref, len_ref, row_ref, grp_ref, n_ref,
     def _ahead():
         start(w + 1, 1 - slot)
 
-    @pl.when(j == 0)
+    first = 0 if window is None else first_block(len_ref[b], window, keys)
+
+    @pl.when(j == first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
         l_scr[:] = jnp.zeros_like(l_scr)
 
+    if heads_per_kv > 1:
+        _grouped_decode_step(j, len_ref[b], q_ref, o_ref, acc, m_scr,
+                             l_scr, k_buf.at[slot], v_buf.at[slot],
+                             lambda: wait(0), lambda: wait(1),
+                             n_ref[w], scale=scale, head_dim=head_dim,
+                             heads_per_kv=heads_per_kv, window=window)
+        return
+    HD = q_ref.shape[2]
     wait(0)
     s = lax.dot_general(
         q_ref[0], k_buf[slot], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (H, G*bs)
     # visibility: key position <= query position (= lengths[b])
     col = j * keys + lax.broadcasted_iota(jnp.int32, (H, keys), 1)
-    s = jnp.where(col <= len_ref[b], s * scale,
+    s = jnp.where(_visible(col, len_ref[b], window), s * scale,
                   jnp.finfo(jnp.float32).min)
     m_prev = m_scr[:, 0:1]                         # (H, 1)
     l_prev = l_scr[:, 0:1]
@@ -386,42 +456,206 @@ def _decode_kernel(bt_ref, len_ref, row_ref, grp_ref, n_ref,
                            keepdims=True).astype(o_ref.dtype)
 
 
+def _grouped_decode_step(j, p, q_ref, o_ref, acc, m_scr, l_scr, k, v,
+                         wait_k, wait_v, n, *, scale: float,
+                         head_dim: int, heads_per_kv: int, window):
+    """``_decode_kernel``'s step for grouped-query heads: group ``j`` of
+    a row whose query sits at ``p`` (``k``, ``v``: the VMEM slot the
+    step's copies land in, ``(G*bs, Hkv*D)``), each KV head against its
+    own ``heads_per_kv`` query rows; V is waited for after every score."""
+    D, G = head_dim, heads_per_kv
+    Hkv = k.shape[1] // D
+    keys = k.shape[0]
+    col = j * keys + lax.broadcasted_iota(jnp.int32, (G, keys), 1)
+    vis = _visible(col, p, window)
+    wait_k()
+    probs = []
+    for h in range(Hkv):
+        rows = slice(h * G, (h + 1) * G)
+        s = lax.dot_general(
+            q_ref[0, rows, :], k[:, h * D:(h + 1) * D],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)    # (G, G*bs)
+        s = jnp.where(vis, s * scale, jnp.finfo(jnp.float32).min)
+        m_prev = m_scr[rows, 0:1]                  # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[rows] = jnp.broadcast_to(
+            l_scr[rows, 0:1] * corr + jnp.sum(pr, axis=-1, keepdims=True),
+            (G, STAT_LANES))
+        m_scr[rows] = jnp.broadcast_to(m_new, (G, STAT_LANES))
+        probs.append((pr, corr))
+    wait_v()
+    for h, (pr, corr) in enumerate(probs):
+        rows = slice(h * G, (h + 1) * G)
+        vh = v[:, h * D:(h + 1) * D]               # (G*bs, D)
+        acc[rows] = acc[rows] * corr + lax.dot_general(
+            pr.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)    # (G, D)
+
+    @pl.when(j == n - 1)
+    def _emit():
+        l = l_scr[:, 0:1]
+        o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _grouped_kernel(bt_ref, len_ref, row_ref, tile_ref, blk_ref, n_ref,
+                    q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
+                    scale: float, block_size: int, window):
+    """Grouped-query attention of a prefill chunk: grid step ``(h, w)``
+    is KV head ``h`` against list entry ``w`` — block ``j = blk[w]`` of
+    tile ``t = tile[w]`` of row ``b = row[w]``, whose ``tq`` query tokens
+    sit at ``lengths[b] + t*tq + [0, tq)``.
+
+    q_ref:  (1, G, tq, D)  — the tile of KV head h's G query heads
+    k_ref:  (1, bs, D)     — KV head h's lanes of pool block ``bt[b, j]``
+    v_ref:  (1, bs, D)
+    o_ref:  (1, G, tq, D)  — written at the tile's last block
+    scratch: acc (G*tq, D) f32, m/l (G*tq, STAT_LANES) f32
+
+    The G heads' tiles are ONE ``(G*tq, D) x (D, bs)`` matmul: row ``r``
+    is head ``r // tq``'s query ``r % tq`` (``tq`` a power of two).  A
+    chunk of S tokens and G heads would need ``G*S`` rows of scores and
+    statistics at once; tiles keep a step's VMEM to a few MB whatever
+    the chunk, and a tile's list stops at ITS last query's block (and,
+    under a window, starts at its first query's first visible one)."""
+    w = pl.program_id(1)
+    b, t, j = row_ref[w], tile_ref[w], blk_ref[w]
+    G, tq, D = q_ref.shape[1:]
+    rows, bs = G * tq, block_size
+    p0 = len_ref[b] + t * tq                       # the tile's first query
+    first = 0 if window is None else first_block(p0, window, bs)
+
+    @pl.when(j == first)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, jnp.finfo(jnp.float32).min)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    q = q_ref[0].reshape(rows, D)
+    s = lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # (rows, bs)
+    col = j * bs + lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+    qpos = p0 + (lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
+                 & (tq - 1))
+    s = jnp.where(_visible(col, qpos, window), s * scale,
+                  jnp.finfo(jnp.float32).min)
+    m_prev, l_prev = m_scr[:, 0:1], l_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[0]
+    acc[:] = acc[:] * corr + lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (rows, D)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == n_ref[w] - 1)
+    def _emit():
+        l = l_scr[:, 0:1]
+        o = acc[:] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = o.reshape(G, tq, D).astype(o_ref.dtype)
+
+
+def _grouped_prefill_call(q, k_pool, v_pool, block_table, lengths, *,
+                          scale: float, interpret: bool, window):
+    """``_grouped_kernel`` over a chunk: the list is (row, tile, block)
+    triples built here at the chunk's tile (``query_tile``), one entry
+    past its bound for the pipeline's look-ahead, and every KV head
+    walks it (the grid's outer axis)."""
+    B, H, S, D = q.shape
+    Hkv = k_pool.shape[-1] // D
+    G, NB, bs = H // Hkv, block_table.shape[1], k_pool.shape[1]
+    tq = query_tile(S, G)
+    row, tile, blk, n, live = work_list(lengths, S, tq, S // tq, bs, NB,
+                                        window)
+    row, tile, blk, n = (jnp.pad(x, (0, 1)) for x in (row, tile, blk, n))
+
+    def q_map(h, w, bt, lens, row, tile, blk, n):
+        return (row[w], h, tile[w], 0)
+
+    def kv_map(h, w, bt, lens, row, tile, blk, n):
+        return (bt[row[w], blk[w]], 0, h)
+
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, scale=scale, block_size=bs,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(Hkv, live),
+            in_specs=[pl.BlockSpec((1, G, tq, D), q_map),
+                      pl.BlockSpec((1, bs, D), kv_map),
+                      pl.BlockSpec((1, bs, D), kv_map)],
+            out_specs=pl.BlockSpec((1, G, tq, D), q_map),
+            scratch_shapes=[pltpu.VMEM((G * tq, D), jnp.float32),
+                            pltpu.VMEM((G * tq, STAT_LANES), jnp.float32),
+                            pltpu.VMEM((G * tq, STAT_LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        # both axes carry a tile's accumulators through its blocks in
+        # order (a v5e chip has one TensorCore)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=GQA_PREFILL_KERNEL,
+    )(block_table.astype(jnp.int32), lengths, row, tile, blk, n,
+      q, k_pool, v_pool)
+
+
 # jitted so that a forward's layers, which call it on equal shapes,
 # trace it and lower its kernel to Mosaic once a program, not once a
 # layer: the decode body's 3 x 2G copy descriptors made a program's
 # lowering 0.2 s longer on the chip's host, forty programs a set-up
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "mode"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "mode", "window"))
 def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
                 scale: float, interpret: bool, mode: str,
                 k_scale=None, v_scale=None, k_new=None, v_new=None,
-                work=None):
+                work=None, window=None):
     B, H, S, D = q.shape
     NB = block_table.shape[1]
     bs = k_pool.shape[1]
     residual = k_new is not None
     lengths = lengths.astype(jnp.int32)
+    Hkv = kv_heads(q, k_pool, k_scale)
+    if S > 1 and Hkv < H:
+        return _grouped_prefill_call(q, k_pool, v_pool, block_table,
+                                     lengths, scale=scale,
+                                     interpret=interpret, window=window)
     G = step_blocks(S, k_pool, k_scale)
     if work is None:
-        work = paged_work(lengths, S, bs, NB, G)
+        work = paged_work(lengths, S, bs, NB, G, window)
     row, blk, n, live = work
 
     def kv_map(w, bt, lens, row, blk, n):
         return (bt[row[w], blk[w]], 0, 0)
 
     lane_dense = S == 1 and mode == "fp32"
-    if lane_dense:
+    name = PAGED_KERNEL
+    if lane_dense and Hkv < H:
+        # grouped-query decode: head-major queries in and out, a KV
+        # head's G query rows against its lanes (_grouped_decode_step)
+        kernel = functools.partial(_decode_kernel, scale=scale,
+                                   head_dim=D, group=G,
+                                   heads_per_kv=H // Hkv, window=window)
+        q = q.reshape(B, H, D)
+        q_block = out_block = (1, H, D)
+        lead, acc_shape, name = (H,), (H, D), GQA_DECODE_KERNEL
+    elif lane_dense:
         # decode over an unquantized pool: block-diagonal queries in,
         # (1, H*D) rows out, the pools in place (_decode_kernel)
         kernel = functools.partial(_decode_kernel, scale=scale,
-                                   head_dim=D, group=G)
+                                   head_dim=D, group=G, window=window)
         eye = jnp.eye(H, dtype=q.dtype)[None, :, :, None]
         q = (q[:, :, 0, None, :] * eye).reshape(B, H, H * D)
         q_block, out_block, lead = (1, H, H * D), (1, 1, H * D), (H,)
-        acc_shape = (H, H * D)
+        acc_shape, name = (H, H * D), DECODE_KERNEL
     else:
         kernel = functools.partial(_paged_kernel, scale=scale,
                                    block_size=bs, mode=mode,
-                                   residual=residual)
+                                   residual=residual, window=window)
         q_block = out_block = (1, H, S, D)
         lead, acc_shape = (H, S), (H, S, D)
 
@@ -441,11 +675,13 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         pltpu.VMEM(lead + (STAT_LANES,), jnp.float32),
     ]
     if lane_dense:
-        # the body copies its groups itself: two (G*bs, H*D) slots a pool
+        # the body copies its groups itself: two (G*bs, lanes) slots a
+        # pool
+        lanes = k_pool.shape[-1]
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         operands += [k_pool, v_pool]
-        scratch += [pltpu.VMEM((2, G * bs, H * D), k_pool.dtype),
-                    pltpu.VMEM((2, G * bs, H * D), v_pool.dtype),
+        scratch += [pltpu.VMEM((2, G * bs, lanes), k_pool.dtype),
+                    pltpu.VMEM((2, G * bs, lanes), v_pool.dtype),
                     pltpu.SemaphoreType.DMA((2, 2))]
     else:
         # every pool leaf is (num_blocks, bs, lanes): codes and, where
@@ -474,10 +710,10 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=DECODE_KERNEL if lane_dense else PAGED_KERNEL,
+        name=name,
     )(block_table.astype(jnp.int32), lengths, row, blk, n, *operands)
     if lane_dense:
-        # (B, 1, H*D) rows back to (B, H, 1, D)
+        # (B, 1, H*D) rows, or (B, H, D) heads, back to (B, H, 1, D)
         out = jnp.moveaxis(out.reshape(B, S, H, D), 2, 1)
     return out
 
@@ -485,12 +721,16 @@ def _paged_call(q, k_pool, v_pool, block_table, lengths, *,
 def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
                            scale=None, interpret: bool = False,
                            k_scale=None, v_scale=None,
-                           k_new=None, v_new=None, work=None):
+                           k_new=None, v_new=None, work=None,
+                           window=None):
     """Fused paged attention over pool blocks — no gathered view.
 
     q:           (B, H, S, D) queries; S=1 decode, S=chunk prefill
-    k_pool:      (num_blocks, block_size, H*D) key pool (token-major,
-                 ops/paged_attention.write_kv layout)
+    k_pool:      (num_blocks, block_size, Hkv*D) key pool (token-major,
+                 ops/paged_attention.write_kv layout); an unquantized
+                 pool may hold fewer KV heads than ``q`` has query heads
+                 (grouped-query attention: ``gqa_decode_attention``,
+                 ``gqa_prefill_attention``)
     v_pool:      idem, values
     block_table: (B, NB) int32 pool block ids, position order; entries
                  past a row's allocation must be the null block (0)
@@ -510,7 +750,9 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     work:        the dispatch's ``paged_attention.paged_work`` at this
                  call's ``step_blocks`` where the caller holds it (one
                  list serves every layer of a forward); None builds it
-                 here
+                 here.  A grouped prefill chunk always builds its own
+                 (its list is of query tiles)
+    window:      None, or a sliding window W (``_visible``)
 
     Returns (B, H, S, D) in q.dtype.  Numerically this is the online-
     softmax evaluation of ops/paged_attention.paged_attention over the
@@ -540,13 +782,14 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths, *,
     return _paged_call(q, k_pool, v_pool, block_table, lengths,
                        scale=scale, interpret=interpret, mode=mode,
                        k_scale=k_scale, v_scale=v_scale,
-                       k_new=k_new, v_new=v_new, work=work)
+                       k_new=k_new, v_new=v_new, work=work, window=window)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                            scale=None, interpret: bool = False,
                            k_scale=None, v_scale=None,
-                           k_new=None, v_new=None, work=None):
+                           k_new=None, v_new=None, work=None,
+                           window=None):
     """Single-token decode specialization (S must be 1) — the serving
     hot path.  Thin wrapper so call sites (and probes) name the phase
     they are on; the list's form is shared with chunked prefill, and so
@@ -559,13 +802,15 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                                   lengths, scale=scale,
                                   interpret=interpret,
                                   k_scale=k_scale, v_scale=v_scale,
-                                  k_new=k_new, v_new=v_new, work=work)
+                                  k_new=k_new, v_new=v_new, work=work,
+                                  window=window)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_table, lengths, *,
                             scale=None, interpret: bool = False,
                             k_scale=None, v_scale=None,
-                            k_new=None, v_new=None, work=None):
+                            k_new=None, v_new=None, work=None,
+                            window=None):
     """Chunked-prefill variant: S = chunk queries per row at positions
     [lengths[b], lengths[b] + S), causal within the chunk and over the
     cache via the same visibility test (col <= q position)."""
@@ -573,7 +818,8 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, lengths, *,
                                   lengths, scale=scale,
                                   interpret=interpret,
                                   k_scale=k_scale, v_scale=v_scale,
-                                  k_new=k_new, v_new=v_new, work=work)
+                                  k_new=k_new, v_new=v_new, work=work,
+                                  window=window)
 
 
 @functools.lru_cache(maxsize=16)
@@ -581,7 +827,8 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
                   head_dim: int = 64, block_size: int = 16,
                   prefill_chunk: int = 64, kv_dtype: str = "fp32",
                   kv_group: int = 32, max_slots: int = 8,
-                  max_blocks: int = 4, sharding=None) -> None:
+                  max_blocks: int = 4, sharding=None, kv_heads=None,
+                  window=None, min_chunk: int = 2) -> None:
     """Compile the kernel for the geometry an engine is about to serve,
     on this backend's Mosaic, at the LARGEST dispatch of each kind:
     decode (S=1) over ``max_slots`` rows, and one row at EVERY pow2
@@ -602,6 +849,10 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
     Successes are cached per geometry (``lru_cache`` does not cache
     exceptions).
 
+    ``kv_heads`` (default ``heads``) makes the pool grouped-query,
+    ``window`` gives every call a sliding window, and ``min_chunk`` is
+    the smallest prefill bucket the engine dispatches past one token.
+
     ``sharding`` places the abstract operands; None is the default
     device.  tests/test_paged_kernel.py passes a device of a deviceless
     TPU topology, which runs the real Mosaic compiler without a chip."""
@@ -612,7 +863,7 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     kw = {}
-    width = heads * head_dim
+    width = (kv_heads or heads) * head_dim
     nblocks = 1 + max_slots * NB
     if kv_dtype == "int4":
         g = min(kv_group, head_dim)
@@ -634,7 +885,8 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
             kw.update(k_new=q, v_new=q)
         try:
             # graft-lint: jit-ok(compile probe: runs once at kernel resolve, not per step)
-            jax.jit(paged_attention_kernel).lower(
+            jax.jit(functools.partial(paged_attention_kernel,
+                                      window=window)).lower(
                 q, pool, pool, arg((B, NB), jnp.int32),
                 arg((B,), jnp.int32), **kw).compile()
         except Exception as e:
@@ -643,4 +895,4 @@ def probe_compile(dtype_name: str = "bfloat16", heads: int = 12,
                 f"{dtype_name} q, kv_dtype={kv_dtype}, H={heads}, "
                 f"D={head_dim}, block_size={block_size}, S={S}, "
                 f"{B} rows x {NB} table blocks: {e}") from e
-        S *= 2
+        S = max(2 * S, min_chunk)

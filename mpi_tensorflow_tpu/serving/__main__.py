@@ -84,7 +84,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decoder family: gpt (CausalLm at gpt_base's "
                         "widths) | phi4_flash (Phi-4-mini-flash-"
                         "reasoning's: state-space, window, full and cross "
-                        "layers)")
+                        "layers) | cohere2_moe (one chip's share of "
+                        "Command A+: parallel attention and experts, "
+                        "grouped-query window and full layers)")
     p.add_argument("--tiny", action="store_true",
                    help="the family's CPU size, not its published widths")
     p.add_argument("--journal", default=None, metavar="PATH",
@@ -99,7 +101,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 #: family -> its served name at published widths
-FAMILIES = {"gpt": "gpt_base", "phi4_flash": "phi4_mini_flash_reasoning"}
+FAMILIES = {"gpt": "gpt_base", "phi4_flash": "phi4_mini_flash_reasoning",
+            "cohere2_moe": "command_a_plus_05_2026"}
 
 
 def _widths(args):
@@ -111,6 +114,10 @@ def _widths(args):
         from mpi_tensorflow_tpu.models import phi4_flash
 
         return phi4_flash.TINY if args.tiny else phi4_flash.Phi4FlashConfig()
+    if args.model == "cohere2_moe":
+        from mpi_tensorflow_tpu.models import cohere2_moe
+
+        return cohere2_moe.TINY if args.tiny else cohere2_moe.CHIP_SHARE
     from mpi_tensorflow_tpu.models import bert
 
     return bert.BERT_TINY if args.tiny else bert.BERT_BASE
@@ -138,11 +145,12 @@ def _build(args, cfg: ServeConfig, seed: int):
     """The model from the seed, and one engine or a router to serve it."""
     import jax
 
-    from mpi_tensorflow_tpu.models import gpt, phi4_flash
+    from mpi_tensorflow_tpu.models import cohere2_moe, gpt, phi4_flash
     from mpi_tensorflow_tpu.serving import PagedDecodeEngine, ReplicaRouter
 
-    family = phi4_flash.Phi4FlashLm if args.model == "phi4_flash" \
-        else gpt.CausalLm
+    family = {"phi4_flash": phi4_flash.Phi4FlashLm,
+              "cohere2_moe": cohere2_moe.Cohere2MoeLm}.get(args.model,
+                                                          gpt.CausalLm)
     model = family(dataclasses.replace(
         _widths(args),
         dtype=Config(precision=args.precision).compute_dtype))

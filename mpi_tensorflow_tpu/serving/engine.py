@@ -477,6 +477,11 @@ class PagedDecodeEngine:
         # layer in many reads the table and the kernel's grid follows the
         # live blocks anyway, a narrower table buys only programs
         self._full_tables = bool(getattr(model, "full_tables", False))
+        # ... and may name the least prefill bucket it is dispatched at
+        # (a prompt's short last chunk then rides a wider program, one of
+        # a few)
+        self._prefill_floor = min(getattr(model, "prefill_floor", 1),
+                                  serve.prefill_chunk)
         if self._slot_state:
             import jax.numpy as jnp
 
@@ -1114,7 +1119,8 @@ class PagedDecodeEngine:
             self._prefill_queue.pop(0)
             self.sched.fail_live(slot, "rejected")
             return
-        sb = _bucket(len(chunk), self.serve.prefill_chunk)
+        sb = max(_bucket(len(chunk), self.serve.prefill_chunk),
+                 self._prefill_floor)
         toks = np.zeros((1, sb), np.int32)
         toks[0, :len(chunk)] = chunk
         tables = self._table_row(seq, self.serve.max_blocks_per_seq)[None]

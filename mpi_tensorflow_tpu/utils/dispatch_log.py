@@ -17,7 +17,9 @@ layers that route, and ``None`` until the device counter was next read
 ``dispatch_extra`` method adds a seventh entry, a dict of what else the
 dispatch obliged of its caches (models/phi4_flash: tokens through the
 scans, keys its window layers and its full layer's cache were read for,
-prefill lanes the cross-decoder was skipped on).  ``totals`` is the
+prefill lanes the cross-decoder was skipped on; models/cohere2_moe: the
+(query, key) pairs of a full and of a window layer, the keys each reads
+at least once, and the pairs the window spares).  ``totals`` is the
 running assignment count per held expert.
 """
 

@@ -1209,6 +1209,22 @@ class TestMosaicCompile:
                          max_slots=128, max_blocks=64,
                          sharding=tpu_topology_device)
 
+    @pytest.mark.parametrize("window,table", [(None, 132), (4096, 24)])
+    def test_grouped_query_geometry_compiles(self, tpu_topology_device,
+                                             window, table):
+        """Command A+'s share (benchmarks/configs/
+        command_a_plus_05_2026.json): 128 query heads over 8 KV heads of
+        128 — ``gqa_decode_attention`` over 32 rows and
+        ``gqa_prefill_attention`` at every chunk from 256 to 2,048 —
+        over the full layer's pool (tables of 132 blocks of 256) and a
+        window layer's ring read as its 4,096 keys plus a chunk (24
+        blocks), with the window bound."""
+        pk.probe_compile.cache_clear()
+        pk.probe_compile("bfloat16", 128, 128, 256, 2048, "fp32", 32,
+                         max_slots=32, max_blocks=table,
+                         sharding=tpu_topology_device, kv_heads=8,
+                         window=window, min_chunk=256)
+
     @pytest.mark.parametrize("block_size,table", [(128, 8), (256, 4),
                                                   (16, 3)])
     def test_decode_body_compiles_across_groups(

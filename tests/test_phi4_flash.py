@@ -22,6 +22,7 @@ import pytest
 from benchmarks.reference import phi4_flash as ref
 from mpi_tensorflow_tpu.models import phi4_flash as pf
 from mpi_tensorflow_tpu.ops import diff_attention as da
+from mpi_tensorflow_tpu.ops.paged_attention import write_ring
 from mpi_tensorflow_tpu.serving import (PagedDecodeEngine, Request,
                                         ServeConfig)
 from mpi_tensorflow_tpu.serving import paged_cache
@@ -363,7 +364,7 @@ class TestKernel:
             * jnp.ones((1, 6, KW))
         pos = 3 + jnp.arange(6)[None]
         valid = jnp.arange(6)[None] < 5             # positions 3..7
-        out = np.asarray(da.write_ring(ring, rows, jnp.asarray([1]), pos,
+        out = np.asarray(write_ring(ring, rows, jnp.asarray([1]), pos,
                                        valid))
         assert out[1, :, 0].tolist() == [2.0, 3.0, 4.0, 5.0]   # 4,5,6,7
         assert not out[0].any()
