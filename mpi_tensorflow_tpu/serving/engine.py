@@ -483,16 +483,13 @@ class PagedDecodeEngine:
         self._prefill_floor = min(getattr(model, "prefill_floor", 1),
                                   serve.prefill_chunk)
         if self._slot_state:
-            import jax.numpy as jnp
-
             slack = serve.max_slots
             self._decode_fn = _SlackFilled(
                 self._decode_fn, 5,
-                lambda a: (jnp.full(a[2].shape, slack, jnp.int32),))
+                lambda a: (np.full(np.shape(a[2]), slack, np.int32),))
             self._prefill_fn = _SlackFilled(
                 self._prefill_fn, 6,
-                lambda a: (jnp.asarray(slack, jnp.int32),
-                           jnp.asarray(1, jnp.int32)))
+                lambda a: (np.int32(slack), np.int32(1)))
         # copy-on-write block copy: pools in, pools out, fixed shapes —
         # exactly ONE compile ever (block ids ride as traced scalars)
         self._cow_fn = jax.jit(
@@ -551,7 +548,7 @@ class PagedDecodeEngine:
             # recompile against the zero-recompile contract
             import jax.numpy as jnp
 
-            z = jnp.asarray(0, jnp.int32)
+            z = np.int32(0)
             self.pools = self._cow_fn(self.pools, z, z)
             if self.serve.prefix_gen == "on":
                 # same contract for the partial-copy dispatch: a zero-
@@ -715,6 +712,15 @@ class PagedDecodeEngine:
         # by the time they were read (EOS, eviction, deadline, failure)
         self.lookahead_dispatches = 0
         self.lookahead_discarded_rows = 0
+        # arrays the host handed the device through JAX's Python array
+        # path (``_put``: the verify and mixed dispatches' inputs, a
+        # promoted block's leaves) — none on the plain decode and prefill
+        # path, whose numpy arguments ride the compiled call's own — and
+        # the table rows the dispatches were cut from, beside
+        # ``sched.table_rows_written``: the rows the scheduler rewrote
+        # because a block list changed
+        self.host_array_puts = 0
+        self.table_rows_dispatched = 0
         # what the attention kernel's grid walks over the decode
         # dispatches of this run — each row's live blocks, a slack row's
         # one, in groups of ``_decode_group`` (ops/paged_attention.
@@ -833,12 +839,9 @@ class PagedDecodeEngine:
         called during the admission match walk, BEFORE the sequence's
         first dispatch, so the promoted content is in place when the
         block table first references it."""
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
-        host = [{k: jnp.asarray(v) for k, v in p.items()} for p in leaves]
-        self.pools = self._promote_fn(self.pools, host,
-                                      jnp.asarray(block, jnp.int32))
+        host = [{k: self._put(v) for k, v in p.items()} for p in leaves]
+        self.pools = self._promote_fn(self.pools, host, np.int32(block))
         self.tier.promote_ms_total += (time.perf_counter() - t0) * 1e3
 
     def _verify_impl(self, params, pools, tokens, lengths, n_valid,
@@ -937,12 +940,11 @@ class PagedDecodeEngine:
         import jax.numpy as jnp
 
         slack = self.serve.max_slots
-        last = self._keep_fn(self._slot_tokens,
-                             jnp.asarray(slack, jnp.int32),
-                             jnp.asarray(0, jnp.int32))
+        last = self._keep_fn(self._slot_tokens, np.int32(slack),
+                             jnp.zeros((), jnp.int32))
         Bb = 1
         while True:
-            slots = jnp.asarray(np.full((Bb,), slack, np.int32))
+            slots = np.full((Bb,), slack, np.int32)
             last = self._keep_fn(last, slots, self._take_fn(last, slots))
             if Bb >= slack:
                 break
@@ -963,18 +965,19 @@ class PagedDecodeEngine:
         replay slows it enough to visit different buckets than the
         stall-free served pass — the same argument that makes
         _prewarm_mixed a build-time obligation."""
-        import jax.numpy as jnp
-        import numpy as np
-
         Bb = 1
         while True:
             NBb = self._table_bucket(1)
+            # the loop's own argument forms: tokens gathered on the
+            # device, the rest numpy
+            tokens = self._take_fn(self._slot_tokens,
+                                   np.full((Bb,), self.serve.max_slots,
+                                           np.int32))
             while True:
                 _, self.pools = self._decode_fn(
-                    self.params, self.pools,
-                    jnp.asarray(np.zeros((Bb,), np.int32)),
-                    jnp.asarray(np.zeros((Bb,), np.int32)),
-                    jnp.asarray(np.zeros((Bb, NBb), np.int32)))
+                    self.params, self.pools, tokens,
+                    np.zeros((Bb,), np.int32),
+                    np.zeros((Bb, NBb), np.int32))
                 if NBb >= self.serve.max_blocks_per_seq:
                     break
                 NBb = min(NBb * 2, self.serve.max_blocks_per_seq)
@@ -1032,8 +1035,6 @@ class PagedDecodeEngine:
             return True
         seq = self.sched.slots[slot]
         bs = self.serve.block_size
-        import jax.numpy as jnp
-
         for j in range(start // bs, (end - 1) // bs + 1):
             if j >= len(seq.block_ids):
                 continue            # growth handled by ensure_block
@@ -1043,11 +1044,10 @@ class PagedDecodeEngine:
             dst = self.sched.alloc_for(slot)
             if dst is None:
                 return False
-            self.pools = self._cow_fn(self.pools,
-                                      jnp.asarray(src, jnp.int32),
-                                      jnp.asarray(dst, jnp.int32))
+            self.pools = self._cow_fn(self.pools, np.int32(src),
+                                      np.int32(dst))
             self.allocator.release([src])
-            seq.block_ids[j] = dst
+            self.sched.replace_block(slot, j, dst)
             self.sched.counters["prefix_cow_copies"] += 1
         return True
 
@@ -1060,24 +1060,25 @@ class PagedDecodeEngine:
         after admit() in step(), so eviction cannot intervene; a slot
         whose sequence left anyway (pin already dropped by the
         scheduler) is skipped."""
-        import jax.numpy as jnp
-
         for seq in self.sched.slots:
             if seq is None or seq.partial_src is None:
                 continue
             self.pools = self._partial_fn(
-                self.pools, jnp.asarray(seq.partial_src, jnp.int32),
-                jnp.asarray(seq.partial_dst, jnp.int32),
-                jnp.asarray(seq.partial_rows, jnp.int32))
+                self.pools, np.int32(seq.partial_src),
+                np.int32(seq.partial_dst), np.int32(seq.partial_rows))
             self.sched._release_partial(seq)
 
     def _track_occupancy(self) -> None:
-        """Advance the pool-occupancy high-water marks (see reset)."""
+        """Advance the pool-occupancy high-water marks (see reset): the
+        live blocks are the distinct non-null entries of the scheduler's
+        table mirror (an empty slot's row is null, and so is the slack
+        row), and with no prefix cache no block is mapped twice."""
         self.peak_blocks_in_use = max(self.peak_blocks_in_use,
                                       self.allocator.num_used)
-        live = {b for s in self.sched.slots if s is not None
-                for b in s.block_ids}
-        self.peak_live_blocks = max(self.peak_live_blocks, len(live))
+        tables = self.sched.tables
+        live = (np.count_nonzero(tables) if self.prefix_cache is None
+                else np.unique(tables).size - 1)
+        self.peak_live_blocks = max(self.peak_live_blocks, int(live))
 
     def _table_bucket(self, blocks: int) -> int:
         """The table width a decode dispatch whose longest row holds
@@ -1085,19 +1086,21 @@ class PagedDecodeEngine:
         cap = self.serve.max_blocks_per_seq
         return cap if self._full_tables else _bucket(blocks, cap)
 
-    def _table_row(self, seq, width: int) -> np.ndarray:
-        row = np.zeros((width,), np.int32)
-        ids = seq.block_ids[:width]
-        row[:len(ids)] = ids
-        return row
+    def _row_slots(self, slots: List[int]) -> np.ndarray:
+        """``slots`` padded with the slack slot to their bucket: the rows
+        of the scheduler's mirrors a dispatch is cut from."""
+        out = np.full((_bucket(len(slots), self.serve.max_slots),),
+                      self.serve.max_slots, np.int32)
+        out[:len(slots)] = slots
+        return out
 
     def _advance_prefill(self) -> None:
         """Advance the oldest mid-prefill sequence by ONE chunk (chunked
         prefill: new prompts trickle into the pool between decode steps
         instead of stalling them for a whole long prompt).  The final
-        chunk's token stays on the device, unread (``_hold``)."""
-        import jax.numpy as jnp
-
+        chunk's token stays on the device, unread (``_hold``).  Every
+        argument is numpy, scalars 0-d ``np.int32``: the compiled call
+        takes them on its own argument path."""
         while self._prefill_queue:
             slot, seq = self._prefill_queue[0]
             if self.sched.slots[slot] is not seq:
@@ -1123,20 +1126,21 @@ class PagedDecodeEngine:
                  self._prefill_floor)
         toks = np.zeros((1, sb), np.int32)
         toks[0, :len(chunk)] = chunk
-        tables = self._table_row(seq, self.serve.max_blocks_per_seq)[None]
         self.dispatch_shapes.add(("prefill", sb))
         self._count_dispatch()
+        self.table_rows_dispatched += 1
         self.state_resets += seq.prefilled == 0
         final = seq.prefilled + len(chunk) >= len(prompt)
-        rows = (jnp.asarray(slot, jnp.int32),
-                jnp.asarray(final, jnp.int32)) if self._slot_state else ()
+        rows = ((np.int32(slot), np.int32(final)) if self._slot_state
+                else ())
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
+        # ``tables[[slot]]`` is a copy: the mirror moves on, the
+        # argument does not
         nxt, self.pools = self._prefill_fn(
-            self.params, self.pools, jnp.asarray(toks),
-            jnp.asarray(seq.prefilled, jnp.int32),
-            jnp.asarray(len(chunk), jnp.int32), jnp.asarray(tables), *rows)
+            self.params, self.pools, toks, np.int32(seq.prefilled),
+            np.int32(len(chunk)), self.sched.tables[[slot]], *rows)
         rec = None
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
@@ -1144,7 +1148,7 @@ class PagedDecodeEngine:
             rec = self._log_dispatch("prefill", n,
                                      n * at + n * (n + 1) // 2,
                                      [at], [n], int(final))
-        seq.prefilled += len(chunk)
+        self.sched.count_prefill(slot, len(chunk))
         if seq.prefilled < len(prompt):
             return
         self._prefill_queue.pop(0)
@@ -1157,7 +1161,15 @@ class PagedDecodeEngine:
         # the prompt's last position already yields the first output
         # token (exactly generate()'s prefill-argmax), so the slot
         # enters the decode pool one token ahead
-        self._hold(nxt, jnp.asarray(slot, jnp.int32), [(slot, seq)], rec)
+        self._hold(nxt, np.int32(slot), [(slot, seq)], rec)
+
+    def _put(self, x):
+        """``x`` on the device through JAX's Python array path, counted
+        (``host_array_puts``)."""
+        import jax.numpy as jnp
+
+        self.host_array_puts += 1
+        return jnp.asarray(x)
 
     def _count_dispatch(self) -> None:
         """One model-forward dispatch is about to be issued; it looks
@@ -1253,54 +1265,54 @@ class PagedDecodeEngine:
     def _dispatch_decode(self) -> None:
         """Assemble and issue one decode dispatch over every live,
         fully prefilled slot whose budget is not already spent by the
-        tokens in flight.  Counts only: no token's value is read."""
-        import jax.numpy as jnp
-
+        tokens in flight.  Counts only: no token's value is read.  The
+        row loop keeps what needs the scheduler (the budget, growth,
+        copy-on-write); lengths and tables are the scheduler's mirrors
+        indexed once, handed to the compiled call as numpy."""
+        sched = self.sched
         live = []
-        for slot in self.sched.live_slots():
-            seq = self.sched.slots[slot]
+        for slot in sched.live_slots():
+            seq = sched.slots[slot]
             if seq is None or seq.prefilled < len(seq.request.prompt) \
                     or seq.spent:
                 # mid-prefill: not in the decode pool; spent: its last
                 # token is computed, it leaves when that is delivered
                 continue
-            if not self.sched.ensure_block(slot):
-                # pool exhausted with nothing left to evict: THIS request
-                # cannot grow — fail it alone (blocks freed, terminal
-                # status recorded); every other in-flight stream keeps
-                # serving.  Unreachable when submit()'s feasibility check
-                # gates admission, kept as defense in depth: one request
-                # must never take the engine down.
-                self.sched.fail_live(slot, "rejected")
-                continue
-            if not self._ensure_private(slot, seq.length - 1, seq.length):
-                self.sched.fail_live(slot, "rejected")
+            if not sched.ensure_block(slot) or (
+                    self.prefix_cache is not None and not
+                    self._ensure_private(slot, seq.length - 1, seq.length)):
+                # pool exhausted with nothing left to evict (for growth
+                # or a private copy): THIS request cannot go on — fail it
+                # alone (blocks freed, terminal status recorded); every
+                # other in-flight stream keeps serving.  Unreachable when
+                # submit()'s feasibility check gates admission, kept as
+                # defense in depth: one request must never take the
+                # engine down.
+                sched.fail_live(slot, "rejected")
                 continue
             live.append(slot)
         # eviction inside ensure_block/CoW may have retired a later slot
-        live = [s for s in live if self.sched.slots[s] is not None]
+        live = [s for s in live if sched.slots[s] is not None]
         self._track_occupancy()
         if not live:
             return
         self._progressed = True
 
-        Bb = _bucket(len(live), self.serve.max_slots)
-        nb = max(len(self.sched.slots[s].block_ids) for s in live)
-        NBb = self._table_bucket(nb)
-        slots = np.full((Bb,), self.serve.max_slots, np.int32)
-        slots[:len(live)] = live
-        lengths = np.zeros((Bb,), np.int32)
-        tables = np.zeros((Bb, NBb), np.int32)
-        rows = []
-        for j, slot in enumerate(live):
-            seq = self.sched.slots[slot]
-            rows.append((slot, seq))
-            # the pending token writes at position length-1: the cache
-            # holds length-1 entries until this step lands it
-            lengths[j] = seq.length - 1
-            tables[j] = self._table_row(seq, NBb)
+        n = len(live)
+        slots = self._row_slots(live)
+        Bb = len(slots)
+        # the pending token writes at position length-1: the cache holds
+        # length-1 entries until this step lands it; slack rows read 0
+        lengths = sched.lengths[slots] - 1
+        lengths[n:] = 0
+        # a row holds the blocks its length needs (admission's, then
+        # ensure_block's), so the longest length sizes the table bucket
+        NBb = self._table_bucket(paged_cache.blocks_for(
+            int(lengths[:n].max()) + 1, self.serve.block_size))
+        tables = sched.tables[slots, :NBb]
         self.dispatch_shapes.add(("decode", Bb, NBb))
         self._count_dispatch()
+        self.table_rows_dispatched += n
         G = self._decode_group
         blocks = np.minimum(lengths // self.serve.block_size + 1, NBb)
         steps = int((-(-blocks // G)).sum())
@@ -1311,18 +1323,15 @@ class PagedDecodeEngine:
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
-        slots = jnp.asarray(slots)
         nxt, self.pools = self._decode_fn(
             self.params, self.pools,
-            self._take_fn(self._slot_tokens, slots),
-            jnp.asarray(lengths), jnp.asarray(tables),
+            self._take_fn(self._slot_tokens, slots), lengths, tables,
             *((slots,) if self._slot_state else ()))
         rec = None
         if tr is not None:
-            n = len(live)
             rec = self._log_dispatch("decode", n, int(lengths.sum()) + n,
                                      lengths[:n], [1] * n, n)
-        self._hold(nxt, slots, rows, rec)
+        self._hold(nxt, slots, [(s, sched.slots[s]) for s in live], rec)
         if tr is not None:
             tr.dispatch_s += time.monotonic() - _m0
 
@@ -1349,8 +1358,6 @@ class PagedDecodeEngine:
         slot's new occupant), ensure_block + CoW over exactly the
         row's write range, trie insertion at full prefill BEFORE
         record_token, and the journal's tok-then-end order."""
-        import jax.numpy as jnp
-
         serve = self.serve
         emitted: List[Tuple[int, int]] = []
         # decode rows: the same admission/CoW discipline as the
@@ -1406,27 +1413,27 @@ class PagedDecodeEngine:
         self._progressed = True
 
         Bb = _bucket(len(rows), serve.max_slots)
+        slots = self._row_slots([r[0] for r in rows])
         Sb = _bucket(max(len(r[2]) for r in rows), serve.prefill_chunk)
         nb = max(len(r[1].block_ids) for r in rows)
         NBb = _bucket(nb, serve.max_blocks_per_seq)
         tokens = np.zeros((Bb, Sb), np.int32)
         lengths = np.zeros((Bb,), np.int32)
         n_valid = np.zeros((Bb,), np.int32)
-        tables = np.zeros((Bb, NBb), np.int32)
+        tables = self.sched.tables[slots, :NBb]
         for j, (slot, seq, lanes, start, _) in enumerate(rows):
             tokens[j, :len(lanes)] = lanes
             n_valid[j] = len(lanes)
             lengths[j] = start
-            tables[j] = self._table_row(seq, NBb)
         self.dispatch_shapes.add(("mixed", Bb, Sb, NBb))
         self.forward_dispatches += 1
+        self.table_rows_dispatched += len(rows)
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
         out, self.pools = self._mixed_fn(
-            self.params, self.pools, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(n_valid),
-            jnp.asarray(tables))
+            self.params, self.pools, self._put(tokens),
+            self._put(lengths), self._put(n_valid), self._put(tables))
         if tr is not None:
             _m1 = time.monotonic()
             tr.dispatch_s += _m1 - _m0
@@ -1436,7 +1443,7 @@ class PagedDecodeEngine:
 
         for j, (slot, seq, lanes, start, is_prefill) in enumerate(rows):
             if is_prefill:
-                seq.prefilled += len(lanes)
+                self.sched.count_prefill(slot, len(lanes))
                 if seq.prefilled < len(seq.request.prompt):
                     continue        # still mid-prefill: no token emitted
                 self._prefill_queue = [
@@ -1473,8 +1480,6 @@ class PagedDecodeEngine:
         position, and only argmax-chain-consistent tokens are emitted.
         A slot whose drafter proposes nothing rides the same dispatch
         with one valid lane — an exact one-token decode step."""
-        import jax.numpy as jnp
-
         serve = self.serve
         bs = serve.block_size
         cap = serve.max_blocks_per_seq * bs
@@ -1535,28 +1540,28 @@ class PagedDecodeEngine:
 
         W = serve.draft_k + 1
         Bb = _bucket(len(live), serve.max_slots)
+        slots = self._row_slots(live)
         nb = max(len(self.sched.slots[s].block_ids) for s in live)
         NBb = _bucket(nb, serve.max_blocks_per_seq)
         tokens = np.zeros((Bb, W), np.int32)
         lengths = np.zeros((Bb,), np.int32)
         n_valid = np.zeros((Bb,), np.int32)
-        tables = np.zeros((Bb, NBb), np.int32)
+        tables = self.sched.tables[slots, :NBb]
         for j, slot in enumerate(live):
             seq = self.sched.slots[slot]
             row = [self._last_token[slot]] + drafts[slot]
             tokens[j, :len(row)] = row
             n_valid[j] = len(row)
             lengths[j] = seq.length - 1
-            tables[j] = self._table_row(seq, NBb)
         self.dispatch_shapes.add(("verify", Bb, NBb))
         self.forward_dispatches += 1
+        self.table_rows_dispatched += len(live)
         tr = self.tracer
         if tr is not None:
             _m0 = time.monotonic()
         out, self.pools = self._verify_fn(
-            self.params, self.pools, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(n_valid),
-            jnp.asarray(tables))
+            self.params, self.pools, self._put(tokens),
+            self._put(lengths), self._put(n_valid), self._put(tables))
         if tr is not None:
             _m1 = time.monotonic()
             tr.dispatch_s += _m1 - _m0
@@ -1761,6 +1766,9 @@ class PagedDecodeEngine:
             # sequence that had left its slot by the time they were read
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
+            # dispatch assembly: Python-path puts (0 on the plain path),
+            # table rows rewritten against rows dispatched
+            **self._assembly_counts(),
             "caches": self.cache_block(),
             # final-token emit time per request on the run clock (the
             # same clock as Request.arrival): attained whole-request
@@ -1893,6 +1901,7 @@ class PagedDecodeEngine:
             "forward_dispatches": self.forward_dispatches,
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
+            **self._assembly_counts(),
             # the decode kernel's grid steps, the live blocks they were
             # obliged to read and the blocks they copied
             "paged_grid_steps": self.paged_grid_steps,
@@ -1904,6 +1913,12 @@ class PagedDecodeEngine:
             "cache_bytes": dict(self._cache_bytes),
             "state_resets": int(self.state_resets),
         }
+
+    def _assembly_counts(self) -> dict:
+        """How the dispatches were assembled (see reset)."""
+        return {"host_array_puts": self.host_array_puts,
+                "table_rows_written": self.sched.table_rows_written,
+                "table_rows_dispatched": self.table_rows_dispatched}
 
     def cache_block(self) -> dict:
         """Bytes the device holds of each kind of cache (``paged`` block

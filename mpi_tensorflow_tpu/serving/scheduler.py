@@ -26,9 +26,10 @@ leaves the system with exactly one terminal status —
 - ``drained``            in flight when a graceful drain's budget
                          expired (the engine cut it off incomplete).
 
-All state here is host-side Python; the engine turns the live slot set
-into bucketed device dispatches.  Pure-Python on purpose: the
-admit/evict invariant tests run without a device.
+All state here is on the host (Python, and numpy mirrors of the block
+tables and lengths that the engine's dispatches are cut from); the
+engine turns the live slot set into bucketed device dispatches.  No
+device on purpose: the admit/evict invariant tests run without one.
 """
 
 from __future__ import annotations
@@ -36,6 +37,8 @@ from __future__ import annotations
 import dataclasses
 from collections import Counter, deque
 from typing import Callable, Dict, List, Optional
+
+import numpy as np
 
 from mpi_tensorflow_tpu.serving.paged_cache import (BlockAllocator,
                                                     blocks_for)
@@ -211,6 +214,17 @@ class Scheduler:
         self.prefix_gen = prefix_gen
         self.waiting: deque = deque()
         self.slots: List[Optional[Sequence]] = [None] * max_slots
+        # host mirrors of every slot's block table and ``length``, in the
+        # form a dispatch hands the device: a dispatch takes its rows in
+        # one indexing op.  A row is written when its slot's block list
+        # changes (admission, growth, a copy-on-write, a rollback, the
+        # slot's release: an empty slot's row is null), a length when
+        # its sequence advances; row ``max_slots`` is the bucket slack's
+        # and stays null
+        self.tables = np.zeros((max_slots + 1, max_blocks_per_seq),
+                               np.int32)
+        self.lengths = np.zeros((max_slots + 1,), np.int32)
+        self.table_rows_written = 0
         self.finished: List[Sequence] = []
         self.failed: List[Request] = []
         self.statuses: Dict[int, str] = {}     # request id -> terminal
@@ -392,6 +406,8 @@ class Scheduler:
             seq.prefilled = seq.prefix_cached = cached_tokens + rows
             self.counters["prefix_partial_copy_tokens"] += rows
         self.slots[slot] = seq
+        self.lengths[slot] = seq.length
+        self._write_row(slot)
 
     def _admit_hit_aware(self, slot: int) -> bool:
         """The block-starved bypass: admit the closest queued request
@@ -428,6 +444,27 @@ class Scheduler:
         return False
 
     # ---------------- per-step bookkeeping ----------------
+
+    def _write_row(self, slot: int) -> None:
+        """Copy ``slot``'s block list into its row of ``tables``, null
+        past it (a whole null row for an empty slot)."""
+        seq = self.slots[slot]
+        ids = seq.block_ids if seq is not None else ()
+        row = self.tables[slot]
+        row[:len(ids)] = ids
+        row[len(ids):] = 0
+        self.table_rows_written += 1
+
+    def replace_block(self, slot: int, j: int, block: int) -> None:
+        """Point entry ``j`` of ``slot``'s table at ``block`` (a
+        copy-on-write target; the caller releases the old block)."""
+        self.slots[slot].block_ids[j] = block
+        self._write_row(slot)
+
+    def count_prefill(self, slot: int, n: int) -> None:
+        """``n`` more prompt tokens of ``slot`` went through prefill."""
+        self.slots[slot].prefilled += n
+        self.lengths[slot] += n
 
     def _release_partial(self, seq: Sequence) -> None:
         """Drop the partial-copy source pin (if any) — called by the
@@ -499,12 +536,15 @@ class Scheduler:
         AND eviction could not free a block for this slot."""
         seq = self.slots[slot]
         need = blocks_for(seq.length, self.block_size)
+        had = len(seq.block_ids)
         while len(seq.block_ids) < need:
             b = self.alloc_for(slot)
             if b is None:
-                return False
+                break
             seq.block_ids.append(b)
-        return True
+        if len(seq.block_ids) != had:
+            self._write_row(slot)
+        return len(seq.block_ids) >= need
 
     def extend_for(self, slot: int, total_tokens: int) -> int:
         """Opportunistically grow the slot's table to cover
@@ -521,6 +561,7 @@ class Scheduler:
         extra = want - len(seq.block_ids)
         if extra > 0 and self.allocator.can_alloc(extra):
             seq.block_ids.extend(self.allocator.alloc(extra))
+            self._write_row(slot)
         return len(seq.block_ids) * self.block_size
 
     def rollback_blocks(self, slot: int, keep_tokens: int) -> int:
@@ -539,6 +580,7 @@ class Scheduler:
             return 0
         victims = seq.block_ids[keep:]
         del seq.block_ids[keep:]
+        self._write_row(slot)
         self.allocator.release(victims)
         return len(victims)
 
@@ -571,6 +613,7 @@ class Scheduler:
         self.allocator.release(seq.block_ids)
         self._release_partial(seq)
         self.slots[victim] = None
+        self._write_row(victim)
         self.evictions += 1
         self.counters["evictions"] += 1
         self.evicted_ids.append(seq.request.id)
@@ -592,6 +635,7 @@ class Scheduler:
         (``Sequence.unread``) without its value.  The first half of
         ``record_token``; ``deliver`` is the second."""
         self.slots[slot].unread += 1
+        self.lengths[slot] += 1
 
     def record_token(self, slot: int, token: int,
                      eos_id: Optional[int] = None) -> None:
@@ -633,6 +677,7 @@ class Scheduler:
             seq.block_ids = []
             self.finished.append(seq)
             self.slots[slot] = None
+            self._write_row(slot)
             self._terminal(seq.request, "ok")
 
     def record_tokens(self, slot: int, tokens: List[int],
@@ -667,6 +712,7 @@ class Scheduler:
         self._release_partial(seq)
         seq.block_ids = []
         self.slots[slot] = None
+        self._write_row(slot)
         self._terminal(seq.request, status)
 
     def expire_deadlines(self, now: float) -> List[int]:

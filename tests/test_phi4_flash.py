@@ -257,10 +257,11 @@ class TestThroughTheEngine:
         eng.prewarm_decode()
         S = 1
         while S <= eng.serve.prefill_chunk:
+            # the loop's own argument forms: numpy, 0-d np.int32 scalars
             _, eng.pools = eng._prefill_fn(
-                eng.params, eng.pools, jnp.zeros((1, S), jnp.int32),
-                jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
-                jnp.zeros((1, eng.serve.max_blocks_per_seq), jnp.int32))
+                eng.params, eng.pools, np.zeros((1, S), np.int32),
+                np.int32(0), np.int32(0),
+                np.zeros((1, eng.serve.max_blocks_per_seq), np.int32))
             S *= 2
         warm = eng.compile_counts()
         assert None not in warm.values()
