@@ -841,7 +841,12 @@ class PagedDecodeEngine:
         block table first references it."""
         t0 = time.perf_counter()
         host = [{k: self._put(v) for k, v in p.items()} for p in leaves]
+        tr = self.tracer
+        if tr is not None:
+            tr.enter("serve.launch")
         self.pools = self._promote_fn(self.pools, host, np.int32(block))
+        if tr is not None:
+            tr.exit()
         self.tier.promote_ms_total += (time.perf_counter() - t0) * 1e3
 
     def _verify_impl(self, params, pools, tokens, lengths, n_valid,
@@ -1044,8 +1049,13 @@ class PagedDecodeEngine:
             dst = self.sched.alloc_for(slot)
             if dst is None:
                 return False
+            tr = self.tracer
+            if tr is not None:
+                tr.enter("serve.launch")
             self.pools = self._cow_fn(self.pools, np.int32(src),
                                       np.int32(dst))
+            if tr is not None:
+                tr.exit()
             self.allocator.release([src])
             self.sched.replace_block(slot, j, dst)
             self.sched.counters["prefix_cow_copies"] += 1
@@ -1060,12 +1070,17 @@ class PagedDecodeEngine:
         after admit() in step(), so eviction cannot intervene; a slot
         whose sequence left anyway (pin already dropped by the
         scheduler) is skipped."""
+        tr = self.tracer
         for seq in self.sched.slots:
             if seq is None or seq.partial_src is None:
                 continue
+            if tr is not None:
+                tr.enter("serve.launch")
             self.pools = self._partial_fn(
                 self.pools, np.int32(seq.partial_src),
                 np.int32(seq.partial_dst), np.int32(seq.partial_rows))
+            if tr is not None:
+                tr.exit()
             self.sched._release_partial(seq)
 
     def _track_occupancy(self) -> None:
@@ -1135,7 +1150,7 @@ class PagedDecodeEngine:
                 else ())
         tr = self.tracer
         if tr is not None:
-            _m0 = time.monotonic()
+            tr.enter("serve.launch")
         # ``tables[[slot]]`` is a copy: the mirror moves on, the
         # argument does not
         nxt, self.pools = self._prefill_fn(
@@ -1143,7 +1158,7 @@ class PagedDecodeEngine:
             np.int32(len(chunk)), self.sched.tables[[slot]], *rows)
         rec = None
         if tr is not None:
-            tr.dispatch_s += time.monotonic() - _m0
+            tr.exit()
             n, at = len(chunk), seq.prefilled
             rec = self._log_dispatch("prefill", n,
                                      n * at + n * (n + 1) // 2,
@@ -1185,10 +1200,15 @@ class PagedDecodeEngine:
         its ``nxt`` where the next dispatch gathers from, count a token
         for every row (``Scheduler.advance``) and keep the values unread
         until ``_deliver`` — nothing here waits for the device."""
+        tr = self.tracer
+        if tr is not None:
+            tr.enter("serve.launch")
         self._slot_tokens = self._keep_fn(self._slot_tokens, slots, nxt)
         # start the few bytes' way to the host as soon as they exist, so
         # the read a dispatch later finds them there
         nxt.copy_to_host_async()
+        if tr is not None:
+            tr.exit()
         for slot, _seq in rows:
             self.sched.advance(slot)
         self._unread.append((nxt, rows, rec))
@@ -1205,15 +1225,17 @@ class PagedDecodeEngine:
         the device's one stream) of any later owner's writes."""
         emitted: List[Tuple[int, int]] = []
         tr = self.tracer
+        if tr is not None:
+            tr.enter("serve.deliver")
         for nxt, rows, rec in self._unread[:n]:
             if tr is not None:
-                _m0 = time.monotonic()
+                # the time the host stands waiting for the device: next
+                # to the step's length it says which side sets the pace
+                tr.enter("serve.wait")
             toks = np.asarray(nxt).reshape(-1).tolist()  # graft-lint: sync-ok(the one bulk read a dispatch, taken after the next dispatch is issued)
             if tr is not None:
+                tr.exit()
                 self._read_counters(rec)
-                # the time the host stood waiting for the device: next
-                # to the step's length it says which side sets the pace
-                tr.consume_s += time.monotonic() - _m0
             for (slot, seq), tok in zip(rows, toks):
                 if self.sched.slots[slot] is not seq:
                     self.lookahead_discarded_rows += 1
@@ -1227,6 +1249,8 @@ class PagedDecodeEngine:
         if n:
             del self._unread[:n]
             self._progressed = True      # tokens reached the host
+        if tr is not None:
+            tr.exit()
         return emitted
 
     def all_done(self) -> bool:
@@ -1240,7 +1264,10 @@ class PagedDecodeEngine:
         decode every live slot once, THEN read and deliver the tokens of
         the previous iteration's dispatches.  Returns the ``(request
         id, token)`` pairs delivered."""
+        tr = self.tracer
         self._progressed = False
+        if tr is not None:
+            tr.enter("serve.admit")
         admitted = self.sched.admit()
         self._track_occupancy()
         if admitted:
@@ -1248,18 +1275,28 @@ class PagedDecodeEngine:
         self._prefill_queue.extend(
             (slot, self.sched.slots[slot]) for slot in admitted)
         self._apply_partial_copies()
+        if tr is not None:
+            tr.exit()
         if self.serve.mixed_batch == "on":
             # the fused path replaces BOTH the prefill and the decode
             # phases below, and reads its output at once
             return self._step_mixed()
         held = len(self._unread)         # the previous iteration's
+        if tr is not None:
+            tr.enter("serve.prefill")
         self._advance_prefill()
+        if tr is not None:
+            tr.exit()
         if self.drafter is not None:
             # the accepted count decides every row's next length, and
             # the drafter reads the delivered stream: the next
             # dispatch's shape hangs on the values, so read at once
             return self._step_verify(self._deliver(len(self._unread)))
+        if tr is not None:
+            tr.enter("serve.decode")
         self._dispatch_decode()
+        if tr is not None:
+            tr.exit()
         return self._deliver(held)
 
     def _dispatch_decode(self) -> None:
@@ -1322,18 +1359,20 @@ class PagedDecodeEngine:
         self.paged_blocks_fetched += steps * G
         tr = self.tracer
         if tr is not None:
-            _m0 = time.monotonic()
+            tr.enter("serve.launch")
+        last = self._take_fn(self._slot_tokens, slots)
+        if tr is not None:
+            tr.exit()
+            tr.enter("serve.launch")
         nxt, self.pools = self._decode_fn(
-            self.params, self.pools,
-            self._take_fn(self._slot_tokens, slots), lengths, tables,
+            self.params, self.pools, last, lengths, tables,
             *((slots,) if self._slot_state else ()))
         rec = None
         if tr is not None:
+            tr.exit()
             rec = self._log_dispatch("decode", n, int(lengths.sum()) + n,
                                      lengths[:n], [1] * n, n)
         self._hold(nxt, slots, [(s, sched.slots[s]) for s in live], rec)
-        if tr is not None:
-            tr.dispatch_s += time.monotonic() - _m0
 
     def _step_mixed(self) -> List[Tuple[int, int]]:
         """The fused replacement for the prefill-then-decode phases
@@ -1360,6 +1399,9 @@ class PagedDecodeEngine:
         record_token, and the journal's tok-then-end order."""
         serve = self.serve
         emitted: List[Tuple[int, int]] = []
+        tr = self.tracer
+        if tr is not None:
+            tr.enter("serve.decode")
         # decode rows: the same admission/CoW discipline as the
         # unfused decode loop, row by row
         rows = []           # (slot, seq, lane tokens, start, is_prefill)
@@ -1409,6 +1451,8 @@ class PagedDecodeEngine:
         rows = [r for r in rows if self.sched.slots[r[0]] is r[1]]
         self._track_occupancy()
         if not rows:
+            if tr is not None:
+                tr.exit()
             return emitted
         self._progressed = True
 
@@ -1428,18 +1472,19 @@ class PagedDecodeEngine:
         self.dispatch_shapes.add(("mixed", Bb, Sb, NBb))
         self.forward_dispatches += 1
         self.table_rows_dispatched += len(rows)
-        tr = self.tracer
         if tr is not None:
-            _m0 = time.monotonic()
+            tr.enter("serve.launch")
         out, self.pools = self._mixed_fn(
             self.params, self.pools, self._put(tokens),
             self._put(lengths), self._put(n_valid), self._put(tables))
         if tr is not None:
-            _m1 = time.monotonic()
-            tr.dispatch_s += _m1 - _m0
+            tr.exit()
+            tr.exit()
+            tr.enter("serve.deliver")
+            tr.enter("serve.wait")
         out = np.asarray(out)  # graft-lint: sync-ok(the one budgeted bulk sync per mixed dispatch)
         if tr is not None:
-            tr.consume_s += time.monotonic() - _m1
+            tr.exit()
 
         for j, (slot, seq, lanes, start, is_prefill) in enumerate(rows):
             if is_prefill:
@@ -1464,6 +1509,8 @@ class PagedDecodeEngine:
             if self._journal is not None:
                 self._journal.record_token(rid, tok)
             self.sched.record_token(slot, tok, serve.eos_id)
+        if tr is not None:
+            tr.exit()
         return emitted
 
     def _step_verify(self, emitted: List[Tuple[int, int]]) \
@@ -1490,6 +1537,9 @@ class PagedDecodeEngine:
         # either way, so auto-tuning can never add a compile)
         k_cap = (self._draft_k_eff if serve.draft_auto == "on"
                  else serve.draft_k)
+        tr = self.tracer
+        if tr is not None:
+            tr.enter("serve.decode")
         live: List[int] = []
         drafts: dict = {}
         full_window: dict = {}
@@ -1535,6 +1585,8 @@ class PagedDecodeEngine:
         live = [s for s in live if self.sched.slots[s] is not None]
         self._track_occupancy()
         if not live:
+            if tr is not None:
+                tr.exit()
             return emitted
         self._progressed = True
 
@@ -1556,18 +1608,19 @@ class PagedDecodeEngine:
         self.dispatch_shapes.add(("verify", Bb, NBb))
         self.forward_dispatches += 1
         self.table_rows_dispatched += len(live)
-        tr = self.tracer
         if tr is not None:
-            _m0 = time.monotonic()
+            tr.enter("serve.launch")
         out, self.pools = self._verify_fn(
             self.params, self.pools, self._put(tokens),
             self._put(lengths), self._put(n_valid), self._put(tables))
         if tr is not None:
-            _m1 = time.monotonic()
-            tr.dispatch_s += _m1 - _m0
+            tr.exit()
+            tr.exit()
+            tr.enter("serve.deliver")
+            tr.enter("serve.wait")
         out = np.asarray(out)  # graft-lint: sync-ok(the one budgeted bulk sync per verify dispatch)
         if tr is not None:
-            tr.consume_s += time.monotonic() - _m1
+            tr.exit()
 
         counters = self.sched.counters
         for j, slot in enumerate(live):
@@ -1621,6 +1674,8 @@ class PagedDecodeEngine:
             # of parking one above it forever
             self._draft_k_eff = max(1, min(
                 serve.draft_k, int(round(self._accept_ewma)) + 1))
+        if tr is not None:
+            tr.exit()
         return emitted
 
     # ---------------- request loop ----------------
@@ -1811,6 +1866,7 @@ class PagedDecodeEngine:
         ``_read_counters`` once the dispatch's tokens are."""
         from mpi_tensorflow_tpu.utils import dispatch_log
 
+        self.tracer.enter("serve.trace")
         extra = getattr(self.model, "dispatch_extra", None)
         rec = dispatch_log.record(
             time.perf_counter(), kind, rows, attended,
@@ -1818,6 +1874,7 @@ class PagedDecodeEngine:
         if self._counters is not None:
             self._snapshots.append(
                 (rec, self._sum_fn(self._counter_leaves())))
+        self.tracer.exit()
         return rec
 
     def _counter_leaves(self) -> list:
@@ -1837,6 +1894,7 @@ class PagedDecodeEngine:
             return
         from mpi_tensorflow_tpu.utils import dispatch_log
 
+        self.tracer.enter("serve.trace")
         tot = None
         while self._snapshots:
             rec, snap = self._snapshots.pop(0)
@@ -1849,8 +1907,8 @@ class PagedDecodeEngine:
                 break
         if tot is not None:
             dispatch_log.set_totals(tot[:, :-1].sum(axis=0).tolist())
-            if self.tracer is not None:
-                self.tracer.moe = self.moe_block(tot)
+            self.tracer.moe = self.moe_block(tot)
+        self.tracer.exit()
 
     def _counter_totals(self):
         """The counter leaves summed over layers, on the host (a sync
@@ -1902,9 +1960,10 @@ class PagedDecodeEngine:
             "lookahead_dispatches": self.lookahead_dispatches,
             "lookahead_discarded_rows": self.lookahead_discarded_rows,
             **self._assembly_counts(),
-            # the decode kernel's grid steps, the live blocks they were
-            # obliged to read and the blocks they copied
+            # the decode kernel's grid steps beside their bound, the live
+            # blocks they were obliged to read and the blocks they copied
             "paged_grid_steps": self.paged_grid_steps,
+            "paged_grid_bound": self.paged_grid_bound,
             "paged_live_blocks": self.paged_live_blocks,
             "paged_blocks_fetched": self.paged_blocks_fetched,
             # the caches by kind (static sizes; ``occupancy`` above is

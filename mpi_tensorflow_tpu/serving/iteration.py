@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import time
 from typing import Dict, List, Optional, Tuple
 
 _HEAP_SETTLED = False
@@ -133,17 +132,18 @@ class EngineLoop:
         work must not buy another dispatch's worth of pool time), one
         ``engine.step()``, then the emit/eviction accounting.  Returns
         the ``(request id, token)`` pairs delivered in this call."""
-        settle_heap()
         eng = self.engine
         tr = self.tracer
         if tr is not None:
-            step_t0 = now
-            tr.begin_step()
-            _m0 = time.monotonic()
+            tr.begin_step(now)
+            tr.enter("serve.sweep")
+        settle_heap()
         eng.sched.expire_deadlines(now)
         if tr is not None:
-            tr.sweep_s += time.monotonic() - _m0
+            tr.exit()
         emitted = eng.step()
+        if tr is not None:
+            tr.enter("serve.account")
         now = time_fn() - t0
         for rid, _tok in emitted:
             if rid in self.last_emit:
@@ -154,8 +154,10 @@ class EngineLoop:
         if tr is not None:
             # span stamping uses the SAME post-step ``now`` as the
             # latency clock above, so span TTFT == stamped TTFT exactly
+            tr.enter("serve.trace")
             tr.observe(eng.sched.occupied_view(),
                        {rid for rid, _tok in emitted}, now)
+            tr.exit()
         # AFTER the emit accounting: an eviction discards the request's
         # samples so far — including a token emitted this very step
         # (prefill-final then evicted by a later slot's ensure_block);
@@ -171,10 +173,14 @@ class EngineLoop:
                 tr.on_evict(rid, now)
         eng.sched.evicted_ids.clear()
         if tr is not None:
+            tr.exit()
             # terminals land AFTER first-token stamping (same ``now``),
             # so ``terminal >= first_token`` holds within every span
+            tr.enter("serve.trace")
             tr.flush_terminals(now)
-            tr.end_step(step_t0, now, len(emitted), eng.load_signals())
+            signals = eng.load_signals()
+            tr.exit()
+            tr.end_step(len(emitted), signals)
         return emitted
 
     def latencies(self) -> List[float]:

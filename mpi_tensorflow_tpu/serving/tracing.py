@@ -18,12 +18,17 @@ Three pieces, all host-side and allocation-light:
   (evicted mid-decode, re-queued, re-prefilled) still sums to exactly
   its wall time: ``queue_s + prefill_s + decode_s == terminal - arrive``.
 - **Step-phase timeline** (``TraceBuffer``): a bounded ring of
-  per-iteration records — phase durations (deadline sweep, dispatch
-  issue, host consume) plus the scheduler/pool gauges from
-  ``engine.load_signals()``.  Fixed capacity, drop-oldest, with an
-  explicit ``dropped`` counter — never unbounded.  The same records
-  feed ``ScaleAdvisor.observe_step`` so autoscale advice is
-  explainable from the trace.
+  per-iteration records — the self seconds of each engine phase
+  (``STEP_SPANS``), the programs built during the step, plus the
+  scheduler/pool gauges from ``engine.load_signals()``.  Fixed
+  capacity, drop-oldest, with an explicit ``dropped`` counter — never
+  unbounded.  The same records feed ``ScaleAdvisor.observe_step`` so
+  autoscale advice is explainable from the trace.  Each phase is ONE
+  measurement with two sinks: its self time in the step record, on
+  ``time.perf_counter`` (the serve loop's clock), and a
+  ``jax.profiler.TraceAnnotation`` of the same name, which a profiler
+  session places on the device trace's clock — so an idle gap of the
+  device can be put down to the phase the host was in.
 - **Exports**: ``merge_spans`` folds harvests across replicas and
   failover incarnations (phase accumulators SUM, so a migrated
   request's queue time accumulates rather than resetting at
@@ -32,13 +37,14 @@ Three pieces, all host-side and allocation-light:
   events, steps as duration events — loadable in Perfetto or
   chrome://tracing.
 
-Hot-path contract: stamping uses the serve loop's existing host clock
-values and ``time.monotonic`` deltas only — zero device syncs, zero
-allocations beyond small per-event tuples, and nothing here touches a
-jitted function, so the graft-lint HOST-SYNC pass stays clean with no
-annotations.  With tracing off the engine never constructs a tracer
-and every instrumentation site is a ``tracer is None`` skip: off is
-byte-for-byte the untraced behavior.
+Hot-path contract: request spans use the serve loop's existing clock
+values, phase spans ``time.perf_counter`` reads — zero device syncs,
+zero allocations beyond small per-event records, and nothing here
+touches a jitted function, so the graft-lint HOST-SYNC pass stays clean
+with no annotations.  With tracing off the engine never constructs a
+tracer and every instrumentation site is a ``tracer is None`` skip (no
+helper call, no context object, no clock read): off is byte-for-byte
+the untraced behavior.
 
 Ownership: an ``EngineTracer`` is single-owner like the scheduler —
 only the thread driving its engine may touch it.  The router archives
@@ -49,13 +55,57 @@ span state ever crosses the ``_GUARDED_BY`` lock.
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional, Tuple
 
 # Fixed ring capacity for step records.  Deliberately NOT a knob: the
 # buffer exists to bound tracing memory, and a configurable bound is a
-# bound someone sets to None.  At ~200 bytes/record this is ~1.6 MB.
+# bound someone sets to None.  At ~1 KB/record this is ~8 MB.
 STEP_CAPACITY = 8192
+
+#: The engine's phase spans (``EngineTracer.enter`` / ``exit``), each
+#: timed where its work happens; a parent's self time is its duration
+#: less its children's.  ``serve.step`` is one ``EngineLoop.iterate``
+#: and holds ``serve.sweep`` (heap settle, deadline sweep),
+#: ``serve.admit`` (admission, occupancy, partial copies),
+#: ``serve.prefill`` (one chunk's host work), ``serve.decode`` (a decode,
+#: verify or mixed dispatch's host work), ``serve.deliver`` (reading and
+#: delivering tokens) and ``serve.account`` (the loop's emit and
+#: eviction accounting).  ``serve.launch`` is ONE call into a compiled
+#: program, argument transfer and enqueue included, inside the phase
+#: that issues it; ``serve.wait`` the blocking token read, inside
+#: ``serve.deliver``; ``serve.trace`` the tracer's own bookkeeping
+#: (traced runs only), wherever it runs.
+STEP_SPANS = ("serve.step", "serve.sweep", "serve.admit", "serve.prefill",
+              "serve.decode", "serve.launch", "serve.deliver", "serve.wait",
+              "serve.account", "serve.trace")
+
+#: The ``jax.monitoring`` event every program built (or loaded from the
+#: persistent cache in its place) fires once.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# programs built in this process since the first tracer was made: ONE
+# process-wide listener (jax.monitoring cannot take one back), which a
+# step record reads at its start and end
+_compiles = 0
+_listening = False
+
+
+def _on_compile(name: str, secs: float, **_kw) -> None:
+    global _compiles
+    if name == COMPILE_EVENT:
+        _compiles += 1
+
+
+def _listen_for_compiles() -> None:
+    global _listening
+    if not _listening:
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_compile)
+        _listening = True
+
 
 # Per-span event cap — a span's event list is the only per-request
 # growth path (one entry per chunk/eviction/terminal), so bound it the
@@ -188,18 +238,30 @@ class EngineTracer:
     ``now`` — after first-token stamping, so ``terminal >=
     first_token`` always holds.
 
-    Step-phase durations (``sweep_s``/``dispatch_s``/``consume_s``)
-    are accumulated by the engine/loop via ``time.monotonic`` deltas
-    between ``begin_step`` and ``end_step``."""
+    Step phases are spans (``STEP_SPANS``) the engine and loop open and
+    close with ``enter`` / ``exit`` between ``begin_step`` and
+    ``end_step``; their self seconds land in the step record's
+    ``phases``."""
 
     def __init__(self, step_capacity: int = STEP_CAPACITY):
+        from jax import profiler
+
+        _listen_for_compiles()
         self.spans: Dict[int, Span] = {}
         self.buffer = TraceBuffer(step_capacity)
         self.pending_terminals: List[Tuple[int, str]] = []
         self.last_step: Optional[dict] = None
-        self.sweep_s = 0.0
-        self.dispatch_s = 0.0
-        self.consume_s = 0.0
+        self._annotation = profiler.TraceAnnotation
+        # open phase spans, outermost first: [name, perf_counter at
+        # entry, seconds of its closed children, its TraceAnnotation]
+        self._open: list = []
+        # where closed spans' self seconds go: the open step's record,
+        # or (a span outside any step, e.g. a run's final delivery) a
+        # sink nothing reads
+        self._outside = dict.fromkeys(STEP_SPANS, 0.0)
+        self.phases = self._outside
+        self._step_t0 = 0.0
+        self._step_compiles = 0
         self._last_now = 0.0
         self.moe: Optional[dict] = None  # routed-expert load as last
                                          # read (engine._read_counters)
@@ -299,25 +361,60 @@ class EngineTracer:
 
     # ---- step timeline -----------------------------------------------
 
-    def begin_step(self) -> None:
-        self.sweep_s = 0.0
-        self.dispatch_s = 0.0
-        self.consume_s = 0.0
+    def enter(self, name: str) -> None:
+        """Open the phase span ``name`` (one of ``STEP_SPANS``) inside
+        whichever is open."""
+        ann = self._annotation(name)
+        ann.__enter__()
+        self._open.append([name, time.perf_counter(), 0.0, ann])
 
-    def end_step(self, t0: float, now: float, emitted: int,
-                 signals: dict) -> None:
+    def exit(self) -> float:
+        """Close the innermost open span; its self seconds go to
+        ``phases``, its whole length to its parent's children.  Returns
+        that length."""
+        t = time.perf_counter()
+        name, t0, inner, ann = self._open.pop()
+        ann.__exit__(None, None, None)
+        dur = t - t0
+        self.phases[name] += dur - inner
+        if self._open:
+            self._open[-1][2] += dur
+        return dur
+
+    def begin_step(self, now: float) -> None:
+        """Open ``serve.step`` at the loop clock's ``now``.  Spans a
+        step that raised left open are dropped."""
+        self._open.clear()
+        self.phases = dict.fromkeys(STEP_SPANS, 0.0)
+        self._step_t0 = now
+        self._step_compiles = _compiles
+        self.enter("serve.step")
+
+    def end_step(self, emitted: int, signals: dict) -> None:
+        """Close ``serve.step`` and record it: ``t0`` / ``t1`` on the
+        loop clock (``t1 - t0`` is the span's length, the sum of
+        ``phases``), the programs built during it, the tokens emitted,
+        the engine's signals.  The record also goes to the process-wide
+        ``utils/dispatch_log`` under the step's ``time.perf_counter()``
+        start, where a benchmark's metric reader finds it after the
+        engine is gone."""
+        from mpi_tensorflow_tpu.utils import dispatch_log
+
+        at = self._open[0][1]
+        dur = self.exit()
         rec = {
-            "t0": t0,
-            "t1": now,
-            "sweep_s": self.sweep_s,
-            "dispatch_s": self.dispatch_s,
-            "consume_s": self.consume_s,
+            "t0": self._step_t0,
+            "t1": self._step_t0 + dur,
+            "phases": self.phases,
+            "compiles": _compiles - self._step_compiles,
             "emitted": int(emitted),
             "signals": signals,
         }
+        self.phases = self._outside
         self.buffer.append(rec)
         self.last_step = rec
-        self._last_now = max(self._last_now, now)
+        self._last_now = max(self._last_now, rec["t1"])
+        dispatch_log.record_step(at, rec)
 
     # ---- harvest ------------------------------------------------------
 
@@ -440,9 +537,9 @@ def write_chrome_trace(path: str, replicas: List[dict]) -> dict:
                            "pid": pid, "tid": 1, "ts": _us(rec["t0"]),
                            "dur": dur,
                            "args": {
-                               "sweep_us": _us(rec["sweep_s"]),
-                               "dispatch_us": _us(rec["dispatch_s"]),
-                               "consume_us": _us(rec["consume_s"]),
+                               **{f"{k}_us": _us(v)
+                                  for k, v in rec["phases"].items()},
+                               "compiles": rec["compiles"],
                                "emitted": rec["emitted"],
                                "signals": rec["signals"],
                            }})

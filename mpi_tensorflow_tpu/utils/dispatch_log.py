@@ -21,12 +21,22 @@ prefill lanes the cross-decoder was skipped on; models/cohere2_moe: the
 (query, key) pairs of a full and of a window layer, the keys each reads
 at least once, and the pairs the window spares).  ``totals`` is the
 running assignment count per held expert.
+
+Beside the dispatches, the traced engine's step records
+(``serving/tracing.EngineTracer.end_step``: the self seconds of each
+engine phase, programs built, the engine's signals), each as ``(its
+time.perf_counter() start, record)``, the newest ``STEP_CAPACITY`` kept.
 """
 
 from __future__ import annotations
 
+from collections import deque
+
+STEP_CAPACITY = 8192
+
 _DISPATCHES: list = []
 _TOTALS: list = []
+_STEPS: deque = deque(maxlen=STEP_CAPACITY)
 
 
 def record(at: float, kind: str, rows: int, attended: int,
@@ -43,6 +53,15 @@ def set_totals(per_expert) -> None:
     _TOTALS[:] = [int(n) for n in per_expert]
 
 
+def record_step(at: float, rec: dict) -> None:
+    _STEPS.append((at, rec))
+
+
+def steps() -> list:
+    """``[(start, step record)]``, oldest first."""
+    return list(_STEPS)
+
+
 def snapshot() -> dict:
     return {"dispatches": [list(r) for r in _DISPATCHES],
             "totals": list(_TOTALS)}
@@ -51,3 +70,4 @@ def snapshot() -> dict:
 def reset() -> None:
     _DISPATCHES.clear()
     _TOTALS.clear()
+    _STEPS.clear()

@@ -371,8 +371,9 @@ def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
     The last three keys read the step ring and say which side sets the
     pace: ``step_ms_p50`` is an iteration's length, ``device_wait_ms_p50``
     the part of it the host stood waiting for the device's tokens (the
-    step record's ``consume_s``: ~0 means the host is the slower side,
-    ~the step's length the device), and ``lookahead_share`` the share of
+    step record's ``phases["serve.wait"]``: ~0 means the host is the
+    slower side, ~the step's length the device), and
+    ``lookahead_share`` the share of
     model dispatches issued while an earlier one's tokens were unread
     (the newest record's running counts; ~1 on the plain path, 0 where
     every dispatch is read at once).  Keys are always exactly
@@ -428,7 +429,7 @@ def breakdown_block(trace, *, enabled=None, stamped_first_s=None) -> dict:
         "step_ms_p50": round(_percentile(
             [(r["t1"] - r["t0"]) * 1e3 for r in steps], 0.5), 3),
         "device_wait_ms_p50": round(_percentile(
-            [r["consume_s"] * 1e3 for r in steps], 0.5), 3),
+            [r["phases"]["serve.wait"] * 1e3 for r in steps], 0.5), 3),
         "lookahead_share": round(
             newest.get("lookahead_dispatches", 0)
             / max(1, newest.get("forward_dispatches", 0)), 4),

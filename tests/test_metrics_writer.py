@@ -215,8 +215,10 @@ class TestBreakdownPace:
         length, the host's wait for the device inside it, and the
         newest record's lookahead count over its dispatch count."""
         def step(t0, t1, wait, ahead, total, discarded=0):
-            return {"t0": t0, "t1": t1, "sweep_s": 0.0, "dispatch_s": 1e-3,
-                    "consume_s": wait, "emitted": 1,
+            return {"t0": t0, "t1": t1,
+                    "phases": {"serve.step": t1 - t0 - wait - 1e-3,
+                               "serve.launch": 1e-3, "serve.wait": wait},
+                    "compiles": 0, "emitted": 1,
                     "signals": {"queue_depth": 0, "occupancy": 0.5,
                                 "forward_dispatches": total,
                                 "lookahead_dispatches": ahead,

@@ -15,6 +15,7 @@ survivor (the ISSUE 17 bugfix).
 
 import dataclasses
 import json
+import time
 
 import numpy as np
 import pytest
@@ -179,13 +180,16 @@ class TestTraceBuffer:
         assert tb["steps"] > 0 and tb["steps_dropped"] == 0
         rec = tb["replicas"][0]["steps"][-1]
         assert rec["t1"] >= rec["t0"]
-        assert rec["dispatch_s"] >= 0 and rec["consume_s"] >= 0
+        assert tuple(rec["phases"]) == tracing.STEP_SPANS
+        assert all(v >= 0 for v in rec["phases"].values())
+        assert rec["compiles"] >= 0
         assert set(rec["signals"]) >= {"queue_depth", "occupancy"}
 
     def test_step_record_says_how_far_the_host_looked_ahead(self):
         """Every step record carries the lookahead's running counts, and
-        ``consume_s`` (the host waiting for the device's tokens) beside
-        the step's length: together they say which side set the pace.
+        ``phases["serve.wait"]`` (the host waiting for the device's
+        tokens) beside the step's length: together they say which side
+        set the pace.
         The counts in the newest record are the run's."""
         eng = _engine("on")
         rng = np.random.default_rng(23)
@@ -196,7 +200,8 @@ class TestTraceBuffer:
             assert 0 <= sig["lookahead_dispatches"] \
                 <= sig["forward_dispatches"]
             assert sig["lookahead_discarded_rows"] >= 0
-            assert rec["consume_s"] <= rec["t1"] - rec["t0"] + 1e-6
+            assert rec["phases"]["serve.wait"] \
+                <= rec["t1"] - rec["t0"] + 1e-6
         counts = [r["signals"]["lookahead_dispatches"] for r in steps]
         assert counts == sorted(counts) and counts[-1] > 0
         assert counts[-1] == res["lookahead_dispatches"]
@@ -207,6 +212,183 @@ class TestTraceBuffer:
             res["lookahead_dispatches"] / res["forward_dispatches"],
             abs=1e-3)
         assert 0 <= bd["device_wait_ms_p50"] <= bd["step_ms_p50"]
+
+
+# ------------------------------------------------------ phase spans
+
+# each span's parent as tracing.STEP_SPANS' comment gives it; None = no
+# span open (a run's last delivery comes after its last step)
+NEST = {
+    "serve.step": {None},
+    "serve.sweep": {"serve.step"},
+    "serve.admit": {"serve.step"},
+    "serve.prefill": {"serve.step"},
+    "serve.decode": {"serve.step"},
+    "serve.launch": {"serve.admit", "serve.prefill", "serve.decode"},
+    "serve.deliver": {"serve.step", None},
+    "serve.wait": {"serve.deliver"},
+    "serve.account": {"serve.step"},
+    "serve.trace": {"serve.step", "serve.account", "serve.prefill",
+                    "serve.decode", "serve.deliver"},
+}
+
+# the engine's compiled programs on the serving path
+COMPILED = ("_prefill_fn", "_decode_fn", "_take_fn", "_keep_fn", "_cow_fn",
+            "_partial_fn", "_verify_fn", "_mixed_fn")
+
+# one run of each dispatch path: plain, the prefix cache (a fully cached
+# prompt of whole blocks recomputes its shared last block: copy-on-write),
+# speculative verify, the fused mixed step
+PATHS = {
+    "plain": (dict(), False),
+    "prefix": (dict(prefix_cache="on", max_slots=1), True),
+    "verify": (dict(speculative="ngram"), False),
+    "mixed": (dict(mixed_batch="on"), False),
+}
+
+
+def _innermost(tr):
+    return tr._open[-1][0] if tr._open else None
+
+
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def watched(request):
+    """A traced run with every span's parent and, for every compiled
+    call, the innermost span open at the call."""
+    kw, repeat = PATHS[request.param]
+    eng = _engine("on", **kw)
+    tr = eng.tracer
+    opened, calls = [], []
+    enter = tr.enter
+
+    def watch_enter(name):
+        opened.append((name, _innermost(tr)))
+        enter(name)
+    tr.enter = watch_enter
+    for attr in COMPILED:
+        def call(*a, _fn=getattr(eng, attr)):
+            calls.append(_innermost(tr))
+            return _fn(*a)
+        setattr(eng, attr, call)
+    rng = np.random.default_rng(29)
+    reqs = _reqs(rng, 6)
+    if repeat:
+        prompt = list(map(int, rng.integers(0, TINY.vocab_size, 8)))
+        reqs = [Request(i, list(prompt), 4) for i in range(3)]
+    res = eng.run(reqs)
+    return request.param, res, opened, calls
+
+
+class TestStepSpans:
+    def test_spans_nest_as_documented(self, watched):
+        path, _res, opened, _calls = watched
+        assert {name for name, _ in opened} <= set(tracing.STEP_SPANS)
+        bad = {(n, p) for n, p in opened if p not in NEST[n]}
+        assert not bad, f"{path}: spans opened inside the wrong parent"
+        assert {"serve.step", "serve.sweep", "serve.admit",
+                "serve.launch", "serve.deliver", "serve.wait",
+                "serve.account", "serve.trace"} \
+            <= {name for name, _ in opened}
+
+    def test_one_launch_span_per_compiled_call(self, watched):
+        path, _res, opened, calls = watched
+        assert calls, path
+        # every compiled call runs inside a launch span of its own
+        assert set(calls) == {"serve.launch"}, path
+        assert sum(n == "serve.launch" for n, _ in opened) == len(calls)
+        if path == "prefix":
+            assert _res["prefix"]["cow_copies"] > 0
+
+    def test_phase_self_times_partition_the_step(self, watched):
+        path, res, _opened, _calls = watched
+        steps = res["trace"]["replicas"][0]["steps"]
+        assert steps
+        for rec in steps:
+            assert tuple(rec["phases"]) == tracing.STEP_SPANS
+            assert min(rec["phases"].values()) >= -1e-12, path
+            total = sum(rec["phases"].values())
+            assert total <= rec["t1"] - rec["t0"] + 1e-9
+            assert total == pytest.approx(rec["t1"] - rec["t0"], abs=1e-9)
+
+    def test_wait_holds_the_blocking_read(self):
+        """The one blocking token read is ``serve.wait``: a device that
+        takes 20 ms to hand a dispatch's tokens over shows there, and
+        not in the delivery around it."""
+        eng = _engine("on")
+        tr = eng.tracer
+        reads = []
+
+        class SlowTokens:
+            def __init__(self, a):
+                self.a = a
+
+            def __array__(self, dtype=None, copy=None):
+                reads.append((_innermost(tr),
+                              bool(tr._open) and tr._open[0][0]
+                              == "serve.step"))
+                time.sleep(0.02)
+                return np.asarray(self.a, dtype)
+
+        deliver = eng._deliver
+
+        def slow_deliver(n):
+            eng._unread[:n] = [(SlowTokens(nxt), rows, rec)
+                               for nxt, rows, rec in eng._unread[:n]]
+            return deliver(n)
+        eng._deliver = slow_deliver
+        res = eng.run(_reqs(np.random.default_rng(31), 4))
+        assert reads and {span for span, _ in reads} == {"serve.wait"}
+        in_steps = sum(inside for _, inside in reads)
+        steps = res["trace"]["replicas"][0]["steps"]
+        wait = sum(r["phases"]["serve.wait"] for r in steps)
+        deliver_self = sum(r["phases"]["serve.deliver"] for r in steps)
+        assert wait >= 0.02 * in_steps
+        assert deliver_self < 0.01 * in_steps
+
+    def test_step_records_count_the_programs_built(self):
+        """A step that builds a program says so; once every shape the
+        requests reach is built, a second pass builds none."""
+        eng = _engine("on")
+        reqs = _reqs(np.random.default_rng(37), 4)
+        eng.run([dataclasses.replace(r) for r in reqs])
+        first = len(eng.tracer.buffer)
+        eng.run([dataclasses.replace(r) for r in reqs])
+        steps = eng.tracer.buffer.records()
+        assert sum(r["compiles"] for r in steps[:first]) > 0
+        assert sum(r["compiles"] for r in steps[first:]) == 0
+
+    def test_step_records_reach_the_dispatch_log(self):
+        """Traced, every step record is also in the process-wide
+        ``utils/dispatch_log`` under its perf_counter start, where a
+        benchmark's metric reader finds it once the engine is gone;
+        untraced, nothing is written there."""
+        from mpi_tensorflow_tpu.utils import dispatch_log
+
+        reqs = _reqs(np.random.default_rng(41), 4)
+        dispatch_log.reset()
+        _engine("off").run([dataclasses.replace(r) for r in reqs])
+        assert dispatch_log.steps() == []
+        eng = _engine("on")
+        t0 = time.perf_counter()
+        eng.run([dataclasses.replace(r) for r in reqs])
+        logged = dispatch_log.steps()
+        dispatch_log.reset()
+        assert [rec for _, rec in logged] == eng.tracer.buffer.records()
+        starts = [at for at, _ in logged]
+        assert starts == sorted(starts) and starts[0] >= t0
+
+    def test_tracing_adds_no_device_read(self):
+        """The spans read host clocks only: the HOST-SYNC pass's marks
+        in the serving loop are the engine's four reads of a device
+        value and its one cold-block demotion, none in the loop or the
+        tracer."""
+        from pathlib import Path
+
+        root = Path(tracing.__file__).parent
+        marks = {name: (root / name).read_text().count("sync-ok")
+                 for name in ("engine.py", "iteration.py", "tracing.py")}
+        assert marks == {"engine.py": 5, "iteration.py": 0,
+                         "tracing.py": 0}
 
 
 # --------------------------------------------------- chrome export
@@ -244,6 +426,9 @@ class TestChromeExport:
         steps = [e for e in events if e["ph"] == "X"]
         assert steps and all(e["tid"] == 1 and e["dur"] >= 1
                              for e in steps)
+        # ... whose args are the step's phases, in microseconds
+        assert all({f"{n}_us" for n in tracing.STEP_SPANS} | {"compiles"}
+                   <= set(e["args"]) for e in steps)
 
 
 # ------------------------------------------------------ breakdown
