@@ -2,14 +2,16 @@
 window put down to the INNERMOST host span that covers it (the covering
 span that started latest, ties to the shorter), with the engine's phase
 spans (``serving/tracing.STEP_SPANS``) read beside the harness's, and each
-host span's self time an engine step.  Run a serving cell with
+host span's self time an iteration.  Run a serving cell with
 ``--trace 1 --keep-trace`` first.
 
     python3 benchmarks/engine_idle.py --workload <cell> [--out <file.json>]
 
 The result line's ``breakdown`` names each idle gap by the harness span
 of largest coverage instead, which over nested spans is always the
-outermost (``serve_iterate``).
+outermost (``serve_iterate``).  ``innermost`` is the rule meant to replace
+that one inside ``harness/trace_reduce.reduce``; once it does, this file
+goes, so that one rule names idle time.
 """
 
 from __future__ import annotations
@@ -50,7 +52,8 @@ def innermost(host, gaps) -> dict:
 
 def describe(raw: dict, window_span: str = "window") -> dict:
     """Busy and idle time of device 0 in the window, the idle by
-    innermost span, and each host span's self ms an engine step."""
+    innermost span, and each host span's self ms an iteration
+    (``serve_iterate``)."""
     from benchmarks.harness import trace_reduce as tr
 
     win = [(s, s + d) for n, s, d in raw["host"] if n == window_span]
@@ -65,7 +68,7 @@ def describe(raw: dict, window_span: str = "window") -> dict:
     steps = 0
     for n, _s, _e, self_ns, _leaf in tr.self_times(host):
         own[n] = own.get(n, 0.0) + self_ns
-        steps += n == "serve.step"
+        steps += n == "serve_iterate"
     return {"window_s": (hi - lo) / 1e9, "busy_s": tr.total(busy) / 1e9,
             "idle_s": tr.total(gaps) / 1e9,
             "idle_innermost_s": innermost(host, gaps), "steps": steps,

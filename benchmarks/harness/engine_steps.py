@@ -2,9 +2,9 @@
 
 A traced ``PagedDecodeEngine`` writes one record an ``EngineLoop.iterate``
 to the program's ``utils/dispatch_log`` (``steps()``: ``(time.perf_counter()
-at the step's start, record)``).  A record's ``t0`` / ``t1`` bound the step
-and its ``phases`` hold the self seconds of each engine phase span
-(``serving/tracing.STEP_SPANS``): ``serve.launch`` the calls into compiled
+at the step's start, record)``).  A record's ``phases`` hold the self
+seconds of each engine phase span (``serving/tracing.STEP_SPANS``), which
+sum to the step's length: ``serve.launch`` the calls into compiled
 programs, ``serve.wait`` the blocking token read, ``serve.trace`` the
 tracer's own bookkeeping, and so on.
 """

@@ -12,7 +12,9 @@ READERS = ("serve_host_ms", "serve_launch_ms")
 
 
 def _step(t0, length, launch, wait, trace):
-    return {"t0": t0, "t1": t0 + length, "compiles": 0, "emitted": 1,
+    # t1 is the loop's post-step clock read, which comes before the step's
+    # accounting and bookkeeping end: the span's length is its phases' sum
+    return {"t0": t0, "t1": t0 + length / 2, "compiles": 0, "emitted": 1,
             "signals": {},
             "phases": {"serve.step": length - launch - wait - trace,
                        "serve.launch": launch, "serve.wait": wait,

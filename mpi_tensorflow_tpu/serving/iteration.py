@@ -180,7 +180,7 @@ class EngineLoop:
             tr.flush_terminals(now)
             signals = eng.load_signals()
             tr.exit()
-            tr.end_step(len(emitted), signals)
+            tr.end_step(now, len(emitted), signals)
         return emitted
 
     def latencies(self) -> List[float]:

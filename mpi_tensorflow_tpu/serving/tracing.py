@@ -390,21 +390,21 @@ class EngineTracer:
         self._step_compiles = _compiles
         self.enter("serve.step")
 
-    def end_step(self, emitted: int, signals: dict) -> None:
-        """Close ``serve.step`` and record it: ``t0`` / ``t1`` on the
-        loop clock (``t1 - t0`` is the span's length, the sum of
-        ``phases``), the programs built during it, the tokens emitted,
-        the engine's signals.  The record also goes to the process-wide
-        ``utils/dispatch_log`` under the step's ``time.perf_counter()``
-        start, where a benchmark's metric reader finds it after the
-        engine is gone."""
+    def end_step(self, now: float, emitted: int, signals: dict) -> None:
+        """Close ``serve.step`` and record it: ``t0`` and ``t1`` (the
+        post-step ``now``) on the loop clock, ``phases`` (which sum to
+        the span's length on ``time.perf_counter``), the programs built
+        during it, the tokens emitted, the engine's signals.  The record
+        also goes to the process-wide ``utils/dispatch_log`` under the
+        step's ``time.perf_counter()`` start, where a benchmark's metric
+        reader finds it after the engine is gone."""
         from mpi_tensorflow_tpu.utils import dispatch_log
 
         at = self._open[0][1]
-        dur = self.exit()
+        self.exit()
         rec = {
             "t0": self._step_t0,
-            "t1": self._step_t0 + dur,
+            "t1": now,
             "phases": self.phases,
             "compiles": _compiles - self._step_compiles,
             "emitted": int(emitted),

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 from collections import deque
 
-STEP_CAPACITY = 8192
+from mpi_tensorflow_tpu.serving.tracing import STEP_CAPACITY
 
 _DISPATCHES: list = []
 _TOTALS: list = []
